@@ -18,8 +18,10 @@ same ``i32_index_scope`` its launches use), finds every ``pallas_call``
 against a frozen :class:`KernelBudget`:
 
 - **VMEM working set** — per grid step, the sum of every VMEM-space
-  block's bytes (×2 for grid-varying blocks: Mosaic double-buffers the
-  pipeline; ×1 for grid-invariant blocks) plus scratch, against the
+  block's bytes at the PADDED footprint (last two dims rounded up to the
+  dtype's (sublanes, 128) tile; ×2 for grid-varying blocks: Mosaic
+  double-buffers the pipeline; ×1 for grid-invariant blocks) plus
+  scratch, likewise padded, against the
   per-generation VMEM cap (:data:`VMEM_CAPS`). ``ANY``/HBM-space operands
   (manually DMA'd pools) and semaphores don't occupy the budget.
 - **Tiling lint** — block shapes against the (sublane, lane) minimums per
@@ -52,9 +54,8 @@ against a frozen :class:`KernelBudget`:
   reuse a block), and arithmetic intensity, banked to
   ``profiles/kernelcheck.json`` and diffed against the composite path's
   hlocheck cost roll-up (``hlocheck.audit`` flops + materialized bytes),
-  so every kernel carries a predicted-speedup record the future on-chip
-  A/B (``tools/flash_autotune.py`` idiom, BENCH_TPU_HISTORY.jsonl) can
-  confirm or refute. Re-running against the bank fails loudly on drift
+  so every kernel carries a predicted-speedup record an on-chip A/B
+  (``tools/flash_autotune.py`` idiom) can confirm or refute. Re-running against the bank fails loudly on drift
   in any analytic field; the composite-measured side is re-measured and
   reported, never hard-pinned (XLA cost models move across versions).
 
@@ -67,9 +68,10 @@ entry the way ``hlocheck.run_step`` audits one step.
 (``FLAGS_use_pallas_kernels``, the unified ``ragged_kernel_eligible``
 rules, flash ``flash_route`` incl. the causal pad-to-block rescue) and
 reports which serving configs reach a Pallas kernel vs the composite —
-PR 11's "int8 decode has no fast kernel" / "head_dim 64 is kernel-less"
-findings flipped to covered when the ragged kernel landed, and the
-report keeps them that way.
+PR 11's "int8 decode has no fast kernel" finding flipped to covered when
+the ragged kernel landed; "head_dim 64 is kernel-less" is open again,
+because the v5e compiler refuses that page DMA (the gate's reason quotes
+it) — a static check on CPU could never see that.
 
 CLI: ``python -m paddle_tpu.analysis kernelcheck [--kernel NAME] [--bank]
 [--json PATH]`` (also ``tools/kernelcheck.py``), exit 0 clean / 1 on any
@@ -222,7 +224,7 @@ from .hlocheck import _fmt_bytes  # noqa: E402 — one formatter, two auditors
 def _find_pallas_eqns(jaxpr, out=None) -> list:
     """Every ``pallas_call`` eqn in a jaxpr, recursing through sub-jaxprs
     (custom_vjp/pjit/scan/cond params carry Jaxpr/ClosedJaxpr values)."""
-    import jax
+    from jax.extend import core as jex_core
 
     out = [] if out is None else out
     for eqn in jaxpr.eqns:
@@ -231,9 +233,9 @@ def _find_pallas_eqns(jaxpr, out=None) -> list:
         for v in eqn.params.values():
             vals = v if isinstance(v, (list, tuple)) else [v]
             for x in vals:
-                if isinstance(x, jax.core.ClosedJaxpr):
+                if isinstance(x, jex_core.ClosedJaxpr):
                     _find_pallas_eqns(x.jaxpr, out)
-                elif isinstance(x, jax.core.Jaxpr):
+                elif isinstance(x, jex_core.Jaxpr):
                     _find_pallas_eqns(x, out)
     return out
 
@@ -246,33 +248,51 @@ def _memory_space(aval) -> str:
 
 
 def _int_block_dims(block_shape) -> list:
-    """(axis, size) for the integer dims of a block shape — ``Mapped`` /
-    squeezed dims don't exist in the VMEM tile."""
-    return [(ax, d) for ax, d in enumerate(block_shape)
-            if isinstance(d, int)]
+    """(axis, size) for the sized dims of a block shape (``Blocked`` /
+    ``Element`` carry a ``block_size``) — ``Squeezed`` dims don't exist in
+    the VMEM tile."""
+    return [(ax, int(d.block_size)) for ax, d in enumerate(block_shape)
+            if hasattr(d, "block_size")]
+
+
+def _block_text(block_shape) -> tuple:
+    """Block dims as strings — the size, or ``None`` for a squeezed dim."""
+    return tuple(str(getattr(d, "block_size", None)) for d in block_shape)
 
 
 def _block_nbytes(bm) -> int:
+    """Logical bytes of one block — what a fetch moves over HBM."""
     import numpy as np
 
-    n = int(np.dtype(bm.array_shape_dtype.dtype).itemsize)
+    n = int(np.dtype(bm.array_aval.dtype).itemsize)
     for _, d in _int_block_dims(bm.block_shape):
         n *= d
     return n
 
 
+def _vmem_nbytes(dims, dtype) -> int:
+    """Bytes a buffer of these dims occupies in VMEM, at the padded tile
+    footprint — the model the kernels' own gates use
+    (``kernels._common.vmem_nbytes``)."""
+    import numpy as np
+
+    from ..kernels._common import vmem_nbytes
+
+    return vmem_nbytes(dims, int(np.dtype(dtype).itemsize))
+
+
 def _index_map_info(bm, n_grid: int):
     """(data_dependent, constant): does the index map read scalar-prefetch
     operands / is it invariant over the grid (all-literal outputs)?"""
-    import jax
+    from jax.extend import core as jex_core
 
     jx = bm.index_map_jaxpr.jaxpr
     used = set()
     for eqn in jx.eqns:
         for v in eqn.invars:
-            if isinstance(v, jax.core.Var):
+            if isinstance(v, jex_core.Var):
                 used.add(v)
-    outs = {v for v in jx.outvars if isinstance(v, jax.core.Var)}
+    outs = {v for v in jx.outvars if isinstance(v, jex_core.Var)}
     scalar_refs = jx.invars[n_grid:]
     data_dependent = any(v in used or v in outs for v in scalar_refs)
     constant = not any(v in used or v in outs for v in jx.invars[:n_grid])
@@ -340,10 +360,8 @@ def _certify_call(eqn, budget: KernelBudget, name: str,
 
     gm = eqn.params["grid_mapping"]
     grid = tuple(gm.grid)
-    cp = eqn.params.get("compiler_params") or {}
-    if not isinstance(cp, dict):
-        cp = getattr(cp, "__dict__", {}) or {}
-    semantics = tuple((cp.get("mosaic") or {}).get("dimension_semantics")
+    cp = (eqn.params.get("compiler_params") or {}).get("mosaic_tpu")
+    semantics = tuple(getattr(cp, "dimension_semantics", None)
                       or ("arbitrary",) * len(grid))
     findings: list[KernelFinding] = []
     blocks = []
@@ -357,11 +375,11 @@ def _certify_call(eqn, budget: KernelBudget, name: str,
     hbm = 0
     in_out = ["in"] * gm.num_inputs + ["out"] * gm.num_outputs
     for kind, bm in zip(in_out, gm.block_mappings):
-        arr = bm.array_shape_dtype
+        arr = bm.array_aval
         dt = np.dtype(arr.dtype)
         space = _memory_space(bm.block_aval)
         nbytes = _block_nbytes(bm)
-        blocks.append((kind, tuple(str(d) for d in bm.block_shape),
+        blocks.append((kind, _block_text(bm.block_shape),
                        tuple(arr.shape), str(dt)))
         data_dep, constant = _index_map_info(bm, len(grid))
 
@@ -374,7 +392,7 @@ def _certify_call(eqn, budget: KernelBudget, name: str,
                 if d < ad and ad % d:
                     findings.append(KernelFinding(
                         "tiling", "error",
-                        f"{name} {kind} block {bm.block_shape} over array "
+                        f"{name} {kind} block {_block_text(bm.block_shape)} over array "
                         f"{tuple(arr.shape)}: axis {ax} dim {ad} is not "
                         f"divisible by block dim {d} — the grid truncates "
                         f"and the partial trailing block is silently "
@@ -384,7 +402,7 @@ def _certify_call(eqn, budget: KernelBudget, name: str,
                 if lane_d % LANE and lane_d < int(arr.shape[lane_ax]):
                     findings.append(KernelFinding(
                         "tiling", "error",
-                        f"{name} {kind} block {bm.block_shape} ({dt}): "
+                        f"{name} {kind} block {_block_text(bm.block_shape)} ({dt}): "
                         f"minor dim {lane_d} is neither a {LANE}-lane "
                         f"multiple nor the whole array axis "
                         f"({arr.shape[lane_ax]}) — Mosaic cannot lay out "
@@ -395,7 +413,7 @@ def _certify_call(eqn, budget: KernelBudget, name: str,
                 if sub_d % min_sub and sub_d < int(arr.shape[sub_ax]):
                     findings.append(KernelFinding(
                         "tiling", "warn",
-                        f"{name} {kind} block {bm.block_shape} ({dt}): "
+                        f"{name} {kind} block {_block_text(bm.block_shape)} ({dt}): "
                         f"sublane dim {sub_d} is below/off the "
                         f"({min_sub}, {LANE}) minimum tile for {dt} — "
                         f"Mosaic pads the tile (wasteful, not wrong)"))
@@ -407,7 +425,9 @@ def _certify_call(eqn, budget: KernelBudget, name: str,
             continue
         if "semaphore" in space:
             continue
-        vmem += nbytes * (1 if constant else 2)
+        vmem += _vmem_nbytes(
+            [d for _, d in _int_block_dims(bm.block_shape)],
+            dt) * (1 if constant else 2)
         # HBM traffic: one fetch per index-map transition in row-major
         # order (consecutive equal indices reuse the resident block)
         if constant:
@@ -435,10 +455,10 @@ def _certify_call(eqn, budget: KernelBudget, name: str,
         shape = getattr(getattr(aval, "inner_aval", aval), "shape", ())
         dtype = getattr(getattr(aval, "inner_aval", aval), "dtype", None)
         try:
-            itemsize = np.dtype(dtype).itemsize
-        except Exception:  # noqa: BLE001 — exotic ref dtypes don't budget
+            np.dtype(dtype)
+        except TypeError:  # exotic ref dtypes don't budget
             continue
-        vmem += int(np.prod(shape)) * itemsize if shape else itemsize
+        vmem += _vmem_nbytes(shape, dtype)
 
     cap = budget.vmem_cap
     if vmem > cap:
@@ -587,7 +607,7 @@ def certify(fn, args, *, name: str | None = None,
             "trace", "error",
             f"{name}: kernel entry point failed to trace "
             f"({type(e).__name__}: {str(e)[:300]}) — every launch would "
-            f"silently take the composite fallback"))
+            f"raise"))
         return KernelCertReport(name=name, findings=tuple(findings))
     eqns = _find_pallas_eqns(jaxpr.jaxpr)
     if not eqns:
@@ -876,16 +896,10 @@ def _build_ragged(mode: str):
     ok, why = rp.ragged_kernel_eligible(d, pps, ps, s, num_heads=h,
                                         quantized=quant,
                                         pipeline_chunk=chunk)
-    ok64, why64 = rp.ragged_kernel_eligible(64, pps, ps, s, num_heads=h,
-                                            quantized=quant)
     constraints = (
         ("ragged_kernel_eligible", ok, why or
          "the canonical shape must pass every unified-kernel gate "
          "(incl. the x2 staged buffers at the certified chunk)"),
-        # the two kernelcheck coverage gaps this kernel exists to close,
-        # certified so they can never silently reopen
-        ("head_dim_64_eligible", ok64, why64 or
-         "head_dim 64 must stay covered by the unified kernel"),
     )
 
     if quant:

@@ -34,7 +34,7 @@ def _dev(device=None):
 def memory_stats(device=None) -> dict:
     """Raw per-device allocator stats (PJRT): bytes_in_use, peak_bytes_in_use,
     bytes_limit, num_allocs, ... Empty dict when the backend doesn't report
-    (e.g. over a remote tunnel)."""
+    (the CPU backend does not)."""
     stats = _dev(device).memory_stats()
     return dict(stats) if stats else {}
 

@@ -10,6 +10,7 @@ fleet `ElasticManager` and broadcast via the same flags.
 """
 from __future__ import annotations
 
+import glob
 import os
 import time
 
@@ -17,6 +18,15 @@ from ..fleet.elastic import ELASTIC_EXIT_CODE, ElasticManager, ElasticStatus
 from .context import Context
 from .master import KVMaster
 from .pod import Container, Pod, script_entrypoint
+
+
+def _host_has_tpu() -> bool:
+    """Whether this host has TPU chips, read from their device nodes
+    (``/dev/accel*``, or numbered ``/dev/vfio`` groups on newer
+    generations) — the launcher must not initialise a JAX backend to find
+    out: a parent that has touched JAX holds the chip, and the workers it
+    forks then fail or hang."""
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
 
 
 class CollectiveController:
@@ -107,9 +117,18 @@ class CollectiveController:
                 env["PADDLE_DEVICES"] = ",".join(mine)
                 env["TPU_VISIBLE_DEVICES"] = ",".join(mine)
             elif args.nproc_per_node > 1:
-                # Multiple trainer procs on one host can't share the TPU
-                # runtime (libtpu is single-process) — this mode is for
-                # CPU-simulation runs, so pin the procs to the CPU backend.
+                # Several trainer procs on one host can't share the TPU
+                # runtime (a chip belongs to one process) — without
+                # --devices this mode is a CPU simulation, and on a host
+                # that has a TPU that must be asked for, not assumed
+                if _host_has_tpu() and os.environ.get(
+                        "JAX_PLATFORMS", "").strip().lower() != "cpu":
+                    raise ValueError(
+                        f"nproc_per_node={args.nproc_per_node} without "
+                        f"--devices on a host that has a TPU would pin "
+                        f"every worker to the CPU. Pass --devices to "
+                        f"partition the chips across the workers, or set "
+                        f"JAX_PLATFORMS=cpu to ask for a CPU simulation.")
                 env["JAX_PLATFORMS"] = "cpu"
             log = os.path.join(args.log_dir, f"workerlog.{grank}")
             self.pod.add(Container(entry, env, log))

@@ -263,17 +263,8 @@ def gather_sequence(x, axis_name, seq_dim=1):
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    from jax.sharding import PartitionSpec  # noqa: F401
-    try:
-        from jax import shard_map as _sm  # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except TypeError:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _default_loss_weight(labels):

@@ -47,57 +47,61 @@ def sdpa_reference(q, k, v, mask=None, is_causal=False, scale=None):
     return jnp.einsum("...qk,...kd->...qd", probs.astype(q.dtype), v)
 
 
-_flash_fallback_logged: set[tuple] = set()
 _edge_logged: set[tuple] = set()
 
 
-def _log_flash_fallback(q, k, e: Exception) -> None:
-    # log once per (shape, error) — a silent fallback to the O(S^2)
-    # composite path invisibly costs HBM and MFU (VERDICT r3 weak #3)
-    sig = (q.shape, k.shape, type(e).__name__)
-    if sig not in _flash_fallback_logged:
-        _flash_fallback_logged.add(sig)
-        import sys
+def _flash(q, k, v, causal, scale):
+    """The flash kernel — per shard under a training mesh. GSPMD cannot
+    partition a Mosaic kernel ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"), so while a
+    multi-device mesh is active (``hybrid_train.mesh_scope``) the call
+    runs inside a shard_map: batch over the data axes, heads over ``mp``
+    — where the column-parallel qkv projection already leaves them, so
+    no collective is added."""
+    from jax.sharding import PartitionSpec as P
 
-        print(f"[paddle_tpu] pallas flash attention failed for "
-              f"q{tuple(q.shape)} k{tuple(k.shape)} "
-              f"({type(e).__name__}: {str(e)[:300]}); falling back to "
-              f"composite O(S^2) attention", file=sys.stderr, flush=True)
+    from ..distributed.fleet.hybrid_train import active_mesh
+    from . import flash_attention as fa
+
+    call = functools.partial(fa.flash_attention, causal=causal, scale=scale)
+    mesh = active_mesh()
+    if mesh is None or mesh.size == 1:
+        return call(q, k, v)
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    batch = tuple(a for a in ("dp", "sharding") if sizes.get(a, 1) > 1)
+    spec = P(batch or None, "mp" if sizes.get("mp", 1) > 1 else None,
+             None, None)
+    return jax.shard_map(call, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def sdpa(q, k, v, mask=None, is_causal=False, scale=None):
     if mask is None and _pallas_wanted():
-        try:
-            from . import flash_attention as fa
-        except ImportError:  # pallas ops moved/absent in this jax build
-            fa = None
-        route = (fa.flash_route(q.shape, k.shape, bool(is_causal))
-                 if fa is not None else "")
-        if route:
-            try:
-                if route == "pad":
-                    # the seq-%512 edge (e.g. 640): causal self-attention
-                    # padded to the next block multiple — padded keys sit
-                    # strictly above the causal diagonal for every real
-                    # query, so the sliced-back rows are exact; counted
-                    # on the pre-seeded gauge where the dispatch Python
-                    # runs (once per traced program under jit — the
-                    # pallas_fallback_total growth-signal contract)
-                    from ..utils import monitor
+        from . import flash_attention as fa
 
-                    monitor.stat_add("serving_flash_pad_total", 1)
-                    s = q.shape[-2]
-                    pad = fa.pad_seq_to_block(s) - s
-                    widths = [(0, 0)] * (q.ndim - 2) + [(0, pad), (0, 0)]
-                    out = fa.flash_attention(
-                        jnp.pad(q, widths), jnp.pad(k, widths),
-                        jnp.pad(v, widths), causal=True, scale=scale)
-                    return out[..., :s, :]
-                return fa.flash_attention(q, k, v, causal=is_causal,
-                                          scale=scale)
-            except Exception as e:  # noqa: BLE001 — fall back on any pallas failure
-                _log_flash_fallback(q, k, e)
-        elif fa is not None and fa.edge_missed(q.shape, k.shape):
+        # a kernel the route called eligible that fails to trace or lower
+        # RAISES: serving the O(S^2) composite in its place would pass
+        # every test while the kernel never ran
+        route = fa.flash_route(q.shape, k.shape, bool(is_causal))
+        if route == "pad":
+            # the seq-%512 edge (e.g. 640): causal self-attention
+            # padded to the next block multiple — padded keys sit
+            # strictly above the causal diagonal for every real
+            # query, so the sliced-back rows are exact; counted
+            # on the pre-seeded gauge where the dispatch Python
+            # runs (once per traced program under jit)
+            from ..utils import monitor
+
+            monitor.stat_add("serving_flash_pad_total", 1)
+            s = q.shape[-2]
+            pad = fa.pad_seq_to_block(s) - s
+            widths = [(0, 0)] * (q.ndim - 2) + [(0, pad), (0, 0)]
+            out = _flash(jnp.pad(q, widths), jnp.pad(k, widths),
+                         jnp.pad(v, widths), True, scale)
+            return out[..., :s, :]
+        if route:
+            return _flash(q, k, v, is_causal, scale)
+        if fa.edge_missed(q.shape, k.shape):
             # flash-shaped, TPU, flag on — yet no kernel route: the
             # loudly-counted fallback (the coverage report's remaining
             # flash edge), never a silent one

@@ -258,12 +258,5 @@ def flash_attention(q, k, v, causal=False, scale=None):
     """q,k,v: [batch, heads, seq, head_dim]."""
     sm_scale = float(scale) if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if _want_splash(causal, q.shape[2], k.shape[2]):
-        try:
-            return _splash(q, k, v, sm_scale).astype(q.dtype)
-        except Exception as e:  # pragma: no cover — fall back to dense-block flash
-            import sys
-
-            print(f"[paddle_tpu] splash attention unavailable "
-                  f"({type(e).__name__}: {e}); using dense-block flash",
-                  file=sys.stderr, flush=True)
+        return _splash(q, k, v, sm_scale).astype(q.dtype)
     return _flash(q, k, v, bool(causal), sm_scale).astype(q.dtype)

@@ -152,7 +152,7 @@ def maybe_fused_layer_norm(x, gamma, beta, eps):
     that is lane-tileable, enough rows to amortize the launch. Returns None
     for the XLA path."""
     from ..utils.flags import flag
-    from ._common import log_once, on_tpu_backend
+    from ._common import on_tpu_backend
 
     if not flag("FLAGS_use_fused_layernorm", True) or not on_tpu_backend():
         return None
@@ -165,10 +165,4 @@ def maybe_fused_layer_norm(x, gamma, beta, eps):
     if gamma is None or beta is None or gamma.shape != (d,) \
             or beta.shape != (d,) or beta.dtype != gamma.dtype:
         return None
-    try:
-        return fused_layer_norm(x, gamma, beta, float(eps))
-    except Exception as e:  # noqa: BLE001 — log once, XLA fallback
-        log_once("fused_layernorm",
-                 f"[paddle_tpu] fused layer_norm pallas kernel failed "
-                 f"({type(e).__name__}: {str(e)[:200]}); using XLA path")
-        return None
+    return fused_layer_norm(x, gamma, beta, float(eps))

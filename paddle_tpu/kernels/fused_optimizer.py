@@ -113,17 +113,9 @@ def maybe_fused_adam(p, g, m, v, lr, bc1, bc2, *, beta1, beta2, eps):
         # padding would copy all four inputs — the exact HBM traffic the
         # kernel exists to avoid; non-tileable sizes take the XLA path
         return None
-    try:
-        return fused_adam_update(p, g, m, v,
-                                 jnp.asarray(lr, jnp.float32),
-                                 jnp.asarray(bc1, jnp.float32),
-                                 jnp.asarray(bc2, jnp.float32),
-                                 beta1=float(beta1), beta2=float(beta2),
-                                 eps=float(eps))
-    except Exception as e:  # noqa: BLE001 — log once, fall back to XLA path
-        from ._common import log_once
-
-        log_once("fused_adam",
-                 f"[paddle_tpu] fused adam pallas kernel failed "
-                 f"({type(e).__name__}: {str(e)[:200]}); using XLA path")
-        return None
+    return fused_adam_update(p, g, m, v,
+                             jnp.asarray(lr, jnp.float32),
+                             jnp.asarray(bc1, jnp.float32),
+                             jnp.asarray(bc2, jnp.float32),
+                             beta1=float(beta1), beta2=float(beta2),
+                             eps=float(eps))

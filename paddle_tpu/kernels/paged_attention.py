@@ -161,10 +161,10 @@ def decode_kernel_eligible(head_dim: int, pages_per_seq: int,
 
     Returns ``(eligible, reason)`` — ``reason`` names the FIRST gate that
     blocks the kernel (empty when eligible). The old library-decode
-    gates — the int8 ban, ``head_dim % 128``, the page-table-width
-    alignment — are GONE: the unified kernel fuses the int8 dequant into
-    its gather and covers whole minor axes, which is exactly how the
-    kernelcheck int8-decode and head_dim-64 findings flipped to covered.
+    gates on int8 and on the page-table width are GONE: the unified
+    kernel fuses the int8 dequant into its gather. ``head_dim % 128``
+    stays a gate of the compiled kernel (the chip's compiler refuses the
+    page DMA otherwise; the reason string quotes it).
     ``num_query_tokens`` generalizes the predicate to the prefill/chunk
     (pad bucket) and spec-verify (``depth + 1``) call shapes."""
     from ..utils.flags import flag
@@ -192,46 +192,13 @@ def _use_ragged_kernel(q, k_pool, page_table,
         num_heads=q.shape[1], quantized=quantized,
         on_tpu=on_tpu_backend(),
         flags_on=bool(flag("FLAGS_use_pallas_kernels", True)),
-        interpret=interp)
+        interpret=interp, q_itemsize=q.dtype.itemsize)
     return ok, interp
 
 
 def _pages_per_block(page_size: int) -> int:
     """Pages per flash block: ~512 KV slots per block, at least one page."""
     return max(1, 512 // page_size)
-
-
-_pallas_fallback_logged: set[tuple] = set()
-
-#: engine-installed fallback observer ``(exc_class_name, signature) -> None``
-#: — lets the serving engine stamp a ``pallas_fallback`` trace event on the
-#: requests whose step just silently degraded to the composite path. The
-#: kernel layer itself only counts the gauge (works engine-less too).
-fallback_hook = None
-
-
-def _note_fallback(e: Exception, q, k_pool) -> None:
-    """A Pallas decode dispatch failed and the composite path is about to
-    serve instead: count the pre-seeded ``serving_pallas_fallback_total``
-    gauge, hand the exception class + dispatch signature to the installed
-    hook (trace events), and keep one stderr line per distinct signature —
-    a silent fallback costs MFU invisibly (VERDICT r3 weak #3), and before
-    this gauge the only record was a one-shot print nobody monitors."""
-    from ..utils import monitor
-
-    sig = f"q{tuple(q.shape)} pool{tuple(k_pool.shape)}"
-    monitor.stat_add("serving_pallas_fallback_total", 1)
-    hook = fallback_hook
-    if hook is not None:
-        hook(type(e).__name__, sig)
-    key = (sig, type(e).__name__)
-    if key not in _pallas_fallback_logged:
-        _pallas_fallback_logged.add(key)
-        import sys
-
-        print(f"[paddle_tpu] pallas paged attention failed for {sig} "
-              f"({type(e).__name__}: {str(e)[:300]}); falling back to "
-              f"gather + composite attention", file=sys.stderr, flush=True)
 
 
 def _pallas_decode(q, k_pool, v_pool, page_table, ctx_lens, scale):
@@ -280,11 +247,10 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
     probability, so the fixed gather width never leaks padding. Returns
     [batch, heads, s, head_dim].
 
-    ``s`` is the num_query_tokens of the call: 1 for plain decode (the
-    Pallas kernel's case), the pad bucket for prefill, and ``depth + 1``
-    for the speculative-decoding verify step — a whole-batch ragged
-    multi-token decode through this same contract (the s > 1 decode-style
-    call always takes the composite gather + masked-sdpa path).
+    ``s`` is the num_query_tokens of the call: 1 for plain decode, the
+    pad bucket for prefill, and ``depth + 1`` for the
+    speculative-decoding verify step — a whole-batch ragged multi-token
+    decode through this same contract.
 
     ``k_scale``/``v_scale`` (both or neither): the pools are int8 codes
     under per-page-per-head scales — the unified kernel fuses the
@@ -296,10 +262,11 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
     spec-verify, fp32 AND int8 — routes through the ONE unified ragged
     kernel (:mod:`.ragged_paged_attention`) when
     ``ragged_kernel_eligible`` holds; anything else (flag off, CPU
-    without ``FLAGS_ragged_interpret``, a context too large for the VMEM
-    gate) takes the composite gather + masked-sdpa path, and a kernel
-    that RAISES falls back loudly (``serving_pallas_fallback_total`` +
-    the engine trace-event hook).
+    without ``FLAGS_ragged_interpret``, a head_dim the chip's compiler
+    refuses, a page too large for the VMEM gate) takes the composite
+    gather + masked-sdpa path — the gate's reason string says which. A
+    kernel the gate called eligible that fails to trace or lower RAISES:
+    nothing here turns a kernel failure into a composite result.
     """
     s = q.shape[2]
     quantized = k_scale is not None
@@ -308,12 +275,9 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
     if use_kernel:
         from . import ragged_paged_attention as _rp
 
-        try:
-            return _rp.ragged_paged_attention(
-                q, k_pool, v_pool, page_table, ctx_lens, scale=scale,
-                k_scale=k_scale, v_scale=v_scale, interpret=interpret)
-        except Exception as e:  # noqa: BLE001 — fall back on any pallas failure
-            _note_fallback(e, q, k_pool)
+        return _rp.ragged_paged_attention(
+            q, k_pool, v_pool, page_table, ctx_lens, scale=scale,
+            k_scale=k_scale, v_scale=v_scale, interpret=interpret)
     from .attention import sdpa
 
     if quantized:

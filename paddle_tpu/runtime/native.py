@@ -1,66 +1,75 @@
 """Builds & loads the native C++ runtime library (csrc/) via ctypes.
 
 No pybind11 in this environment — the C ABI + ctypes is the binding layer.
-The build is lazy and cached in ~/.cache/paddle_tpu; failures leave `lib = None`
-and every consumer falls back to pure Python.
+The build is lazy and kept in ``<checkout>/.native_build`` (or
+``$PADDLE_TPU_CACHE``) under a name keyed by a hash of ``csrc/*.cc``, so
+what loads is always built from the sources beside it — an older build
+that merely exists is never reused. Failures leave `lib = None` and every
+consumer falls back to pure Python.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import pathlib
 import subprocess
 import tempfile
 
-_CSRC = pathlib.Path(__file__).resolve().parent.parent.parent / "csrc"
+_ROOT = pathlib.Path(__file__).resolve().parent.parent.parent
+_CSRC = _ROOT / "csrc"
 _CACHE = pathlib.Path(
-    os.environ.get("PADDLE_TPU_CACHE", os.path.expanduser("~/.cache/paddle_tpu"))
-)
-_SO = _CACHE / "libpaddle_tpu_runtime.so"
+    os.environ.get("PADDLE_TPU_CACHE") or _ROOT / ".native_build")
 
 lib = None
 
 
+def _sources() -> list[pathlib.Path]:
+    return sorted(_CSRC.glob("*.cc"))
+
+
+def _so_path() -> pathlib.Path:
+    h = hashlib.sha256()
+    for src in _sources():
+        h.update(src.name.encode())
+        h.update(src.read_bytes())
+    return _CACHE / f"libpaddle_tpu_runtime-{h.hexdigest()[:16]}.so"
+
+
 def build(force=False):
-    global lib
-    if _SO.exists() and not force:
-        return _load()
-    sources = sorted(str(p) for p in _CSRC.glob("*.cc"))
+    sources = _sources()
     if not sources:
         return None
+    so = _so_path()
+    if so.exists() and not force:
+        return _load(so)
     _CACHE.mkdir(parents=True, exist_ok=True)
+    # build beside the target and rename: a concurrent process never
+    # loads a half-written library
+    fd, tmp = tempfile.mkstemp(suffix=".so", dir=_CACHE)
+    os.close(fd)
     cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-pthread",
-           "-o", str(_SO), *sources]
+           "-o", tmp, *map(str, sources)]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except Exception:
+        os.replace(tmp, so)
+    except (OSError, subprocess.SubprocessError):
+        if os.path.exists(tmp):
+            os.unlink(tmp)
         return None
-    return _load()
+    return _load(so)
 
 
-_rebuilt_once = False
-
-
-def _load():
-    global lib, _rebuilt_once
+def _load(so: pathlib.Path):
+    global lib
     try:
-        l = ctypes.CDLL(str(_SO))
-        _declare(l)
-        lib = l
-        return lib
-    except (OSError, AttributeError):
-        # AttributeError: cached .so predates newly added csrc symbols —
-        # rebuild once (a bounded retry; a persistent mismatch means the
-        # sources themselves are stale and rebuilding again can't help)
+        loaded = ctypes.CDLL(str(so))
+    except OSError:
         lib = None
-        if _SO.exists() and not _rebuilt_once:
-            _rebuilt_once = True
-            try:
-                _SO.unlink()
-            except OSError:
-                return None
-            return build()
         return None
+    _declare(loaded)
+    lib = loaded
+    return lib
 
 
 def _declare(l):
@@ -141,5 +150,5 @@ def _declare(l):
 
 
 # attempt load of an existing build at import (no compile at import time)
-if _SO.exists():
-    _load()
+if _sources() and _so_path().exists():
+    _load(_so_path())

@@ -542,29 +542,17 @@ class ServingEngine:
             self._spec = None
             self._hist = None
             self._draft = self._draft_p = None
-        # pallas-fallback surfacing: the kernel layer counts the
-        # pre-seeded serving_pallas_fallback_total gauge itself; this
-        # hook additionally stamps a `pallas_fallback` trace event (exc
-        # class + dispatch signature) on every request running in the
-        # step whose dispatch just degraded. Module-level: the kernel
-        # can't know the engine — last-constructed engine owns the hook,
-        # through a weakref so a dropped engine (and its KV pools) is
-        # collectable instead of pinned forever by the module global.
-        import weakref
-
         from ..kernels import paged_attention as _pa
         from ..kernels._common import on_tpu_backend
         from ..utils.flags import flag
 
         # whether the unified ragged kernel is even dispatchable for this
         # engine's shapes — the single decode_kernel_eligible predicate
-        # (now the ragged_kernel_eligible gate), read once per mode;
-        # per-step the kernel A/B additionally checks the fallback
-        # counter so a trace-time degrade flips the measured dispatch
-        # times onto the composite leg. The A/B gauge legs key on the
-        # kernelcheck certificate the dispatch actually exercises:
-        # ragged_paged (fp32 decode) / ragged_paged_q8 (int8 decode),
-        # plus ragged_paged_verify for the spec K+1 dispatch.
+        # (now the ragged_kernel_eligible gate), read once per mode. The
+        # A/B gauge legs key on the kernelcheck certificate the dispatch
+        # actually exercises: ragged_paged (fp32 decode) /
+        # ragged_paged_q8 (int8 decode), plus ragged_paged_verify for the
+        # spec K+1 dispatch.
         _gate_kw = dict(
             num_heads=mc.num_heads, quantized=self.cache.cfg.quantized,
             on_tpu=on_tpu_backend(),
@@ -592,14 +580,6 @@ class ServingEngine:
             self._verify_pallas_eligible = False
             self._verify_ab_name = None
 
-        _self = weakref.ref(self)
-
-        def _fallback_hook(exc_name, signature, _ref=_self):
-            eng = _ref()
-            if eng is not None:
-                eng._on_pallas_fallback(exc_name, signature)
-
-        _pa.fallback_hook = _fallback_hook
         self._fault_injector = fault_injector
         if fault_injector is not None and self.cache.host_tier is not None:
             # the restore_fail fault point: consulted by the cache right
@@ -850,22 +830,6 @@ class ServingEngine:
         """Engine time: the pluggable clock plus any slow_step fault skew —
         the time base for deadlines and run() budgets."""
         return self._clock() + self._skew
-
-    def _on_pallas_fallback(self, exc_name: str, signature: str) -> None:
-        """kernels/paged_attention fallback hook: the Pallas decode
-        dispatch raised at trace time and the composite path is serving
-        instead. The kernel layer already counted the pre-seeded
-        ``serving_pallas_fallback_total`` gauge; here every request
-        active in the degraded step gets a ``pallas_fallback`` trace
-        event (a Chrome-trace instant) carrying the exception class and
-        dispatch signature — the machine-readable record of which
-        traffic lost its fast kernel."""
-        tr = self._tracer
-        if tr is None:
-            return
-        for slot in np.flatnonzero(self._active):
-            tr.event(int(self._rids[slot]), "pallas_fallback",
-                     exc=exc_name, signature=signature)
 
     def add_request(self, prompt, max_new_tokens: int,
                     deadline_s: float | None = None,
@@ -1569,14 +1533,11 @@ class ServingEngine:
                 # (where the device time lands) + per-slot bookkeeping.
                 # The same interval feeds the roofline tracker and — for
                 # the kernel-eligible decode dispatch — the predicted-vs-
-                # measured kernel A/B, on whichever leg actually served
-                # (Pallas, unless ineligible or a fallback was counted).
+                # measured kernel A/B, on the leg the gate chose.
                 dt = att.mark("decode")
                 self._roofline.on_call("decode", dt)
-                pallas = self._decode_pallas_eligible and monitor.stat_get(
-                    "serving_pallas_fallback_total", 0) == 0
                 self._roofline.on_kernel_call(self._kernel_ab_name, dt,
-                                              pallas)
+                                              self._decode_pallas_eligible)
 
         cs = self.cache.stats()
         self.metrics.on_state(
@@ -1687,15 +1648,12 @@ class ServingEngine:
             # verify phase: the batched K+1 dispatch + packed fetch +
             # accept bookkeeping, roofline-tracked under its audit label
             # AND — the K+1 contract being unified-kernel-eligible — fed
-            # to the ragged_paged_verify A/B leg, same fallback check as
-            # the decode leg
+            # to the ragged_paged_verify A/B leg
             dt = self._attr.mark("verify")
             self._roofline.on_call("verify", dt)
             if self._verify_ab_name is not None:
-                pallas = self._verify_pallas_eligible and monitor.stat_get(
-                    "serving_pallas_fallback_total", 0) == 0
                 self._roofline.on_kernel_call(self._verify_ab_name, dt,
-                                              pallas)
+                                              self._verify_pallas_eligible)
         return n_slots, n_accepted
 
     def run(self, max_steps: int = 100000,

@@ -391,20 +391,29 @@ def init_pools(cfg: PagedCacheConfig) -> list[dict]:
     """Per-layer {k_pool, v_pool} device arrays, zero-filled; quantized
     pools add the zero-initialized {k_scale, v_scale} leaves (a zero scale
     marks an all-zero page — the write path substitutes 1.0 before any
-    division)."""
+    division). Under tensor parallelism every leaf is CREATED under its
+    heads-axis sharding — each device allocates only its own
+    [num_pages, page_size, heads/tp, head_dim] shard; a pool sized for
+    the mesh never exists whole on one device (it would not fit)."""
     import jax.numpy as jnp
 
+    pool_sh, scale_sh = (cfg.tp.pool_shardings() if cfg.tp is not None
+                         else (None, None))
     shape = (cfg.num_pages, cfg.page_size, cfg.num_heads, cfg.head_dim)
-    if cfg.quantized:
-        sshape = (cfg.num_pages, cfg.num_heads)
-        return [{"k_pool": jnp.zeros(shape, jnp.int8),
-                 "v_pool": jnp.zeros(shape, jnp.int8),
-                 "k_scale": jnp.zeros(sshape, jnp.float32),
-                 "v_scale": jnp.zeros(sshape, jnp.float32)}
-                for _ in range(cfg.num_layers)]
-    dt = cfg.dtype or jnp.float32
-    return [{"k_pool": jnp.zeros(shape, dt), "v_pool": jnp.zeros(shape, dt)}
-            for _ in range(cfg.num_layers)]
+    dt = jnp.int8 if cfg.quantized else (cfg.dtype or jnp.float32)
+
+    def layer():
+        leaf = {"k_pool": jnp.zeros(shape, dt, device=pool_sh),
+                "v_pool": jnp.zeros(shape, dt, device=pool_sh)}
+        if cfg.quantized:
+            sshape = (cfg.num_pages, cfg.num_heads)
+            leaf |= {"k_scale": jnp.zeros(sshape, jnp.float32,
+                                          device=scale_sh),
+                     "v_scale": jnp.zeros(sshape, jnp.float32,
+                                          device=scale_sh)}
+        return leaf
+
+    return [layer() for _ in range(cfg.num_layers)]
 
 
 class PagedKVCache:
@@ -425,14 +434,11 @@ class PagedKVCache:
                 "enable_prefix_caching=True (nothing would ever spill)")
         self.cfg = cfg
         self.allocator = PageAllocator(cfg.num_pages)
+        # under tensor parallelism the pools' heads axis is sharded across
+        # the mesh; the page ids in the (host-side) table stay logical, so
+        # every allocator/prefix-cache/COW decision below is
+        # sharding-agnostic
         self.pools = init_pools(cfg)
-        if cfg.tp is not None:
-            # tensor parallelism shards the pools' heads axis across the
-            # mesh: each device owns [num_pages, page_size, heads/tp,
-            # head_dim] per layer — the page ids in the (host-side) table
-            # stay logical, so every allocator/prefix-cache/COW decision
-            # below is sharding-agnostic
-            self.pools = cfg.tp.shard_pools(self.pools)
         self.page_table = np.full((cfg.max_batch, cfg.pages_per_seq),
                                   NULL_PAGE, np.int32)
         self._slot_pages: dict[int, list[int]] = {}

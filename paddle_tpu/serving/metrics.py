@@ -75,23 +75,20 @@ Chunked prefill + SLO admission (pre-seeded like everything else):
 
 Kernel-dispatch counters (pre-seeded):
 
-- serving_pallas_fallback_total  Pallas kernel dispatches that raised and
-                                 silently degraded to the composite path
-                                 (incremented by kernels/paged_attention
-                                 at the fallback site; each also stamps a
-                                 ``pallas_fallback`` trace event on the
-                                 running requests via the engine hook).
-                                 0 is the certified steady state — any
-                                 growth means the serving hot path lost
-                                 its fast kernel.
+- serving_pallas_fallback_total  always 0: nothing increments it since
+                                 the kernel dispatch stopped catching a
+                                 failing Pallas kernel (an eligible
+                                 kernel that fails to trace or lower now
+                                 raises). The name and its watchdog rule
+                                 stay only because scrape goldens carry
+                                 them (ROADMAP D9 removes both).
 - serving_flash_pad_total        flash dispatch SITES that took the
                                  causal pad-to-block route (the seq %512
                                  edge, e.g. 640 -> 1024): exact results,
                                  visible pad presence. Counted where the
                                  dispatch Python runs — once per traced
-                                 program under jit, per call when eager —
-                                 the serving_pallas_fallback_total
-                                 growth-signal contract, NOT a
+                                 program under jit, per call when eager:
+                                 a growth signal, NOT a
                                  per-inference-dispatch count
 - serving_flash_edge_fallback_total  flash-shaped dispatch sites (seqs
                                  >= 128, 64-aligned head_dim, TPU, flag

@@ -39,7 +39,7 @@ each program's first trace — the same hlocheck audit single-chip steps
 pass at budget ZERO.
 
 The wrappers run the UNCHANGED engine step bodies inside ``shard_map``
-(params/pools sharded, everything else replicated, ``check_rep=False`` —
+(params/pools sharded, everything else replicated, ``check_vma=False`` —
 the outputs are replicated by construction: every device computes the
 same post-psum values). The engine's CompileGuards wrap the sharded
 callables exactly as they wrap the single-chip ones, so ``compile_counts``
@@ -208,17 +208,11 @@ class TPContext:
             leaf |= {"k_scale": P(*_SCALE_AXES), "v_scale": P(*_SCALE_AXES)}
         return [dict(leaf) for _ in range(num_layers)]
 
-    def shard_pools(self, pools: list) -> list:
-        """Shard the freshly initialized per-layer pools on the heads axis
-        (codes and, quantized, their per-page scale leaves)."""
-        import jax
-
-        pool_sh = self._sharding(*_POOL_AXES)
-        scale_sh = self._sharding(*_SCALE_AXES)
-        return [{k: jax.device_put(v, scale_sh if k.endswith("_scale")
-                                   else pool_sh)
-                 for k, v in pl.items()}
-                for pl in pools]
+    def pool_shardings(self):
+        """(pool sharding, scale sharding): the paged pools shard their
+        heads axis, and quantized, their per-page scale leaves the same
+        axis. ``kv_cache.init_pools`` creates every leaf under these."""
+        return self._sharding(*_POOL_AXES), self._sharding(*_SCALE_AXES)
 
     # -------------------------------------------------------- step wrappers
     def _shard_map(self, fn, in_specs, out_specs):
@@ -227,10 +221,11 @@ class TPContext:
         # in the hlocheck registry (tp2_engine_prefill/_prefill_chunk/
         # _decode + the per-shard cache movers) and certified under
         # debug_checks — exactly what lint rule PT010 exists to enforce
-        from jax.experimental.shard_map import shard_map  # lint: disable=PT010
+        import jax
 
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(  # lint: disable=PT010
+            fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False)
 
     def wrap_step(self, fn, num_layers: int, n_rest: int,
                   quantized: bool = False):

@@ -21,9 +21,10 @@ def get_include():
 
 
 def get_lib():
-    """Directory containing libpaddle_tpu_runtime.so (sysconfig.py:38).
+    """Directory containing the runtime library (sysconfig.py:38).
 
-    The runtime builds lazily into ~/.cache/paddle_tpu (runtime/native.py);
+    The runtime builds lazily into <checkout>/.native_build under a name
+    keyed by a hash of its sources (runtime/native.py);
     calling this triggers the build so the returned dir actually holds the
     library, matching the reference's contract that get_lib() is linkable.
     """

@@ -7,6 +7,9 @@ import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 os.environ["JAX_PLATFORMS"] = "cpu"
+# tests leave the persistent compilation cache off, here and in every
+# process they start (paddle_tpu/_compile_cache.py turns it on otherwise)
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import jax
 

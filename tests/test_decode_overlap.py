@@ -348,22 +348,27 @@ def test_vmem_model_prices_double_buffered_staging():
     assert single == pinned
     chunked = rp._vmem_working_set(d, total_kv, nq, bh, pps, False,
                                    pipeline_chunk=8)
-    per_page_kv = (total_kv // pps)
-    # staging shrinks 32 pages -> 2 x 8 pages per pool (K and V, fp32)
-    expected_delta = 2 * (total_kv - 2 * 8 * per_page_kv) * bh * d * 4
-    assert single - chunked == expected_delta
+    chunk_kv = 8 * (total_kv // pps)
+    # staging shrinks 32 pages -> 2 x 8 pages per pool (K and V, fp32) at
+    # the padded footprint: the one-head block pads to an 8-sublane tile
+    staging = 2 * (total_kv - 2 * chunk_kv) * 8 * d * 4
+    # the body's live values shrink with the chunk too: the staged K and
+    # V transposed heads-major, and the logits + probabilities (the one
+    # query token pads to 8 sublanes)
+    live = 2 * bh * (total_kv - chunk_kv) * d * 4 \
+        + 2 * bh * 8 * (total_kv - chunk_kv) * 4
+    assert single - chunked == staging + live
 
 
 # ------------------------------------------------------- quantized psum
 def test_quantized_psum_parity_and_safety():
     if len(jax.devices()) < 4:
         pytest.skip("needs the conftest 8-device CPU mesh")
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("tp",))
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda xs: quantized_psum(xs[0], "tp"), mesh=mesh,
         in_specs=(P("tp", None, None),), out_specs=P()))
 

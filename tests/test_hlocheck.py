@@ -127,7 +127,9 @@ def test_tp8_over_budget_raises_naming_the_op(tp8_report):
     msg = str(ei.value)
     assert "all-reduce" in msg and "budget of 0" in msg
     assert "128 B" in msg            # the payload volume
-    assert "%all-reduce" in msg      # the offending HLO instruction
+    # the offending HLO instruction, by its name (the installed XLA names
+    # instructions after the JAX op: `%psum.7 = f32[..] all-reduce(`)
+    assert f"%{tp8_report.collectives[0].instr} = " in msg
 
 
 def test_tp8_byte_cap_raises(tp8_report):

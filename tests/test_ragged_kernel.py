@@ -12,7 +12,7 @@
   sync-free certification unchanged, zero fallbacks; kernel A/B gauges
   seeded from the bank; ineligible (CPU, flag off) stays composite with
   the fallback gauge at zero
-- the flash seq-%512 pad-or-fallback satellite (kernels/attention.py)
+- the flash seq-%512 pad route and edge counter (kernels/attention.py)
 """
 import json
 
@@ -128,11 +128,19 @@ def test_scale_override_matches_composite():
 def test_ragged_kernel_eligible_gates():
     ok, why = rp.ragged_kernel_eligible(128, 32, 16, 1, num_heads=8)
     assert ok and why == ""
-    # int8, head_dim 64, unaligned widths, multi-token: all served
+    # int8, unaligned widths, multi-token: all served
     for kw in (dict(quantized=True), dict(num_query_tokens=5),
                dict(num_query_tokens=64)):
-        ok, why = rp.ragged_kernel_eligible(64, 30, 16, num_heads=8, **kw)
+        ok, why = rp.ragged_kernel_eligible(128, 30, 16, num_heads=8, **kw)
         assert ok, (kw, why)
+    # head_dim 64: the chip's compiler refuses the page DMA — the gate
+    # says so in its words; the interpreter (the CPU test path) has no
+    # such rule
+    ok, why = rp.ragged_kernel_eligible(64, 30, 16, num_heads=8)
+    assert not ok and "aligned to tiling (128), but is 64" in why
+    ok, why = rp.ragged_kernel_eligible(64, 30, 16, num_heads=8,
+                                        on_tpu=False, interpret=True)
+    assert ok, why
     ok, why = rp.ragged_kernel_eligible(128, 32, 16, flags_on=False)
     assert not ok and "FLAGS_use_pallas_kernels" in why
     ok, why = rp.ragged_kernel_eligible(128, 32, 16, on_tpu=False)
@@ -140,8 +148,13 @@ def test_ragged_kernel_eligible_gates():
     ok, why = rp.ragged_kernel_eligible(128, 32, 16, on_tpu=False,
                                         interpret=True)
     assert ok  # the interpreter sanctions the CPU backend
-    ok, why = rp.ragged_kernel_eligible(128, 4096, 512)
+    # the default chunk shrinks until the working set fits, so only a
+    # page too large to stage one at a time trips the VMEM gate
+    ok, why = rp.ragged_kernel_eligible(128, 64, 4096)
     assert not ok and "VMEM" in why
+    assert rp.pipeline_chunk_for(16, 16, 128, 64, block_heads=8) == 32
+    assert rp.pipeline_chunk_for(16, 16, 128, 64, block_heads=8,
+                                 num_query_tokens=512) == 16
 
 
 def test_validate_ragged_tuned():
@@ -180,7 +193,11 @@ def test_ragged_tuned_load_rejects_bad_entry(tmp_path, monkeypatch):
     good.write_text(json.dumps({"4,2,8": 2}))
     monkeypatch.setattr(rp, "_TUNED_PATH", str(good))
     assert rp.block_heads_for(4, 2, 8) == 2
-    assert rp.block_heads_for(16, 8, 128) == 1  # untuned default
+    # untuned default: one sublane tile of the pool dtype, or every head
+    assert rp.block_heads_for(16, 8, 128) == 8
+    assert rp.block_heads_for(16, 16, 128) == 8
+    assert rp.block_heads_for(16, 16, 128, pool_itemsize=1) == 16
+    assert rp.block_heads_for(16, 4, 128) == 4
     monkeypatch.setattr(rp, "_TUNED", None)
 
 
@@ -306,21 +323,20 @@ def test_flash_route_and_pad_edge():
                           causal=True) == ""
 
 
-def test_sdpa_pad_route_counts_gauge_and_is_exact(monkeypatch):
+def test_sdpa_pad_route_counts_gauge_and_a_failing_kernel_raises(monkeypatch):
     """Force the TPU gates on CPU: the 640 causal dispatch takes the pad
-    route (counted on serving_flash_pad_total), the padded flash raises
-    on the CPU backend, and the logged fallback serves the exact
-    composite — no silent fast-path loss anywhere on the way."""
+    route (counted on serving_flash_pad_total) and the padded flash,
+    which cannot lower on the CPU backend, RAISES — the composite is not
+    served in a routed kernel's place."""
     from paddle_tpu.kernels import attention as at
 
     monkeypatch.setattr(at, "_on_tpu", lambda: True)
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(1, 2, 640, 64), jnp.float32)
     before_pad = monitor.stat_get("serving_flash_pad_total", 0)
-    out = at.sdpa(q, q, q, is_causal=True)
+    with pytest.raises(ValueError, match="interpret mode"):
+        at.sdpa(q, q, q, is_causal=True)
     assert monitor.stat_get("serving_flash_pad_total", 0) == before_pad + 1
-    ref = at.sdpa_reference(q, q, q, is_causal=True)
-    assert np.allclose(np.asarray(out), np.asarray(ref))
     # non-causal 640: no route — the loudly-counted composite fallback
     before_edge = monitor.stat_get("serving_flash_edge_fallback_total", 0)
     at.sdpa(q, q, q, is_causal=False)

@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from paddle_tpu.distributed.sequence_parallel import (
     ring_attention,
@@ -46,7 +45,7 @@ def test_ring_attention_forward(causal):
     q, k, v = _qkv()
     mesh = _mesh()
     spec = P(None, None, "sp", None)
-    f = shard_map(
+    f = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, "sp", causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )
@@ -62,7 +61,7 @@ def test_ring_attention_grads(causal):
     spec = P(None, None, "sp", None)
 
     def loss_ring(q, k, v):
-        f = shard_map(
+        f = jax.shard_map(
             lambda q, k, v: ring_attention(q, k, v, "sp", causal),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         )
@@ -84,7 +83,7 @@ def test_ulysses_attention(causal):
     q, k, v = _qkv(2)
     mesh = _mesh()
     spec = P(None, None, "sp", None)
-    f = shard_map(
+    f = jax.shard_map(
         lambda q, k, v: ulysses_attention(q, k, v, "sp", causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )
@@ -102,12 +101,8 @@ def test_split_gather_sequence():
         assert loc.shape == (2, S // SP, 4)
         return gather_sequence(loc, "sp", seq_dim=1)
 
-    try:
-        f = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
                       check_vma=False)
-    except TypeError:
-        f = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                      check_rep=False)
     out = jax.jit(f)(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x))
 
@@ -116,7 +111,7 @@ def test_ring_attention_bf16():
     q, k, v = (t.astype(jnp.bfloat16) for t in _qkv(3))
     mesh = _mesh()
     spec = P(None, None, "sp", None)
-    f = shard_map(
+    f = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, "sp", True),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )

@@ -25,6 +25,7 @@ debug-audited engine feeds three tests) and single-bucket configs are
 used wherever a second pad bucket adds no coverage.
 """
 import itertools
+import re
 
 import jax
 import numpy as np
@@ -234,7 +235,8 @@ def test_zero_budget_variant_raises_naming_the_collective(model):
         eng.run()
     msg = str(ei.value)
     assert "all-reduce" in msg and "budget of 0" in msg
-    assert "%all-reduce" in msg  # the HLO instruction is named
+    # the HLO instruction line is quoted: `%<name> = <type> all-reduce(`
+    assert re.search(r"%[\w.\-]+ = \S+ all-reduce\(", msg)
 
 
 def test_report_reenforcement_against_zero_budget_raises(debug_engine):

@@ -1,0 +1,198 @@
+"""Ahead-of-time compiles for the chip, without the chip.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+device that is described, not attached (``jax.experimental.topologies``).
+Interpret mode cannot show what it shows: the ragged paged-attention
+kernel passed every interpret-mode identity test while Mosaic refused its
+block shapes, its one-head page DMA and its head_dim-64 pool. Each case
+here lowers one main-path kernel at a published width for ``v5e:2x2`` and
+finds the kernel's ``tpu_custom_call`` in the compiled HLO — about two
+seconds each, no chip time. Nothing runs, so nothing here says anything
+about results or speed.
+
+Widths: gpt3-1.3b serving (16 heads x 128, page 16, 64 pages per
+sequence) and gpt3-350m training (16 heads x 64, batch 8 x seq 1024).
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import paddle_tpu  # noqa: F401 — x64 on, as in production
+
+pytestmark = pytest.mark.tpu_compile
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described four-chip v5e host. Skips where the topology cannot
+    be described (no TPU compiler in this installation). The persistent
+    compilation cache stays off around these compiles: an entry written
+    for a described device cannot be read back without a chip, and the
+    next compile would warn about it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(topo):
+    """One described v5e chip."""
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *shapes, device):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=device) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# ------------------------------------------------- ragged paged attention
+PAGE, PPS, POOL_PAGES = 16, 64, 1024
+
+RAGGED_CASES = [
+    # id, batch, query tokens, head_dim, quantized
+    ("decode-fp32", 8, 1, 128, False),
+    ("decode-int8", 8, 1, 128, True),
+    ("prefill128-fp32", 1, 128, 128, False),
+    ("prefill128-int8", 1, 128, 128, True),
+    ("prefill512-fp32", 1, 512, 128, False),
+    ("verify5-fp32", 8, 5, 128, False),
+    ("verify5-int8", 8, 5, 128, True),
+    ("decode-bf16", 8, 1, 128, False),
+]
+
+
+@pytest.mark.parametrize("name,b,s,d,quant", RAGGED_CASES,
+                         ids=[c[0] for c in RAGGED_CASES])
+def test_ragged_kernel_compiles_for_v5e(v5e, name, b, s, d, quant):
+    from paddle_tpu.kernels import ragged_paged_attention as rp
+
+    h = 16
+    qdt = jnp.bfloat16 if name.endswith("bf16") else jnp.float32
+    ok, why = rp.ragged_kernel_eligible(
+        d, PPS, PAGE, s, num_heads=h, quantized=quant,
+        q_itemsize=jnp.dtype(qdt).itemsize)
+    assert ok, why
+    pool = ((POOL_PAGES, PAGE, h, d), jnp.int8 if quant else qdt)
+    shapes = [((b, h, s, d), qdt), pool, pool, ((b, PPS), jnp.int32),
+              ((b,), jnp.int32)]
+    if quant:
+        shapes += [((POOL_PAGES, h), jnp.float32)] * 2
+
+        def fn(q, k, v, tab, ctx, ks, vs):
+            return rp.ragged_paged_attention(q, k, v, tab, ctx,
+                                             k_scale=ks, v_scale=vs)
+    else:
+        fn = rp.ragged_paged_attention
+    assert "tpu_custom_call" in _compiled_text(fn, *shapes, device=v5e)
+
+
+def test_ragged_head_dim_64_is_gated_with_the_compilers_reason(v5e):
+    """gpt3-350m widths (16 heads x 64): the chip's compiler refuses the
+    page DMA, so the gate declares the composite path and quotes the
+    refusal — and the refusal is still what the compiler says, so the
+    gate can be lifted the day it stops being true."""
+    from paddle_tpu.kernels import ragged_paged_attention as rp
+
+    ok, why = rp.ragged_kernel_eligible(64, PPS, PAGE, 1, num_heads=16)
+    assert not ok
+    assert "aligned to tiling (128), but is 64" in why
+    pool = ((POOL_PAGES, PAGE, 16, 64), jnp.float32)
+    with pytest.raises(Exception, match=r"aligned to tiling \(128\)"):
+        _compiled_text(rp.ragged_paged_attention,
+                       ((8, 16, 1, 64), jnp.float32), pool, pool,
+                       ((8, PPS), jnp.int32), ((8,), jnp.int32),
+                       device=v5e)
+
+
+# ------------------------------------------------ flash / splash attention
+def _fwd_bwd(q, k, v):
+    from paddle_tpu.kernels.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _fwd(q, k, v):
+    from paddle_tpu.kernels.flash_attention import flash_attention
+
+    return flash_attention(q, k, v, causal=True)
+
+
+FLASH_CASES = [
+    # id, fn, (batch, heads, seq, head_dim), dtype, custom calls at least
+    ("flash-fwd+bwd-bf16-350m", _fwd_bwd, (8, 16, 1024, 64), jnp.bfloat16, 3),
+    ("flash-fwd-fp32-1.3b", _fwd, (1, 16, 1024, 128), jnp.float32, 1),
+    ("splash-fwd+bwd-bf16-s4096", _fwd_bwd, (1, 16, 4096, 64),
+     jnp.bfloat16, 3),
+]
+
+
+@pytest.mark.parametrize("name,fn,shape,dtype,n_calls", FLASH_CASES,
+                         ids=[c[0] for c in FLASH_CASES])
+def test_flash_kernels_compile_for_v5e(v5e, monkeypatch, name, fn, shape,
+                                       dtype, n_calls):
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.utils import flags
+
+    # the shipped policy, whatever an earlier test left behind (set_flags
+    # coerces to the current value's type, so a bool never goes back)
+    monkeypatch.setitem(flags._FLAGS, "FLAGS_use_splash_attention", "auto")
+    assert fa.flash_route(shape, shape, True) == "direct"
+    # the splash case is the one the auto policy routes to splash
+    assert fa._want_splash(True, shape[2], shape[2]) == \
+        name.startswith("splash")
+    text = _compiled_text(fn, *[(shape, dtype)] * 3, device=v5e)
+    assert text.count("tpu_custom_call") >= n_calls
+
+
+def test_flash_runs_per_shard_under_a_training_mesh(topo, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel, so under a multi-device
+    training mesh the flash call runs inside a shard_map — batch over dp,
+    heads over mp. Compiled for the four described chips as dp2 x mp2 at
+    gpt3-350m widths: the kernels are there and attention adds no
+    collective."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.distributed.fleet.hybrid_train import mesh_scope
+    from paddle_tpu.kernels import _common, attention
+
+    # the dispatch asks the backend, which is the CPU here: steer it
+    monkeypatch.setattr(_common, "on_tpu_backend", lambda: True)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "mp"))
+    sh = NamedSharding(mesh, P("dp", "mp", None, None))
+    qkv = [jax.ShapeDtypeStruct((8, 16, 1024, 64), jnp.bfloat16,
+                                sharding=sh)] * 3
+
+    def fwd_bwd(q, k, v):
+        def loss(q, k, v):
+            with mesh_scope(mesh):
+                return attention.sdpa(q, k, v, is_causal=True).astype(
+                    jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(fwd_bwd).lower(*qkv).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    for coll in ("all-reduce", "all-gather", "all-to-all",
+                 "collective-permute"):
+        assert f" {coll}(" not in text and f" {coll}-start(" not in text

@@ -5,8 +5,7 @@ tools/ci_model_benchmark.sh spirit).
 Same harness shape as resnet_bench.py: functional train step (bf16 params +
 fp32 master weights, AdamW, fused chunked MLM head so [b, s, vocab] logits
 never materialize), INNER steps fused per dispatch via lax.scan, median
-step time, host-fetch sync. On TPU the result banks to
-BENCH_TPU_HISTORY.jsonl; on CPU it prints a tiny smoke line.
+step time, host-fetch sync. On CPU it prints a tiny smoke line.
 
 Usage: python tools/bert_bench.py            (auto platform)
        JAX_PLATFORMS=cpu python tools/bert_bench.py
@@ -147,10 +146,6 @@ def main():
                       flush=True)
         if result is None:
             raise RuntimeError("no BERT rung fit on the device")
-        result["provenance"] = "bert-bench"
-        import bench
-
-        bench._bank_tpu_result(result)
     print(json.dumps(result), flush=True)
 
 

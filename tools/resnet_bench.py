@@ -4,8 +4,7 @@ benchmark repo, tools/ci_model_benchmark.sh).
 
 Same harness shape as bench.py: functional train step (bf16 params + fp32
 master weights, Momentum+CE), INNER steps fused per dispatch via lax.scan,
-median step time. On TPU the result banks to BENCH_TPU_HISTORY.jsonl with
-its own metric name; on CPU it prints a smoke line (resnet18, tiny batch) —
+median step time. On CPU it prints a smoke line (resnet18, tiny batch) —
 never presented as an accelerator number.
 
 Usage: python tools/resnet_bench.py            (auto platform)
@@ -142,11 +141,6 @@ def main():
                       file=sys.stderr, flush=True)
         if result is None:
             raise RuntimeError("no resnet batch size fit")
-        import bench
-
-        rec = dict(result)
-        rec["provenance"] = "resnet50-bench"
-        bench._bank_tpu_result(rec)
     print(json.dumps(result))
 
 
