@@ -214,21 +214,19 @@ def main():
           f"{sum(r.donated_leaves for r in audits.values())} donated pool "
           f"buffers all aliased; peak step HBM {peak / 1024:.1f} KiB")
 
-    # ---- goodput attribution: the SAME audits now back live gauges —
-    # measured dispatch time divided by the audited flops/HBM model gives
-    # MFU and per-program cost-model drift (no second lowering); every
-    # step's wall time splits exactly across its phases; the clean demo
-    # fires no watchdog alerts; and the flight recorder bundles it all
-    # into one schema-validated black-box dump
+    # ---- goodput attribution: every step's wall time splits exactly
+    # across its phases, the parts of the decode and prefill phases
+    # (upload / dispatch / fetch / emit) ride beside them as sub-spans —
+    # the same boundaries that land as serve.* events in a profiler
+    # trace; the clean demo fires no watchdog alerts; and the flight
+    # recorder bundles it all into one schema-validated black-box dump
     from paddle_tpu.obs import validate_flight_record
 
-    assert snap4["serving_mfu"] > 0, "audited engine published no MFU"
-    drift = {k.split("program=")[1].rstrip("}"): v
-             for k, v in sorted(snap4.items())
-             if k.startswith("serving_cost_model_drift{") and v > 0}
-    assert set(drift) == set(audits), (drift, audits)
     for rec in eng3.timeline.records():
         assert abs(sum(rec.phase_s.values()) - rec.duration) < 1e-9, rec
+    fetch_s = sum(rec.span_s.get("decode.fetch", 0.0)
+                  for rec in eng3.timeline.records())
+    assert fetch_s > 0, "no decode.fetch sub-span recorded"
     assert eng3.alerts() == [] and all(
         v == 0 for k, v in snap4.items()
         if k.startswith("serving_alerts_total")), \
@@ -236,9 +234,8 @@ def main():
     flight = validate_flight_record(eng3.flight_record())
     assert flight["alerts"] == [] and flight["steps"][-1]["phase_s"]
     assert set(flight["programs"]) == set(audits)
-    print(f"attribution: serving_mfu={snap4['serving_mfu']:.2e}, "
-          f"drift over {len(drift)} programs (max "
-          f"{max(drift.values()):.3g}x), phase times sum exactly, "
+    print(f"attribution: phase times sum exactly, {fetch_s * 1e3:.2f} ms "
+          f"of the decode phases was the token fetch, "
           f"0 watchdog alerts, flight record validated "
           f"({len(flight['steps'])} steps, {len(flight['requests'])} "
           f"request summaries)")
@@ -354,7 +351,7 @@ def main():
             audits7["decode"].enforce(SINGLE_CHIP)
             raise AssertionError("zero budget must reject a sharded step")
         except CollectiveBudgetError as e:
-            assert "all-reduce" in str(e) and "%all-reduce" in str(e)
+            assert "all-reduce(" in str(e)  # the instruction line XLA prints
         snap7 = eng7.metrics.snapshot()
         shard = eng7.cache.pools[0]["k_pool"].addressable_shards[0].data
         print(f"tensor parallel: TP=2 outputs bit-identical across "

@@ -38,6 +38,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import inspect
+import re
 import threading
 
 import numpy as np
@@ -142,6 +143,13 @@ def explain_signature_diff(prior: tuple, new: tuple) -> list[str]:
 
 
 # ------------------------------------------------------------ CompileGuard
+def _identifier(x) -> str:
+    """``x`` as part of a program's name: a group id such as ``(512,)``
+    reads ``512``, ``(8, 128)`` reads ``8_128``."""
+    parts = x if isinstance(x, (tuple, list)) else (x,)
+    return re.sub(r"\W+", "_", "_".join(str(p) for p in parts)).strip("_")
+
+
 class CompileGuard:
     """``jax.jit`` with an audit trail: trace counting, per-trace abstract
     signatures, compile budgets, retrace explanation, and donation checks.
@@ -164,15 +172,25 @@ class CompileGuard:
     aggregate budget of N would let a real same-bucket retrace hide inside
     unused-bucket headroom; with it, a second trace of any group is a
     retrace even when the aggregate budget has room.
+
+    What it jits is NAMED: after ``program`` (default: the guard's
+    ``name``) and, under ``group_by``, the group — one ``jax.jit`` per
+    group, so each compiled program has a module name of its own
+    (``jit_<program>`` / ``jit_<program>_<group>``: the serving prefill's
+    pad buckets read ``jit_serve_prefill_128`` and
+    ``jit_serve_prefill_512`` on the profiler's "XLA Modules" line; one
+    shared jit would give every bucket the same name). Each group still
+    compiles at most once: nothing is compiled that one shared jit would
+    not compile.
     """
 
     def __init__(self, fn, name: str | None = None, *, budget: int | None
                  = None, strict: bool = False, static_argnums=(),
-                 donate_argnums=(), group_by=None, compiler_options=None):
-        import jax
-
+                 donate_argnums=(), group_by=None, compiler_options=None,
+                 program: str | None = None):
         self.fn = fn
         self.name = name or getattr(fn, "__name__", "jitted")
+        self.program = _identifier(program or self.name)
         self.budget = budget
         self.strict = strict
         self.static_argnums = tuple(static_argnums)
@@ -194,11 +212,26 @@ class CompileGuard:
         except (TypeError, ValueError):
             self._params = []
 
+        self._jits: dict = {}  # group id (None ungrouped) -> its jax.jit
+
+    def _jit_for(self, group):
+        """The ``jax.jit`` of ``group``'s program, made at its first call
+        and named after ``program`` and the group."""
+        jitted = self._jits.get(group)
+        if jitted is not None:
+            return jitted
+        import jax
+
+        fn = self.fn
+
         @functools.wraps(fn)
         def counted(*args, **kwargs):
             self.traces += 1
             return fn(*args, **kwargs)
 
+        # the module is named jit_<__name__>
+        counted.__name__ = counted.__qualname__ = self.program if \
+            group is None else f"{self.program}_{_identifier(group)}"
         jit_kwargs = {}
         if self.static_argnums:
             jit_kwargs["static_argnums"] = self.static_argnums
@@ -206,7 +239,8 @@ class CompileGuard:
             jit_kwargs["donate_argnums"] = self.donate_argnums
         if self.compiler_options:
             jit_kwargs["compiler_options"] = self.compiler_options
-        self._jit = jax.jit(counted, **jit_kwargs)
+        jitted = self._jits[group] = jax.jit(counted, **jit_kwargs)
+        return jitted
 
     # ------------------------------------------------------------- auditing
     def signature_of(self, args, kwargs=None) -> tuple:
@@ -290,7 +324,7 @@ class CompileGuard:
                 raise RetraceError(self._explain(
                     sig, group if regroup else None))
         before = self.traces
-        out = self._jit(*args, **kwargs)
+        out = self._jit_for(group)(*args, **kwargs)
         if self.traces > before:
             # shape/dtype metadata stays readable on donated-and-deleted
             # arrays (only the data is gone), so post-call recording is safe

@@ -254,7 +254,9 @@ def build_hybrid_step(model, optimizer, loss_fn, mesh: Mesh, zero_stage: int = 0
         (loss, new_b), grads = grad_fn(
             state["p"], state["frozen"], state["b"], key, inputs, labels
         )
-        new_p, new_opt = optimizer.functional_update(state["p"], grads, state["opt"], lr)
+        with jax.named_scope("optimizer"):
+            new_p, new_opt = optimizer.functional_update(
+                state["p"], grads, state["opt"], lr)
         return loss, {"p": new_p, "frozen": state["frozen"], "b": new_b,
                       "opt": new_opt}
 

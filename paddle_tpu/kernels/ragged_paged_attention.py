@@ -69,8 +69,8 @@ Certification: the ``ragged_paged`` / ``ragged_paged_q8`` /
 freeze the VMEM budget (the ×2 staged buffers priced by the scratch
 shapes themselves), prove the data-dependent output map injective at
 canonical runtime arguments, and bank the roofline + predicted speedup to
-``profiles/kernelcheck.json``; the live A/B rides the engine's
-``serving_kernel_speedup_*{kernel=}`` gauges (obs/attribution.py).
+``profiles/kernelcheck.json``; the measured share of its roofline is the
+benchmark's ``ragged_paged_attention_roofline`` (PERF.md).
 
 Dispatch lives in :mod:`.paged_attention` (``paged_attention()`` routes
 every eligible call here; ``decode_kernel_eligible`` delegates to

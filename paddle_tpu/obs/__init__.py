@@ -25,10 +25,10 @@ doing right before it died.
   preemptions, per-phase wall-time attribution, host syncs under
   ``debug_checks``.
 - :mod:`~paddle_tpu.obs.attribution` — goodput attribution:
-  :class:`PhaseAccumulator` (exact per-phase step wall-time split) and
-  :class:`RooflineTracker` (live MFU / HBM-bandwidth utilization /
-  cost-model drift against the engine's own hlocheck audits, plus the
-  kernelcheck predicted-vs-measured speedup A/B).
+  :class:`PhaseAccumulator`, the one span mechanism inside
+  ``engine.step()``: each boundary writes its seconds on the engine's
+  clock (exact per-phase split + the sub-spans' own extents) and a
+  ``serve.*`` ``TraceAnnotation`` into the profiler's own trace.
 - :mod:`~paddle_tpu.obs.alerts` — anomaly watchdogs (:class:`Watchdog`):
   edge-triggered rules over host-resident step state — retrace after
   warmup, Pallas fallback, speculative-acceptance collapse, eviction
@@ -75,10 +75,8 @@ host syncs to the decode loop (the SyncTally certification is unchanged).
 """
 from .alerts import RULES as ALERT_RULES  # noqa: F401
 from .alerts import Alert, Watchdog, WatchdogConfig  # noqa: F401
-from .attribution import (DEFAULT_PEAK_FLOPS_PER_S,  # noqa: F401
-                          DEFAULT_PEAK_HBM_BYTES_PER_S, PHASES,
-                          PhaseAccumulator, RooflineTracker,
-                          load_banked_kernel_speedups)
+from .attribution import (NO_SPAN, PHASES, SPAN_PREFIX,  # noqa: F401
+                          PhaseAccumulator)
 from .export import (chrome_trace, latency_table,  # noqa: F401
                      prometheus_text, write_chrome_trace)
 from .fleetscope import (FLEET_RECORD_SCHEMA,  # noqa: F401
@@ -105,9 +103,7 @@ __all__ = ["Histogram", "HistogramFamily", "LATENCY_EDGES_S",
            "OCCUPANCY_EDGES", "QUANTILES", "split_labels",
            "Tracer", "RequestTrace", "TraceEvent",
            "StepTimeline", "StepRecord",
-           "PHASES", "PhaseAccumulator", "RooflineTracker",
-           "DEFAULT_PEAK_FLOPS_PER_S", "DEFAULT_PEAK_HBM_BYTES_PER_S",
-           "load_banked_kernel_speedups",
+           "PHASES", "SPAN_PREFIX", "NO_SPAN", "PhaseAccumulator",
            "Alert", "ALERT_RULES", "Watchdog", "WatchdogConfig",
            "JOURNEY_SCHEMA", "Journey", "JourneyBook",
            "validate_journey", "format_journey",

@@ -1,57 +1,75 @@
 """Goodput attribution: where does an engine step's wall time actually go.
 
-Two host-only instruments, closing the static->runtime loop the analysis
-layer left open. hlocheck (PR 6) freezes an analytic cost model — flops
-and peak HBM bytes — for every compiled program, and kernelcheck (PR 11)
-banks a predicted speedup for every Pallas kernel; nothing ever compared
-those predictions to measured wall time. This module does, off the
-pluggable engine clock, with ZERO device syncs added (clock reads only —
-the SyncTally decode-loop certification is byte-identical with
-attribution on):
+One host-only instrument, :class:`PhaseAccumulator`, and one mechanism
+with two records. Every boundary inside ``ServingEngine.step()`` is
+marked at ONE call site (``with att.span("decode.fetch"):``), and that
+site writes both:
 
-- :class:`PhaseAccumulator` — splits one step's wall time across the
-  phases the step actually ran (admit/restore, swap resume, prefill,
-  chunked prefill, decode-or-verify, eviction/preemption, residual
-  "other") by stamping a mark at each phase boundary. The interval since
-  the previous mark is charged to the named phase, so the per-phase
-  times SUM EXACTLY to the step's wall time by construction — no
-  sampling, no double counting. The engine rolls the split into the
-  ``serving_step_phase_s{phase=}`` histogram family and onto each
-  :class:`~paddle_tpu.obs.timeline.StepRecord`.
-- :class:`RooflineTracker` — accumulates measured per-program dispatch
-  times (each engine dispatch site times dispatch -> sanctioned fetch,
-  so device time is included via the fetch's block) against the
-  predictions the engine's own first-trace hlocheck audits already hold
-  (NO second lowering), and publishes:
+- **seconds on the engine's clock** (pluggable, virtual in the tests),
+  rolled onto each :class:`~paddle_tpu.obs.timeline.StepRecord` and into
+  the ``serving_step_phase_s{phase=}`` histogram family, and
+- **a** ``jax.profiler.TraceAnnotation`` — a TraceMe event in the host
+  plane of the profiler's own trace (the xplane), on the same timeline as
+  the ``/device:TPU:n`` planes — so a gap of the device can be put down to
+  the part of the step that the host was in. With no profiler session the
+  TraceMe records nothing (under a microsecond).
 
-  * ``serving_mfu`` — achieved flops/s over the audited programs'
-    measured time, divided by the device peak,
-  * ``serving_hbm_bw_util`` — same for the audits' HBM byte roll-up
-    against peak memory bandwidth,
-  * ``serving_cost_model_drift{program=}`` — measured mean step time /
-    roofline-predicted time (``max(flops/peak_flops, bytes/peak_bw)``)
-    per compiled program, kept as a high-watermark — the live answer to
-    "is the analytic cost model still telling the truth",
-  * ``serving_kernel_speedup_{predicted,measured,drift}{kernel=}`` —
-    kernelcheck's banked predicted speedup beside the measured
-    composite/kernel dispatch-time ratio whenever a Pallas kernel
-    actually serves traffic, so the on-chip A/B the ROADMAP demands is a
-    gauge read, not a bespoke experiment.
+The span tree (every span carries ``step=<engine step index>``, the
+per-request ones ``rid=``; a span takes its attributes when it opens, so
+counts known only at its end stay on the ``StepRecord`` and join by
+``step``):
 
-Peaks default to TPU v5e (the generation kernelcheck's VMEM caps are
-certified against); override per deployment via
-``ServingConfig(peak_flops_per_s=, peak_hbm_bytes_per_s=)``. On CPU the
-absolute MFU number is nonsense-but-stable — drift ratios and phase
-attribution remain meaningful, which is what the tests pin.
+======================  ====================================  ==============
+span                    extent                                attributes
+======================  ====================================  ==============
+``serve.step``          all of ``ServingEngine.step()``       step
+``serve.admit``         deadline sweep, ``scheduler.admit``,  queue_depth
+                        restore failures
+``serve.prefill``       one per prefilled request             rid, bucket,
+                                                              cached, tail
+``serve.prefill.upload``    building the padded ids and the   bytes
+                            five device operands
+``serve.prefill.dispatch``  the call of the jitted program
+``serve.prefill.fetch``     the first-token fetch (blocks)
+``serve.chunk_prefill``  the chunk loop                       chunks
+``serve.evict``         fault sites, decode-page pressure,
+                        preemption
+``serve.decode``        the decode phase                      batch
+``serve.decode.upload``     the six device operands           bytes
+``serve.decode.dispatch``   the call of the jitted program
+``serve.decode.fetch``      the token fetch (blocks)
+``serve.decode.emit``       the per-slot loop, retirements
+``serve.verify``        the speculative verify phase          batch
+``serve.account``       cache stats, gauges, the step record,
+                        watchdogs, the SLO controller
+``serve.add_request``   ``ServingEngine.add_request``         rid, prompt_len
+``serve.cow_copy``      one copy-on-write page copy           pages
+======================  ====================================  ==============
+
+The seconds come in two kinds. A span named after one of :data:`PHASES`
+is a **phase** of the top-level split: the interval since the previous
+mark is charged to it when it closes, so the phases plus the residual
+``"other"`` SUM EXACTLY to the step's wall time by construction — no
+sampling, no double counting (``StepRecord.phase_s``). Every other span
+(the dotted ``*.upload`` / ``*.dispatch`` / ``*.fetch`` / ``*.emit``,
+``cow_copy``, ``account``) measures its own extent, lies inside a phase
+and is no part of that sum (``StepRecord.span_s``).
+
+ZERO device syncs either way (clock reads and TraceMe events only — the
+SyncTally decode-loop certification is byte-identical with attribution
+on). With ``enable_tracing=False`` the engine holds no accumulator and
+every site costs one ``is not None`` check: no span object is made.
 
 Imports nothing from ``paddle_tpu.serving`` (serving imports us) and
 touches no device state.
 """
 from __future__ import annotations
 
-__all__ = ["PHASES", "PhaseAccumulator", "RooflineTracker",
-           "DEFAULT_PEAK_FLOPS_PER_S", "DEFAULT_PEAK_HBM_BYTES_PER_S",
-           "load_banked_kernel_speedups"]
+import contextlib
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+__all__ = ["PHASES", "SPAN_PREFIX", "NO_SPAN", "PhaseAccumulator"]
 
 #: the phase vocabulary — the pre-seeded label set of the
 #: ``serving_step_phase_s{phase=}`` histogram family. "admit" covers the
@@ -61,26 +79,71 @@ __all__ = ["PHASES", "PhaseAccumulator", "RooflineTracker",
 PHASES = ("admit", "swap", "prefill", "chunk_prefill", "decode", "verify",
           "evict", "other")
 
-# TPU v5e: ~197 TFLOP/s bf16 and ~819 GB/s HBM per chip — the same
-# generation kernelcheck's VMEM caps are certified at. Deployments on
-# other parts override via ServingConfig.
-DEFAULT_PEAK_FLOPS_PER_S = 1.97e14
-DEFAULT_PEAK_HBM_BYTES_PER_S = 8.19e11
+#: what every span's name starts with in the profiler's trace
+SPAN_PREFIX = "serve."
+
+#: what a span site enters with tracing off — ``with (att.span(...) if att
+#: is not None else NO_SPAN):`` — one shared do-nothing context: no span
+#: object is made, the site costs its ``is not None`` check
+NO_SPAN = contextlib.nullcontext()
+
+
+class _Span:
+    """One open span: the TraceMe event, and where its seconds go."""
+
+    __slots__ = ("_acc", "_name", "_phase", "_ann", "_t0")
+
+    def __init__(self, acc, name: str, attrs: dict):
+        self._acc = acc
+        self._name = name
+        self._phase = name in PHASES
+        self._ann = TraceAnnotation(SPAN_PREFIX + name, **attrs)
+        self._t0 = None
+
+    def __enter__(self):
+        self._ann.__enter__()
+        # a span that is no phase reads the clock for its own extent, and
+        # only while a step's record is open to take it
+        if self._acc.open and not self._phase:
+            self._t0 = self._acc._clock()
+        return self
+
+    def __exit__(self, *exc):
+        acc = self._acc
+        if self._phase:
+            acc.mark(self._name)
+        elif self._t0 is not None and acc.open:
+            dt = acc._clock() - self._t0
+            acc._spans[self._name] = acc._spans.get(self._name, 0.0) + dt
+        self._ann.__exit__(*exc)
+        return False
 
 
 class PhaseAccumulator:
-    """Mark-based wall-time splitter for one engine step at a time.
+    """Wall-time splitter and span source for one engine step at a time.
 
-    ``begin(t)`` opens a step; each ``mark(phase)`` charges the interval
-    since the previous mark (or begin) to ``phase`` and returns it;
-    ``finish()`` charges the remainder to ``"other"`` and returns
-    ``(t_end, {phase: seconds})``. Exactness contract: the returned
-    phase dict's values are precisely the consecutive clock deltas, so
-    on any clock they sum to ``t_end - t_begin`` up to float addition —
-    and EXACTLY on the integer-valued virtual clocks the tests use.
+    The seconds: ``begin(t)`` opens a step's record; each ``mark(phase)``
+    charges the interval since the previous mark (or begin) to ``phase``
+    and returns it; ``finish()`` charges the remainder to ``"other"`` and
+    returns ``(t_end, {phase: seconds})``. Exactness contract: the
+    returned phase dict's values are precisely the consecutive clock
+    deltas, so on any clock they sum to ``t_end - t_begin`` up to float
+    addition — and EXACTLY on the integer-valued virtual clocks the tests
+    use.
+
+    The spans: ``enter_step(step)`` opens ``serve.step`` in the
+    profiler's trace and ``exit_step()`` closes it (and ``serve.account``
+    with it); in between ``span(name, **attrs)`` is a context manager
+    that opens ``serve.<name>`` there and, when it closes, writes the
+    seconds: a name from :data:`PHASES` is a ``mark`` of that phase, any
+    other name adds its own extent to ``span_s``. ``account()`` opens
+    ``serve.account``, which outlives the record: what of it lies before
+    ``finish()`` is in ``span_s["account"]``, the rest is in the
+    profiler's trace only.
     """
 
-    __slots__ = ("_clock", "open", "t0", "_last", "_acc")
+    __slots__ = ("_clock", "open", "t0", "_last", "_acc", "_spans", "step",
+                 "_step_ann", "_account")
 
     def __init__(self, clock):
         self._clock = clock
@@ -88,12 +151,51 @@ class PhaseAccumulator:
         self.t0 = 0.0
         self._last = 0.0
         self._acc: dict[str, float] = {}
+        self._spans: dict[str, float] = {}
+        self.step = 0
+        self._step_ann = None
+        self._account = None
 
+    # -------------------------------------------------------------- spans
+    def enter_step(self, step: int) -> None:
+        """Open ``serve.step`` (a step event: the profiler groups what the
+        device ran by it). No clock read: the record opens at ``begin``."""
+        self.step = step
+        self._step_ann = StepTraceAnnotation(
+            SPAN_PREFIX + "step", step_num=step, step=step)
+        self._step_ann.__enter__()
+
+    def exit_step(self) -> None:
+        """Close ``serve.account`` if it is open, then ``serve.step``."""
+        if self._account is not None:
+            self._account.__exit__(None, None, None)
+            self._account = None
+        if self._step_ann is not None:
+            self._step_ann.__exit__(None, None, None)
+            self._step_ann = None
+
+    def span(self, name: str, **attrs) -> _Span:
+        """``with att.span("decode.fetch"):`` — see the class docstring.
+        ``step`` is the open step's unless given."""
+        attrs.setdefault("step", self.step)
+        return _Span(self, name, attrs)
+
+    def account(self) -> None:
+        """Open ``serve.account``; ``exit_step`` closes it."""
+        self._account = self.span("account").__enter__()
+
+    @property
+    def span_s(self) -> dict:
+        """{span: seconds} of the spans that are no phase, this step."""
+        return self._spans
+
+    # ------------------------------------------------------------ seconds
     def begin(self, t: float | None = None) -> float:
         t = self._clock() if t is None else t
         self.open = True
         self.t0 = self._last = t
         self._acc = {}
+        self._spans = {}
         return t
 
     def mark(self, phase: str, t: float | None = None) -> float:
@@ -106,161 +208,11 @@ class PhaseAccumulator:
         return dt
 
     def finish(self, t: float | None = None) -> tuple[float, dict]:
-        """Close the step: residual time goes to ``"other"``; returns
-        ``(t_end, phases)``."""
+        """Close the step's record: residual time goes to ``"other"``;
+        returns ``(t_end, phases)``."""
         t = self._clock() if t is None else t
         self.mark("other", t)
+        if self._account is not None and self._account._t0 is not None:
+            self._spans["account"] = t - self._account._t0
         self.open = False
         return t, self._acc
-
-
-def load_banked_kernel_speedups() -> dict[str, float]:
-    """kernelcheck's banked ``predicted_speedup`` per kernel, from
-    ``profiles/kernelcheck.json`` — {} when the bank (or the analysis
-    package) is unavailable, so obs never hard-depends on it."""
-    try:
-        import json
-
-        from ..analysis.kernelcheck import bank_path
-
-        with open(bank_path()) as fh:
-            banked = json.load(fh)
-    except Exception:  # noqa: BLE001 — optional input, absence is normal
-        return {}
-    return {name: rec["predicted_speedup"]
-            for name, rec in banked.items()
-            if isinstance(rec, dict)
-            and isinstance(rec.get("predicted_speedup"), (int, float))}
-
-
-class RooflineTracker:
-    """Measured-vs-predicted accounting per compiled program.
-
-    Predictions arrive once per program from the engine's first-trace
-    hlocheck audit (``on_program``); measurements accrue per dispatch
-    (``on_call`` — dispatch-to-fetch wall seconds). ``publish`` pushes
-    the derived gauges through a ``ServingMetrics`` and is a no-op until
-    both sides of at least one program exist, so a non-debug engine
-    (no audits) pays one boolean check per step.
-    """
-
-    def __init__(self, peak_flops_per_s: float = 0.0,
-                 peak_hbm_bytes_per_s: float = 0.0,
-                 banked_kernels: dict[str, float] | None = None):
-        self.peak_flops = float(peak_flops_per_s) or DEFAULT_PEAK_FLOPS_PER_S
-        self.peak_bw = (float(peak_hbm_bytes_per_s)
-                        or DEFAULT_PEAK_HBM_BYTES_PER_S)
-        if self.peak_flops <= 0 or self.peak_bw <= 0:
-            raise ValueError(
-                f"device peaks must be positive, got flops/s "
-                f"{self.peak_flops}, bytes/s {self.peak_bw}")
-        # label -> (flops, hbm_bytes) predicted per step of this program
-        self._predicted: dict[str, tuple[float, float]] = {}
-        # label -> [seconds, calls] measured
-        self._measured: dict[str, list[float]] = {}
-        # kernel A/B: name -> banked predicted speedup; measured split by
-        # which path served the dispatch
-        self._kernel_predicted = dict(banked_kernels or {})
-        self._kernel_s: dict[str, list[float]] = {}  # [k_s, k_n, c_s, c_n]
-        self._dirty = False
-
-    # ------------------------------------------------------------- feeding
-    def on_program(self, label: str, flops: float, hbm_bytes: float) -> None:
-        """One hlocheck audit's analytic roll-up for a compiled program."""
-        self._predicted[label] = (float(flops), float(hbm_bytes))
-
-    def on_call(self, label: str, seconds: float) -> None:
-        """One measured dispatch of ``label`` (dispatch -> fetch wall)."""
-        acc = self._measured.get(label)
-        if acc is None:
-            acc = self._measured[label] = [0.0, 0]
-        acc[0] += seconds
-        acc[1] += 1
-        if label in self._predicted:
-            self._dirty = True
-
-    def on_kernel_call(self, name: str, seconds: float,
-                       pallas: bool) -> None:
-        """One measured dispatch of a kernel-eligible step: ``pallas``
-        says whether the Pallas kernel (True) or the composite fallback
-        path (False) served it."""
-        acc = self._kernel_s.get(name)
-        if acc is None:
-            acc = self._kernel_s[name] = [0.0, 0, 0.0, 0]
-        i = 0 if pallas else 2
-        acc[i] += seconds
-        acc[i + 1] += 1
-        # a sample only moves a published gauge once BOTH legs have been
-        # measured (the A/B ratio); the banked predicted gauges are
-        # published at engine construction, so a one-legged steady state
-        # (every dispatch on the same path) keeps publish() a no-op
-        if acc[1] and acc[3]:
-            self._dirty = True
-
-    # ------------------------------------------------------------ deriving
-    def predicted_step_s(self, label: str) -> float | None:
-        """The roofline time for one step of ``label``: whichever of
-        compute and memory traffic binds at the configured peaks."""
-        pred = self._predicted.get(label)
-        if pred is None:
-            return None
-        flops, nbytes = pred
-        return max(flops / self.peak_flops, nbytes / self.peak_bw)
-
-    def gauges(self) -> dict:
-        """The derived gauge values:
-
-        - ``mfu`` / ``hbm_bw_util``: achieved/(peak) over every program
-          with both a prediction and measured time,
-        - ``drift``: {label: measured mean / predicted} per such program,
-        - ``kernels``: {name: {predicted, measured, drift}} — measured
-          present only once BOTH dispatch paths have samples.
-        """
-        flops = nbytes = seconds = 0.0
-        drift: dict[str, float] = {}
-        for label, (s, n) in self._measured.items():
-            pred_s = self.predicted_step_s(label)
-            if pred_s is None or not n or s <= 0:
-                continue
-            f, b = self._predicted[label]
-            flops += f * n
-            nbytes += b * n
-            seconds += s
-            if pred_s > 0:
-                drift[label] = (s / n) / pred_s
-        out = {
-            "mfu": flops / seconds / self.peak_flops if seconds else 0.0,
-            "hbm_bw_util": (nbytes / seconds / self.peak_bw
-                            if seconds else 0.0),
-            "drift": drift,
-            "kernels": {},
-        }
-        for name in {*self._kernel_predicted, *self._kernel_s}:
-            predicted = self._kernel_predicted.get(name)
-            entry: dict = {}
-            if predicted is not None:
-                entry["predicted"] = predicted
-            acc = self._kernel_s.get(name)
-            if acc and acc[1] and acc[3] and acc[0] > 0:
-                measured = (acc[2] / acc[3]) / (acc[0] / acc[1])
-                entry["measured"] = measured
-                if predicted:
-                    entry["drift"] = measured / predicted
-            out["kernels"][name] = entry
-        return out
-
-    def publish(self, metrics) -> None:
-        """Push the gauges through a ``ServingMetrics``. No-op (one
-        boolean check) unless new measurements landed since the last
-        publish."""
-        if not self._dirty:
-            return
-        self._dirty = False
-        g = self.gauges()
-        metrics.on_roofline(g["mfu"], g["hbm_bw_util"])
-        for label, ratio in g["drift"].items():
-            metrics.on_drift(label, ratio)
-        for name, entry in g["kernels"].items():
-            metrics.on_kernel_ab(name, predicted=entry.get("predicted"),
-                                 measured=entry.get("measured"),
-                                 drift=entry.get("drift"))

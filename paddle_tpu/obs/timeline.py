@@ -38,6 +38,10 @@ class StepRecord:
     # {phase: seconds} over obs.attribution.PHASES — sums to duration
     # exactly (the PhaseAccumulator mark contract); {} with tracing off
     # or on pre-attribution records
+    span_s: dict = field(default_factory=dict)  # {span: seconds} of the
+    # spans that are no phase (``decode.upload`` / ``.dispatch`` /
+    # ``.fetch`` / ``.emit``, ``prefill.*``, ``cow_copy``, ``account``):
+    # each its own extent inside a phase, no part of the phase sum
     extra: dict = field(default_factory=dict)  # exporter passthrough
 
     @property

@@ -141,17 +141,65 @@ ONE attribute check per event site when tracing is off, and ZERO new
 host syncs on the decode loop either way (the SyncTally certification
 in bench/demo is unchanged with tracing enabled).
 
-Goodput attribution (rides ``enable_tracing``): each step's wall time is
-split exactly across its phases (admit/swap/prefill/chunk_prefill/
-decode-or-verify/evict/other) by clock-read marks at the phase
-boundaries — recorded on every StepRecord and rolled into the
-``serving_step_phase_s{phase=}`` histogram family — and each dispatch
-site's measured time accrues per compiled program against the analytic
-flops/HBM model the engine's own first-trace hlocheck audits hold, so
-``serving_mfu`` / ``serving_hbm_bw_util`` /
-``serving_cost_model_drift{program=}`` (and the kernelcheck
-predicted-vs-measured speedup A/B) are live gauge reads under
-``debug_checks``. Anomaly watchdogs (``enable_watchdogs``, default on)
+Goodput attribution (rides ``enable_tracing``; obs/attribution.py): one
+span mechanism inside ``step()``. Every boundary is ONE call site
+(``with att.span("decode.fetch"):``) that writes two records — seconds on
+the engine clock (a span named after a phase joins the exact split of the
+step's wall time, ``StepRecord.phase_s`` and the
+``serving_step_phase_s{phase=}`` histogram family; every other span adds
+its own extent to ``StepRecord.span_s``) and a
+``jax.profiler.TraceAnnotation`` in the host plane of the profiler's own
+trace, the timeline of the ``/device:TPU:n`` planes, so a gap of the
+device is put down to the part of the step the host was in. Every span
+carries ``step=``, the per-request ones ``rid=``:
+
+====================================  ==================================
+span                                  extent; attributes
+====================================  ==================================
+``serve.step``                        all of ``step()``, the record /
+                                      watchdog / SLO work after the body
+                                      included
+``serve.admit``                       deadline sweep, ``scheduler.admit``,
+                                      restore failures; ``queue_depth``
+``serve.prefill``                     one per prefilled request; ``rid``,
+                                      ``bucket``, ``cached``, ``tail``
+``serve.prefill.upload``              the padded ids and the five device
+                                      operands; ``bytes``
+``serve.prefill.dispatch``            the call of the jitted program
+``serve.prefill.fetch``               the first-token fetch (blocks: the
+                                      device time lands here)
+``serve.chunk_prefill``               the chunk loop; ``chunks``
+``serve.evict``                       fault sites, decode-page pressure,
+                                      preemption
+``serve.decode``                      the decode phase; ``batch``
+``serve.decode.upload``               the six device operands, the whole
+                                      page table among them; ``bytes``
+``serve.decode.dispatch``             the call of the jitted program
+``serve.decode.fetch``                the token fetch (blocks)
+``serve.decode.emit``                 the per-slot loop, retirements
+``serve.verify``                      the speculative verify phase, one
+                                      span; ``batch``
+``serve.account``                     cache stats, gauges, the step
+                                      record, watchdogs, SLO controller:
+                                      the obs layer's own cost per step
+``serve.add_request``                 ``add_request`` once the request has
+                                      its id; ``rid``, ``prompt_len``
+``serve.cow_copy``                    one copy-on-write page copy
+                                      (kv_cache.py); ``pages``
+====================================  ==================================
+
+A span takes its attributes when it opens, so counts known at its end
+(admitted, prefills, preempted, tokens emitted, accepted) stay on the
+``StepRecord`` and join by ``step``; the request's lifecycle
+(``engine.trace(rid)``, ``engine.journey(rid)``: the engine step of each
+hop) joins by ``step`` and ``rid``. On the device the compiled programs
+are named by their CompileGuards (``jit_serve_decode``,
+``jit_serve_prefill_<bucket>``, ``jit_serve_verify``) and the model's
+regions by ``jax.named_scope`` (``embed``, ``block/attn``, ``block/mlp``,
+``final_norm``, ``kv_write``, ``sample``). With no profiler session an
+annotation records nothing; with ``enable_tracing=False`` no span object
+is made and each site costs one ``is not None`` check. Zero added device
+syncs either way. Anomaly watchdogs (``enable_watchdogs``, default on)
 evaluate edge-triggered rules over host-resident ints at each step
 boundary — retrace-after-warmup, Pallas fallback, speculative-acceptance
 collapse, eviction thrash, queue stall — each firing a structured Alert
@@ -193,11 +241,10 @@ from ..analysis import hlocheck
 from ..analysis.tracecheck import (CompileGuard, DonationViolation,
                                    RetraceError, SyncTally, donation_audit)
 from ..core.tensor import Tensor
-from ..obs import (ALERT_RULES, JourneyBook, PhaseAccumulator,
-                   RooflineTracker, StepRecord, StepTimeline, TenantLedger,
-                   TenantSLO, Tracer, Watchdog, WatchdogConfig,
-                   build_flight_record, check_tenant_name, chrome_trace,
-                   load_banked_kernel_speedups, write_chrome_trace)
+from ..obs import (ALERT_RULES, NO_SPAN, JourneyBook, PhaseAccumulator,
+                   StepRecord, StepTimeline, TenantLedger, TenantSLO, Tracer,
+                   Watchdog, WatchdogConfig, build_flight_record,
+                   check_tenant_name, chrome_trace, write_chrome_trace)
 from ..obs.recorder import MAX_FLIGHT_JOURNEYS as _MAX_FLIGHT_JOURNEYS
 from ..obs.recorder import dump_flight_record as _write_flight_record
 from ..text.generation import sample_logits
@@ -301,11 +348,6 @@ class ServingConfig:
     # instant on the engine track.
     watchdog: WatchdogConfig | None = None  # rule thresholds; None =
     # the conservative defaults (a clean engine never fires)
-    peak_flops_per_s: float = 0.0  # device peak for serving_mfu; 0 = the
-    # TPU v5e default (obs/attribution.py) — the generation kernelcheck
-    # certifies VMEM caps against
-    peak_hbm_bytes_per_s: float = 0.0  # device peak memory bandwidth for
-    # serving_hbm_bw_util; 0 = the v5e default
     flight_record_path: str | None = None  # where the automatic flight-
     # record dumps go (engine-fatal paths, stuck-engine backstop, any
     # step that retired a request FAILED); None keeps the record only on
@@ -434,23 +476,10 @@ class ServingEngine:
         self.metrics.on_tp_degree(cfg.tensor_parallel)
         self.metrics.on_kv_bytes_per_token(self.cache.cfg.kv_bytes_per_token)
         self.metrics.on_spec_depth(cfg.spec.depth if cfg.spec else 0)
-        # labeled-family presence: the watchdog rule counters, the
-        # per-program drift gauges (this engine's compiled-program set is
-        # known here), and the kernel A/B gauges for every banked
-        # kernelcheck roofline — all read 0 before anything happens, the
-        # same contract _SEEDED gives the scalars
+        # labeled-family presence: the watchdog rule counters read 0
+        # before anything happens, the same contract _SEEDED gives the
+        # scalars
         self.metrics.seed_family("alerts_total", ALERT_RULES)
-        programs = [f"prefill[{b}]" for b in self.prefill_buckets] \
-            + ["decode"] + (["verify"] if cfg.spec is not None else [])
-        self.metrics.seed_family("cost_model_drift", programs)
-        banked_kernels = load_banked_kernel_speedups()
-        for fam in ("kernel_speedup_predicted", "kernel_speedup_measured",
-                    "kernel_speedup_drift"):
-            self.metrics.seed_family(fam, banked_kernels)
-        for kname, speedup in banked_kernels.items():
-            # the banked prediction is static — publish it now, so the
-            # A/B is half-populated before a kernel ever dispatches
-            self.metrics.on_kernel_ab(kname, predicted=speedup)
         params, _ = model.functional_state()
         self._p = {k: v._value for k, v in params.items()}
         if self._tp is not None:
@@ -477,14 +506,13 @@ class ServingEngine:
             # the per-tenant goodput/badput ledger (obs/tenant.py) —
             # observe-only, fed once per retirement in _trace_retire
             self._tenants = TenantLedger(cfg.tenants)
-            # goodput attribution (obs/attribution.py): the per-phase
-            # wall-time splitter and the measured-vs-predicted roofline
-            # tracker — clock reads and host floats only, zero device
-            # syncs (the SyncTally certification is pinned unchanged)
+            # goodput attribution (obs/attribution.py): the one span
+            # mechanism inside step() — each boundary writes its seconds
+            # on the engine clock and a serve.* TraceAnnotation into the
+            # profiler's trace; clock reads and TraceMe events only, zero
+            # device syncs (the SyncTally certification is pinned
+            # unchanged)
             self._attr = PhaseAccumulator(self.now)
-            self._roofline = RooflineTracker(
-                cfg.peak_flops_per_s, cfg.peak_hbm_bytes_per_s,
-                banked_kernels=banked_kernels)
             # anomaly watchdogs: edge-triggered rules over the step
             # record + host counter totals, evaluated at step boundaries
             self._watchdog = (Watchdog(cfg.watchdog or WatchdogConfig(),
@@ -494,7 +522,6 @@ class ServingEngine:
             self._tracer = None
             self._timeline = None
             self._attr = None
-            self._roofline = None
             self._watchdog = None
             self._journeys = None
             self._tenants = None
@@ -547,38 +574,13 @@ class ServingEngine:
         from ..utils.flags import flag
 
         # whether the unified ragged kernel is even dispatchable for this
-        # engine's shapes — the single decode_kernel_eligible predicate
-        # (now the ragged_kernel_eligible gate), read once per mode. The
-        # A/B gauge legs key on the kernelcheck certificate the dispatch
-        # actually exercises: ragged_paged (fp32 decode) /
-        # ragged_paged_q8 (int8 decode), plus ragged_paged_verify for the
-        # spec K+1 dispatch.
-        _gate_kw = dict(
+        # engine's decode shapes — the single decode_kernel_eligible
+        # predicate (now the ragged_kernel_eligible gate), read once
+        self._decode_pallas_eligible, _ = _pa.decode_kernel_eligible(
+            mc.hidden_size // mc.num_heads, pages_per_seq, cfg.page_size,
             num_heads=mc.num_heads, quantized=self.cache.cfg.quantized,
             on_tpu=on_tpu_backend(),
             flags_on=bool(flag("FLAGS_use_pallas_kernels", True)))
-        self._decode_pallas_eligible, _ = _pa.decode_kernel_eligible(
-            mc.hidden_size // mc.num_heads, pages_per_seq, cfg.page_size,
-            **_gate_kw)
-        self._kernel_ab_name = ("ragged_paged_q8"
-                                if self.cache.cfg.quantized
-                                else "ragged_paged")
-        if cfg.spec is not None:
-            self._verify_pallas_eligible, _ = _pa.decode_kernel_eligible(
-                mc.hidden_size // mc.num_heads, pages_per_seq,
-                cfg.page_size, num_query_tokens=cfg.spec.depth + 1,
-                **_gate_kw)
-            # the verify A/B leg only has an fp32 banked baseline
-            # (ragged_paged_verify) — an int8 engine's verify times
-            # against it would read as spurious drift (int8 moves ~4x
-            # fewer HBM bytes), so the quantized verify leg stays off
-            # the gauge until an int8-verify certificate is banked
-            self._verify_ab_name = ("ragged_paged_verify"
-                                    if not self.cache.cfg.quantized
-                                    else None)
-        else:
-            self._verify_pallas_eligible = False
-            self._verify_ab_name = None
 
         self._fault_injector = fault_injector
         if fault_injector is not None and self.cache.host_tier is not None:
@@ -605,6 +607,15 @@ class ServingEngine:
         self._active = np.zeros(b, bool)
         self._rids = np.zeros(b, np.int32)  # per-slot rid (PRNG stream id)
         self._gen = np.zeros(b, np.int32)   # per-slot generated-token count
+        # what a decode step uploads, from the operands' shapes: the whole
+        # page table and the five per-slot vectors (serve.decode.upload)
+        self._decode_upload_bytes = sum(
+            a.nbytes for a in (self.cache.page_table, self._ctx,
+                               self._last_tok, self._active, self._rids,
+                               self._gen))
+        # the cache's copy-on-write page copy is a span of the same
+        # mechanism (serve.cow_copy); None with tracing off
+        self.cache.spans = self._attr
         self._finished: dict[int, np.ndarray] = {}
         self._retired: dict[int, Request] = {}  # cancelled/expired/failed/shed
         self._requests: dict[int, Request] = {}
@@ -643,15 +654,18 @@ class ServingEngine:
             decode_impl = self._tp.wrap_step(
                 decode_impl, mc.num_layers, n_rest=6,
                 quantized=self.cache.cfg.quantized)
+        # ``program=`` names what the guard jits, so the profiler's
+        # "XLA Modules" line reads jit_serve_prefill_<bucket> (one name
+        # per pad bucket), jit_serve_decode, jit_serve_verify
         self._prefill_jit = CompileGuard(
             prefill_impl, "prefill", donate_argnums=(1,),
             budget=len(self.prefill_buckets), strict=cfg.debug_checks,
             group_by=lambda *a: tuple(a[2].shape),
-            compiler_options=xla_opts)
+            compiler_options=xla_opts, program="serve_prefill")
         self._decode_jit = CompileGuard(
             decode_impl, "decode", donate_argnums=(1,),
             budget=1, strict=cfg.debug_checks,
-            compiler_options=xla_opts)
+            compiler_options=xla_opts, program="serve_decode")
         self.guards = {"prefill": self._prefill_jit,
                        "decode": self._decode_jit}
         if cfg.spec is not None:
@@ -671,7 +685,7 @@ class ServingEngine:
             self._verify_jit = CompileGuard(
                 verify_impl, "verify", donate_argnums=(1,),
                 budget=1, strict=cfg.debug_checks,
-                compiler_options=xla_opts)
+                compiler_options=xla_opts, program="serve_verify")
             self.guards["verify"] = self._verify_jit
         else:
             self._verify_jit = None
@@ -715,12 +729,14 @@ class ServingEngine:
         valid = (jnp.arange(n, dtype=jnp.int32) < tail_len)[None, :]
         logits, new_pools = self._run_model(
             p_arrays, pools, table, ctx, valid, padded_ids[None, :])
-        last = logits[0, tail_len - 1, :]
-        if self.config.do_sample:
-            tok = self._sample_row(last, self._req_key(rid, 0))
-        else:
-            tok = jnp.argmax(last, axis=-1)
-        return new_pools, tok.astype(jnp.int32)
+        with jax.named_scope("sample"):
+            last = logits[0, tail_len - 1, :]
+            if self.config.do_sample:
+                tok = self._sample_row(last, self._req_key(rid, 0))
+            else:
+                tok = jnp.argmax(last, axis=-1)
+            tok = tok.astype(jnp.int32)
+        return new_pools, tok
 
     def _decode_impl(self, p_arrays, pools, table, ctx, last_tok, active,
                      rids, gen_idx):
@@ -729,14 +745,16 @@ class ServingEngine:
         batch composition never changes the compiled program."""
         logits, new_pools = self._run_model(
             p_arrays, pools, table, ctx, active[:, None], last_tok[:, None])
-        last = logits[:, -1, :]
-        if self.config.do_sample:
-            keys = jax.vmap(self._req_key)(rids, gen_idx)
-            tok = jax.vmap(self._sample_row)(last, keys)
-        else:
-            tok = jnp.argmax(last, axis=-1)
-        tok = jnp.where(active, tok,
-                        jnp.asarray(self.config.pad_token_id)).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            last = logits[:, -1, :]
+            if self.config.do_sample:
+                keys = jax.vmap(self._req_key)(rids, gen_idx)
+                tok = jax.vmap(self._sample_row)(last, keys)
+            else:
+                tok = jnp.argmax(last, axis=-1)
+            tok = jnp.where(
+                active, tok,
+                jnp.asarray(self.config.pad_token_id)).astype(jnp.int32)
         return new_pools, tok
 
     def _propose_draft(self, draft_p, win):
@@ -804,15 +822,16 @@ class ServingEngine:
         valid = jnp.broadcast_to(active[:, None], ids.shape)
         logits, new_pools = self._run_model(
             p_arrays, pools, table, ctx, valid, ids)
-        if cfg.do_sample:
-            offs = jnp.arange(K + 1, dtype=jnp.int32)
-            keys = jax.vmap(lambda r, g: jax.vmap(
-                lambda j: self._req_key(r, g + j))(offs))(rids, gen_idx)
-            target = jax.vmap(jax.vmap(self._sample_row))(logits, keys)
-        else:
-            target = jnp.argmax(logits, axis=-1)
-        target = jnp.where(active[:, None], target.astype(jnp.int32),
-                           cfg.pad_token_id).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            if cfg.do_sample:
+                offs = jnp.arange(K + 1, dtype=jnp.int32)
+                keys = jax.vmap(lambda r, g: jax.vmap(
+                    lambda j: self._req_key(r, g + j))(offs))(rids, gen_idx)
+                target = jax.vmap(jax.vmap(self._sample_row))(logits, keys)
+            else:
+                target = jnp.argmax(logits, axis=-1)
+            target = jnp.where(active[:, None], target.astype(jnp.int32),
+                               cfg.pad_token_id).astype(jnp.int32)
         accepted = jnp.where(active, accept_counts(cand, target),
                              0).astype(jnp.int32)
         packed = jnp.concatenate([target, accepted[:, None]], axis=1)
@@ -886,23 +905,31 @@ class ServingEngine:
                                 if deadline_s is not None else None),
                       tenant=tenant,
                       **({} if rid is None else {"rid": int(rid)}))
-        try:
-            shed = self.scheduler.add(req)  # validates against pool capacity
-        except EngineOverloaded:
-            self.metrics.on_rejected()
-            raise
-        tr = self._tracer
-        if tr is not None:
-            # journey first: the tracer's begin() stamps "enqueued",
-            # which the journal tap routes onto the journey just opened
-            self._journeys.begin(req.rid, tenant)
-            tr.begin(req.rid)
-        if shed is not None:
-            self._requests.pop(shed.rid, None)
-            self._retired[shed.rid] = shed
-            self.metrics.on_shed()
-            self._trace_retire(shed, SHED)
-        self._requests[req.rid] = req
+        # serve.add_request opens once the request has its id (a span
+        # takes its attributes when it opens): the queueing, the shed
+        # and the trace's first stamp; step = the step that runs next
+        att = self._attr
+        with (att.span("add_request", step=self._step_idx, rid=req.rid,
+                       prompt_len=req.prompt_len)
+              if att is not None else NO_SPAN):
+            try:
+                # validates against pool capacity
+                shed = self.scheduler.add(req)
+            except EngineOverloaded:
+                self.metrics.on_rejected()
+                raise
+            tr = self._tracer
+            if tr is not None:
+                # journey first: the tracer's begin() stamps "enqueued",
+                # which the journal tap routes onto the journey just opened
+                self._journeys.begin(req.rid, tenant)
+                tr.begin(req.rid)
+            if shed is not None:
+                self._requests.pop(shed.rid, None)
+                self._retired[shed.rid] = shed
+                self.metrics.on_shed()
+                self._trace_retire(shed, SHED)
+            self._requests[req.rid] = req
         return req.rid
 
     def cancel(self, rid: int) -> bool:
@@ -1057,8 +1084,6 @@ class ServingEngine:
         unchanged. Returns the first generated token when this chunk
         completed the prefill, else None; a request-local failure retires
         the request FAILED here (engine-fatal failures re-raise)."""
-        from .. import profiler
-
         cfg = self.config
         start = req.prefilled_tokens
         n = min(cfg.chunk_size, req.prompt_len - start)
@@ -1073,22 +1098,21 @@ class ServingEngine:
                 jnp.asarray(req.rid, jnp.int32))
         if cfg.debug_checks:
             self._audit_step(self._prefill_jit, args, f"prefill[{bucket}]")
-        with profiler.RecordEvent("serving::prefill_chunk"):
-            try:
-                pools, tok = self._prefill_jit(*args)
-            except Exception as e:  # noqa: BLE001 — isolate the request
-                if isinstance(e, (RetraceError, DonationViolation)):
-                    # a strict-guard refusal is an AUDIT failure, not a
-                    # request fault — surface it
-                    raise
-                if any(arr.is_deleted() for pl in self.cache.pools
-                       for arr in pl.values()):
-                    # donation consumed the pools before the failure:
-                    # every sequence's KV is gone — engine-fatal
-                    raise
-                self._retire(req, FAILED, e)
-                self.metrics.on_failed()
-                return None
+        try:
+            pools, tok = self._prefill_jit(*args)
+        except Exception as e:  # noqa: BLE001 — isolate the request
+            if isinstance(e, (RetraceError, DonationViolation)):
+                # a strict-guard refusal is an AUDIT failure, not a
+                # request fault — surface it
+                raise
+            if any(arr.is_deleted() for pl in self.cache.pools
+                   for arr in pl.values()):
+                # donation consumed the pools before the failure:
+                # every sequence's KV is gone — engine-fatal
+                raise
+            self._retire(req, FAILED, e)
+            self.metrics.on_failed()
+            return None
         self.cache.pools = pools
         req.prefilled_tokens = start + n
         self.metrics.on_prefill_chunk(n)
@@ -1173,7 +1197,20 @@ class ServingEngine:
         syncs accumulate into ``serving_analysis_host_syncs_total``) and is
         followed by a ``PagedKVCache.check_invariants()`` sweep; the
         CompileGuards are strict, so an unexpected retrace or donation
-        misuse raises instead of silently recompiling."""
+        misuse raises instead of silently recompiling.
+
+        With tracing on, the whole call is the ``serve.step`` span of the
+        profiler's trace (module docstring, "Goodput attribution")."""
+        att = self._attr
+        if att is None:
+            return self._step_and_account()
+        att.enter_step(self._step_idx)
+        try:
+            return self._step_and_account()
+        finally:
+            att.exit_step()  # closes serve.account, then serve.step
+
+    def _step_and_account(self) -> list[int]:
         try:
             if self.config.debug_checks:
                 with SyncTally() as tally:
@@ -1190,6 +1227,9 @@ class ServingEngine:
             # black box must survive the crash it exists to explain
             self._on_fatal(e)
             raise
+        # from here to the end of step() is the rest of serve.account
+        # (opened in _step): in the profiler's trace only, the step's
+        # record is closed
         retraces = sum(g.retraces for g in
                        (*self.guards.values(), *self.cache.guards.values()))
         # the counters are pre-seeded at 0, so the non-debug hot loop only
@@ -1212,10 +1252,6 @@ class ServingEngine:
             for phase, secs in record.phase_s.items():
                 if secs > 0:
                     self.metrics.on_phase(phase, secs)
-            # roofline gauges: recomputed only when new measurements
-            # landed against an audited program (one boolean check
-            # otherwise) — host floats, zero device syncs
-            self._roofline.publish(self.metrics)
             # anomaly watchdogs: edge-triggered rules over the step
             # record + already-host-resident counter totals
             if self._watchdog is not None:
@@ -1238,8 +1274,6 @@ class ServingEngine:
         return finished
 
     def _step(self) -> list[int]:
-        from .. import profiler
-
         # the ONLY injector read of the step (pinned by a test): the
         # uninstalled path costs one attribute lookup and None-checks
         inj = self._fault_injector
@@ -1250,33 +1284,38 @@ class ServingEngine:
             slow = inj.hit("slow_step", step=step_idx)
             if slow is not None:
                 self._skew += slow.delay_s
-        self._sweep_deadlines()
 
-        # goodput attribution: the phase accumulator opens with the step
-        # and every phase boundary below stamps a clock-read mark — the
-        # per-phase seconds sum EXACTLY to the step's wall time. None
-        # with tracing off (one attribute check per site).
+        # goodput attribution: every boundary below is ONE site that
+        # writes both records (obs/attribution.py) — a serve.* span in
+        # the profiler's trace and seconds on the engine clock; the
+        # phases' seconds sum EXACTLY to the step's wall time. None with
+        # tracing off: one check per site, no span object.
         att = self._attr
-        t_start = att.begin() if att is not None else 0.0
         preempt0 = self.scheduler.preemption_count
         n_prefills = n_active = 0
         finished_now = []
-        # a paused engine (run(budget_s=) drain) admits no NEWCOMERS, but
-        # still resumes preemption victims — they are in-flight work.
-        # Under SLO degradation, warm prefix-cache waiters jump cold ones
-        # (their uncached tail barely touches the throttled chunk budget).
-        admitted = self.scheduler.admit(
-            resume_only=self.admit_paused,
-            prefer_cached=self._slo is not None and self._slo.degraded)
-        # a failed host-tier restore (restore_fail injection or a real
-        # scatter error) aborted that request's admission cleanly — the
-        # stale tier entries are dropped, the pool state is the pre-admit
-        # state: retire it FAILED and keep serving everyone else
-        for req, err in self.scheduler.pop_restore_failures():
-            self._retire(req, FAILED, err)
-            self.metrics.on_failed()
-        if att is not None:
-            att.mark("admit")  # deadline sweep + admission + restores
+        with (att.span("admit", queue_depth=self.scheduler.queue_depth)
+              if att is not None else NO_SPAN):
+            self._sweep_deadlines()
+            # the step's record opens here (after the sweep, as it always
+            # has: a deadline is read off the clock before the record is)
+            t_start = att.begin() if att is not None else 0.0
+            # a paused engine (run(budget_s=) drain) admits no NEWCOMERS,
+            # but still resumes preemption victims — they are in-flight
+            # work. Under SLO degradation, warm prefix-cache waiters jump
+            # cold ones (their uncached tail barely touches the throttled
+            # chunk budget).
+            admitted = self.scheduler.admit(
+                resume_only=self.admit_paused,
+                prefer_cached=self._slo is not None and self._slo.degraded)
+            # a failed host-tier restore (restore_fail injection or a real
+            # scatter error) aborted that request's admission cleanly —
+            # the stale tier entries are dropped, the pool state is the
+            # pre-admit state: retire it FAILED and keep serving everyone
+            # else
+            for req, err in self.scheduler.pop_restore_failures():
+                self._retire(req, FAILED, err)
+                self.metrics.on_failed()
         for req in admitted:
             if req.generated:  # swap-resume: KV restored by admit(); there
                 slot = req.slot   # is no prefill here for prefill_fail to hit
@@ -1294,7 +1333,7 @@ class ServingEngine:
                     tr.event(req.rid, "swap_in", tokens=len(req.generated))
                     tr.event(req.rid, "resumed", tokens=len(req.generated))
                 if att is not None:
-                    att.mark("swap")
+                    att.mark("swap")  # seconds only: a few host writes
                 continue
             if inj is not None and \
                     inj.hit("prefill_fail", step=step_idx, rid=req.rid):
@@ -1338,86 +1377,21 @@ class ServingEngine:
                 if att is not None:
                     att.mark("admit")  # PREFILLING handoff is admission
                 continue
-            with profiler.RecordEvent("serving::prefill"):
-                # prefix-cache hit: only the uncached tail is prefilled,
-                # padded to the smallest bucket that holds it
-                cached = req.cached_tokens
-                tail = req.prompt[cached:]
-                bucket = next(b for b in self.prefill_buckets
-                              if b >= len(tail))
-                padded = np.full(bucket, self.config.pad_token_id, np.int32)
-                padded[:len(tail)] = tail
-                tr = self._tracer
-                if tr is not None:
-                    tr.event(req.rid, "prefill_start", tokens=len(tail),
-                             cached=cached, bucket=bucket)
-                args = (self._p, self.cache.pools, jnp.asarray(padded),
-                        jnp.asarray(len(tail), jnp.int32),
-                        jnp.asarray(cached, jnp.int32),
-                        jnp.asarray(self.cache.page_table[req.slot]),
-                        jnp.asarray(req.rid, jnp.int32))
-                if self.config.debug_checks:
-                    self._audit_step(self._prefill_jit, args,
-                                     f"prefill[{bucket}]")
-                try:
-                    pools, tok = self._prefill_jit(*args)
-                except Exception as e:  # noqa: BLE001 — isolate the request
-                    if isinstance(e, (RetraceError, DonationViolation)):
-                        # a strict-guard refusal is an AUDIT failure — the
-                        # contract debug_checks exists to surface — not a
-                        # request-level fault to retire and serve past
-                        raise
-                    if any(arr.is_deleted() for pl in self.cache.pools
-                           for arr in pl.values()):
-                        # the failure landed after donation consumed the
-                        # pools: every sequence's KV is gone, so "retire one
-                        # request and keep serving" would hand the rest
-                        # deleted buffers — engine-fatal, not isolable
-                        raise
-                    self._retire(req, FAILED, e)
-                    self.metrics.on_failed()
-                    if att is not None:
-                        att.mark("prefill")  # the failed attempt's time
-                    continue
-            self.cache.pools = pools
-            # the prefill's sanctioned device->host sync: its first-token
-            # fetch, routed through the same np.asarray site PT005 polices
-            # (a bare int() coercion would sync invisibly to the linter)
-            tok = int(np.asarray(tok))  # lint: disable=PT005
-            req.generated.append(tok)
-            req.tokens_emitted += 1
-            self._ctx[req.slot] = req.prompt_len
-            self._last_tok[req.slot] = tok
-            self._active[req.slot] = True
-            self._rids[req.slot] = req.rid
-            self._gen[req.slot] = 1
-            req.fresh = True
-            self._hist_sync(req)
-            n_prefills += 1
-            if tr is not None:
-                # prefill_end IS first-token time: the prefill pass samples
-                # the request's first output token from its last logit
-                tr.event(req.rid, "prefill_end", tokens=len(tail))
-                tr.event(req.rid, "first_token")
-            # every full prompt page is now resident: index it for reuse
-            self.cache.register_prefix(req.slot, req.prompt)
-            self.metrics.on_prefill(len(tail))
-            if self.config.enable_prefix_caching:
-                if cached > 0:
-                    self.metrics.on_prefix_hit(cached)
-                else:
-                    self.metrics.on_prefix_miss()
-            self.metrics.on_tokens(1)
-            if att is not None:
-                # this iteration's interval is this request's prefill
-                # (dispatch + the sanctioned first-token fetch, which is
-                # where the device time lands) — phase-attributed and
-                # fed to the roofline tracker under the program's audit
-                # label
-                self._roofline.on_call(f"prefill[{bucket}]",
-                                       att.mark("prefill"))
-            if self._maybe_finish(req, tok):
-                finished_now.append(req.rid)
+            # prefix-cache hit: only the uncached tail is prefilled,
+            # padded to the smallest bucket that holds it. This
+            # iteration's interval is this request's prefill (a failed
+            # attempt's time too).
+            cached = req.cached_tokens
+            tail = req.prompt[cached:]
+            bucket = next(b for b in self.prefill_buckets if b >= len(tail))
+            with (att.span("prefill", rid=req.rid, bucket=bucket,
+                           cached=cached, tail=len(tail))
+                  if att is not None else NO_SPAN):
+                tok = self._prefill_request(req, cached, tail, bucket)
+                if tok is not None:
+                    n_prefills += 1
+                    if self._maybe_finish(req, tok):
+                        finished_now.append(req.rid)
 
         # ---- chunked prefill phase: every PREFILLING request advances one
         # chunk through the SAME prefill program, oldest admitted first,
@@ -1432,113 +1406,54 @@ class ServingEngine:
                 (r for r in self.scheduler.running.values()
                  if r.state == PREFILLING),
                 key=lambda r: r.admit_seq)
-            for req in prefilling[:limit]:
-                if inj is not None and \
-                        inj.hit("chunk_fail", step=step_idx, rid=req.rid):
-                    # before the chunk touches the pools: the partial
-                    # prefill's pages drain with the retirement, survivors
-                    # keep prefilling/decoding this very step
-                    self._retire(req, FAILED, InjectedFault(
-                        f"chunk_fail injected (step {step_idx}, "
-                        f"rid {req.rid})"))
-                    self.metrics.on_failed()
-                    continue
-                tok = self._prefill_chunk(req)
-                n_chunks += 1
-                if tok is not None:  # final chunk: first token sampled
-                    n_prefills += 1
-                    if self._maybe_finish(req, tok):
-                        finished_now.append(req.rid)
-            if att is not None and (n_chunks or prefilling):
-                att.mark("chunk_prefill")
+            if prefilling:
+                with (att.span("chunk_prefill",
+                               chunks=len(prefilling[:limit]))
+                      if att is not None else NO_SPAN):
+                    for req in prefilling[:limit]:
+                        if inj is not None and inj.hit(
+                                "chunk_fail", step=step_idx, rid=req.rid):
+                            # before the chunk touches the pools: the
+                            # partial prefill's pages drain with the
+                            # retirement, survivors keep prefilling /
+                            # decoding this very step
+                            self._retire(req, FAILED, InjectedFault(
+                                f"chunk_fail injected (step {step_idx}, "
+                                f"rid {req.rid})"))
+                            self.metrics.on_failed()
+                            continue
+                        tok = self._prefill_chunk(req)
+                        n_chunks += 1
+                        if tok is not None:  # final chunk: first token
+                            n_prefills += 1
+                            if self._maybe_finish(req, tok):
+                                finished_now.append(req.rid)
 
-        if inj is not None:
-            for slot in np.nonzero(self._active)[0]:
-                req = self.scheduler.running.get(int(slot))
-                if req is None:
-                    continue
-                if inj.hit("decode_fail", step=step_idx, rid=req.rid):
-                    # before the decode launches: the failed request leaves,
-                    # the rest of the batch decodes normally this very step
-                    self._retire(req, FAILED, InjectedFault(
-                        f"decode_fail injected (step {step_idx}, "
-                        f"rid {req.rid})"))
-                    self.metrics.on_failed()
-                    continue
-                if self._spec is not None and \
-                        inj.hit("verify_fail", step=step_idx, rid=req.rid):
-                    # before the verify dispatch: the faulted request
-                    # retires FAILED with its pages — including any
-                    # speculative over-reservation — draining via the
-                    # normal evict path (the draft proposer holds no
-                    # per-request state to clean); survivors verify this
-                    # very step
-                    self._retire(req, FAILED, InjectedFault(
-                        f"verify_fail injected (step {step_idx}, "
-                        f"rid {req.rid})"))
-                    self.metrics.on_failed()
-            if self.scheduler.running and \
-                    inj.hit("pool_exhausted", step=step_idx):
-                self._preempt_one(self.scheduler.pick_victim())
-
-        for req, slot in self.scheduler.ensure_decode_pages():
-            self._preempt_one(req, slot)
-        if att is not None:
-            # injected faults + decode-page pressure: preemption, swap-out
-            # and eviction sweeps all happen in this window
-            att.mark("evict")
+        # injected faults + decode-page pressure: preemption, swap-out and
+        # eviction sweeps all happen in this window
+        with (att.span("evict") if att is not None else NO_SPAN):
+            if inj is not None:
+                self._inject_decode_faults(inj, step_idx)
+            for req, slot in self.scheduler.ensure_decode_pages():
+                self._preempt_one(req, slot)
 
         n_accepted = 0
-        if self._active.any() and self._spec is not None:
-            # speculative decoding: the verify step replaces plain decode
-            # wholesale — one batched K+1-token ragged pass, one packed
-            # fetch, 1..K+1 tokens emitted per slot
-            n_active, n_accepted = self._verify_phase(finished_now)
-        elif self._active.any():
-            with profiler.RecordEvent("serving::decode"):
-                args = (self._p, self.cache.pools,
-                        jnp.asarray(self.cache.page_table),
-                        jnp.asarray(self._ctx), jnp.asarray(self._last_tok),
-                        jnp.asarray(self._active), jnp.asarray(self._rids),
-                        jnp.asarray(self._gen))
-                if self.config.debug_checks:
-                    self._audit_step(self._decode_jit, args, "decode")
-                pools, toks = self._decode_jit(*args)
-            self.cache.pools = pools
-            # the step's ONE sanctioned device->host sync: the token fetch
-            toks = np.asarray(toks)  # lint: disable=PT005
-            self.metrics.on_decode_step()
-            n_new = 0
-            tr = self._tracer
-            for slot in np.nonzero(self._active)[0]:
-                req = self.scheduler.running[int(slot)]
-                tok = int(toks[slot])
-                req.generated.append(tok)
-                req.tokens_emitted += 1
-                req.fresh = False  # it has decoded: fair game for preemption
-                self._ctx[slot] += 1
-                self._last_tok[slot] = tok
-                self._gen[slot] += 1
-                n_new += 1
-                if tr is not None and \
-                        len(req.generated) % tr.mark_every == 0:
-                    tr.event(req.rid, "decode_mark",
-                             tokens=len(req.generated))
-                if self._maybe_finish(req, tok):
-                    finished_now.append(req.rid)
-            self.metrics.on_tokens(n_new)
-            n_active = n_new
-            if att is not None:
-                # decode phase: dispatch + the sanctioned token fetch
-                # (where the device time lands) + per-slot bookkeeping.
-                # The same interval feeds the roofline tracker and — for
-                # the kernel-eligible decode dispatch — the predicted-vs-
-                # measured kernel A/B, on the leg the gate chose.
-                dt = att.mark("decode")
-                self._roofline.on_call("decode", dt)
-                self._roofline.on_kernel_call(self._kernel_ab_name, dt,
-                                              self._decode_pallas_eligible)
+        if self._active.any():
+            if self._spec is not None:
+                # speculative decoding: the verify step replaces plain
+                # decode wholesale — one batched K+1-token ragged pass,
+                # one packed fetch, 1..K+1 tokens emitted per slot (one
+                # span, no parts: no benchmark cell runs it yet)
+                with (att.span("verify", batch=int(self._active.sum()))
+                      if att is not None else NO_SPAN):
+                    n_active, n_accepted = self._verify_phase(finished_now)
+            else:
+                n_active = self._decode_phase(finished_now)
 
+        # serve.account: the obs layer's own cost per step. It outlives
+        # the record (closed a few lines down) and ends with step().
+        if att is not None:
+            att.account()
         cs = self.cache.stats()
         self.metrics.on_state(
             queue_depth=self.scheduler.queue_depth,
@@ -1568,8 +1483,167 @@ class ServingEngine:
                 "preemptions": self.scheduler.preemption_count - preempt0,
                 "queue_depth": self.scheduler.queue_depth,
                 "pages_in_use": cs["pages_in_use"],
-                "phase_s": phase_s}
+                "phase_s": phase_s, "span_s": att.span_s}
         return finished_now
+
+    def _prefill_request(self, req: Request, cached: int, tail, bucket: int
+                         ) -> int | None:
+        """One admitted request's whole uncached tail through the prefill
+        program of its pad bucket, inside the caller's ``serve.prefill``
+        span: upload, dispatch, the sanctioned first-token fetch (where
+        the device time lands), then the slot's bookkeeping. Returns the
+        first generated token; a request-local failure retires the
+        request FAILED and returns None (engine-fatal failures
+        re-raise)."""
+        att, tr = self._attr, self._tracer
+        page_row = self.cache.page_table[req.slot]
+        with (att.span("prefill.upload", rid=req.rid,
+                       bytes=4 * bucket + page_row.nbytes + 12)
+              if att is not None else NO_SPAN):
+            padded = np.full(bucket, self.config.pad_token_id, np.int32)
+            padded[:len(tail)] = tail
+            args = (self._p, self.cache.pools, jnp.asarray(padded),
+                    jnp.asarray(len(tail), jnp.int32),
+                    jnp.asarray(cached, jnp.int32),
+                    jnp.asarray(page_row),
+                    jnp.asarray(req.rid, jnp.int32))
+        if tr is not None:
+            tr.event(req.rid, "prefill_start", tokens=len(tail),
+                     cached=cached, bucket=bucket)
+        if self.config.debug_checks:
+            self._audit_step(self._prefill_jit, args, f"prefill[{bucket}]")
+        try:
+            with (att.span("prefill.dispatch", rid=req.rid)
+                  if att is not None else NO_SPAN):
+                pools, tok = self._prefill_jit(*args)
+        except Exception as e:  # noqa: BLE001 — isolate the request
+            if isinstance(e, (RetraceError, DonationViolation)):
+                # a strict-guard refusal is an AUDIT failure — the
+                # contract debug_checks exists to surface — not a
+                # request-level fault to retire and serve past
+                raise
+            if any(arr.is_deleted() for pl in self.cache.pools
+                   for arr in pl.values()):
+                # the failure landed after donation consumed the pools:
+                # every sequence's KV is gone, so "retire one request and
+                # keep serving" would hand the rest deleted buffers —
+                # engine-fatal, not isolable
+                raise
+            self._retire(req, FAILED, e)
+            self.metrics.on_failed()
+            return None
+        self.cache.pools = pools
+        # the prefill's sanctioned device->host sync: its first-token
+        # fetch, routed through the same np.asarray site PT005 polices
+        # (a bare int() coercion would sync invisibly to the linter)
+        with (att.span("prefill.fetch", rid=req.rid)
+              if att is not None else NO_SPAN):
+            tok = int(np.asarray(tok))  # lint: disable=PT005
+        req.generated.append(tok)
+        req.tokens_emitted += 1
+        self._ctx[req.slot] = req.prompt_len
+        self._last_tok[req.slot] = tok
+        self._active[req.slot] = True
+        self._rids[req.slot] = req.rid
+        self._gen[req.slot] = 1
+        req.fresh = True
+        self._hist_sync(req)
+        if tr is not None:
+            # prefill_end IS first-token time: the prefill pass samples
+            # the request's first output token from its last logit
+            tr.event(req.rid, "prefill_end", tokens=len(tail))
+            tr.event(req.rid, "first_token")
+        # every full prompt page is now resident: index it for reuse
+        self.cache.register_prefix(req.slot, req.prompt)
+        self.metrics.on_prefill(len(tail))
+        if self.config.enable_prefix_caching:
+            if cached > 0:
+                self.metrics.on_prefix_hit(cached)
+            else:
+                self.metrics.on_prefix_miss()
+        self.metrics.on_tokens(1)
+        return tok
+
+    def _inject_decode_faults(self, inj, step_idx: int) -> None:
+        """The armed injector's step-boundary consults before the decode
+        (or verify) launches: ``decode_fail`` / ``verify_fail`` retire the
+        named requests FAILED, ``pool_exhausted`` preempts a victim."""
+        for slot in np.nonzero(self._active)[0]:
+            req = self.scheduler.running.get(int(slot))
+            if req is None:
+                continue
+            if inj.hit("decode_fail", step=step_idx, rid=req.rid):
+                # before the decode launches: the failed request leaves,
+                # the rest of the batch decodes normally this very step
+                self._retire(req, FAILED, InjectedFault(
+                    f"decode_fail injected (step {step_idx}, "
+                    f"rid {req.rid})"))
+                self.metrics.on_failed()
+                continue
+            if self._spec is not None and \
+                    inj.hit("verify_fail", step=step_idx, rid=req.rid):
+                # before the verify dispatch: the faulted request
+                # retires FAILED with its pages — including any
+                # speculative over-reservation — draining via the
+                # normal evict path (the draft proposer holds no
+                # per-request state to clean); survivors verify this
+                # very step
+                self._retire(req, FAILED, InjectedFault(
+                    f"verify_fail injected (step {step_idx}, "
+                    f"rid {req.rid})"))
+                self.metrics.on_failed()
+        if self.scheduler.running and \
+                inj.hit("pool_exhausted", step=step_idx):
+            self._preempt_one(self.scheduler.pick_victim())
+
+    def _decode_phase(self, finished_now: list) -> int:
+        """One decode step for the whole batch, as the ``serve.decode``
+        span and its four parts: the six operands uploaded (the whole
+        page table among them), the dispatch, the step's ONE sanctioned
+        device->host sync (the token fetch, where the device time lands),
+        and the per-slot bookkeeping. Returns the slots that decoded."""
+        att = self._attr
+        with (att.span("decode", batch=int(self._active.sum()))
+              if att is not None else NO_SPAN):
+            with (att.span("decode.upload", bytes=self._decode_upload_bytes)
+                  if att is not None else NO_SPAN):
+                args = (self._p, self.cache.pools,
+                        jnp.asarray(self.cache.page_table),
+                        jnp.asarray(self._ctx), jnp.asarray(self._last_tok),
+                        jnp.asarray(self._active), jnp.asarray(self._rids),
+                        jnp.asarray(self._gen))
+            if self.config.debug_checks:
+                self._audit_step(self._decode_jit, args, "decode")
+            with (att.span("decode.dispatch")
+                  if att is not None else NO_SPAN):
+                pools, toks = self._decode_jit(*args)
+            self.cache.pools = pools
+            with (att.span("decode.fetch")
+                  if att is not None else NO_SPAN):
+                toks = np.asarray(toks)  # lint: disable=PT005
+            self.metrics.on_decode_step()
+            n_new = 0
+            tr = self._tracer
+            with (att.span("decode.emit")
+                  if att is not None else NO_SPAN):
+                for slot in np.nonzero(self._active)[0]:
+                    req = self.scheduler.running[int(slot)]
+                    tok = int(toks[slot])
+                    req.generated.append(tok)
+                    req.tokens_emitted += 1
+                    req.fresh = False  # it has decoded: preemptible now
+                    self._ctx[slot] += 1
+                    self._last_tok[slot] = tok
+                    self._gen[slot] += 1
+                    n_new += 1
+                    if tr is not None and \
+                            len(req.generated) % tr.mark_every == 0:
+                        tr.event(req.rid, "decode_mark",
+                                 tokens=len(req.generated))
+                    if self._maybe_finish(req, tok):
+                        finished_now.append(req.rid)
+                self.metrics.on_tokens(n_new)
+        return n_new
 
     def _verify_phase(self, finished_now: list) -> tuple[int, int]:
         """The speculative twin of the decode phase: ONE verify dispatch
@@ -1579,22 +1653,19 @@ class ServingEngine:
         (1..K+1 tokens) and the pages its rejected span over-reserved
         recycle through the refcounted allocator. Returns (active slots,
         candidates accepted)."""
-        from .. import profiler
-
         cfg = self.config
         K = self._spec.depth
         tr = self._tracer
-        with profiler.RecordEvent("serving::verify"):
-            args = (self._p, self.cache.pools,
-                    jnp.asarray(self.cache.page_table),
-                    jnp.asarray(self._ctx), jnp.asarray(self._last_tok),
-                    jnp.asarray(self._active), jnp.asarray(self._rids),
-                    jnp.asarray(self._gen), jnp.asarray(self._spec_hist()))
-            if self._spec.method == "draft":
-                args = args + (self._draft_p,)
-            if cfg.debug_checks:
-                self._audit_step(self._verify_jit, args, "verify")
-            pools, packed = self._verify_jit(*args)
+        args = (self._p, self.cache.pools,
+                jnp.asarray(self.cache.page_table),
+                jnp.asarray(self._ctx), jnp.asarray(self._last_tok),
+                jnp.asarray(self._active), jnp.asarray(self._rids),
+                jnp.asarray(self._gen), jnp.asarray(self._spec_hist()))
+        if self._spec.method == "draft":
+            args = args + (self._draft_p,)
+        if cfg.debug_checks:
+            self._audit_step(self._verify_jit, args, "verify")
+        pools, packed = self._verify_jit(*args)
         self.cache.pools = pools
         # the step's ONE sanctioned device->host sync: the packed
         # (target tokens, accept count) fetch
@@ -1644,16 +1715,6 @@ class ServingEngine:
                        req.tokens_resident] = req.generated[-emitted:]
         self.metrics.on_tokens(n_new)
         self.metrics.on_spec(proposed=K * n_slots, accepted=n_accepted)
-        if self._attr is not None:
-            # verify phase: the batched K+1 dispatch + packed fetch +
-            # accept bookkeeping, roofline-tracked under its audit label
-            # AND — the K+1 contract being unified-kernel-eligible — fed
-            # to the ragged_paged_verify A/B leg
-            dt = self._attr.mark("verify")
-            self._roofline.on_call("verify", dt)
-            if self._verify_ab_name is not None:
-                self._roofline.on_kernel_call(self._verify_ab_name, dt,
-                                              self._verify_pallas_eligible)
         return n_slots, n_accepted
 
     def run(self, max_steps: int = 100000,
@@ -1793,7 +1854,7 @@ class ServingEngine:
                     preemptions=0,
                     queue_depth=self.scheduler.queue_depth,
                     pages_in_use=self.cache.allocator.pages_in_use,
-                    phase_s=phase_s, extra=fatal))
+                    phase_s=phase_s, span_s=att.span_s, extra=fatal))
                 self._step_stats = None
             elif self._timeline is not None and self._step_stats is not None:
                 # _step completed (attribution closed, full stats built)
@@ -1850,13 +1911,6 @@ class ServingEngine:
             collective_ops=len(report.collectives),
             host_transfers=len(report.host_transfers),
             peak_hbm_bytes=report.peak_bytes, flops=report.flops)
-        if self._roofline is not None:
-            # the roofline tracker's prediction side: this audit IS the
-            # engine's analytic cost model for the program — no second
-            # lowering, serving_mfu / serving_cost_model_drift{program=}
-            # divide measured dispatch time by exactly these numbers
-            self._roofline.on_program(label, report.flops,
-                                      report.peak_bytes)
         if self._tp is not None:
             # the EQuARX baseline gauges, fed straight from the census:
             # collective ops per step and collective bytes per token this

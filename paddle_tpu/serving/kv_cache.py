@@ -69,6 +69,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ..obs import NO_SPAN
+
 NULL_PAGE = 0
 _RESERVED_PAGES = 1  # page 0 = null page
 
@@ -470,6 +472,10 @@ class PagedKVCache:
         # restore (the ``restore_fail`` fault point); None costs one
         # attribute check per admission that would restore
         self.restore_fault = None
+        # engine-installed span source (obs.PhaseAccumulator): the
+        # copy-on-write page copy is the serve.cow_copy span of the
+        # engine's step. None (tracing off, or no engine) costs one check.
+        self.spans = None
         self._build_jits()
 
     @property
@@ -889,9 +895,9 @@ class PagedKVCache:
         """Jitted donated single-page pool copy (the COW data move)."""
         import jax.numpy as jnp
 
-        from .. import profiler
-
-        with profiler.RecordEvent("serving::cow_copy"):
+        spans = self.spans
+        with (spans.span("cow_copy", pages=1) if spans is not None
+              else NO_SPAN):
             self.pools = self._copy_jit(
                 self.pools, jnp.asarray(src, jnp.int32),
                 jnp.asarray(dst, jnp.int32))

@@ -163,21 +163,6 @@ a sampled gauge misses.
 
 Goodput attribution + watchdogs + flight recorder (PR 12):
 
-- serving_mfu                     gauge: achieved flops/s over the
-                                  audited programs' measured dispatch
-                                  time / device peak (0 until debug
-                                  audits supply the flops model)
-- serving_hbm_bw_util             gauge: same for the HBM byte roll-up
-                                  against peak memory bandwidth
-- serving_cost_model_drift{program=}  stat_max family: measured mean
-                                  step time / roofline-predicted time
-                                  per compiled program
-- serving_kernel_speedup_predicted{kernel=}  kernelcheck's banked
-                                  predicted speedup, surfaced live
-- serving_kernel_speedup_measured{kernel=}   measured composite/kernel
-                                  dispatch-time ratio once both paths
-                                  have served traffic
-- serving_kernel_speedup_drift{kernel=}      measured / predicted
 - serving_step_phase_s{phase=}    histogram family: per-phase step
                                   wall-time attribution (admit / swap /
                                   prefill / chunk_prefill / decode /
@@ -286,7 +271,7 @@ _SEEDED = ("tokens_total", "prefills_total", "prefill_tokens_total",
            "ici_bytes_per_token", "dcn_bytes_per_token",
            "collective_time_predicted_s",
            "tokens_per_sec", "queue_depth", "active_requests",
-           "page_pool_used", "page_utilization", "mfu", "hbm_bw_util",
+           "page_pool_used", "page_utilization",
            "fleet_replicas", "fleet_prefix_affinity_hits_total",
            "fleet_spills_total",
            "fleet_goodput_tokens_total", "fleet_inflight_exchanges",
@@ -307,10 +292,6 @@ _SEEDED = ("tokens_total", "prefills_total", "prefill_tokens_total",
 _FAMILIES = {
     "step_phase_s": "phase",              # histogram family (below)
     "alerts_total": "rule",               # counter: watchdog firings
-    "cost_model_drift": "program",        # stat_max: measured/predicted
-    "kernel_speedup_predicted": "kernel",  # banked kernelcheck contract
-    "kernel_speedup_measured": "kernel",   # live composite/kernel ratio
-    "kernel_speedup_drift": "kernel",      # measured / predicted
     "tenant_goodput_tokens_total": "tenant",   # in_slo tokens per tenant
     "tenant_badput_tokens_total": "tenant",    # everything-else tokens
     "tenant_retired_total": ("tenant", "class"),  # retirements per
@@ -673,38 +654,6 @@ class ServingMetrics:
         zero-time phases are not observed — the StepRecord keeps the
         exact split)."""
         self.phase_hist.observe(phase, seconds)
-
-    def on_roofline(self, mfu: float, hbm_bw_util: float) -> None:
-        """The live roofline gauges, recomputed from measured dispatch
-        time against the engine's own hlocheck audits."""
-        monitor.stat_set(PREFIX + "mfu", float(mfu))
-        monitor.stat_set(PREFIX + "hbm_bw_util", float(hbm_bw_util))
-
-    def on_drift(self, program: str, ratio: float) -> None:
-        """Measured/predicted step-time ratio for one compiled program —
-        a high-watermark, so the worst drift ever seen survives
-        sampling."""
-        monitor.stat_max(PREFIX + f"cost_model_drift{{program={program}}}",
-                         float(ratio))
-
-    def on_kernel_ab(self, kernel: str, predicted: float | None = None,
-                     measured: float | None = None,
-                     drift: float | None = None) -> None:
-        """One kernel's predicted-vs-measured speedup A/B: kernelcheck's
-        banked prediction beside the live composite/kernel dispatch-time
-        ratio (absent until both paths have served traffic)."""
-        if predicted is not None:
-            monitor.stat_set(
-                PREFIX + f"kernel_speedup_predicted{{kernel={kernel}}}",
-                float(predicted))
-        if measured is not None:
-            monitor.stat_set(
-                PREFIX + f"kernel_speedup_measured{{kernel={kernel}}}",
-                float(measured))
-        if drift is not None:
-            monitor.stat_set(
-                PREFIX + f"kernel_speedup_drift{{kernel={kernel}}}",
-                float(drift))
 
     def on_alert(self, rule: str) -> None:
         """One watchdog firing (the rule's family member is pre-seeded

@@ -13,6 +13,8 @@ import math
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
+import jax
+
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn import functional as F
@@ -225,15 +227,17 @@ class GPTAttention(nn.Layer):
             # head absmax scales), dequantize inside the attention gather —
             # the ragged mask, page tables, and everything downstream stay
             # byte-for-byte layout-blind (serving/kv_cache.py kv_dtype)
-            k_pool, v_pool, k_sc, v_sc = pa.paged_write_quant(
-                k_pool, v_pool, cache["k_scale"], cache["v_scale"],
-                k_new, v_new, page_ids, offsets)
+            with jax.named_scope("kv_write"):
+                k_pool, v_pool, k_sc, v_sc = pa.paged_write_quant(
+                    k_pool, v_pool, cache["k_scale"], cache["v_scale"],
+                    k_new, v_new, page_ids, offsets)
             out = pa.paged_attention(q, k_pool, v_pool, table, ctx,
                                      k_scale=k_sc, v_scale=v_sc)
             scales = {"k_scale": k_sc, "v_scale": v_sc}
         else:
-            k_pool, v_pool = pa.paged_write(k_pool, v_pool, k_new, v_new,
-                                            page_ids, offsets)
+            with jax.named_scope("kv_write"):
+                k_pool, v_pool = pa.paged_write(k_pool, v_pool, k_new, v_new,
+                                                page_ids, offsets)
             out = pa.paged_attention(q, k_pool, v_pool, table, ctx)
             scales = {}
         # -1, not h: under tensor parallelism the local heads span h / tp
@@ -277,13 +281,21 @@ class GPTBlock(nn.Layer):
         self.dropout = nn.Dropout(cfg.dropout)
 
     def forward(self, x, attn_mask=None, cache=None, pos=None):
+        # block/attn and block/mlp (LayerNorm and residual add included)
+        # reach every operation's op_name: metadata only, the compiled
+        # program is the same
         if cache is not None:
-            a, new_cache = self.attn(self.ln1(x), attn_mask, cache=cache, pos=pos)
-            x = x + a
-            x = x + self.mlp(self.ln2(x))
+            with jax.named_scope("block/attn"):
+                a, new_cache = self.attn(self.ln1(x), attn_mask,
+                                         cache=cache, pos=pos)
+                x = x + a
+            with jax.named_scope("block/mlp"):
+                x = x + self.mlp(self.ln2(x))
             return x, new_cache
-        x = x + self.dropout(self.attn(self.ln1(x), attn_mask))
-        x = x + self.mlp(self.ln2(x))
+        with jax.named_scope("block/attn"):
+            x = x + self.dropout(self.attn(self.ln1(x), attn_mask))
+        with jax.named_scope("block/mlp"):
+            x = x + self.mlp(self.ln2(x))
         return x
 
 
@@ -337,8 +349,9 @@ class GPTModel(nn.Layer):
                 off = sp_local_offset(s)  # global positions when sequence-parallel
                 if not isinstance(off, int) or off != 0:
                     position_ids = position_ids + off
-        x = self.wte(input_ids) + self.wpe(position_ids)
-        x = self.drop(x)
+        with jax.named_scope("embed"):
+            x = self.wte(input_ids) + self.wpe(position_ids)
+            x = self.drop(x)
         if caches is not None:
             if attn_mask is not None:
                 raise NotImplementedError(
@@ -349,7 +362,8 @@ class GPTModel(nn.Layer):
             for blk, cache in zip(self.blocks, caches):
                 x, nc = blk(x, None, cache=cache, pos=pos)
                 new_caches.append(nc)
-            return self.ln_f(x), new_caches
+            with jax.named_scope("final_norm"):
+                return self.ln_f(x), new_caches
         if self.cfg.recompute:
             from ..distributed.fleet.recompute import recompute
 
@@ -359,7 +373,8 @@ class GPTModel(nn.Layer):
         else:
             for blk in self.blocks:
                 x = blk(x, attn_mask)
-        return self.ln_f(x)
+        with jax.named_scope("final_norm"):
+            return self.ln_f(x)
 
 
 class GPTForCausalLM(nn.Layer):
@@ -382,26 +397,29 @@ class GPTForCausalLM(nn.Layer):
             h, new_caches = self.gpt(input_ids, attn_mask, caches=caches, pos=pos)
             from ..tensor_ops.math import matmul
 
-            if _TP_AXIS is not None:
-                # hidden-contraction-sharded LM head: one all-reduce of the
-                # logits, head FLOPs split across the mesh
-                w = (self.lm_head.weight if self.lm_head is not None
-                     else self.gpt.wte.weight)
-                return _tp_logits(h, w, self.lm_head is None), new_caches
-            if self.lm_head is not None:
-                return self.lm_head(h), new_caches
-            return matmul(h, self.gpt.wte.weight, transpose_y=True), new_caches
+            with jax.named_scope("lm_head"):
+                if _TP_AXIS is not None:
+                    # hidden-contraction-sharded LM head: one all-reduce of
+                    # the logits, head FLOPs split across the mesh
+                    w = (self.lm_head.weight if self.lm_head is not None
+                         else self.gpt.wte.weight)
+                    return _tp_logits(h, w, self.lm_head is None), new_caches
+                if self.lm_head is not None:
+                    return self.lm_head(h), new_caches
+                return matmul(h, self.gpt.wte.weight,
+                              transpose_y=True), new_caches
         h = self.gpt(input_ids, attn_mask)
         if labels is not None:
             # Fused head+CE: scans vocab projection in sequence chunks so the
             # [b, s, vocab] logits (3.3 GB fp32 at b16/s1024/v50k) never hit HBM.
-            if self.lm_head is not None:
+            with jax.named_scope("head_ce"):
+                if self.lm_head is not None:
+                    return F.linear_cross_entropy(
+                        h, self.lm_head.weight, labels,
+                        chunk_size=self.cfg.loss_chunk_size)
                 return F.linear_cross_entropy(
-                    h, self.lm_head.weight, labels,
+                    h, self.gpt.wte.weight, labels, transpose_y=True,
                     chunk_size=self.cfg.loss_chunk_size)
-            return F.linear_cross_entropy(
-                h, self.gpt.wte.weight, labels, transpose_y=True,
-                chunk_size=self.cfg.loss_chunk_size)
         if self.lm_head is not None:
             logits = self.lm_head(h)
         else:

@@ -1,14 +1,12 @@
-"""Goodput attribution layer: per-phase step accounting, live MFU /
-roofline drift, anomaly watchdogs, and the black-box flight recorder.
+"""Goodput attribution layer: per-phase step accounting, anomaly
+watchdogs, and the black-box flight recorder (the spans of the same
+mechanism in the profiler's trace: tests/test_serve_spans.py).
 
-Five layers of coverage:
+Four layers of coverage:
 
 - attribution exactness: per-phase times sum to the step's wall time on a
   virtual clock (exact — the PhaseAccumulator mark construction), and the
   phase vocabulary matches what the step actually did.
-- roofline math: MFU / bandwidth-utilization / drift goldens on the
-  tracker alone, then the engine-level gauges computed from the engine's
-  OWN hlocheck audits (no second lowering) under ``debug_checks``.
 - watchdogs: every rule fired deterministically exactly once (synthetic
   step feeds for the windowed rules, live engines for queue_stall and
   pallas_fallback) and quiescent on a clean run; zero added host syncs
@@ -32,8 +30,8 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.analysis import SyncTally
 from paddle_tpu.obs import (ALERT_RULES, PHASES, PhaseAccumulator,
-                            RooflineTracker, StepRecord, Watchdog,
-                            WatchdogConfig, validate_flight_record)
+                            StepRecord, Watchdog, WatchdogConfig,
+                            validate_flight_record)
 from paddle_tpu.obs.__main__ import main as obs_main
 from paddle_tpu.serving import FaultInjector, ServingConfig, ServingEngine
 from paddle_tpu.text.gpt import GPTConfig, GPTForCausalLM
@@ -135,63 +133,6 @@ def test_phase_family_histograms_fed_and_pre_seeded(model):
     prom = engine.metrics.prometheus()
     assert '_bucket{le="' in prom and ',phase="decode"}' in prom
     assert "# TYPE serving_step_phase_s histogram" in prom
-
-
-# ------------------------------------------------------------ roofline math
-def test_roofline_tracker_goldens():
-    rt = RooflineTracker(peak_flops_per_s=100.0, peak_hbm_bytes_per_s=1000.0)
-    rt.on_program("decode", flops=100.0, hbm_bytes=1000.0)
-    assert rt.predicted_step_s("decode") == 1.0  # both roofs bind at 1 s
-    assert rt.predicted_step_s("unknown") is None
-    rt.on_call("decode", 2.0)
-    g = rt.gauges()
-    # 100 flops in 2 s = 50 flops/s against a 100 flops/s peak
-    assert g["mfu"] == pytest.approx(0.5)
-    assert g["hbm_bw_util"] == pytest.approx(0.5)
-    assert g["drift"]["decode"] == pytest.approx(2.0)
-
-
-def test_roofline_kernel_ab_measured_vs_banked():
-    rt = RooflineTracker(banked_kernels={"paged_decode": 1.5})
-    assert rt.gauges()["kernels"]["paged_decode"] == {"predicted": 1.5}
-    rt.on_kernel_call("paged_decode", 1.0, pallas=True)
-    assert "measured" not in rt.gauges()["kernels"]["paged_decode"]
-    rt.on_kernel_call("paged_decode", 3.0, pallas=False)
-    entry = rt.gauges()["kernels"]["paged_decode"]
-    # composite mean 3 s / kernel mean 1 s = 3x measured vs 1.5x banked
-    assert entry["measured"] == pytest.approx(3.0)
-    assert entry["drift"] == pytest.approx(2.0)
-
-
-def test_engine_mfu_and_drift_from_own_audits(model):
-    engine = _engine(model, debug_checks=True)
-    snap = engine.metrics.snapshot()
-    assert snap["serving_mfu"] == 0  # pre-seeded presence
-    assert snap["serving_hbm_bw_util"] == 0
-    assert snap["serving_cost_model_drift{program=decode}"] == 0
-    assert snap["serving_cost_model_drift{program=prefill[8]}"] == 0
-    for i in range(2):
-        engine.add_request(_prompt(5, seed=i), 5)
-    engine.run()
-    snap = engine.metrics.snapshot()
-    # the gauges divide measured dispatch time by the flops/HBM model the
-    # engine's own first-trace hlocheck audits hold — both sides known
-    assert set(engine.hlo_audits) == {"prefill[8]", "decode"}
-    assert snap["serving_mfu"] > 0
-    assert snap["serving_hbm_bw_util"] > 0
-    assert snap["serving_cost_model_drift{program=decode}"] > 0
-    assert snap["serving_cost_model_drift{program=prefill[8]}"] > 0
-
-
-def test_mfu_stays_zero_without_audits(model):
-    # no debug_checks -> no hlocheck audits -> no prediction side: the
-    # gauges stay at their seeded zeros instead of inventing numbers
-    engine = _engine(model)
-    engine.add_request(_prompt(5), 4)
-    engine.run()
-    snap = engine.metrics.snapshot()
-    assert snap["serving_mfu"] == 0
-    assert snap["serving_cost_model_drift{program=decode}"] == 0
 
 
 # --------------------------------------------------------------- watchdogs

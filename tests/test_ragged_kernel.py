@@ -280,28 +280,13 @@ def test_engine_kernel_on_int8_bit_identical(ragged_interpret):
 
 def test_engine_ineligible_stays_composite_with_zero_fallbacks():
     """CPU without the interpret flag: the gate (not a fallback) routes
-    to the composite — the fallback gauge stays at its pre-seeded zero
-    and the A/B predicted gauges are seeded from the bank."""
+    to the composite — the fallback gauge stays at its pre-seeded
+    zero."""
     eng = _mk_engine()
     assert eng._decode_pallas_eligible is False
     _drive(eng, budget=4)
     snap = eng.metrics.snapshot()
     assert snap["serving_pallas_fallback_total"] == 0
-    # the banked unified-kernel predictions seed the A/B gauges
-    pred = snap.get("serving_kernel_speedup_predicted{kernel=ragged_paged}")
-    assert pred is not None and pred > 1.0
-    assert snap.get(
-        "serving_kernel_speedup_predicted{kernel=ragged_paged_q8}") > 1.0
-    # measured legs absent until both dispatch paths have samples
-    assert snap.get(
-        "serving_kernel_speedup_measured{kernel=ragged_paged}", 0.0) == 0.0
-
-
-def test_engine_ab_keys_follow_kv_dtype():
-    eng = _mk_engine()
-    assert eng._kernel_ab_name == "ragged_paged"
-    eng8 = _mk_engine(kv="int8")
-    assert eng8._kernel_ab_name == "ragged_paged_q8"
 
 
 # ------------------------------------------- flash %512 pad-or-fallback
