@@ -366,7 +366,7 @@ def program_custom_calls(engine) -> dict:
     pools = engine.cache.pools
     b = cfg.max_batch
     programs = {"decode": (engine.guards["decode"],
-                           (i32(b, pps), i32(b), i32(b),
+                           (i32(b, pps), i32(b), i32(b), i32(b),
                             jax.ShapeDtypeStruct((b,), jnp.bool_),
                             i32(b), i32(b)))}
     for bucket in SERVE_BUCKETS:

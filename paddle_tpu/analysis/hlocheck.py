@@ -680,11 +680,8 @@ def _build_engine_step(which: str, tensor_parallel: int = 1,
                 jnp.asarray(1, jnp.int32))
         return (eng._prefill_jit, args, None,
                 eng._step_budget(f"prefill[{bucket}]"))
-    args = (eng._p, eng.cache.pools, jnp.asarray(eng.cache.page_table),
-            jnp.asarray(eng._ctx), jnp.asarray(eng._last_tok),
-            jnp.asarray(eng._active), jnp.asarray(eng._rids),
-            jnp.asarray(eng._gen))
-    return eng._decode_jit, args, None, eng._step_budget("decode")
+    return (eng._decode_jit, eng._decode_args(), None,
+            eng._step_budget("decode"))
 
 
 def _build_cache_step(which: str, tensor_parallel: int = 1,

@@ -37,8 +37,11 @@ span                    extent                                attributes
 ``serve.decode``        the decode phase                      batch
 ``serve.decode.upload``     the six device operands           bytes
 ``serve.decode.dispatch``   the call of the jitted program
-``serve.decode.fetch``      the token fetch (blocks)
+``serve.decode.fetch``      the token fetch of the PREVIOUS   of_step
+                            step's launch (blocks)
 ``serve.decode.emit``       the per-slot loop, retirements
+``serve.drain``         an early fetch + emit of the decode   reason
+                        in flight
 ``serve.verify``        the speculative verify phase          batch
 ``serve.account``       cache stats, gauges, the step record,
                         watchdogs, the SLO controller

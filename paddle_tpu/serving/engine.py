@@ -2,9 +2,10 @@
 
 Static-shape discipline is the whole design: the decode step is a single
 ``jax.jit``-compiled function of (params, pools, page_table [max_batch,
-pages_per_seq], ctx_lens [max_batch], last_tok [max_batch], active
-[max_batch], rids [max_batch], gen_idx [max_batch]) — every array keeps its
-shape for the life of the engine, so requests joining and leaving the batch
+pages_per_seq], ctx_lens [max_batch], prev_toks [max_batch], override
+[max_batch], active [max_batch], rids [max_batch], gen_idx [max_batch]) —
+every array keeps its shape for the life of the engine, so requests
+joining and leaving the batch
 NEVER retrigger compilation (the e2e test asserts exactly-one trace per
 function via ``compile_counts``, which is now a read-through view of the
 ``analysis.tracecheck.CompileGuard`` wrapping each jitted step — the guard
@@ -78,6 +79,54 @@ and AIMD-adapts how many prefill chunks each step may admit; while
 degraded, waiters with warm prefix-cache hits are admitted ahead of cold
 ones (their uncached tail is cheap). The current limit is mirrored in the
 ``serving_chunk_limit`` gauge.
+
+The order of a step, and when a token is handed over. ``step()`` sweeps
+deadlines, admits and prefills (a prefill blocks on its own first-token
+fetch, as it always has), makes room for the decode, LAUNCHES decode k,
+and only then fetches, emits and retires the tokens of decode k-1, which
+the previous ``step()`` launched and which has been running on the device
+while the host came round. So everything the host does between two
+launches (the fetch's tail, emit, accounting, the caller's loop, admit,
+evict, upload, dispatch) happens under a decode program and not between
+two of them. What it takes:
+
+- the last token stays on the device: the decode program takes the
+  previous launch's token output as it is (``prev_toks``, not donated —
+  it is still to be fetched) and merges it in-program with the host's
+  ``override`` for the slots whose last token the host does know (just
+  prefilled, swap-resumed, or every slot of a drained engine); no eager
+  operation runs between two steps;
+- what does not depend on a token's value advances at the launch
+  (``_ctx``, ``_gen``, the page that ``ensure_decode_pages`` reserves);
+  what does (``req.generated``, ``tokens_emitted``, ``_last_tok``, the
+  finish, ``decode_mark``, ``on_tokens``) advances at the fetch. A
+  caller sees a decode token when it is appended to ``req.generated``:
+  one ``step()`` after the step that launched it. A prefill's first
+  token is handed over by the step that prefilled;
+- finish by length is known at the launch (tokens emitted + in flight):
+  such a slot is left out of the next launch and retires at its fetch.
+  Finish by EOS is known one step late: the surplus token of the launch
+  already made is dropped at its fetch — never appended, counted or
+  indexed; its KV write went to a page of the request's own, freed with
+  it. Outputs are token for token those of an engine that fetches every
+  step;
+- ``_drain(reason)`` fetches and emits what is in flight, now, at every
+  site that needs the host's view whole before it acts
+  (``DRAIN_REASONS``): preemption and swap-out, ``cancel`` and the
+  deadline sweep when they hit a request with a token in flight, the
+  fault injector's decode-phase hits, ``debug_checks`` (every step: the
+  invariant sweep and the sync tally read a whole step), the flight
+  record and the fatal path, the end of ``run()``. A drained engine is
+  exactly the engine that fetched every step; a ``step()`` with a decode
+  in flight and nothing to launch only fetches. A device error of decode
+  k surfaces at its fetch in step k+1, with a note naming step k;
+- speculative decoding (``_verify_phase``) replaces plain decode
+  wholesale, keeps its one packed fetch in the same step, and never has
+  a decode in flight.
+
+``serving_decode_overlapped_total`` over ``serving_decode_steps`` is the
+share of launches made under a decode in flight;
+``serving_decode_drains_total{reason=}`` counts the early fetches.
 
 Decode semantics match text/generation.py: prefill picks the first token
 from the last prompt logit, each decode step feeds the previous token back
@@ -171,12 +220,20 @@ span                                  extent; attributes
 ``serve.chunk_prefill``               the chunk loop; ``chunks``
 ``serve.evict``                       fault sites, decode-page pressure,
                                       preemption
-``serve.decode``                      the decode phase; ``batch``
+``serve.decode``                      the decode phase; ``batch`` (the
+                                      slots launched)
 ``serve.decode.upload``               the six device operands, the whole
                                       page table among them; ``bytes``
-``serve.decode.dispatch``             the call of the jitted program
-``serve.decode.fetch``                the token fetch (blocks)
-``serve.decode.emit``                 the per-slot loop, retirements
+``serve.decode.dispatch``             the call of the jitted program:
+                                      this step's launch
+``serve.decode.fetch``                the token fetch of the PREVIOUS
+                                      step's launch (blocks for what is
+                                      left of it); ``of_step``
+``serve.decode.emit``                 the per-slot loop over the fetched
+                                      tokens, retirements
+``serve.drain``                       an early fetch + emit of the decode
+                                      in flight (``decode.fetch`` and
+                                      ``decode.emit`` inside); ``reason``
 ``serve.verify``                      the speculative verify phase, one
                                       span; ``batch``
 ``serve.account``                     cache stats, gauges, the step
@@ -370,6 +427,13 @@ class ServingConfig:
     # byte-identical with tenants on.
 
 
+#: why a decode in flight was fetched before the next launch (the sites
+#: that need the host's view whole before they act): the label values of
+#: ``serving_decode_drains_total{reason=}`` and of ``serve.drain`` spans
+DRAIN_REASONS = ("preempt", "cancel", "deadline", "fault", "debug_checks",
+                 "flight_record", "fatal", "run_end")
+
+
 def prefill_buckets(max_prompt_len: int) -> list[int]:
     """The fixed prefill pad buckets: powers of two from 8 up, capped at
     (and always including) ``max_prompt_len``. Each bucket compiles the
@@ -541,6 +605,9 @@ class ServingEngine:
             self.cache, cfg.max_batch, max_waiting=cfg.max_waiting,
             shed_policy=cfg.shed_policy, preemption_mode=cfg.preemption_mode,
             tracer=self._tracer)
+        # a victim is picked among, and preempted or swapped out with,
+        # what the host knows
+        self.scheduler.before_preempt = lambda: self._drain("preempt")
         # speculative decoding (serving/spec.py): proposer state plus the
         # host-mirrored token-history buffer the proposers read — shipped
         # with every verify call via _spec_hist (full buffer for n-gram,
@@ -607,6 +674,23 @@ class ServingEngine:
         self._active = np.zeros(b, bool)
         self._rids = np.zeros(b, np.int32)  # per-slot rid (PRNG stream id)
         self._gen = np.zeros(b, np.int32)   # per-slot generated-token count
+        # the decode launched and not yet fetched: (its token output on the
+        # device, the step that launched it, [(slot, request)]). step()
+        # launches decode k and only then fetches decode k-1, so the host's
+        # work between two launches runs under a decode program. _ctx and
+        # _gen are ahead of the host's tokens by the one in flight;
+        # _last_tok is the host's mirror and lags it.
+        self._inflight = None
+        # the last launch's token output, the next launch's last tokens for
+        # the slots whose token the host has not seen; until the first
+        # launch a placeholder that every slot overrides
+        prev = np.full(b, cfg.pad_token_id, np.int32)
+        self._prev_toks = (jnp.asarray(prev) if self._tp is None
+                           else self._tp.replicated(prev))
+        # requests that a drain outside step() finished (cancel, a flight
+        # record): the next step() returns their ids
+        self._drained_finished: list[int] = []
+        self.metrics.seed_family("decode_drains_total", DRAIN_REASONS)
         # what a decode step uploads, from the operands' shapes: the whole
         # page table and the five per-slot vectors (serve.decode.upload)
         self._decode_upload_bytes = sum(
@@ -652,7 +736,7 @@ class ServingEngine:
                 prefill_impl, mc.num_layers, n_rest=5,
                 quantized=self.cache.cfg.quantized)
             decode_impl = self._tp.wrap_step(
-                decode_impl, mc.num_layers, n_rest=6,
+                decode_impl, mc.num_layers, n_rest=7,
                 quantized=self.cache.cfg.quantized)
         # ``program=`` names what the guard jits, so the profiler's
         # "XLA Modules" line reads jit_serve_prefill_<bucket> (one name
@@ -738,11 +822,16 @@ class ServingEngine:
             tok = tok.astype(jnp.int32)
         return new_pools, tok
 
-    def _decode_impl(self, p_arrays, pools, table, ctx, last_tok, active,
-                     rids, gen_idx):
+    def _decode_impl(self, p_arrays, pools, table, ctx, prev_toks,
+                     override, active, rids, gen_idx):
         """One token for every running slot. Inactive slots run the same
         computation against the null page and emit pad — branch-free, so the
-        batch composition never changes the compiled program."""
+        batch composition never changes the compiled program. A slot's last
+        token is ``prev_toks`` (the previous launch's output, still on the
+        device, never donated: the host fetches it after this launch) unless
+        the host knows it and says so with ``override >= 0``: a slot just
+        prefilled or swap-resumed, or every slot of a drained engine."""
+        last_tok = jnp.where(override >= 0, override, prev_toks)
         logits, new_pools = self._run_model(
             p_arrays, pools, table, ctx, active[:, None], last_tok[:, None])
         with jax.named_scope("sample"):
@@ -937,6 +1026,8 @@ class ServingEngine:
         True when something was cancelled; False for unknown or already
         terminal requests."""
         req = self._requests.get(rid)
+        if req is not None and req.tokens_in_flight:
+            self._drain("cancel")  # it may finish here, as it would have
         if req is None or req.state not in (WAITING, RUNNING, PREFILLING):
             return False
         self._retire(req, CANCELLED)
@@ -1009,9 +1100,11 @@ class ServingEngine:
         if not with_deadline:
             return
         now = self.now()
-        for req in with_deadline:
-            if now >= req.deadline and \
-                    req.state in (WAITING, RUNNING, PREFILLING):
+        expired = [r for r in with_deadline if now >= r.deadline]
+        if any(r.tokens_in_flight for r in expired):
+            self._drain("deadline")
+        for req in expired:
+            if req.state in (WAITING, RUNNING, PREFILLING):
                 self._retire(req, EXPIRED)
                 self.metrics.on_expired()
 
@@ -1188,10 +1281,15 @@ class ServingEngine:
 
     def step(self) -> list[int]:
         """One continuous-batching iteration: sweep deadlines, admit +
-        prefill (or swap-resume) joiners, one decode step for the whole
-        batch, retire finishers. Returns the request ids that finished
-        during this step. Injected faults retire only the requests they
-        name; everything else keeps being served.
+        prefill (or swap-resume) joiners, launch one decode step for the
+        whole batch, then fetch the tokens of the decode that the
+        PREVIOUS step launched and retire finishers. A decode token is
+        handed over (appended to ``req.generated``, counted, traced) one
+        ``step()`` after the step that launched it; a prefill's first
+        token in the step that prefilled it. Returns the request ids
+        whose finish this step saw (those that a drain between two steps
+        finished among them). Injected faults retire only the requests
+        they name; everything else keeps being served.
 
         Under ``debug_checks`` the step body runs inside a SyncTally (host
         syncs accumulate into ``serving_analysis_host_syncs_total``) and is
@@ -1438,7 +1536,7 @@ class ServingEngine:
                 self._preempt_one(req, slot)
 
         n_accepted = 0
-        if self._active.any():
+        if self._active.any() or self._inflight is not None:
             if self._spec is not None:
                 # speculative decoding: the verify step replaces plain
                 # decode wholesale — one batched K+1-token ragged pass,
@@ -1469,6 +1567,8 @@ class ServingEngine:
             host_tier_hits=cs["host_tier_hits"],
             host_tier_spills=cs["host_tier_spills"],
             host_tier_restores=cs["host_tier_restores"])
+        if self._drained_finished:
+            finished_now = self._take_drained() + finished_now
         if self._timeline is not None:
             # close the attribution: the residual (state roll-up, this
             # very bookkeeping) lands in "other", and the phase dict sums
@@ -1570,11 +1670,15 @@ class ServingEngine:
         named requests FAILED, ``pool_exhausted`` preempts a victim."""
         for slot in np.nonzero(self._active)[0]:
             req = self.scheduler.running.get(int(slot))
-            if req is None:
-                continue
+            if req is None or req.all_launched:
+                continue  # no decode is launched for it: nothing to fail
             if inj.hit("decode_fail", step=step_idx, rid=req.rid):
-                # before the decode launches: the failed request leaves,
+                # before the decode launches: the failed request leaves
+                # with every token it has (the drain may even finish it),
                 # the rest of the batch decodes normally this very step
+                self._drain("fault")
+                if req.state != RUNNING:
+                    continue
                 self._retire(req, FAILED, InjectedFault(
                     f"decode_fail injected (step {step_idx}, "
                     f"rid {req.rid})"))
@@ -1594,56 +1698,136 @@ class ServingEngine:
                 self.metrics.on_failed()
         if self.scheduler.running and \
                 inj.hit("pool_exhausted", step=step_idx):
-            self._preempt_one(self.scheduler.pick_victim())
+            self._drain("fault")
+            if self.scheduler.running:
+                self._preempt_one(self.scheduler.pick_victim())
+
+    def _decode_args(self, active=None, override=None) -> tuple:
+        """The decode program's operands as a launch uploads them: the
+        whole page table and the five per-slot vectors from the host, and
+        the previous launch's tokens where they are, on the device. The
+        defaults are a drained engine's launch: every active slot with the
+        last token the host knows."""
+        # a private copy of each: the transfer may read its source after
+        # jnp.asarray returns, and the host writes _ctx and _gen right
+        # after the dispatch, the page table and the rest while it runs
+        up = lambda a: jnp.asarray(a.copy())  # noqa: E731
+        return (self._p, self.cache.pools, up(self.cache.page_table),
+                up(self._ctx), self._prev_toks,
+                up(self._last_tok if override is None else override),
+                up(self._active if active is None else active),
+                up(self._rids), up(self._gen))
 
     def _decode_phase(self, finished_now: list) -> int:
-        """One decode step for the whole batch, as the ``serve.decode``
-        span and its four parts: the six operands uploaded (the whole
-        page table among them), the dispatch, the step's ONE sanctioned
-        device->host sync (the token fetch, where the device time lands),
-        and the per-slot bookkeeping. Returns the slots that decoded."""
+        """Launch one decode step for the whole batch, THEN fetch and emit
+        the tokens of the decode that the previous step launched: the
+        ``serve.decode`` span and its four parts (upload, dispatch, the
+        fetch of the previous launch — this step's ONE sanctioned
+        device->host sync — and the per-slot bookkeeping of those tokens).
+        What does not depend on a token's value advances at the launch
+        (``_ctx``, ``_gen``; the page was reserved by
+        ``ensure_decode_pages``), what does advances at the fetch, one
+        step later. A slot whose last token is already in flight is left
+        out of the launch; with nothing to launch the phase only fetches.
+        Returns the slots launched."""
         att = self._attr
-        with (att.span("decode", batch=int(self._active.sum()))
+        prev = self._inflight
+        active = np.zeros_like(self._active)
+        override = self._last_tok.copy()
+        launched = []
+        for slot in np.nonzero(self._active)[0]:
+            req = self.scheduler.running[int(slot)]
+            if req.all_launched:
+                continue  # finish by length: it retires at the fetch below
+            active[slot] = True
+            if req.tokens_in_flight:
+                override[slot] = -1  # its last token is in _prev_toks
+            launched.append((int(slot), req))
+        with (att.span("decode", batch=len(launched))
               if att is not None else NO_SPAN):
-            with (att.span("decode.upload", bytes=self._decode_upload_bytes)
-                  if att is not None else NO_SPAN):
-                args = (self._p, self.cache.pools,
-                        jnp.asarray(self.cache.page_table),
-                        jnp.asarray(self._ctx), jnp.asarray(self._last_tok),
-                        jnp.asarray(self._active), jnp.asarray(self._rids),
-                        jnp.asarray(self._gen))
-            if self.config.debug_checks:
-                self._audit_step(self._decode_jit, args, "decode")
-            with (att.span("decode.dispatch")
-                  if att is not None else NO_SPAN):
-                pools, toks = self._decode_jit(*args)
-            self.cache.pools = pools
-            with (att.span("decode.fetch")
-                  if att is not None else NO_SPAN):
-                toks = np.asarray(toks)  # lint: disable=PT005
-            self.metrics.on_decode_step()
-            n_new = 0
-            tr = self._tracer
-            with (att.span("decode.emit")
-                  if att is not None else NO_SPAN):
-                for slot in np.nonzero(self._active)[0]:
-                    req = self.scheduler.running[int(slot)]
-                    tok = int(toks[slot])
-                    req.generated.append(tok)
-                    req.tokens_emitted += 1
-                    req.fresh = False  # it has decoded: preemptible now
+            if launched:
+                with (att.span("decode.upload",
+                               bytes=self._decode_upload_bytes)
+                      if att is not None else NO_SPAN):
+                    args = self._decode_args(active, override)
+                if self.config.debug_checks:
+                    self._audit_step(self._decode_jit, args, "decode")
+                with (att.span("decode.dispatch")
+                      if att is not None else NO_SPAN):
+                    pools, toks = self._decode_jit(*args)
+                self.cache.pools = pools
+                self._prev_toks = toks
+                self.metrics.on_decode_step(overlapped=prev is not None)
+                for slot, req in launched:
+                    req.tokens_in_flight += 1
                     self._ctx[slot] += 1
-                    self._last_tok[slot] = tok
                     self._gen[slot] += 1
-                    n_new += 1
-                    if tr is not None and \
-                            len(req.generated) % tr.mark_every == 0:
-                        tr.event(req.rid, "decode_mark",
-                                 tokens=len(req.generated))
-                    if self._maybe_finish(req, tok):
-                        finished_now.append(req.rid)
-                self.metrics.on_tokens(n_new)
-        return n_new
+                self._inflight = (toks, self._now_step, launched)
+            else:
+                self._inflight = None
+            if prev is not None:
+                self._fetch_and_emit(prev, finished_now)
+            if self.config.debug_checks:
+                # the invariant sweep and the sync tally that follow read
+                # a whole step: nothing stays in flight under debug_checks
+                self._drain("debug_checks")
+        return len(launched)
+
+    def _fetch_and_emit(self, inflight: tuple, finished_now: list) -> None:
+        """Fetch one launch's tokens and hand them over: append, count,
+        trace, retire finishers. A slot whose request has left it since
+        the launch (finish by EOS is known one step late) computed a
+        surplus token: dropped here, never appended, counted or indexed;
+        its KV write went to a page of the request's own, freed with it."""
+        att, tr = self._attr, self._tracer
+        toks, step, launched = inflight
+        with (att.span("decode.fetch", of_step=step)
+              if att is not None else NO_SPAN):
+            try:
+                toks = np.asarray(toks)  # lint: disable=PT005
+            except Exception as e:
+                e.add_note(f"raised at the fetch of the decode that step "
+                           f"{step} launched")
+                raise
+        n_new = 0
+        with (att.span("decode.emit") if att is not None else NO_SPAN):
+            for slot, req in launched:
+                req.tokens_in_flight -= 1
+                if self.scheduler.running.get(slot) is not req:
+                    continue
+                tok = int(toks[slot])
+                req.generated.append(tok)
+                req.tokens_emitted += 1
+                req.fresh = False  # it has decoded: preemptible now
+                self._last_tok[slot] = tok
+                n_new += 1
+                if tr is not None and \
+                        len(req.generated) % tr.mark_every == 0:
+                    tr.event(req.rid, "decode_mark",
+                             tokens=len(req.generated))
+                if self._maybe_finish(req, tok):
+                    finished_now.append(req.rid)
+            self.metrics.on_tokens(n_new)
+
+    def _drain(self, reason: str) -> bool:
+        """Fetch and emit what is in flight, now: for a site that needs
+        the host's view whole before it acts (``DRAIN_REASONS``). A
+        drained engine is the engine that fetched every step. Requests it
+        finishes are reported by the step that is running, or the next.
+        True when something was in flight."""
+        inflight, self._inflight = self._inflight, None
+        if inflight is None:
+            return False
+        att = self._attr
+        with (att.span("drain", reason=reason)
+              if att is not None else NO_SPAN):
+            self._fetch_and_emit(inflight, self._drained_finished)
+        self.metrics.on_decode_drain(reason)
+        return True
+
+    def _take_drained(self) -> list[int]:
+        done, self._drained_finished = self._drained_finished, []
+        return done
 
     def _verify_phase(self, finished_now: list) -> tuple[int, int]:
         """The speculative twin of the decode phase: ONE verify dispatch
@@ -1757,6 +1941,10 @@ class ServingEngine:
                     raise err
         finally:
             self.admit_paused = paused_before
+        # a finish by EOS leaves its surplus launch in flight
+        self._drain("run_end")
+        for rid in self._take_drained():
+            done[rid] = self._finished[rid]
         return done
 
     # -------------------------------------------------------- observability
@@ -1792,7 +1980,9 @@ class ServingEngine:
         the alert history, a full gauge snapshot, the per-program
         hlocheck audit roll-ups, the per-request latency summaries, the
         per-tenant goodput roll-ups, and a bounded ring of wire
-        journeys — schema-versioned, JSON-ready."""
+        journeys — schema-versioned, JSON-ready. Drains a decode in
+        flight first: the record holds every token computed."""
+        self._drain("flight_record")
         cfg = self.config
         programs = {
             label: {"flops": r.flops, "peak_hbm_bytes": r.peak_bytes,
@@ -1844,8 +2034,16 @@ class ServingEngine:
         record. Best-effort: nothing here may mask the original
         exception."""
         try:
+            self._drain("fatal")
+        except Exception:  # noqa: BLE001 — the fetch may be what raised
+            pass
+        try:
             att = self._attr
-            fatal = {"fatal": f"{type(exc).__name__}: {exc}"}
+            # a device error of decode k surfaces at its fetch in step
+            # k+1: the note names the step that launched it
+            fatal = {"fatal": "; ".join(
+                [f"{type(exc).__name__}: {exc}",
+                 *getattr(exc, "__notes__", ())])}
             if self._timeline is not None and att is not None and att.open:
                 t_end, phase_s = att.finish()
                 self._timeline.append(StepRecord(

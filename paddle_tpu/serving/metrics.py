@@ -14,7 +14,17 @@ them with no new plumbing):
 - serving_prefill_tokens_total counter: tokens actually prefilled (a prefix
                             cache hit prefills only the uncached tail, so
                             this is the FLOPs-weighted prefill cost)
-- serving_decode_steps      counter
+- serving_decode_steps      counter: decode programs launched
+- serving_decode_overlapped_total counter: launches made while the
+                            previous launch's tokens were still to be
+                            fetched (the host's work between two launches
+                            ran under a decode program); over
+                            serving_decode_steps, the overlapped share
+- serving_decode_drains_total{reason=} counter family: decodes in flight
+                            fetched before the next launch, by the site
+                            that needed the host's view whole (preempt /
+                            cancel / deadline / fault / debug_checks /
+                            flight_record / fatal / run_end)
 - serving_preemptions_total counter
 
 Resilience counters (pre-seeded to 0 so they always appear in snapshots):
@@ -166,7 +176,12 @@ Goodput attribution + watchdogs + flight recorder (PR 12):
 - serving_step_phase_s{phase=}    histogram family: per-phase step
                                   wall-time attribution (admit / swap /
                                   prefill / chunk_prefill / decode /
-                                  verify / evict / other)
+                                  verify / evict / other). Inside
+                                  "decode", the decode.fetch span
+                                  (StepRecord.span_s) is the wait for
+                                  the PREVIOUS step's launch, which has
+                                  been running since; the launch of
+                                  this step is fetched by the next
 - serving_alerts_total{rule=}     counter family: watchdog firings per
                                   rule (retrace_after_warmup /
                                   pallas_fallback /
@@ -250,7 +265,7 @@ PREFIX = "serving_"
 # stat_set/stat_max)
 _SEEDED = ("tokens_total", "prefills_total", "prefill_tokens_total",
            "prefill_chunks_total", "chunk_limit", "slo_throttles_total",
-           "decode_steps", "preemptions_total",
+           "decode_steps", "decode_overlapped_total", "preemptions_total",
            "rejected", "shed", "expired", "cancelled", "failed",
            "swap_outs", "swap_ins",
            "prefix_hits", "prefix_misses", "prefix_tokens_saved",
@@ -292,6 +307,8 @@ _SEEDED = ("tokens_total", "prefills_total", "prefill_tokens_total",
 _FAMILIES = {
     "step_phase_s": "phase",              # histogram family (below)
     "alerts_total": "rule",               # counter: watchdog firings
+    "decode_drains_total": "reason",      # counter: early fetches of the
+    # decode in flight, by site (engine.DRAIN_REASONS)
     "tenant_goodput_tokens_total": "tenant",   # in_slo tokens per tenant
     "tenant_badput_tokens_total": "tenant",    # everything-else tokens
     "tenant_retired_total": ("tenant", "class"),  # retirements per
@@ -341,6 +358,7 @@ COUNTER_STATS = frozenset(
         "hlo_collective_ops", "hlo_host_transfers")) \
     | frozenset({  # labeled counter family bases
         PREFIX + "alerts_total",
+        PREFIX + "decode_drains_total",
         PREFIX + "tenant_goodput_tokens_total",
         PREFIX + "tenant_badput_tokens_total",
         PREFIX + "tenant_retired_total",
@@ -540,8 +558,17 @@ class ServingMetrics:
         rate = (total - n0) / (now - t0) if now > t0 else 0.0
         monitor.stat_set(PREFIX + "tokens_per_sec", rate)
 
-    def on_decode_step(self) -> None:
+    def on_decode_step(self, overlapped: bool = False) -> None:
+        """One decode (or verify) launch; ``overlapped`` when the previous
+        launch was still in flight."""
         monitor.stat_add(PREFIX + "decode_steps", 1)
+        if overlapped:
+            monitor.stat_add(PREFIX + "decode_overlapped_total", 1)
+
+    def on_decode_drain(self, reason: str) -> None:
+        """One decode in flight fetched before the next launch."""
+        monitor.stat_add(PREFIX + f"decode_drains_total{{reason={reason}}}",
+                         1)
 
     def on_spec_depth(self, depth: int) -> None:
         """The configured speculation depth K (0 = speculation off), set

@@ -111,6 +111,11 @@ class Request:        # generated dataclass __eq__ chokes on ndarray fields
     # accrues this at retirement so per-tenant goodput+badput token
     # totals reconcile exactly with serving_tokens_total (which also
     # counts re-emissions); len(generated) is the client-visible count
+    tokens_in_flight: int = 0  # decodes launched for this request and not
+    # fetched yet: 1 from one step's decode phase to the next (the engine
+    # launches decode k+1 before it fetches decode k; 2 only in between).
+    # That token exists on the device only; the next launch consumes it
+    # there
 
     @property
     def prompt_len(self) -> int:
@@ -118,10 +123,18 @@ class Request:        # generated dataclass __eq__ chokes on ndarray fields
 
     @property
     def tokens_resident(self) -> int:
-        """Tokens whose KV lives in the cache: prompt + generated (each
-        generated token's KV is written by the decode step that consumes
-        it)."""
-        return self.prompt_len + len(self.generated)
+        """Tokens whose KV lives in the cache once the next decode has
+        run: prompt + generated + the one in flight (each generated
+        token's KV is written by the decode step that consumes it)."""
+        return self.prompt_len + len(self.generated) + self.tokens_in_flight
+
+    @property
+    def all_launched(self) -> bool:
+        """Its last token is emitted or in flight: finish by length is
+        known before the fetch, so no further decode is launched for it
+        and it retires when the token in flight comes back."""
+        return len(self.generated) + self.tokens_in_flight \
+            >= self.max_new_tokens
 
     @property
     def done(self) -> bool:
@@ -164,6 +177,11 @@ class Scheduler:
         # 0 = plain decode, byte-identical accounting to the pre-spec
         # engine.
         self.decode_reserve = 0
+        # called before a victim is picked; True when it changed what is
+        # running (the engine drains its decode in flight here, so that a
+        # victim is chosen and preempted with every token it has on the
+        # host) and the page demand has to be read again
+        self.before_preempt = None
         self._head_skips = 0  # prefer_cached fairness counter
         # (request, error) pairs whose host-tier restore failed mid-admit:
         # the admission was undone (pool state = pre-admit), the request
@@ -361,19 +379,24 @@ class Scheduler:
         ``decode_reserve`` (speculative decoding) adds its K candidate
         writes at ``ctx + 1 .. ctx + K`` on top — for decoding slots only;
         a PREFILLING request isn't in the verify batch and holds its full
-        prompt allocation already. Preempts per ``pick_victim`` until the
-        survivors fit. Returns (request, vacated slot) pairs — the engine
-        must deactivate those slots."""
+        prompt allocation already. A request whose last token is already
+        in flight (``all_launched``) writes nothing more. Preempts per
+        ``pick_victim`` until the survivors fit. Returns (request, vacated
+        slot) pairs — the engine must deactivate those slots."""
         preempted = []
         for slot in sorted(self.running,
                            key=lambda s: self.running[s].admit_seq):
             req = self.running.get(slot)
-            if req is None:  # already preempted this round
+            if req is None:  # already preempted (or retired by a drain)
+                continue
+            if req.all_launched:
                 continue
             reserve = self.decode_reserve if req.state != PREFILLING else 0
             while req.slot is not None \
                     and not self.cache.grow(slot,
                                             req.tokens_resident + reserve):
+                if self.before_preempt is not None and self.before_preempt():
+                    continue  # tokens came back, requests may have retired
                 victim = self.pick_victim()
                 preempted.append((victim, self.preempt(victim)))
                 # admission-time fits_ever() guarantees a lone request can

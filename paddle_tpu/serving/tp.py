@@ -196,6 +196,13 @@ class TPContext:
             placed[name] = jax.device_put(arr, self._sharding(*axes))
         return placed
 
+    def replicated(self, arr):
+        """``arr`` on every device of the mesh: the placement of a step's
+        replicated token output, for an operand that stands in for one."""
+        import jax
+
+        return jax.device_put(arr, self._sharding())
+
     def _pool_specs(self, num_layers: int, quantized: bool = False):
         from jax.sharding import PartitionSpec as P
 

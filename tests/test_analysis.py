@@ -271,8 +271,7 @@ def test_engine_debug_checks_retrace_raises_naming_argument():
             engine._p, engine.cache.pools,
             jnp.asarray(engine.cache.page_table),
             jnp.zeros((b + 1,), jnp.int32),  # <- ctx grew an element
-            jnp.asarray(engine._last_tok), jnp.asarray(engine._active),
-            jnp.asarray(engine._rids), jnp.asarray(engine._gen))
+            *engine._decode_args()[4:])
     msg = str(ei.value)
     assert "'decode'" in msg and "ctx" in msg
     assert f"axis 0: {b} -> {b + 1}" in msg

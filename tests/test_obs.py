@@ -515,10 +515,13 @@ def test_obs_off_is_one_attribute_check_per_event_site():
     engine.add_request(_prompt(4), 3)
     assert CountingEngine.reads == 1  # the enqueue site
     CountingEngine.reads = 0
-    engine.step()  # prefill site + decode site
-    assert CountingEngine.reads == 2
+    engine.step()  # prefill site; the decode it launches has no site
+    assert CountingEngine.reads == 1
     CountingEngine.reads = 0
-    engine.step()  # decode site + the finish (retire) site
+    engine.step()  # the emit site of the decode launched one step before
+    assert CountingEngine.reads == 1
+    CountingEngine.reads = 0
+    engine.step()  # emit site + the finish (retire) site
     assert CountingEngine.reads == 2
 
 

@@ -229,7 +229,10 @@ def test_request_spans_carry_rid_and_their_attributes(plain):
     assert [s.stats["bytes"] for s in up] == [4 * 8 + row + 12,
                                               4 * 16 + row + 12]
     decodes = [s for s in spans if s.name == "decode"]
-    assert decodes and all(s.stats["batch"] in (1, 2) for s in decodes)
+    # batch = the slots launched: none in the last, which only fetches
+    assert [s.stats["batch"] for s in decodes][-1] == 0
+    assert decodes[:-1] and all(s.stats["batch"] in (1, 2)
+                                for s in decodes[:-1])
     assert {s.stats["bytes"] for s in spans if s.name == "decode.upload"} \
         == {engine._decode_upload_bytes}
     assert [s.stats["queue_depth"] for s in spans
@@ -238,16 +241,28 @@ def test_request_spans_carry_rid_and_their_attributes(plain):
 
 def test_fetch_ends_after_dispatch_inside_the_phase(plain):
     spans = plain["spans"]
-    for phase in ("decode", "prefill"):
-        for p in (s for s in spans if s.name == phase):
-            inside = {s.name: s for s in spans
-                      if _parent(s, spans) is p}
-            up, disp, fetch = (inside[f"{phase}.{k}"]
-                               for k in ("upload", "dispatch", "fetch"))
-            assert p.start <= up.start and up.end <= disp.start
-            assert disp.end <= fetch.start and fetch.end <= p.end
-            if phase == "decode":
-                assert fetch.end <= inside["decode.emit"].start
+    for p in (s for s in spans if s.name == "prefill"):
+        inside = {s.name: s for s in spans if _parent(s, spans) is p}
+        up, disp, fetch = (inside[f"prefill.{k}"]
+                           for k in ("upload", "dispatch", "fetch"))
+        assert p.start <= up.start and up.end <= disp.start
+        assert disp.end <= fetch.start and fetch.end <= p.end
+    # a decode phase launches, then fetches the launch of the step before:
+    # the first has nothing to fetch, the last nothing to launch
+    decodes = [s for s in spans if s.name == "decode"]
+    for i, p in enumerate(decodes):
+        inside = {s.name: s for s in spans if _parent(s, spans) is p}
+        assert set(inside) == \
+            ({"decode.upload", "decode.dispatch"} if i < len(decodes) - 1
+             else set()) | ({"decode.fetch", "decode.emit"} if i else set())
+        order = [inside[f"decode.{k}"]
+                 for k in ("upload", "dispatch", "fetch", "emit")
+                 if f"decode.{k}" in inside]
+        assert p.start <= order[0].start and order[-1].end <= p.end
+        assert all(a.end <= b.start for a, b in zip(order, order[1:]))
+        if i:
+            assert inside["decode.fetch"].stats["of_step"] == \
+                decodes[i - 1].stats["step"]
 
 
 def test_attribute_counts(chunked, spec, cow):
@@ -455,11 +470,8 @@ def test_training_step_carries_scope_in_op_name(train_step, scope):
 @pytest.mark.parametrize("scope", SCOPES_SERVE)
 def test_serving_program_carries_scope_in_op_name(plain, scope):
     engine = plain["engine"]
-    args = (engine._p, engine.cache.pools,
-            jnp.asarray(engine.cache.page_table), jnp.asarray(engine._ctx),
-            jnp.asarray(engine._last_tok), jnp.asarray(engine._active),
-            jnp.asarray(engine._rids), jnp.asarray(engine._gen))
-    text = jax.jit(engine.guards["decode"].fn).lower(*args).as_text(
+    text = jax.jit(engine.guards["decode"].fn).lower(
+        *engine._decode_args()).as_text(
         debug_info=True)
     assert re.search(r'loc\("jit\([^"]*/' + re.escape(scope) + "/", text)
 

@@ -209,11 +209,8 @@ def test_quantized_pool_hbm_under_0p3x_fp32(model):
         eng = ServingEngine(model, ServingConfig(
             max_batch=2, num_pages=4096, page_size=4, max_prompt_len=8,
             kv_dtype=kv_dtype))
-        args = (eng._p, eng.cache.pools,
-                jnp.asarray(eng.cache.page_table), jnp.asarray(eng._ctx),
-                jnp.asarray(eng._last_tok), jnp.asarray(eng._active),
-                jnp.asarray(eng._rids), jnp.asarray(eng._gen))
-        return audit_guard(eng._decode_jit, args, name=f"decode-{kv_dtype}")
+        return audit_guard(eng._decode_jit, eng._decode_args(),
+                           name=f"decode-{kv_dtype}")
 
     r32 = decode_report("float32")
     r8 = decode_report("int8")
