@@ -39,7 +39,7 @@ def silent_leaves(ref_grad_norm: dict) -> set[str]:
 def train_numbers(prog: dict, ref: dict) -> tuple[dict, dict]:
     """(numbers, notes). ``prog`` and ``ref`` hold ``losses`` (one per
     checked step), ``grad_norm`` and ``change_norm`` (per leaf, a fused
-    qkv leaf as its three parts)."""
+    leaf as the parts that its family splits it into)."""
     numbers = {}
     loss_gaps = [abs(a - b) / abs(b)
                  for a, b in zip(prog["losses"], ref["losses"])]

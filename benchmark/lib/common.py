@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import gc
 import glob
+import importlib
 import json
 import os
 import shutil
@@ -25,6 +26,7 @@ class Run:
     peaks: dict               # this device's row of peaks.json
     t_process: float          # time.time() at process start
     control: bool = False     # also judge the control and planted faults
+    counters: frozenset = frozenset()   # the counters its metric files read
     facts: dict = field(default_factory=dict)
 
 
@@ -34,15 +36,10 @@ def say(what: str, **fields) -> None:
                                            default=str), flush=True)
 
 
-def check_preset(config: dict, gcfg) -> None:
-    """The configuration's sizes are the program preset's, or nothing
-    runs."""
-    m = config["model"]
-    for key in ("vocab_size", "hidden_size", "num_layers", "num_heads",
-                "ffn_hidden"):
-        if m[key] != getattr(gcfg, key):
-            raise SystemExit(f"{config['name']}: {key} {m[key]} is not the "
-                             f"program preset's {getattr(gcfg, key)}")
+def family_of(config: dict):
+    """The module of the configuration's family:
+    ``benchmark/families/<family>.py``, found by that name alone."""
+    return importlib.import_module("benchmark.families." + config["family"])
 
 
 def install_weights(model, mine: dict) -> None:

@@ -10,15 +10,16 @@ executed program. The host plane holds the ``bench.*`` annotations that
 ``run.py``'s drivers put around their calls.
 
 A reader (``READERS``) takes ``(trace, facts, args, peaks)`` and returns a
-number, or None when it finds nothing to read — never 0 for a share.
+number, or None when it finds nothing to read — never 0 for a share. A
+metric's ``args.work`` names a function of ``facts["work"]``, the ``WORK``
+of the configuration's family; its ``args.counter`` / ``args.over`` name
+entries of ``facts["counters"]``.
 """
 from __future__ import annotations
 
 import re
 from collections import defaultdict
 from dataclasses import dataclass, field
-
-from . import work
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
@@ -251,8 +252,8 @@ def read_mfu(trace, facts, args, peaks):
         if not trace.devices:
             return None
         facts = _with_calls(facts, trace.module_calls(args["module"]))
-    flops = work.FUNCTIONS[args["work"]](facts["model"],
-                                         _traced(facts))["flops"]
+    flops = facts["work"][args["work"]](facts["model"],
+                                        _traced(facts))["flops"]
     if not flops or trace.window_s <= 0:
         return None
     return 100.0 * flops / (trace.window_s * peaks["bf16_flops_per_s"])
@@ -261,7 +262,7 @@ def read_mfu(trace, facts, args, peaks):
 def _least_seconds(facts, args, peaks) -> float:
     """The least time the chip could take for the work ``args`` names: the
     larger of FLOPs over peak and bytes over bandwidth."""
-    w = work.FUNCTIONS[args["work"]](facts["model"], _traced(facts))
+    w = facts["work"][args["work"]](facts["model"], _traced(facts))
     return max(w["flops"] / peaks["bf16_flops_per_s"],
                w["bytes"] / peaks["hbm_bytes_per_s"])
 

@@ -1,8 +1,7 @@
-"""The ``serve`` driver: ``ServingEngine`` (a copy of
-``chip_smoke.build_serve_model`` / ``serve_config`` as PR 21 ran them)
-given the benchmark's weights, driven through ``add_request`` and
-``step()`` by an open or a closed loop from one thread. The benchmark times
-every request itself, from when it was due."""
+"""The ``serve`` driver: the ``ServingEngine`` that the configuration's
+family builds over the benchmark's weights, driven through ``add_request``
+and ``step()`` by an open or a closed loop from one thread. The benchmark
+times every request itself, from when it was due."""
 from __future__ import annotations
 
 import time
@@ -11,8 +10,7 @@ from collections import Counter
 import numpy as np
 
 from . import check, reference, traffic, weights
-from .common import (Run, TraceWindow, check_preset, install_weights,
-                     peak_bytes, release, say)
+from .common import Run, TraceWindow, family_of, peak_bytes, release, say
 from .reduce import percentile
 
 TERMINAL = ("finished", "cancelled", "expired", "failed", "shed")
@@ -20,39 +18,39 @@ TERMINAL = ("finished", "cancelled", "expired", "failed", "shed")
 DRAIN_S = 60.0
 #: requests to a block of the reference
 REFERENCE_ROWS = 8
+#: a step this long by the host's clock is a stall: the driver then keeps
+#: where the engine's own spans say the time went
+STALL_S = 0.1
+
+
+#: the engine's counters that the driver itself checks
+CHECKED_COUNTERS = ("serving_prefix_tokens_saved",
+                    "serving_preemptions_total")
+DRAINS = "serving_decode_drains_total{"
+
+
+def pool_pages(sv: dict, page_bytes: int) -> int:
+    """The pages of a configuration's ``serve`` group: their number, or
+    those that fit its share of the device's limit at ``page_bytes`` a
+    page."""
+    import jax
+
+    if "num_pages" in sv:
+        return sv["num_pages"]
+    limit = int(jax.devices()[0].memory_stats()["bytes_limit"])
+    return int(limit * sv["pool_share_of_device_limit"]) // page_bytes
 
 
 def build(run: Run):
     import jax
 
-    import paddle_tpu as paddle
-    from paddle_tpu.serving import ServingConfig, ServingEngine
-    from paddle_tpu.text.gpt import GPTForCausalLM, gpt_config
-
+    family = family_of(run.config)
     t0 = time.perf_counter()
-    cfg, m, sv = run.config, run.config["model"], run.config["serve"]
-    gcfg = gpt_config(cfg["program_preset"], max_seq_len=m["max_seq_len"],
-                      dropout=m["dropout"])
-    check_preset(cfg, gcfg)
-    # shapes only (LazyGuard): the program's own initializers never run
-    with paddle.LazyGuard():
-        model = GPTForCausalLM(gcfg)
-    model.eval()
-    install_weights(model, weights.make_weights(m, run.seed))
-    dev = jax.devices()[0]
-    page_bytes = 2 * m["num_layers"] * sv["page_size"] * m["hidden_size"] * 4
-    if "num_pages" in sv:
-        pages = sv["num_pages"]
-    else:
-        limit = int(dev.memory_stats()["bytes_limit"])
-        pages = int(limit * sv["pool_share_of_device_limit"]) // page_bytes
-    engine = ServingEngine(model, ServingConfig(
-        max_batch=sv["max_batch"], num_pages=pages,
-        page_size=sv["page_size"], max_prompt_len=sv["max_prompt_len"],
-        enable_prefix_caching=sv["enable_prefix_caching"],
-        do_sample=sv["do_sample"], tensor_parallel=sv["tensor_parallel"],
-        chunk_size=sv["chunk_size"]))
-    say("serve.built", pool_pages=pages, pool_bytes=pages * page_bytes,
+    engine = family.build_serving(
+        run, weights.for_program(family, run.config, run.seed))
+    say("serve.built", pool_pages=engine.config.num_pages,
+        pool_bytes=sum(int(a.nbytes) for a in
+                       jax.tree_util.tree_leaves(engine.cache.pools)),
         seconds=round(time.perf_counter() - t0, 2),
         since_process_start=round(time.time() - run.t_process, 2))
     return engine
@@ -90,10 +88,22 @@ class Loop:
         waiting = [r for r in self.live.values() if r["admitted"] is None]
         decoding = [(r, len(r["obj"].generated)) for r in self.live.values()
                     if r["admitted"] is not None]
+        cpu0 = time.thread_time()
         self.engine.step()
         t1 = now()
         st = {"t0": t0, "t1": t1, "prefills": [], "decode_tokens": 0,
               "decode_ctx_tokens": 0}
+        if t1 - t0 > STALL_S:
+            # this thread's CPU seconds tell a host that computed (a
+            # collection, a retrace) from one that waited (for the device,
+            # a transfer, or its turn on a shared core)
+            last = getattr(self.engine.timeline, "last", None)
+            st["stall"] = {
+                "at_s": round(t0, 2), "ms": round(1e3 * (t1 - t0), 1),
+                "thread_cpu_ms": round(1e3 * (time.thread_time() - cpu0), 1),
+                "spans_ms": {k: round(1e3 * v, 1) for k, v in
+                             {**last.phase_s, **last.span_s}.items()
+                             if v > 0.001} if last else None}
         for r in waiting:
             o = r["obj"]
             if o.state != "waiting":
@@ -155,10 +165,15 @@ def _bucket(engine, tail: int) -> int:
     return next(b for b in engine.prefill_buckets if b >= tail)
 
 
-def counters(engine) -> dict:
+def counters(engine, names) -> dict:
+    """The engine's counters now: those the driver checks, every one of
+    ``names`` that the engine has (``run.counters``: what the cell's metric
+    files read), and the drains by reason."""
     snap = engine.metrics.snapshot()
-    return {k: float(snap.get(k, 0)) for k in (
-        "serving_prefix_tokens_saved", "serving_preemptions_total")}
+    out = {k: float(snap.get(k, 0)) for k in CHECKED_COUNTERS}
+    out.update({k: float(v) for k, v in snap.items()
+                if k in names or k.startswith(DRAINS)})
+    return out
 
 
 def drive(run: Run) -> dict:
@@ -183,7 +198,7 @@ def measure(run: Run, engine) -> list:
     mix, vocab = run.mix, run.config["model"]["vocab_size"]
     requests = traffic.serve_requests(mix, vocab, run.seed, run.seconds)
     compiles0 = dict(engine.compile_counts)
-    count0 = counters(engine)
+    count0 = counters(engine, run.counters)
     loop, tw = Loop(engine), TraceWindow(run)
     open_loop = mix["kind"] == "open_loop"
     nxt = 0
@@ -226,7 +241,7 @@ def measure(run: Run, engine) -> list:
     window_s = now()
     tw.close()
     in_window_steps = len(loop.steps)
-    count1 = counters(engine)
+    count1 = counters(engine, run.counters)
     backlog = len(loop.live)
 
     # ---- past the close: nothing new is offered; what was due is awaited
@@ -253,10 +268,9 @@ def measure(run: Run, engine) -> list:
         out_tokens += len(tt)
         gaps.extend(1e3 * (b - a) for a, b in zip(tt, tt[1:]))
     cached = Counter(r["cached"] for r in recs if r["cached"] is not None)
-    saved = count1["serving_prefix_tokens_saved"] \
-        - count0["serving_prefix_tokens_saved"]
-    preempted = count1["serving_preemptions_total"] \
-        - count0["serving_preemptions_total"]
+    counted = {k: v - count0.get(k, 0.0) for k, v in count1.items()}
+    saved = counted["serving_prefix_tokens_saved"]
+    preempted = counted["serving_preemptions_total"]
     say("serve.window", window_s=window_s, drain_s=drain_s,
         requests=len(recs), finished=len(done), failed=failed,
         refused=loop.refused, backlog_at_close=backlog,
@@ -264,6 +278,8 @@ def measure(run: Run, engine) -> list:
         steps=in_window_steps, out_tokens=out_tokens,
         cached_tokens_per_request=dict(cached),
         prefix_tokens_saved=saved, preemptions=preempted,
+        counters={k: v for k, v in counted.items()
+                  if k not in CHECKED_COUNTERS},
         compile_counts_before=compiles0, compile_counts_after=compiles1,
         compiles_in_window=sum(compiles1.values()) - sum(compiles0.values()))
     # where the window's time went by the host's clock: a stall of the
@@ -282,6 +298,8 @@ def measure(run: Run, engine) -> list:
             between_steps_ms_total=float(1e3 * window_s - took.sum()),
             longest_steps_ms=[round(float(x), 2)
                               for x in np.sort(took)[::-1][:6]],
+            stalls=sorted((s["stall"] for s in loop.steps[:in_window_steps]
+                           if "stall" in s), key=lambda x: -x["ms"])[:4],
             stalled_ms_total=float(over[over > 0].sum()))
     if compiles1 != compiles0:
         raise SystemExit("a program was compiled inside the window: "
@@ -306,8 +324,12 @@ def measure(run: Run, engine) -> list:
     run.facts.update(
         memory_peak_bytes=memory, window_s=window_s, attempted=len(recs),
         failed=failed, end_to_end=e2e, spans=spans, trace=tw,
-        counters={"prefix_tokens_saved": saved_calm,
-                  "prompt_tokens_offered": prompt_tokens},
+        # the driver's own two, of the calm requests; the engine's, over
+        # the window
+        counters=dict({k: v for k, v in counted.items()
+                       if not k.startswith(DRAINS)},
+                      prefix_tokens_saved=saved_calm,
+                      prompt_tokens_offered=prompt_tokens),
         traced=traced_facts(loop.steps, tw, w0))
     return done
 
@@ -362,27 +384,27 @@ def compare(run: Run, sample: list) -> dict:
     """The reference's float32 logits over each sampled prompt with its
     served tokens, ``REFERENCE_ROWS`` requests to a block: how far every
     served token lies below the reference's best."""
-    import jax
     import jax.numpy as jnp
 
-    m, rows = run.config["model"], REFERENCE_ROWS
+    family, m, rows = family_of(run.config), run.config["model"], \
+        REFERENCE_ROWS
     t0 = time.perf_counter()
-    p = weights.make_weights(m, run.seed)
+    leaves_of = weights.for_reference(family, run.config, run.seed)
     # the longest a request can be: its prompt and all its output
     n_out = run.mix["output_tokens"]["max"]
     length = run.config["serve"]["max_prompt_len"] + n_out
     low = run.config["precision"]["controls"][0]
 
-    @jax.jit
-    def gaps_fn(p, ids, pos, tok):
+    def gaps_fn(ids, pos, tok):
         """(the served tokens' gaps, the control's): the control is the
         token that the lower precision puts first at each position of the
         same prompts and served tokens, read under the same logits."""
-        logits = reference.logits_at(p, ids, pos, m)
+        logits = family.logits_at(leaves_of, ids, pos, m)
         if not run.control:
             return reference.below_best(logits, tok), None
-        return (reference.below_best(logits, tok), reference.below_best(
-            logits, reference.first_tokens(p, ids, pos, m, low)))
+        return reference.below_best(logits, tok), reference.below_best(
+            logits, reference.first_tokens(family.logits_at, leaves_of, ids,
+                                           pos, m, low))
 
     gaps, control = [], []
     for at in range(0, len(sample), rows):
@@ -397,8 +419,7 @@ def compare(run: Run, sample: list) -> dict:
             pos[i] = np.minimum(len(prompt) - 1 + np.arange(n_out),
                                 len(seq) - 1)
             tok[i, :len(served)] = served
-        g, c = gaps_fn(p, jnp.asarray(ids), jnp.asarray(pos),
-                       jnp.asarray(tok))
+        g, c = gaps_fn(jnp.asarray(ids), jnp.asarray(pos), jnp.asarray(tok))
         g, c = np.asarray(g), np.asarray(c) if run.control else None
         for i, r in enumerate(block):
             gaps.append(g[i, :len(r["tokens"])])

@@ -1,7 +1,8 @@
 """The ``train`` driver: the compiled step of ``build_hybrid_step`` on a
-one-device mesh (a copy of ``chip_smoke.build_train`` as PR 21 ran it),
-given the benchmark's weights, driven through its first checked steps in
-set-up and then through the window by the same call and feed."""
+one-device mesh (a copy of ``chip_smoke.build_train`` as PR 21 ran it)
+over the model that the configuration's family builds with the benchmark's
+weights, driven through its first checked steps in set-up and then through
+the window by the same call and feed."""
 from __future__ import annotations
 
 import collections
@@ -10,8 +11,7 @@ import time
 import numpy as np
 
 from . import check, reference, traffic, weights
-from .common import (Run, TraceWindow, check_preset, install_weights,
-                     peak_bytes, release, say)
+from .common import Run, TraceWindow, family_of, peak_bytes, release, say
 
 #: the steps that set-up drives and the reference follows
 CHECKED_STEPS = 3
@@ -20,34 +20,21 @@ CHECKED_STEPS = 3
 REFERENCE_ROWS = 1
 
 
-def build(run: Run):
-    """(step, state, feed): the jitted step, its state holding the
-    benchmark's weights, and the feed that puts a host batch on the
-    device."""
+def hybrid_step(run: Run, model):
+    """(step, state, feed) over a family's model that holds the benchmark's
+    weights: the jitted step, its state, and the feed that puts a host
+    batch on the device. The step's state aliases the model's weights (and
+    donates them), and the fp32 master starts as their exact copy."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import Mesh
 
     import paddle_tpu as paddle
     from paddle_tpu.distributed.fleet.hybrid_train import build_hybrid_step
-    from paddle_tpu.text.gpt import GPTForCausalLM, gpt_config
 
-    cfg, m, tr = run.config, run.config["model"], run.config["train"]
+    cfg, tr = run.config, run.config["train"]
     if list(tr["mesh"].values()) != [1]:
         raise SystemExit("the train driver runs a one-device mesh")
     mesh = Mesh(np.array(jax.devices()[:1]), tuple(tr["mesh"]))
-    gcfg = gpt_config(cfg["program_preset"], max_seq_len=m["max_seq_len"],
-                      dropout=m["dropout"],
-                      loss_chunk_size=tr["loss_chunk_size"],
-                      recompute=tr["recompute"])
-    check_preset(cfg, gcfg)
-    # shapes only (LazyGuard): the program's own initializers never run
-    with paddle.LazyGuard():
-        model = GPTForCausalLM(gcfg)
-    # the benchmark's weights, in the type they are trained in, go into the
-    # model before the step is built: the step's state then aliases them
-    # (and donates them), and the fp32 master starts as their exact copy
-    install_weights(model, weights.make_weights(m, run.seed, jnp.bfloat16))
     model.to(dtype=cfg["precision"]["parameters"])
     o = cfg["optimizer"]
     opt = paddle.optimizer.AdamW(
@@ -60,33 +47,27 @@ def build(run: Run):
     state = init_fn()
 
     def feed(step_index: int):
-        ids, labels = traffic.train_batch(run.mix, m["vocab_size"],
+        ids, labels = traffic.train_batch(run.mix, cfg["model"]["vocab_size"],
                                           run.seed, step_index)
         return tuple(shard_batch([ids, labels]))
 
     return step, state, feed
 
 
-def start_f32(run: Run) -> dict:
-    """The weights the run started from, as the reference takes them: the
-    bfloat16 values, held in float32 (what the program's fp32 master
-    starts as)."""
-    import jax
-    import jax.numpy as jnp
-
-    low = weights.make_weights(run.config["model"], run.seed, jnp.bfloat16)
-    return jax.jit(lambda t: {n: v.astype(jnp.float32)
-                              for n, v in t.items()})(low)
+def start_f32(run: Run, family) -> dict:
+    """The weights the run started from, as the reference takes them (what
+    the program's fp32 master starts as). A following consumes them."""
+    return weights.for_reference(family, run.config, run.seed)()
 
 
 def drive(run: Run) -> dict:
     import jax
-    import jax.numpy as jnp
 
-    o, mix = run.config["optimizer"], run.mix
+    family, o, mix = family_of(run.config), run.config["optimizer"], run.mix
     tokens_per_step = mix["batch"] * mix["seq"]
     t0 = time.perf_counter()
-    step, state, feed = build(run)
+    step, state, feed = family.build_training(
+        run, weights.for_program(family, run.config, run.seed))
     say("train.built", seconds=round(time.perf_counter() - t0, 2),
         since_process_start=round(time.time() - run.t_process, 2))
     key = jax.random.key(0)                      # dropout is 0: unused
@@ -107,14 +88,16 @@ def drive(run: Run) -> dict:
                       for n, s in state["opt"]["slots"].items()}
             prog["grad_norm"] = {
                 n: float(x) / (1.0 - o["beta1"])
-                for n, x in reference.leaf_norms(moment).items()}
+                for n, x in
+                reference.leaf_norms(moment, family.parts).items()}
             del moment
     # between two steps the chip has room for the weights the run started
     # from: made again from the seed, compared, dropped
     master = {n: s["master_weight"] for n, s in state["opt"]["slots"].items()}
-    start = weights.make_weights(run.config["model"], run.seed, jnp.bfloat16)
+    start = weights.for_program(family, run.config, run.seed)
     prog["change_norm"] = {
-        n: float(x) for n, x in reference.delta_norms(master, start).items()}
+        n: float(x) for n, x in
+        reference.delta_norms(master, start, family.parts).items()}
     del master, start
     say("train.checked_steps", losses=prog["losses"])
 
@@ -174,10 +157,11 @@ def drive(run: Run) -> dict:
     del state, compiled, step, pending, loss, tail
     release()
     t0 = time.perf_counter()
-    p0 = start_f32(run)
+    p0 = start_f32(run, family)
     batches = [traffic.train_batch(mix, run.config["model"]["vocab_size"],
                                    run.seed, i) for i in range(checked)]
-    ref = reference.follow_training(p0, batches, run.config["model"], o,
+    ref = reference.follow_training(family, p0, batches,
+                                    run.config["model"], o,
                                     rows=REFERENCE_ROWS)
     say("train.reference", seconds=round(time.perf_counter() - t0, 2),
         losses=ref["losses"])
@@ -192,10 +176,10 @@ def drive(run: Run) -> dict:
                   for c in run.config["precision"]["controls"]),
                 ("fault_half_batch",
                  {"keep_rows": slice(0, mix["batch"] // 2)})):
-            p0 = start_f32(run)
+            p0 = start_f32(run, family)
             got = reference.follow_training(
-                p0, batches, run.config["model"], o, rows=REFERENCE_ROWS,
-                **kw)
+                family, p0, batches, run.config["model"], o,
+                rows=REFERENCE_ROWS, **kw)
             n, at = check.train_numbers(got, ref)
             ok, rows = check.judge(n, run.check["limits"])
             say("train." + label, correct=ok, compared=rows,

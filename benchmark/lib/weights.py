@@ -1,16 +1,19 @@
 """Weights from ``--seed``, made by the benchmark for the program and for
 the plain reference alike: one jitted call on the device, every leaf drawn
 from its own fold of the seed, so the same seed gives the same model
-whoever asks and in whatever order.
+whoever asks, in whatever order and in however many parts.
 
-Leaves carry the names the program's ``GPTForCausalLM.functional_state()``
-uses, because that dict is how weights are handed to it. The published
-initialisation is N(0, 0.02) for matrices and embeddings, zeros for biases
-and ones for LayerNorm scales; biases and scales here get a small seeded
+A ``table`` is ``{name: (shape, kind)}``, as a family's ``leaf_table``
+gives it (``benchmark/families/<family>.py``); the names are those by which
+the program takes its weights. The rule is the published initialisation:
+N(0, 0.02) for a ``matrix`` (embeddings too), ones for a ``scale`` and
+zeros for a ``bias``; scales and biases here get a small seeded
 perturbation so that a path that dropped one would show in ``correct``.
 """
 from __future__ import annotations
 
+import functools
+import math
 import zlib
 
 import jax
@@ -19,45 +22,9 @@ import jax.numpy as jnp
 INIT_STD = 0.02
 BIAS_STD = 0.01
 
-#: per-block leaves: name -> (shape as a function of (h, f), kind)
-_BLOCK = {
-    "ln1.weight": (lambda h, f: (h,), "scale"),
-    "ln1.bias": (lambda h, f: (h,), "bias"),
-    "attn.qkv_proj.weight": (lambda h, f: (h, 3 * h), "matrix"),
-    "attn.qkv_proj.bias": (lambda h, f: (3 * h,), "bias"),
-    "attn.out_proj.weight": (lambda h, f: (h, h), "matrix"),
-    "attn.out_proj.bias": (lambda h, f: (h,), "bias"),
-    "ln2.weight": (lambda h, f: (h,), "scale"),
-    "ln2.bias": (lambda h, f: (h,), "bias"),
-    "mlp.fc1.weight": (lambda h, f: (h, f), "matrix"),
-    "mlp.fc1.bias": (lambda h, f: (f,), "bias"),
-    "mlp.fc2.weight": (lambda h, f: (f, h), "matrix"),
-    "mlp.fc2.bias": (lambda h, f: (h,), "bias"),
-}
 
-
-def leaf_table(model: dict) -> dict[str, tuple[tuple[int, ...], str]]:
-    """name -> (shape, kind) for every leaf of a configuration's ``model``
-    group (tied embedding: no separate head)."""
-    h, f = model["hidden_size"], model["ffn_hidden"]
-    table = {"gpt.wte.weight": ((model["vocab_size"], h), "matrix"),
-             "gpt.wpe.weight": ((model["max_seq_len"], h), "matrix")}
-    for i in range(model["num_layers"]):
-        for name, (shape, kind) in _BLOCK.items():
-            table[f"gpt.blocks.{i}.{name}"] = (shape(h, f), kind)
-    table["gpt.ln_f.weight"] = ((h,), "scale")
-    table["gpt.ln_f.bias"] = ((h,), "bias")
-    return table
-
-
-def num_params(model: dict) -> int:
-    n = 0
-    for shape, _ in leaf_table(model).values():
-        k = 1
-        for d in shape:
-            k *= d
-        n += k
-    return n
+def num_params(table: dict) -> int:
+    return sum(math.prod(shape) for shape, _ in table.values())
 
 
 def seed_key(seed: int):
@@ -77,10 +44,17 @@ def _leaf(key, name: str, shape, kind: str):
     return BIAS_STD * x
 
 
-def make_weights(model: dict, seed: int, dtype=jnp.float32) -> dict:
-    """Every leaf, drawn in float32 and rounded to ``dtype`` (the type the
-    configuration trains or serves in), from one jitted call."""
-    table = leaf_table(model)
+def make_weights(table: dict, seed: int, dtype=jnp.float32,
+                 only=None) -> dict:
+    """Every leaf of ``table``, drawn in float32 and rounded to ``dtype``
+    (the type the configuration trains or serves in), from one jitted call.
+    ``only`` draws a part: the leaves whose name starts with it (a prefix)
+    or is in it (a set), with the values the whole call gives them, so that
+    a reference can hold one layer at a time."""
+    if isinstance(only, str):
+        table = {n: t for n, t in table.items() if n.startswith(only)}
+    elif only is not None:
+        table = {n: table[n] for n in only}
 
     @jax.jit
     def make(key):
@@ -88,3 +62,32 @@ def make_weights(model: dict, seed: int, dtype=jnp.float32) -> dict:
                 for n, (s, k) in table.items()}
 
     return make(seed_key(seed))
+
+
+@jax.jit
+def _held_in_float32(tree: dict) -> dict:
+    return {n: v.astype(jnp.float32) for n, v in tree.items()}
+
+
+def for_program(family, config: dict, seed: int) -> dict:
+    """The leaves of a configuration's model as the program gets them: in
+    the type the configuration trains or serves in."""
+    return make_weights(family.leaf_table(config["model"]), seed,
+                        config["precision"]["parameters"])
+
+
+def for_reference(family, config: dict, seed: int):
+    """``leaves_of(only=None)``, as a family's reference draws its leaves:
+    the values the program got, held in float32, all of them or the part
+    that ``only`` names. A reference that asks for all can hold all, and
+    gets the same dict each time. Two calls and not one: inside one program
+    the TPU compiler drops a cast down and up again as excess precision."""
+    table = family.leaf_table(config["model"])
+    dtype = jnp.dtype(config["precision"]["parameters"])
+
+    def draw(only):
+        low = make_weights(table, seed, dtype, only)
+        return low if dtype == jnp.float32 else _held_in_float32(low)
+
+    whole = functools.cache(lambda: draw(None))
+    return lambda only=None: whole() if only is None else draw(only)

@@ -10,7 +10,10 @@ timed path produced with the plain reference, and prints one JSON object as
 the last line of standard output. Everything a cell is made of is data that
 this file finds by name: ``BENCHMARK.json`` (cells, metrics), and under
 ``benchmark/``: ``configs/<config>.json``, ``traffic/<traffic>.json``,
-``limits/<workload>.json``, ``metrics/<metric>.json``, ``peaks.json``.
+``limits/<workload>.json``, ``metrics/<metric>.json``, ``peaks.json``, and
+``families/<family>.py`` for the ``family`` that the configuration names
+(the model's leaves, the program's model built for the driver, the plain
+reference, the work functions of its rooflines).
 It exits non-zero, and prints no result, without a TPU whose kind is in
 ``peaks.json``, with fewer chips than the cell asks for, or without the
 program beside it.
@@ -47,6 +50,18 @@ def applies(metric: dict, workload: str) -> bool:
     return "workloads" not in metric or workload in metric["workloads"]
 
 
+def cell_files(workload: str):
+    """(BENCHMARK.json, the cell's entry, its configuration, mix and
+    limits), each found by the name the one before gives."""
+    bench = load(ROOT, "BENCHMARK.json")
+    cell = by_name(bench["workloads"], workload, "workload")
+    config = load(ROOT, by_name(bench["configs"], cell["config"],
+                                "configuration")["file"])
+    mix = load(HERE, "traffic", cell["traffic"] + ".json")
+    check = load(HERE, "limits", cell["name"] + ".json")
+    return bench, cell, config, mix, check
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -63,12 +78,7 @@ def main() -> None:
                          "driver's runs never ask for it.")
     args = ap.parse_args()
 
-    bench = load(ROOT, "BENCHMARK.json")
-    cell = by_name(bench["workloads"], args.workload, "workload")
-    config = load(ROOT, by_name(bench["configs"], cell["config"],
-                                "configuration")["file"])
-    mix = load(HERE, "traffic", cell["traffic"] + ".json")
-    check = load(HERE, "limits", cell["name"] + ".json")
+    bench, cell, config, mix, check = cell_files(args.workload)
     seconds = float(bench["run_seconds"]) if args.seconds is None \
         else args.seconds
     if not os.path.isdir(os.path.join(ROOT, "paddle_tpu")):
@@ -97,11 +107,32 @@ def main() -> None:
               t_process=T_PROCESS, control=bool(args.control))
     say("start", workload=cell["name"], seed=args.seed, seconds=seconds,
         trace=args.trace, device_kind=devs[0].device_kind,
-        devices=len(devs), jax=jax.__version__)
+        devices=len(devs), jax=jax.__version__,
+        since_process_start=round(time.time() - T_PROCESS, 2))
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": cell["chips"]}
     result = execute(run, bench, device)
     print(json.dumps(result), flush=True)
+
+
+def import_program() -> None:
+    """``import paddle_tpu``, before the first compile of the process: the
+    import places JAX's persistent compilation cache and sets what is kept
+    there. Most of its seconds on the chip's host are not the program's:
+    ``paddle_tpu.distributed.checkpoint`` imports ``orbax``, that imports
+    ``google.cloud.logging``, and every ``google.*`` package then asks
+    ``importlib.metadata.packages_distributions()``, 4 to 12 s a call there,
+    for the name it would print in a warning that it does not give. The
+    table cannot change while one process imports, so it is built once."""
+    import functools
+    import importlib.metadata as metadata
+
+    real = metadata.packages_distributions
+    metadata.packages_distributions = functools.cache(real)
+    try:
+        import paddle_tpu  # noqa: F401
+    finally:
+        metadata.packages_distributions = real
 
 
 def execute(run, bench: dict, device: dict) -> dict:
@@ -109,7 +140,18 @@ def execute(run, bench: dict, device: dict) -> dict:
     compare with the reference, reduce the trace. Returns the object of
     the last line (and prints the numbers compared to standard error)."""
     from benchmark.lib import check, reduce, serve, train
+    from benchmark.lib.common import family_of, say
 
+    import_program()
+    say("program.imported",
+        since_process_start=round(time.time() - run.t_process, 2))
+    per_layer = [(m, load(HERE, "metrics", m["name"] + ".json"))
+                 for m in bench["per_layer"] if applies(m, run.workload)]
+    # the engine's counters that a metric of this cell reads: the serving
+    # driver snapshots them at both ends of the window
+    run.counters = frozenset(
+        spec["args"][k] for _, spec in per_layer
+        for k in ("counter", "over") if k in spec.get("args", {}))
     numbers = {"train": train.drive, "serve": serve.drive}[
         run.config["driver"]](run)
     facts = run.facts
@@ -125,10 +167,8 @@ def execute(run, bench: dict, device: dict) -> dict:
         trace = reduce.load(path)
         tw.discard()
         facts["model"] = run.config["model"]
-        for m in bench["per_layer"]:
-            if not applies(m, run.workload):
-                continue
-            spec = load(HERE, "metrics", m["name"] + ".json")
+        facts["work"] = family_of(run.config).WORK
+        for m, spec in per_layer:
             value = reduce.READERS[spec["reader"]](
                 trace, facts, spec.get("args", {}), run.peaks)
             if value is not None:

@@ -78,11 +78,13 @@ def test_trace_busy_idle_and_modules():
         t, {}, {"span": "bench.step"}, PEAKS) == pytest.approx(1750.0)
 
 
-def test_roofline_readers_and_silence(monkeypatch):
+def test_roofline_readers_and_silence():
     t = trace()
-    monkeypatch.setitem(reduce.work.FUNCTIONS, "unit",
-                        lambda model, traced: {"flops": 60.0, "bytes": 15.0})
-    facts = {"model": {}, "traced": {}}
+    # a metric's ``args.work`` is looked up in the family's WORK, which
+    # ``run.execute`` hands over as facts["work"]
+    facts = {"model": {}, "traced": {}, "work": {
+        "unit": lambda model, traced: {"flops": 60.0, "bytes": 15.0},
+        "none": lambda model, traced: {"flops": 0.0, "bytes": 0.0}}}
     # least time max(60/100, 15/10) = 1.5 s over 3 s of kern_a
     assert reduce.read_kernel_roofline(
         t, facts, {"op": "kern_a", "work": "unit"}, PEAKS) \
@@ -95,8 +97,6 @@ def test_roofline_readers_and_silence(monkeypatch):
         == pytest.approx(100 * 1.5 / 6.5)
     assert reduce.read_mfu(t, facts, {"work": "unit"}, PEAKS) \
         == pytest.approx(100 * 60.0 / (10.0 * 100.0))
-    monkeypatch.setitem(reduce.work.FUNCTIONS, "none",
-                        lambda model, traced: {"flops": 0.0, "bytes": 0.0})
     assert reduce.read_mfu(t, facts, {"work": "none"}, PEAKS) is None
 
 
@@ -117,7 +117,7 @@ def run_ahead_trace(step=2.0, first=0.0, n=7, lo=1.0, hi=10.5):
     return reduce.Trace(evs)
 
 
-def test_work_is_counted_over_the_window_its_time_is(monkeypatch):
+def test_work_is_counted_over_the_window_its_time_is():
     """Seven steps of 2 s from 0 to 14, traced from 1 to 10.5: the window
     holds 0.5 + 4 + 0.25 steps, whatever number the host dispatched in it
     (with eight in flight, the old count: one more than the window
@@ -126,12 +126,11 @@ def test_work_is_counted_over_the_window_its_time_is(monkeypatch):
     assert t.window_s == pytest.approx(9.5)
     assert t.module_calls("jit_step") == pytest.approx(4.75)
     assert t.module_calls("decode") == 0.0
-    monkeypatch.setitem(
-        reduce.work.FUNCTIONS, "unit", lambda model, traced: {
-            "flops": 25.0 * traced.get("calls", 0),
-            "bytes": 1.0 * traced.get("calls", 0)})
+    work = {"unit": lambda model, traced: {
+        "flops": 25.0 * traced.get("calls", 0),
+        "bytes": 1.0 * traced.get("calls", 0)}}
     # the host's own count is not read, however wrong
-    facts = {"model": {}, "traced": {"calls": 99}}
+    facts = {"model": {}, "traced": {"calls": 99}, "work": work}
     step = {"work": "unit", "module": "jit_step"}
     # a step of 2 s does 25 FLOPs at a peak of 100 FLOP/s: 12.5%, however
     # the window cuts the steps (here 4.75 x 25 over 9.5 x 100)
@@ -153,7 +152,7 @@ def test_work_is_counted_over_the_window_its_time_is(monkeypatch):
     # synchronous steps give it: five calls' work over the 2.5 s of the
     # five kernel events that touch the window
     assert reduce.read_kernel_roofline(
-        t, {"model": {}, "traced": {"calls": 5}},
+        t, {"model": {}, "traced": {"calls": 5}, "work": work},
         {"op": "flash", "work": "unit"}, PEAKS) == pytest.approx(50.0)
 
 

@@ -44,8 +44,17 @@ def test_exits_non_zero_without_the_program(tmp_path):
     assert "paddle_tpu" in p.stderr
 
 
+#: what a driver takes from a configuration's family, beside ``check``,
+#: ``leaf_table`` and ``WORK``
+FAMILY_PARTS = {"serve": ("build_serving", "logits_at"),
+                "train": ("build_training", "loss_and_grads", "parts")}
+
+
 def test_benchmark_json_and_data_files_agree():
-    bench = tiny.bench_json()
+    from benchmark.lib import reduce
+    from benchmark.lib.common import family_of
+
+    bench, work = tiny.bench_json(), {}
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     cells = {w["name"] for w in bench["workloads"]}
@@ -55,9 +64,15 @@ def test_benchmark_json_and_data_files_agree():
         cfg = tiny.load(*c["file"].split("/")[1:])
         assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
         assert cfg["reduced"] == c["reduced"]
-        m = cfg["model"]
-        assert m["head_size"] * m["num_heads"] == m["hidden_size"]
-        assert m["ffn_hidden"] == 4 * m["hidden_size"]
+        # its family resolves by name, agrees with its sizes and offers
+        # the parts that its driver takes
+        family = family_of(cfg)
+        family.check(cfg)
+        assert family.leaf_table(cfg["model"])
+        assert cfg["model"]["vocab_size"] > 0
+        for part in FAMILY_PARTS[cfg["driver"]]:
+            assert callable(getattr(family, part)), (cfg["family"], part)
+        work[c["name"]] = family.WORK
     for w in bench["workloads"]:
         assert w["name"] == f'{w["config"]}.{w["traffic"]}'
         tiny.load("traffic", w["traffic"] + ".json")
@@ -71,10 +86,11 @@ def test_benchmark_json_and_data_files_agree():
         spec = tiny.load("metrics", m["name"] + ".json")
         assert spec["layer"] == m["layer"] and spec["unit"] == m["unit"]
         assert spec["moves"] == m["moves"] and spec["source"] == m["source"]
-        from benchmark.lib import reduce, work
         assert spec["reader"] in reduce.READERS
         if "work" in spec.get("args", {}):
-            assert spec["args"]["work"] in work.FUNCTIONS
+            # in the family of every cell that reports the metric
+            for w in m["workloads"]:
+                assert spec["args"]["work"] in work[w.rsplit(".", 1)[0]]
         assert set(m["workloads"]) <= cells
         moved = e2e[m["moves"]]
         assert set(m["workloads"]) <= set(moved.get("workloads", cells))
