@@ -608,9 +608,24 @@ class StepSpec:
     min_devices: int = 1
 
 
+def _tiny_latent_model():
+    """A toy Kimi-K2 (text/kimi_k2.py): MLA over the latent pool, a dense
+    layer and an expert layer that holds 4 of 8 experts."""
+    from ..text.kimi_k2 import KimiK2Config, KimiK2ForCausalLM
+
+    return KimiK2ForCausalLM(KimiK2Config(
+        vocab_size=97, hidden_size=32, intermediate_size=64,
+        moe_intermediate_size=16, num_hidden_layers=2,
+        num_attention_heads=2, n_routed_experts=8, num_experts_per_tok=2,
+        kv_lora_rank=16, q_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8, max_position_embeddings=32,
+        held_experts=(2, 4)))
+
+
 def _build_engine_step(which: str, tensor_parallel: int = 1,
                        kv_dtype: str = "float32",
-                       quantized_logits: bool = False):
+                       quantized_logits: bool = False,
+                       latent: bool = False):
     """Engine-step audit targets. ``tensor_parallel=2`` builds the SAME
     step on a 2-device mesh (Megatron weight + KV-pool shards via
     serving/tp.py shard_map) with the budget the engine itself declares:
@@ -637,7 +652,10 @@ def _build_engine_step(which: str, tensor_parallel: int = 1,
     from ..text.gpt import GPTConfig, GPTForCausalLM
 
     paddle.seed(7)
-    model = GPTForCausalLM(GPTConfig(
+    # ``latent``: the same steps over a latent-attention model's one-leaf
+    # pool (the model's own counters ride behind the tokens) — single
+    # chip, zero collectives, the donated pool aliased
+    model = _tiny_latent_model() if latent else GPTForCausalLM(GPTConfig(
         vocab_size=97, hidden_size=32, num_layers=2, num_heads=2,
         max_seq_len=32, dropout=0.0))
     model.eval()
@@ -842,6 +860,17 @@ REGISTRY: dict[str, StepSpec] = {s.name: s for s in (
              lambda: _build_engine_step("decode", tensor_parallel=2,
                                         kv_dtype="int8"),
              min_devices=2),
+    # ---- the latent-attention model (text/kimi_k2.py) under the same
+    # engine: one kv_pool leaf a layer, donated and aliased; the dropless
+    # expert layer's sort and grouped product add no host transfer
+    StepSpec("engine_prefill_latent", "serving prefill step of the latent-"
+             "attention expert model (expanded MLA over rows read back "
+             "from the pool; budget: zero collectives)",
+             lambda: _build_engine_step("prefill", latent=True)),
+    StepSpec("engine_decode_latent", "serving decode step of the latent-"
+             "attention expert model (absorbed MLA over the latent pool, "
+             "dropless expert layer; budget: zero collectives)",
+             lambda: _build_engine_step("decode", latent=True)),
     # ---- quantized logits all-reduce (tp_quantized_logits=True): the
     # b*s*V f32 logits payload ships as int8 codes + a 4-byte shared
     # scale — budget 2L+2 all-reduces with the logits byte term counted

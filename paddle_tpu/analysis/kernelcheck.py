@@ -942,6 +942,53 @@ def _build_ragged(mode: str):
         composite=composite, composite_args=args)
 
 
+def _build_mla_decode():
+    """The absorbed latent-attention decode kernel at a canonical serving
+    shape: 2 rows of 4 heads over a 640-wide latent pool (rank 512 + 64
+    rotary + the lane pad), 32 pages a row staged 16 pages a chunk through
+    two alternating buffers. The output map is the row's own index, so no
+    data-dependent output needs a proof; the HBM model counts the q and
+    output blocks (the pool rides manual DMA)."""
+    import jax.numpy as jnp
+
+    from ..kernels import latent_paged_attention as lp
+
+    b, h, rank, rope, ps, pps, npages = 2, 4, 512, 64, 16, 32, 64
+    width = lp.padded_width(rank + rope)
+    q_lat = _sds((b, h, rank), jnp.float32)
+    q_rope = _sds((b, h, rope), jnp.float32)
+    pool = _sds((npages, ps, width), jnp.float32)
+    table = _sds((b, pps), jnp.int32)
+    ctx = _sds((b,), jnp.int32)
+    ok, why = lp.mla_kernel_eligible(h, width, rank, ps, pps, itemsize=4)
+    constraints = (
+        ("mla_kernel_eligible", ok, why or
+         "the canonical shape must pass the absorbed-decode kernel's gate"),
+    )
+
+    def fn(ql, qr, pool, t, c):
+        q = jnp.concatenate(
+            [ql, qr, jnp.zeros((b, h, width - rank - rope), ql.dtype)], -1)
+        return lp.mla_decode_kernel_call(q, pool, t, c, rank=rank,
+                                         scale=0.1)
+
+    def composite(ql, qr, pool, t, c):
+        seq = lp.latent_gather(pool, t)
+        scores = (jnp.einsum("bhr,bsr->bhs", ql, seq[..., :rank])
+                  + jnp.einsum("bhd,bsd->bhs", qr,
+                               seq[..., rank:rank + rope])) * 0.1
+        w = lp._ragged_softmax(scores[:, :, None, :], c, 1)[:, :, 0]
+        return jnp.einsum("bhs,bsr->bhr", w, seq[..., :rank])
+
+    args = (q_lat, q_rope, pool, table, ctx)
+    return dict(
+        fn=fn, args=args, budget=KernelBudget(), constraints=constraints,
+        # scores over rank + rope, values over rank, all heads, the whole
+        # gathered width, x2 flops/MAC
+        flops=float(2 * b * h * ps * pps * (2 * rank + rope)),
+        composite=composite, composite_args=args)
+
+
 def _build_ln(which: str):
     import jax.numpy as jnp
 
@@ -1054,6 +1101,10 @@ REGISTRY: dict[str, KernelSpec] = {s.name: s for s in (
                "single-row chunked-prefill tail (64-pad bucket, "
                "ctx0=192) — prefill and chunk ride the same program",
                lambda: _build_ragged("prefill")),
+    KernelSpec("mla_decode", "absorbed latent-attention (MLA) decode over "
+               "a latent paged pool: one grid step a row, the row's live "
+               "pages staged once for all heads through two alternating "
+               "buffers, online softmax", _build_mla_decode),
     KernelSpec("fused_layernorm_fwd", "fused LayerNorm forward (one HBM "
                "pass per row block, stats saved for the backward)",
                lambda: _build_ln("fwd")),

@@ -447,25 +447,25 @@ def prefill_buckets(max_prompt_len: int) -> list[int]:
 
 
 class ServingEngine:
-    """Continuous-batching engine over a GPTForCausalLM-shaped model (any
-    model exposing ``functional_state``/``functional_call`` with the paged
-    cache contract of text/gpt.py works)."""
+    """Continuous-batching engine over any model with the paged-cache
+    contract (text/gpt.py's ``GPTForCausalLM``, text/kimi_k2.py's
+    ``KimiK2ForCausalLM``): ``functional_state`` / ``functional_call``,
+    ``forward(ids, caches=[{<its pool leaves>, page_table, ctx_lens,
+    valid, kv_limit}, ...]) -> (logits, new_caches)``, and
+    ``paged_cache_spec(...)``, by which the model states what it keeps a
+    layer (``kv_cache.PagedCacheSpec``) and refuses what it cannot do.
+    The engine reads no model by its field names."""
 
     def __init__(self, model, config: ServingConfig | None = None,
                  clock=None, fault_injector=None, draft_model=None):
         self.config = cfg = config or ServingConfig()
         self.model = model
         model.eval()
-        mc = model.cfg
         if draft_model is not None and (
                 cfg.spec is None or cfg.spec.method != "draft"):
             raise ValueError(
                 "draft_model= is the spec proposer — it needs "
                 "ServingConfig(spec=SpecConfig(method='draft', ...))")
-        if cfg.max_prompt_len > mc.max_seq_len:
-            raise ValueError(
-                f"max_prompt_len {cfg.max_prompt_len} exceeds the model's "
-                f"max_seq_len {mc.max_seq_len}")
         if cfg.chunk_size < 0:
             raise ValueError(f"chunk_size {cfg.chunk_size} < 0")
         if cfg.chunk_size > cfg.max_prompt_len:
@@ -494,6 +494,15 @@ class ServingEngine:
                 "host_tier_bytes gives evicted INDEXED prefix pages a "
                 "second life — enable_prefix_caching=False would leave "
                 "nothing to spill; enable it or drop the tier")
+        # what the model keeps a layer, and its refusal (with the reason)
+        # of what it cannot do under this configuration
+        self._cache_spec = spec = model.paged_cache_spec(
+            kv_dtype=cfg.kv_dtype, tensor_parallel=cfg.tensor_parallel,
+            speculative=cfg.spec is not None)
+        if cfg.max_prompt_len > spec.max_seq_len:
+            raise ValueError(
+                f"max_prompt_len {cfg.max_prompt_len} exceeds the model's "
+                f"max_seq_len {spec.max_seq_len}")
         if cfg.flight_record_steps < 1:
             raise ValueError(
                 f"flight_record_steps {cfg.flight_record_steps} < 1")
@@ -510,31 +519,34 @@ class ServingEngine:
             # the first verify trace; a prebuilt draft_model's real
             # config wins over spec.draft
             cfg.spec.validate(
-                mc, draft_model.cfg if draft_model is not None else None)
+                model.cfg,
+                draft_model.cfg if draft_model is not None else None)
         if cfg.tensor_parallel > 1:
             # mesh + Megatron shard specs + shard_map wrappers; validates
             # divisibility (heads/hidden/ffn) and the visible device count
             from .tp import TPContext
             self._tp = TPContext(
-                cfg.tensor_parallel, mc,
+                cfg.tensor_parallel, model.cfg,
                 overlap_scheduler=cfg.tp_overlap_scheduler,
                 quantized_logits=cfg.tp_quantized_logits)
         else:
             self._tp = None
         pages_per_seq = cfg.pages_per_seq or \
-            -(-mc.max_seq_len // cfg.page_size)
+            -(-spec.max_seq_len // cfg.page_size)
         self.cache = PagedKVCache(PagedCacheConfig(
-            num_layers=mc.num_layers, num_heads=mc.num_heads,
-            head_dim=mc.hidden_size // mc.num_heads,
+            num_layers=spec.num_layers, leaves=spec.leaves,
             num_pages=cfg.num_pages, page_size=cfg.page_size,
             max_batch=cfg.max_batch, pages_per_seq=pages_per_seq,
-            dtype=model.gpt.wte.weight._value.dtype,
+            dtype=spec.dtype,
             enable_prefix_caching=cfg.enable_prefix_caching,
             debug_checks=cfg.debug_checks, tp=self._tp,
             kv_dtype=cfg.kv_dtype, host_tier_bytes=cfg.host_tier_bytes))
-        # the jitted steps thread every pool leaf through — scale leaves
-        # ride beside the codes in quantized mode, nothing else changes
+        # the jitted steps thread every pool leaf through — the model's
+        # own (scale leaves ride beside the codes in quantized mode)
         self._pool_keys = self.cache.cfg.pool_leaf_keys
+        # what the model counts a launch (an expert layer's assignments):
+        # an int32 vector behind the launch's tokens, in the same fetch
+        self._n_counters = len(spec.counters)
         self.prefill_buckets = prefill_buckets(cfg.max_prompt_len)
         self.metrics = ServingMetrics()
         self.metrics.on_tp_degree(cfg.tensor_parallel)
@@ -620,7 +632,7 @@ class ServingEngine:
             # count is known: admission and per-step growth must reserve
             # those K slots (over-allocation recycles via cache.shrink)
             self.scheduler.decode_reserve = cfg.spec.depth
-            self._hist = np.zeros((cfg.max_batch, mc.max_seq_len),
+            self._hist = np.zeros((cfg.max_batch, spec.max_seq_len),
                                   np.int32)
             if cfg.spec.method == "draft":
                 if draft_model is None:
@@ -636,18 +648,11 @@ class ServingEngine:
             self._spec = None
             self._hist = None
             self._draft = self._draft_p = None
-        from ..kernels import paged_attention as _pa
-        from ..kernels._common import on_tpu_backend
-        from ..utils.flags import flag
-
-        # whether the unified ragged kernel is even dispatchable for this
-        # engine's decode shapes — the single decode_kernel_eligible
-        # predicate (now the ragged_kernel_eligible gate), read once
-        self._decode_pallas_eligible, _ = _pa.decode_kernel_eligible(
-            mc.hidden_size // mc.num_heads, pages_per_seq, cfg.page_size,
-            num_heads=mc.num_heads, quantized=self.cache.cfg.quantized,
-            on_tpu=on_tpu_backend(),
-            flags_on=bool(flag("FLAGS_use_pallas_kernels", True)))
+        # whether a Pallas kernel is even dispatchable for this engine's
+        # decode shapes: the model's own gate (GPT: the unified ragged
+        # kernel's ragged_kernel_eligible), read once
+        self._decode_pallas_eligible = model.decode_kernel_eligible(
+            pages_per_seq, cfg.page_size, self.cache.cfg.quantized)
 
         self._fault_injector = fault_injector
         if fault_injector is not None and self.cache.host_tier is not None:
@@ -684,7 +689,7 @@ class ServingEngine:
         # the last launch's token output, the next launch's last tokens for
         # the slots whose token the host has not seen; until the first
         # launch a placeholder that every slot overrides
-        prev = np.full(b, cfg.pad_token_id, np.int32)
+        prev = np.full(b + self._n_counters, cfg.pad_token_id, np.int32)
         self._prev_toks = (jnp.asarray(prev) if self._tp is None
                            else self._tp.replicated(prev))
         # requests that a drain outside step() finished (cancel, a flight
@@ -733,10 +738,10 @@ class ServingEngine:
             # wrap the sharded callables, so compile counts, budgets, and
             # the retrace/donation audits are identical to single-chip
             prefill_impl = self._tp.wrap_step(
-                prefill_impl, mc.num_layers, n_rest=5,
+                prefill_impl, spec.num_layers, n_rest=5,
                 quantized=self.cache.cfg.quantized)
             decode_impl = self._tp.wrap_step(
-                decode_impl, mc.num_layers, n_rest=7,
+                decode_impl, spec.num_layers, n_rest=7,
                 quantized=self.cache.cfg.quantized)
         # ``program=`` names what the guard jits, so the profiler's
         # "XLA Modules" line reads jit_serve_prefill_<bucket> (one name
@@ -764,7 +769,7 @@ class ServingEngine:
             if self._tp is not None:
                 n_rest = 7 + (1 if cfg.spec.method == "draft" else 0)
                 verify_impl = self._tp.wrap_step(
-                    verify_impl, mc.num_layers, n_rest=n_rest,
+                    verify_impl, spec.num_layers, n_rest=n_rest,
                     quantized=self.cache.cfg.quantized)
             self._verify_jit = CompileGuard(
                 verify_impl, "verify", donate_argnums=(1,),
@@ -788,14 +793,35 @@ class ServingEngine:
         return sample_logits(logits_row[None, :], key, cfg.temperature,
                              cfg.top_k, cfg.top_p)[0]
 
-    def _run_model(self, p_arrays, pools, table, ctx, valid, ids):
-        caches = [dict(pl, page_table=table, ctx_lens=ctx, valid=valid)
-                  for pl in pools]
+    def _run_model(self, p_arrays, pools, table, ctx, valid, ids,
+                   kv_limit=None):
+        """(logits, new_pools, counters): one paged call of the model.
+        ``kv_limit`` is a static bound on the positions this call can
+        reach (a prefill stays inside ``max_prompt_len``), for a model
+        whose prefill reads its context back from the pool; None is the
+        whole page table. ``counters`` is what the layers counted, summed
+        (int32 [len(spec.counters)]), or None for a model that counts
+        nothing."""
+        caches = [dict(pl, page_table=table, ctx_lens=ctx, valid=valid,
+                       kv_limit=kv_limit) for pl in pools]
         (logits, new_caches), _ = self.model.functional_call(
             p_arrays, {}, Tensor(ids), caches=caches)
         new_pools = [{k: c[k] for k in self._pool_keys}
                      for c in new_caches]
-        return logits._value, new_pools
+        counters = None
+        if self._n_counters:
+            counters = sum(c["counters"] for c in new_caches
+                           if "counters" in c).astype(jnp.int32)
+        return logits._value, new_pools, counters
+
+    @staticmethod
+    def _with_counters(tok, counters):
+        """The launch's tokens with the model's counters behind them: one
+        array, so one fetch. A model that counts nothing returns its
+        tokens as they are."""
+        if counters is None:
+            return tok
+        return jnp.concatenate([jnp.atleast_1d(tok), counters])
 
     def _prefill_impl(self, p_arrays, pools, padded_ids, tail_len, ctx0,
                       page_row, rid):
@@ -811,8 +837,9 @@ class ServingEngine:
         table = page_row[None, :]
         ctx = jnp.reshape(ctx0.astype(jnp.int32), (1,))
         valid = (jnp.arange(n, dtype=jnp.int32) < tail_len)[None, :]
-        logits, new_pools = self._run_model(
-            p_arrays, pools, table, ctx, valid, padded_ids[None, :])
+        logits, new_pools, counters = self._run_model(
+            p_arrays, pools, table, ctx, valid, padded_ids[None, :],
+            kv_limit=self.config.max_prompt_len)
         with jax.named_scope("sample"):
             last = logits[0, tail_len - 1, :]
             if self.config.do_sample:
@@ -820,7 +847,7 @@ class ServingEngine:
             else:
                 tok = jnp.argmax(last, axis=-1)
             tok = tok.astype(jnp.int32)
-        return new_pools, tok
+        return new_pools, self._with_counters(tok, counters)
 
     def _decode_impl(self, p_arrays, pools, table, ctx, prev_toks,
                      override, active, rids, gen_idx):
@@ -831,8 +858,10 @@ class ServingEngine:
         device, never donated: the host fetches it after this launch) unless
         the host knows it and says so with ``override >= 0``: a slot just
         prefilled or swap-resumed, or every slot of a drained engine."""
+        if self._n_counters:    # the last launch's counters ride behind
+            prev_toks = prev_toks[:override.shape[0]]
         last_tok = jnp.where(override >= 0, override, prev_toks)
-        logits, new_pools = self._run_model(
+        logits, new_pools, counters = self._run_model(
             p_arrays, pools, table, ctx, active[:, None], last_tok[:, None])
         with jax.named_scope("sample"):
             last = logits[:, -1, :]
@@ -844,7 +873,7 @@ class ServingEngine:
             tok = jnp.where(
                 active, tok,
                 jnp.asarray(self.config.pad_token_id)).astype(jnp.int32)
-        return new_pools, tok
+        return new_pools, self._with_counters(tok, counters)
 
     def _propose_draft(self, draft_p, win):
         """The draft proposer, in-jit: decode K candidates greedily from a
@@ -863,7 +892,7 @@ class ServingEngine:
         sp, dc = self.config.spec, self._draft.cfg
         K, W = sp.depth, sp.window
         b = win.shape[0]
-        dt = self._draft.gpt.wte.weight._value.dtype
+        dt = self._draft.paged_cache_spec().dtype
         shape = (b, dc.num_heads, W + K, dc.hidden_size // dc.num_heads)
         caches = [{"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
                   for _ in range(dc.num_layers)]
@@ -909,7 +938,7 @@ class ServingEngine:
         cand = jnp.where(active[:, None], cand, cfg.pad_token_id)
         ids = jnp.concatenate([last_tok[:, None], cand], axis=1)
         valid = jnp.broadcast_to(active[:, None], ids.shape)
-        logits, new_pools = self._run_model(
+        logits, new_pools, _ = self._run_model(
             p_arrays, pools, table, ctx, valid, ids)
         with jax.named_scope("sample"):
             if cfg.do_sample:
@@ -984,10 +1013,10 @@ class ServingEngine:
                 f"prompt_len {prompt.shape[0]} exceeds max_prompt_len "
                 f"{self.config.max_prompt_len}")
         total = prompt.shape[0] + int(max_new_tokens)
-        if total > self.model.cfg.max_seq_len:
+        if total > self._cache_spec.max_seq_len:
             raise ValueError(
                 f"prompt_len + max_new_tokens = {total} exceeds max_seq_len "
-                f"{self.model.cfg.max_seq_len}")
+                f"{self._cache_spec.max_seq_len}")
         req = Request(prompt=prompt.astype(np.int32),
                       max_new_tokens=int(max_new_tokens),
                       deadline=(self.now() + float(deadline_s)
@@ -1638,7 +1667,12 @@ class ServingEngine:
         # (a bare int() coercion would sync invisibly to the linter)
         with (att.span("prefill.fetch", rid=req.rid)
               if att is not None else NO_SPAN):
-            tok = int(np.asarray(tok))  # lint: disable=PT005
+            tok = np.asarray(tok)  # lint: disable=PT005
+        if self._n_counters:
+            self.metrics.on_model_counters(self._cache_spec.counters,
+                                           tok[1:])
+            tok = tok[0]
+        tok = int(tok)
         req.generated.append(tok)
         req.tokens_emitted += 1
         self._ctx[req.slot] = req.prompt_len
@@ -1789,6 +1823,9 @@ class ServingEngine:
                 e.add_note(f"raised at the fetch of the decode that step "
                            f"{step} launched")
                 raise
+        if self._n_counters:
+            self.metrics.on_model_counters(self._cache_spec.counters,
+                                           toks[-self._n_counters:])
         n_new = 0
         with (att.span("decode.emit") if att is not None else NO_SPAN):
             for slot, req in launched:
@@ -2165,7 +2202,7 @@ class ServingEngine:
         if self._tp is None:
             return hlocheck.SINGLE_CHIP
         b, s = self._step_shape(label)
-        itemsize = np.dtype(self.model.gpt.wte.weight._value.dtype).itemsize
+        itemsize = np.dtype(self._cache_spec.dtype).itemsize
         return self._tp.step_budget(batch=b, seq=s, itemsize=itemsize)
 
     @property

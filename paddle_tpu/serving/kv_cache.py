@@ -1,10 +1,14 @@
 """Paged KV cache: preallocated page pool + refcounted allocator + page
 tables + automatic prefix caching.
 
-The device side is a per-layer pool ``[num_pages, page_size, heads,
-head_dim]`` (k and v), updated only functionally (``.at[]`` scatters in
-kernels/paged_attention.py) so the whole cache threads through the engine's
-jitted step. The host side is bookkeeping only: a refcounted block allocator
+The device side is a per-layer pool whose leaves the MODEL states
+(``PagedCacheSpec``: GPT keeps ``k_pool`` and ``v_pool`` of ``[num_pages,
+page_size, heads, head_dim]``, a latent-attention model one ``kv_pool`` of
+``[num_pages, page_size, width]`` with no heads axis), updated only
+functionally (``.at[]`` scatters in kernels/paged_attention.py and
+kernels/latent_paged_attention.py) so the whole cache threads through the
+engine's jitted step. Nothing here reads a leaf by name: the movers (swap,
+copy-on-write, spill) thread ``cfg.pool_leaf_keys`` in order. The host side is bookkeeping only: a refcounted block allocator
 and per-slot page tables, mirrored into a dense ``[max_batch,
 pages_per_seq]`` int32 array each step — static shape, so table churn never
 recompiles.
@@ -193,29 +197,44 @@ class HostTierRestoreError(RuntimeError):
     stale tier entries dropped; the engine retires the request FAILED."""
 
 
+def _present(*arrays) -> tuple:
+    return tuple(a for a in arrays if a is not None)
+
+
+def _by_field(arrays) -> dict:
+    """A pool's arrays, in leaf order, under the handles' field names."""
+    if len(arrays) > 4:
+        raise ValueError("a host handle carries at most four pool leaves")
+    return dict(zip(("k", "v", "k_scale", "v_scale"), arrays))
+
+
 @dataclass(eq=False)  # ndarray fields: identity semantics (lint rule PT001)
 class SwapHandle:
     """Host-memory copy of one sequence's KV pages (swap-style preemption).
 
-    ``k``/``v`` are stacked over layers: ``[num_layers, n_pages, page_size,
-    heads, head_dim]`` in page-table row order, so restoring into ANY
-    n_pages free pages (in order) preserves every token position exactly.
-    Quantized pools additionally carry the per-page-per-head scales
-    ``[num_layers, n_pages, heads]`` — the handle holds the pool's raw
-    bytes either way, so a swap round-trip is bit-exact in both modes.
+    One array a pool leaf, in ``PagedCacheConfig.pool_leaf_keys`` order
+    (the field names are those of the two-leaf pool: ``k`` is the first
+    leaf, ``v`` the second where the pool has one — a latent pool has one
+    leaf), each stacked over layers: ``[num_layers, n_pages, page_size,
+    ...]`` in page-table row order, so restoring into ANY n_pages free
+    pages (in order) preserves every token position exactly. Quantized
+    pools additionally carry the per-page-per-head scales ``[num_layers,
+    n_pages, heads]`` — the handle holds the pool's raw bytes either way,
+    so a swap round-trip is bit-exact in both modes.
     """
     n_pages: int
     k: np.ndarray
-    v: np.ndarray
+    v: np.ndarray | None = None
     k_scale: np.ndarray | None = None
     v_scale: np.ndarray | None = None
 
     @property
+    def arrays(self) -> tuple:
+        return _present(self.k, self.v, self.k_scale, self.v_scale)
+
+    @property
     def nbytes(self) -> int:
-        n = self.k.nbytes + self.v.nbytes
-        if self.k_scale is not None:
-            n += self.k_scale.nbytes + self.v_scale.nbytes
-        return n
+        return sum(a.nbytes for a in self.arrays)
 
 
 @dataclass(eq=False)  # ndarray fields: identity semantics (lint rule PT001)
@@ -225,17 +244,19 @@ class SpilledPage:
     per-layer page bytes — codes + scales in quantized mode."""
     key: tuple
     serial: int
-    k: np.ndarray  # [num_layers, page_size, heads, head_dim]
-    v: np.ndarray
+    k: np.ndarray  # the pool's first leaf: [num_layers, page_size, ...]
+    v: np.ndarray | None = None  # its second, where it has one
     k_scale: np.ndarray | None = None  # [num_layers, heads] (quantized)
     v_scale: np.ndarray | None = None
 
     @property
+    def arrays(self) -> tuple:
+        """One array a pool leaf, in ``pool_leaf_keys`` order."""
+        return _present(self.k, self.v, self.k_scale, self.v_scale)
+
+    @property
     def nbytes(self) -> int:
-        n = self.k.nbytes + self.v.nbytes
-        if self.k_scale is not None:
-            n += self.k_scale.nbytes + self.v_scale.nbytes
-        return n
+        return sum(a.nbytes for a in self.arrays)
 
 
 class HostTier:
@@ -329,10 +350,59 @@ def prefix_digest(tokens, page_size: int) -> tuple:
 
 
 @dataclass(frozen=True)
+class CacheLeaf:
+    """One leaf of a layer's pool, as the MODEL states it: what a token
+    keeps (``shape``, behind the pool's ``[num_pages, page_size]``), or
+    with ``per_page`` what a page keeps (behind ``[num_pages]``: the int8
+    pool's scales)."""
+    name: str
+    shape: tuple
+    dtype: object
+    per_page: bool = False
+
+    @property
+    def nbytes(self) -> int:
+        return math.prod(self.shape) * np.dtype(self.dtype).itemsize
+
+
+@dataclass(frozen=True)
+class PagedCacheSpec:
+    """What a model keeps in the paged cache — its answer to
+    ``model.paged_cache_spec(kv_dtype=, tensor_parallel=, speculative=)``,
+    which the engine asks once at construction (a model that cannot do
+    what is asked raises ValueError with the reason). ``dtype`` is the
+    weights'. ``counters`` names what each layer's returned cache may
+    carry under ``"counters"`` (an int32 vector, summed over the layers
+    of a launch and fetched with its tokens)."""
+    num_layers: int
+    max_seq_len: int
+    dtype: object
+    leaves: tuple
+    counters: tuple = ()
+
+
+def kv_heads_leaves(num_heads: int, head_dim: int, dtype=None,
+                    quantized: bool = False) -> tuple:
+    """The leaves of a keys-and-values pool with a heads axis: ``k_pool``
+    and ``v_pool`` of ``[heads, head_dim]`` a token; int8 codes beside
+    per-page-per-head float32 scales when quantized."""
+    dt = np.int8 if quantized else (dtype if dtype is not None
+                                    else np.float32)
+    leaves = (CacheLeaf("k_pool", (num_heads, head_dim), dt),
+              CacheLeaf("v_pool", (num_heads, head_dim), dt))
+    if quantized:
+        leaves += (CacheLeaf("k_scale", (num_heads,), np.float32, True),
+                   CacheLeaf("v_scale", (num_heads,), np.float32, True))
+    return leaves
+
+
+@dataclass(frozen=True)
 class PagedCacheConfig:
     num_layers: int
-    num_heads: int
-    head_dim: int
+    # a keys-and-values pool by its two sizes (``leaves`` then follows),
+    # or any pool by its ``leaves`` (then these stay 0)
+    num_heads: int = 0
+    head_dim: int = 0
     num_pages: int = 64
     page_size: int = 16
     max_batch: int = 4
@@ -353,32 +423,40 @@ class PagedCacheConfig:
     host_tier_bytes: int = 0  # host-memory spill tier bound; 0 = off.
     # Evicted refcount-0 prefix pages spill here (keeping their index keys)
     # instead of being purged, and restore on the next prefix hit.
+    leaves: tuple | None = None  # CacheLeaf a pool leaf, as the model's
+    # PagedCacheSpec states them; None: the keys-and-values pool of
+    # num_heads x head_dim in ``dtype`` (int8 + scales when quantized)
 
     @property
     def quantized(self) -> bool:
         return self.kv_dtype == "int8"
 
     @property
+    def layer_leaves(self) -> tuple:
+        """The CacheLeaf of every leaf of one layer's pool, in the fixed
+        order that the engine and the movers thread them in."""
+        if self.leaves is not None:
+            return self.leaves
+        return kv_heads_leaves(self.num_heads, self.head_dim, self.dtype,
+                               self.quantized)
+
+    @property
     def pool_leaf_keys(self) -> tuple:
         """The per-layer pool dict's leaf names, in a fixed order — the
-        engine and the movers use this to stay mode-agnostic."""
-        return (("k_pool", "v_pool", "k_scale", "v_scale")
-                if self.quantized else ("k_pool", "v_pool"))
+        engine and the movers use this to stay layout-agnostic."""
+        return tuple(leaf.name for leaf in self.layer_leaves)
 
     @property
     def kv_bytes_per_token(self) -> int:
-        """Device bytes one resident token costs across all layers (k+v
-        codes plus, quantized, the per-page scales amortized per token) —
+        """Device bytes one resident token costs across all layers (every
+        per-token leaf, plus the per-page leaves amortized per token) —
         the ``serving_kv_bytes_per_token`` gauge."""
-        per = 2 * self.num_layers * self.num_heads * self.head_dim
-        if self.quantized:
-            return per + (2 * self.num_layers * self.num_heads * 4
-                          + self.page_size - 1) // self.page_size
-        # the fp32-path pools are allocated in cfg.dtype (the MODEL's
-        # dtype — bf16 pools cost 2 B/elem, not 4)
-        itemsize = np.dtype(self.dtype).itemsize if self.dtype is not None \
-            else 4
-        return per * itemsize
+        leaves = self.layer_leaves
+        per_token = sum(lf.nbytes for lf in leaves if not lf.per_page)
+        per_page = sum(lf.nbytes for lf in leaves if lf.per_page)
+        return self.num_layers * per_token + (
+            self.num_layers * per_page + self.page_size - 1
+        ) // self.page_size
 
     @property
     def max_tokens_per_seq(self) -> int:
@@ -390,30 +468,26 @@ class PagedCacheConfig:
 
 
 def init_pools(cfg: PagedCacheConfig) -> list[dict]:
-    """Per-layer {k_pool, v_pool} device arrays, zero-filled; quantized
-    pools add the zero-initialized {k_scale, v_scale} leaves (a zero scale
-    marks an all-zero page — the write path substitutes 1.0 before any
-    division). Under tensor parallelism every leaf is CREATED under its
-    heads-axis sharding — each device allocates only its own
-    [num_pages, page_size, heads/tp, head_dim] shard; a pool sized for
-    the mesh never exists whole on one device (it would not fit)."""
+    """Per-layer dicts of the pool's leaves (``cfg.layer_leaves``: GPT's
+    {k_pool, v_pool}, a latent model's {kv_pool}), device arrays,
+    zero-filled; quantized pools add the zero-initialized {k_scale,
+    v_scale} leaves (a zero scale marks an all-zero page — the write path
+    substitutes 1.0 before any division). Under tensor parallelism every
+    leaf is CREATED under its heads-axis sharding — each device allocates
+    only its own [num_pages, page_size, heads/tp, head_dim] shard; a pool
+    sized for the mesh never exists whole on one device (it would not
+    fit)."""
     import jax.numpy as jnp
 
     pool_sh, scale_sh = (cfg.tp.pool_shardings() if cfg.tp is not None
                          else (None, None))
-    shape = (cfg.num_pages, cfg.page_size, cfg.num_heads, cfg.head_dim)
-    dt = jnp.int8 if cfg.quantized else (cfg.dtype or jnp.float32)
 
     def layer():
-        leaf = {"k_pool": jnp.zeros(shape, dt, device=pool_sh),
-                "v_pool": jnp.zeros(shape, dt, device=pool_sh)}
-        if cfg.quantized:
-            sshape = (cfg.num_pages, cfg.num_heads)
-            leaf |= {"k_scale": jnp.zeros(sshape, jnp.float32,
-                                          device=scale_sh),
-                     "v_scale": jnp.zeros(sshape, jnp.float32,
-                                          device=scale_sh)}
-        return leaf
+        return {lf.name: jnp.zeros(
+            (cfg.num_pages,) + (() if lf.per_page else (cfg.page_size,))
+            + tuple(lf.shape), lf.dtype,
+            device=scale_sh if lf.per_page else pool_sh)
+            for lf in cfg.layer_leaves}
 
     return [layer() for _ in range(cfg.num_layers)]
 
@@ -491,47 +565,26 @@ class PagedKVCache:
         from ..analysis.tracecheck import CompileGuard
 
         quantized = self.cfg.quantized
+        keys = self.cfg.pool_leaf_keys
 
         def gather(pools, idx):
-            # index each layer BEFORE stacking: stacking whole pools would
+            # one stacked array a pool leaf, in ``pool_leaf_keys`` order.
+            # Index each layer BEFORE stacking: stacking whole pools would
             # materialize an O(pool) concatenate per swap event — the exact
             # cost this jit exists to avoid; this way only the gathered
             # pages ([layers, pages_per_seq, ...]) are ever copied.
             # Quantized pools move their raw codes + the touched pages'
             # scale rows — never dequantized, so a round-trip is bit-exact.
-            k = jnp.stack([pl["k_pool"][idx] for pl in pools])
-            v = jnp.stack([pl["v_pool"][idx] for pl in pools])
-            if quantized:
-                ks = jnp.stack([pl["k_scale"][idx] for pl in pools])
-                vs = jnp.stack([pl["v_scale"][idx] for pl in pools])
-                return k, v, ks, vs
-            return k, v
+            return tuple(jnp.stack([pl[k][idx] for pl in pools])
+                         for k in keys)
 
-        def scatter(pools, idx, k_all, v_all, *scales):
-            if quantized:
-                ks_all, vs_all = scales
-                return [{"k_pool": pl["k_pool"].at[idx].set(k_all[i]),
-                         "v_pool": pl["v_pool"].at[idx].set(v_all[i]),
-                         "k_scale": pl["k_scale"].at[idx].set(ks_all[i]),
-                         "v_scale": pl["v_scale"].at[idx].set(vs_all[i])}
-                        for i, pl in enumerate(pools)]
-            return [{"k_pool": pl["k_pool"].at[idx].set(k_all[i]),
-                     "v_pool": pl["v_pool"].at[idx].set(v_all[i])}
+        def scatter(pools, idx, *stacked):
+            return [{k: pl[k].at[idx].set(a[i])
+                     for k, a in zip(keys, stacked)}
                     for i, pl in enumerate(pools)]
 
         def copy_page(pools, src, dst):
-            if quantized:
-                return [{"k_pool":
-                         pl["k_pool"].at[dst].set(pl["k_pool"][src]),
-                         "v_pool":
-                         pl["v_pool"].at[dst].set(pl["v_pool"][src]),
-                         "k_scale":
-                         pl["k_scale"].at[dst].set(pl["k_scale"][src]),
-                         "v_scale":
-                         pl["v_scale"].at[dst].set(pl["v_scale"][src])}
-                        for pl in pools]
-            return [{"k_pool": pl["k_pool"].at[dst].set(pl["k_pool"][src]),
-                     "v_pool": pl["v_pool"].at[dst].set(pl["v_pool"][src])}
+            return [{k: pl[k].at[dst].set(pl[k][src]) for k in keys}
                     for pl in pools]
 
         # gather READS the pools — donation would delete the other
@@ -734,24 +787,16 @@ class PagedKVCache:
         w = self.cfg.pages_per_seq
         for at in range(0, len(pages), w):
             chunk = pages[at:at + w]
-            got = self._gather_jit(self.pools,
-                                   jnp.asarray(self._padded_idx(chunk)))
-            if self.cfg.quantized:
-                k, v, ks, vs = (np.asarray(a) for a in got)
-            else:
-                k, v = (np.asarray(a) for a in got)
-                ks = vs = None
+            got = [np.asarray(a) for a in self._gather_jit(
+                self.pools, jnp.asarray(self._padded_idx(chunk)))]
             for j, page in enumerate(chunk):
                 out.append(SpilledPage(
                     key=self._page_key[page],
                     serial=self._page_serial[page],
-                    k=k[:, j].copy(), v=v[:, j].copy(),
-                    k_scale=None if ks is None else ks[:, j].copy(),
-                    v_scale=None if vs is None else vs[:, j].copy()))
+                    **_by_field([a[:, j].copy() for a in got])))
         out.extend(SpilledPage(
-            key=e.key, serial=e.serial, k=e.k.copy(), v=e.v.copy(),
-            k_scale=None if e.k_scale is None else e.k_scale.copy(),
-            v_scale=None if e.v_scale is None else e.v_scale.copy())
+            key=e.key, serial=e.serial,
+            **_by_field([a.copy() for a in e.arrays]))
             for e in spilled)
         return out
 
@@ -771,8 +816,8 @@ class PagedKVCache:
             raise ValueError(
                 "import_spilled_chain needs the host tier "
                 "(host_tier_bytes > 0) as its landing zone")
-        want_dtype = np.dtype(np.int8) if self.cfg.quantized \
-            else np.dtype(np.float32)
+        want_dtype = np.dtype(self.cfg.layer_leaves[0].dtype)
+        n_leaves = len(self.cfg.pool_leaf_keys)
         by_parent: dict[int, SpilledPage] = {}
         for e in entries:
             by_parent.setdefault(int(e.key[0]), e)
@@ -782,12 +827,12 @@ class PagedKVCache:
         while src_parent in by_parent:
             e = by_parent.pop(src_parent)
             src_parent = int(e.serial)
-            if e.k.dtype != want_dtype \
-                    or (e.k_scale is None) == self.cfg.quantized:
+            if e.k.dtype != want_dtype or len(e.arrays) != n_leaves:
                 raise ValueError(
                     f"imported page dtype {e.k.dtype}/scales="
                     f"{e.k_scale is not None} does not match this "
-                    f"pool (kv_dtype={self.cfg.kv_dtype!r})")
+                    f"pool (kv_dtype={self.cfg.kv_dtype!r}, leaves "
+                    f"{self.cfg.pool_leaf_keys})")
             key = (parent, tuple(e.key[1]))
             page = self._key_to_page.get(key)
             if page is not None:
@@ -800,11 +845,7 @@ class PagedKVCache:
             serial = next(self._serials)
             self.host_tier.put(SpilledPage(
                 key=key, serial=serial,
-                k=np.array(e.k, copy=True), v=np.array(e.v, copy=True),
-                k_scale=None if e.k_scale is None
-                else np.array(e.k_scale, copy=True),
-                v_scale=None if e.v_scale is None
-                else np.array(e.v_scale, copy=True)))
+                **_by_field([np.array(a, copy=True) for a in e.arrays])))
             if self.host_tier.get(key, touch=False) is None:
                 break  # refused at the byte bound: descendants would
                 # chain onto a parent the tier no longer holds
@@ -832,20 +873,13 @@ class PagedKVCache:
         w = self.cfg.pages_per_seq
         for at in range(0, len(pages), w):
             chunk = pages[at:at + w]
-            got = self._gather_jit(self.pools,
-                                   jnp.asarray(self._padded_idx(chunk)))
-            if self.cfg.quantized:
-                k, v, ks, vs = (np.asarray(a) for a in got)
-            else:
-                k, v = (np.asarray(a) for a in got)
-                ks = vs = None
+            got = [np.asarray(a) for a in self._gather_jit(
+                self.pools, jnp.asarray(self._padded_idx(chunk)))]
             for j, page in enumerate(chunk):
                 self.host_tier.put(SpilledPage(
                     key=self._page_key[page],
                     serial=self._page_serial[page],
-                    k=k[:, j].copy(), v=v[:, j].copy(),
-                    k_scale=None if ks is None else ks[:, j].copy(),
-                    v_scale=None if vs is None else vs[:, j].copy()))
+                    **_by_field([a[:, j].copy() for a in got])))
                 self.spills += 1
 
     def _alloc_or_evict(self, n: int) -> list[int] | None:
@@ -925,21 +959,14 @@ class PagedKVCache:
         w = c.pages_per_seq
         for at in range(0, len(entries), w):
             es = entries[at:at + w]
-            k_all = np.zeros((c.num_layers, w, c.page_size, c.num_heads,
-                              c.head_dim), es[0].k.dtype)
-            v_all = np.zeros_like(k_all)
+            # one [layers, w, ...] array a pool leaf, entry j in row j
+            stacked = [np.zeros((c.num_layers, w) + a.shape[1:], a.dtype)
+                       for a in es[0].arrays]
             for j, e in enumerate(es):
-                k_all[:, j] = e.k
-                v_all[:, j] = e.v
-            args = [jnp.asarray(self._padded_idx(pages[at:at + w])),
-                    jnp.asarray(k_all), jnp.asarray(v_all)]
-            if c.quantized:
-                ks = np.zeros((c.num_layers, w, c.num_heads), np.float32)
-                vs = np.zeros_like(ks)
-                for j, e in enumerate(es):
-                    ks[:, j] = e.k_scale
-                    vs[:, j] = e.v_scale
-                args += [jnp.asarray(ks), jnp.asarray(vs)]
+                for full, a in zip(stacked, e.arrays):
+                    full[:, j] = a
+            args = [jnp.asarray(self._padded_idx(pages[at:at + w]))] \
+                + [jnp.asarray(a) for a in stacked]
             try:
                 self.pools = self._scatter_jit(self.pools, *args)
             except Exception as err:  # noqa: BLE001 — isolate the restore
@@ -1107,17 +1134,8 @@ class PagedKVCache:
         n = len(pages)
         got = self._gather_jit(self.pools,
                                jnp.asarray(self._padded_idx(pages)))
-        if self.cfg.quantized:
-            k, v, ks, vs = got
-            handle = SwapHandle(
-                n_pages=n, k=np.asarray(k)[:, :n].copy(),
-                v=np.asarray(v)[:, :n].copy(),
-                k_scale=np.asarray(ks)[:, :n].copy(),
-                v_scale=np.asarray(vs)[:, :n].copy())
-        else:
-            k, v = got
-            handle = SwapHandle(n_pages=n, k=np.asarray(k)[:, :n].copy(),
-                                v=np.asarray(v)[:, :n].copy())
+        handle = SwapHandle(n_pages=n, **_by_field(
+            [np.asarray(a)[:, :n].copy() for a in got]))
         self.release(slot)
         return handle
 
@@ -1135,20 +1153,11 @@ class PagedKVCache:
         if pages is None:
             return False
         w = self.cfg.pages_per_seq
-        k_all = np.zeros((handle.k.shape[0], w) + handle.k.shape[2:],
-                         handle.k.dtype)
-        v_all = np.zeros_like(k_all)
-        k_all[:, :handle.n_pages] = handle.k
-        v_all[:, :handle.n_pages] = handle.v
-        args = [jnp.asarray(self._padded_idx(pages)),
-                jnp.asarray(k_all), jnp.asarray(v_all)]
-        if self.cfg.quantized:
-            ks = np.zeros((handle.k_scale.shape[0], w)
-                          + handle.k_scale.shape[2:], handle.k_scale.dtype)
-            vs = np.zeros_like(ks)
-            ks[:, :handle.n_pages] = handle.k_scale
-            vs[:, :handle.n_pages] = handle.v_scale
-            args += [jnp.asarray(ks), jnp.asarray(vs)]
+        args = [jnp.asarray(self._padded_idx(pages))]
+        for a in handle.arrays:
+            full = np.zeros((a.shape[0], w) + a.shape[2:], a.dtype)
+            full[:, :handle.n_pages] = a
+            args.append(jnp.asarray(full))
         # pad rows scatter zeros into the null page — never read unmasked
         self.pools = self._scatter_jit(self.pools, *args)
         self._slot_pages[slot] = pages

@@ -49,6 +49,14 @@ Prefix-cache counters/gauges (pre-seeded like the resilience set):
 
 KV quantization + host cache tier (pre-seeded like everything else):
 
+- serving_moe_assignments_total, serving_moe_local_assignments_total,
+  serving_moe_expert_slots_total, serving_moe_expert_hits_total
+                                  an expert-layer model's counts, summed
+                                  over its expert layers a launch and
+                                  fetched with the launch's tokens: tokens
+                                  x top-k; those routed to experts held
+                                  here; held experts x layers x launches;
+                                  of those, the ones that got a token
 - serving_kv_bytes_per_token      gauge: device bytes one resident token
                                   costs across layers (codes + amortized
                                   scales), set at construction — 4x lower
@@ -276,6 +284,8 @@ _SEEDED = ("tokens_total", "prefills_total", "prefill_tokens_total",
            "kv_bytes_per_token", "host_tier_pages", "host_tier_bytes",
            "host_tier_hits_total", "host_tier_spills_total",
            "host_tier_restores_total",
+           "moe_assignments_total", "moe_local_assignments_total",
+           "moe_expert_slots_total", "moe_expert_hits_total",
            "pallas_fallback_total",
            "flash_pad_total", "flash_edge_fallback_total",
            "analysis_retraces_total", "analysis_host_syncs_total",
@@ -585,6 +595,16 @@ class ServingMetrics:
                              int(accepted))
         monitor.stat_set(PREFIX + "spec_acceptance_rate",
                          a / p if p else 0.0)
+
+    def on_model_counters(self, names, values) -> None:
+        """What the model's layers counted in one launch, summed over the
+        layers and fetched with the launch's tokens (``PagedCacheSpec.
+        counters`` names them; every name a model may give is seeded). An
+        expert layer's: token-to-expert assignments, those to experts held
+        here, held experts x layers, and of those the ones that got a
+        token."""
+        for name, value in zip(names, values):
+            monitor.stat_add(PREFIX + name, int(value))
 
     def on_kv_bytes_per_token(self, nbytes: int) -> None:
         """Device bytes one resident token costs (set once at engine

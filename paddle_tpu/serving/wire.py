@@ -200,6 +200,12 @@ def encode_page(page: SpilledPage, *, span=None) -> bytes:
     """One :class:`SpilledPage` as a wire frame — key, serial, dtype,
     shape, and the raw KV bytes (plus scale planes when quantized)."""
     parent, block = page.key
+    if page.v is None:
+        # version 1 of the frame is a keys-and-values page; a one-leaf
+        # (latent) page needs a frame of its own before it can cross
+        raise ValueError("the wire's page frame carries a keys-and-values "
+                         "pool's two leaves: a one-leaf page cannot be "
+                         "sent yet")
     k = np.ascontiguousarray(page.k)
     v = np.ascontiguousarray(page.v)
     if k.dtype not in _DTYPE_TAGS:

@@ -428,6 +428,39 @@ class GPTForCausalLM(nn.Layer):
             logits = matmul(h, self.gpt.wte.weight, transpose_y=True)
         return logits
 
+    # ---------------------------------------------------- serving contract
+    def paged_cache_spec(self, kv_dtype: str = "float32",
+                         tensor_parallel: int = 1,
+                         speculative: bool = False):
+        """What this model keeps in a paged cache, for ``ServingEngine``:
+        keys and values with a heads axis, ``k_pool`` and ``v_pool`` of
+        ``[heads, head_dim]`` a token in the weights' dtype (int8 codes
+        beside per-page-per-head scales under ``kv_dtype="int8"``). Every
+        mode the engine has is supported."""
+        from ..serving.kv_cache import PagedCacheSpec, kv_heads_leaves
+
+        c = self.cfg
+        dtype = self.gpt.wte.weight._value.dtype
+        return PagedCacheSpec(
+            num_layers=c.num_layers, max_seq_len=c.max_seq_len, dtype=dtype,
+            leaves=kv_heads_leaves(c.num_heads, c.hidden_size // c.num_heads,
+                                   dtype, quantized=kv_dtype == "int8"))
+
+    def decode_kernel_eligible(self, pages_per_seq: int, page_size: int,
+                               quantized: bool = False) -> bool:
+        """Whether the unified ragged kernel is dispatchable for a decode
+        step of this model: the single ``decode_kernel_eligible`` gate."""
+        from ..kernels import paged_attention as _pa
+        from ..kernels._common import on_tpu_backend
+        from ..utils.flags import flag
+
+        c = self.cfg
+        return _pa.decode_kernel_eligible(
+            c.hidden_size // c.num_heads, pages_per_seq, page_size,
+            num_heads=c.num_heads, quantized=quantized,
+            on_tpu=on_tpu_backend(),
+            flags_on=bool(flag("FLAGS_use_pallas_kernels", True)))[0]
+
     def generate(self, input_ids, **kwargs):
         """KV-cache autoregressive decoding — see text/generation.py."""
         from .generation import generate

@@ -1,0 +1,178 @@
+"""The guards between the program and the benchmark's families (tier-1, so
+that a program change which renames a leaf or moves a reference breaks
+here and not unseen in a chip run): every file under ``benchmark/configs/``
+resolves its family; each family's ``leaf_table`` is the program's
+``functional_state()`` by name and shape at a tiny size; and the
+benchmark's copy of each plain reference agrees with the repo's.
+"""
+import glob
+import json
+import os
+import sys
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for path in (ROOT, os.path.join(os.path.dirname(__file__), "refs")):
+    if path not in sys.path:
+        sys.path.insert(0, path)
+
+import paddle_tpu as paddle  # noqa: E402
+
+CONFIGS = sorted(glob.glob(os.path.join(ROOT, "benchmark", "configs",
+                                        "*.json")))
+
+TINY_GPT3 = dict(vocab_size=128, hidden_size=64, num_layers=2, num_heads=2,
+                 head_size=32, ffn_hidden=256, max_seq_len=32)
+TINY_KIMI = dict(vocab_size=96, hidden_size=64, intermediate_size=160,
+                 moe_intermediate_size=48, num_hidden_layers=3,
+                 num_attention_heads=4, n_routed_experts=4, router_width=16,
+                 held_experts_first=8, num_experts_per_tok=4,
+                 kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16,
+                 qk_rope_head_dim=8, v_head_dim=16,
+                 max_position_embeddings=64)
+
+
+def load(path):
+    with open(path) as f:
+        return json.load(f)
+
+
+def tiny_model(config: dict) -> dict:
+    tiny = {"gpt3": TINY_GPT3, "kimi_k2": TINY_KIMI}[config["family"]]
+    return dict(config["model"], **tiny)
+
+
+def program_model(config: dict, model: dict):
+    """The program's model of a family at the tiny size, shapes only."""
+    if config["family"] == "gpt3":
+        from paddle_tpu.text.gpt import GPTConfig, GPTForCausalLM
+
+        with paddle.LazyGuard():
+            return GPTForCausalLM(GPTConfig(
+                vocab_size=model["vocab_size"],
+                hidden_size=model["hidden_size"],
+                num_layers=model["num_layers"],
+                num_heads=model["num_heads"],
+                max_seq_len=model["max_seq_len"]))
+    from benchmark.families import kimi_k2
+    from paddle_tpu.text.kimi_k2 import KimiK2ForCausalLM
+
+    with paddle.LazyGuard():
+        return KimiK2ForCausalLM(kimi_k2.program_config(model))
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=os.path.basename)
+def test_every_configuration_resolves_its_family(path):
+    from benchmark.lib.common import family_of
+
+    config = load(path)
+    family = family_of(config)
+    family.check(config)
+    assert family.leaf_table(config["model"]) and family.WORK
+    assert callable(family.logits_at) and callable(
+        getattr(family, "build_serving", None)
+        or getattr(family, "build_training"))
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=os.path.basename)
+def test_leaf_table_is_the_programs_functional_state(path):
+    from benchmark.lib.common import family_of
+
+    config = load(path)
+    model = tiny_model(config)
+    table = family_of(config).leaf_table(model)
+    params, _ = program_model(config, model).functional_state()
+    assert {n: shape for n, (shape, _) in table.items()} \
+        == {n: tuple(t._value.shape) for n, t in params.items()}
+    assert {kind for _, kind in table.values()} <= {"matrix", "scale", "bias"}
+
+
+def test_kimi_k2_copy_of_the_reference_gives_the_repos_logits():
+    """The same float32 leaves through ``benchmark/families/kimi_k2.py``'s
+    ``logits_at`` (a layer's leaves at a time) and through
+    ``tests/refs/kimi_k2_reference.py``: the same logits, to float32
+    round-off of values of order 0.5 (2e-6)."""
+    import kimi_k2_reference as ref
+
+    from benchmark.families import kimi_k2
+    from benchmark.lib import weights
+
+    config = {"model": dict(load(os.path.join(
+        ROOT, "benchmark", "configs", "kimi-k2-ep32-serve.json"))["model"],
+        **TINY_KIMI), "precision": {"parameters": "bfloat16"}}
+    model = config["model"]
+    leaves_of = weights.for_reference(kimi_k2, config, seed=2147483659)
+    rng = np.random.default_rng(0)
+    ids = jnp.asarray(rng.integers(0, 96, (2, 24)), jnp.int32)
+    positions = jnp.asarray([[3, 10, 23], [0, 5, 22]], jnp.int32)
+    got = kimi_k2.logits_at(leaves_of, ids, positions, model)
+    cfg = kimi_k2.program_config(model)
+    want = ref.forward(kimi_k2.as_used(leaves_of()), ids, cfg,
+                       held=cfg.held_experts)
+    want = jnp.take_along_axis(want, positions[..., None], axis=1)
+    assert got.shape == want.shape == (2, 3, 96)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+    # the control's policy runs, and is not the reference
+    low = kimi_k2.logits_at(leaves_of, ids, positions, model, "fp8")
+    assert 1e-4 < float(jnp.max(jnp.abs(low - want))) < 1.0
+
+
+def test_kimi_k2_copy_gives_an_expert_its_tokens_or_raises():
+    """Over 4,096 tokens a block the copy computes an expert over the
+    tokens routed to it, an eighth of the block at most: the padding
+    behind the last position asked for is routed nowhere, the logits are
+    the repo's, and an expert routed more than its room RAISES."""
+    import kimi_k2_reference as ref
+
+    from benchmark.families import kimi_k2
+    from benchmark.lib import weights
+
+    model = dict(load(os.path.join(
+        ROOT, "benchmark", "configs", "kimi-k2-ep32-serve.json"))["model"],
+        **dict(TINY_KIMI, max_position_embeddings=1536,
+               num_hidden_layers=2))
+    config = {"model": model, "precision": {"parameters": "bfloat16"}}
+    leaves_of = weights.for_reference(kimi_k2, config, seed=11)
+    rng = np.random.default_rng(3)
+    ids = np.zeros((3, 1536), np.int32)          # 4,608 tokens: room 576
+    ids[:, :100] = rng.integers(1, 96, (3, 100))
+    positions = jnp.asarray([[5, 99], [0, 80], [42, 60]], jnp.int32)
+    got = kimi_k2.logits_at(leaves_of, jnp.asarray(ids), positions, model)
+    cfg = kimi_k2.program_config(model)
+    want = ref.forward(kimi_k2.as_used(leaves_of()),
+                       jnp.asarray(ids[:, :100]), cfg,
+                       held=cfg.held_experts)
+    want = jnp.take_along_axis(want, positions[..., None], axis=1)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+    # every position asked for: the zeros behind all route alike
+    with pytest.raises(ValueError, match="an expert was routed"):
+        kimi_k2.logits_at(leaves_of, jnp.asarray(ids),
+                          jnp.asarray([[5, 1535]] * 3, jnp.int32), model)
+
+
+def test_gpt3_copy_of_the_reference_gives_the_programs_logits():
+    """GPT-3's copy against the program itself in float32 on the CPU (the
+    repo's own reference of it, ``tests/numpy_gpt.py``, is a training
+    harness): logits of order 1, agreement to 2e-5."""
+    from benchmark.families import gpt3
+    from benchmark.lib import weights
+    from benchmark.lib.common import install_weights
+
+    config = load(os.path.join(ROOT, "benchmark", "configs",
+                               "gpt3-1.3b-serve.json"))
+    model = tiny_model(config)
+    table = gpt3.leaf_table(model)
+    leaves = weights.make_weights(table, 7, "float32")
+    program = program_model(config, model)
+    install_weights(program, leaves)
+    program.eval()
+    ids = np.random.default_rng(1).integers(0, 128, (2, 16)).astype(np.int32)
+    positions = jnp.asarray([[0, 7, 15], [1, 2, 3]], jnp.int32)
+    want = jnp.take_along_axis(program(paddle.to_tensor(ids))._value,
+                               positions[..., None], axis=1)
+    got = gpt3.logits_at(lambda only=None: leaves, jnp.asarray(ids),
+                         positions, model)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-5

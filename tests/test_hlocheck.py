@@ -289,7 +289,9 @@ def test_registry_names_are_stable():
                              "tp2_swap_scatter", "tp2_cow_copy",
                              "engine_decode_q8", "swap_gather_q8",
                              "swap_scatter_q8", "tp2_engine_decode_q8",
-                             "tp2_engine_decode_qlogits"}
+                             "tp2_engine_decode_qlogits",
+                             "engine_prefill_latent",
+                             "engine_decode_latent"}
     assert REGISTRY["tp8_decode"].min_devices == 8
     assert all(REGISTRY[n].min_devices == 2 for n in REGISTRY
                if n.startswith("tp2_"))

@@ -438,12 +438,13 @@ def test_kernelcheck_certs_declarations_match_registry():
     entries, and every registry entry is declared by exactly one module —
     PT011's declaration can't go stale in either direction."""
     from paddle_tpu.kernels import (flash_attention, fused_layernorm,
-                                    fused_optimizer, paged_attention,
-                                    ragged_paged_attention)
+                                    fused_optimizer, latent_paged_attention,
+                                    paged_attention, ragged_paged_attention)
 
     declared = []
     for mod in (flash_attention, fused_layernorm, fused_optimizer,
-                paged_attention, ragged_paged_attention):
+                latent_paged_attention, paged_attention,
+                ragged_paged_attention):
         certs = mod.KERNELCHECK_CERTS
         assert certs, mod.__name__
         declared.extend(certs)
