@@ -857,10 +857,12 @@ def _build_ragged(mode: str):
     (the spec K+1=5 contract), ``prefill`` (single-row chunk tail, 64-pad
     bucket at ctx0=192). All four trace to the SAME program shape — one
     kernel, four certificates — and all four certify the PIPELINED form
-    (``pipeline_chunk=8`` over the 32-page canonical row: 4 chunks
-    through 2 alternating staging buffers), so the scratch the VMEM
-    model prices carries the ×2 double-buffer cost explicitly in its
-    leading axis. ``index_args`` carry the canonical runtime
+    (``pipeline_chunk=8``, the serving default of 128 tokens, over the
+    32-page canonical row: its live chunks, 3 and 2 of 4 at the canonical
+    ctx_lens, through 2 alternating staging buffers), so the scratch the
+    VMEM model prices carries the ×2 double-buffer cost explicitly in its
+    leading axis (and the one SMEM word that carries the staging buffer
+    from a grid step to the next). ``index_args`` carry the canonical runtime
     scalar-prefetch values (ctx_lens, cu_q_lens, page table) so the
     data-dependent output index map is PROVEN injective, and the HBM
     model counts the canonical call's actual block transitions."""

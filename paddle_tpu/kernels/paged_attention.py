@@ -42,7 +42,7 @@ import jax.numpy as jnp
 
 __all__ = ["paged_write", "paged_write_quant", "paged_gather",
            "paged_gather_quant", "paged_attention", "ragged_mask",
-           "decode_kernel_eligible", "QMAX"]
+           "decode_kernel_eligible", "pages_staged_fn", "QMAX"]
 
 #: symmetric int8 code range: codes in [-127, 127], dequant = code*scale/127
 QMAX = 127.0
@@ -177,23 +177,59 @@ def decode_kernel_eligible(head_dim: int, pages_per_seq: int,
         interpret=bool(flag("FLAGS_ragged_interpret", False)))
 
 
-def _use_ragged_kernel(q, k_pool, page_table,
-                       quantized: bool) -> tuple[bool, bool]:
-    """Runtime dispatch gate: ``(eligible, interpret)`` for this call's
-    shapes. ``FLAGS_ragged_interpret`` routes the kernel through the
-    Pallas interpreter (CPU bit-identity test/bench path)."""
+def _ragged_dispatch(head_dim: int, num_heads: int, page_size: int,
+                     pages_per_seq: int, num_query_tokens: int,
+                     quantized: bool, q_itemsize: int) -> tuple[bool, bool]:
+    """Runtime dispatch gate: ``(eligible, interpret)`` for a call at
+    these shapes. ``FLAGS_ragged_interpret`` routes the kernel through
+    the Pallas interpreter (CPU bit-identity test/bench path)."""
     from ..utils.flags import flag
     from ._common import on_tpu_backend
     from .ragged_paged_attention import ragged_kernel_eligible
 
     interp = bool(flag("FLAGS_ragged_interpret", False))
     ok, _ = ragged_kernel_eligible(
-        q.shape[-1], page_table.shape[1], k_pool.shape[1], q.shape[2],
-        num_heads=q.shape[1], quantized=quantized,
+        head_dim, pages_per_seq, page_size, num_query_tokens,
+        num_heads=num_heads, quantized=quantized,
         on_tpu=on_tpu_backend(),
         flags_on=bool(flag("FLAGS_use_pallas_kernels", True)),
-        interpret=interp, q_itemsize=q.dtype.itemsize)
+        interpret=interp, q_itemsize=q_itemsize)
     return ok, interp
+
+
+def _use_ragged_kernel(q, k_pool, page_table,
+                       quantized: bool) -> tuple[bool, bool]:
+    return _ragged_dispatch(q.shape[-1], q.shape[1], k_pool.shape[1],
+                            page_table.shape[1], q.shape[2], quantized,
+                            q.dtype.itemsize)
+
+
+def pages_staged_fn(head_dim: int, num_heads: int, page_size: int,
+                    pages_per_seq: int, num_query_tokens: int, *,
+                    quantized: bool = False, q_itemsize: int = 4):
+    """``ctx_lens [rows] -> pages staged [rows]`` for a
+    :func:`paged_attention` call at these shapes, by the path the call
+    takes: the ragged kernel's own loop bound at the chunk and query tile
+    the launch resolves (``ragged_paged_attention.pages_staged``), or the
+    table's width where the composite gathers it. Host arithmetic for the
+    engine's ``serving_attention_pages_staged_total``; resolved once a
+    shape, not once a launch."""
+    import functools
+
+    from . import ragged_paged_attention as _rp
+
+    ok, _ = _ragged_dispatch(head_dim, num_heads, page_size, pages_per_seq,
+                             num_query_tokens, quantized, q_itemsize)
+    chunk = None
+    if ok:
+        _, chunk = _rp._launch_params(
+            page_size, num_heads, head_dim, pages_per_seq,
+            num_query_tokens, quantized, q_itemsize,
+            1 if quantized else q_itemsize)
+    return functools.partial(
+        _rp.pages_staged, num_query_tokens=num_query_tokens,
+        page_size=page_size, pages_per_seq=pages_per_seq,
+        chunk_pages=chunk)
 
 
 def _pages_per_block(page_size: int) -> int:

@@ -21,19 +21,32 @@ one program shape:
   in the SAME order as the composite path, so interpret mode is
   bit-identical to the jitted composite (the CPU-pinnable correctness
   contract; the tests pin it for all four modes x fp32/int8).
-- **Chunked DMA pipeline** (``pipeline_chunk < pages_per_seq``) — the
-  row's pages are staged through TWO alternating VMEM buffers: while
-  chunk ``c``'s attention contribution is computed, chunk ``c+1``'s page
-  DMAs are already in flight — the fetch latency hides under the
-  matmuls, not just under other fetches. The per-chunk contributions
-  combine through flash-style online softmax (running max / rescaled
-  sum / fp32 accumulator), which reorders the fp32 reduction — parity
-  vs the composite is the established bounded-divergence pin (mean
-  greedy common-prefix >= 0.5), with page accounting and invariants
-  exact; the single-chunk path stays the bit-identity contract. The
-  default chunk is the largest that fits the VMEM gate: one chunk at
-  test sizes, a pipelined chunk at serving widths (a 1024-token row of
-  16 heads x 128 in fp32 is 16 MiB of K+V before any compute).
+- **Chunked DMA pipeline over the LIVE chunks** (``pipeline_chunk <
+  pages_per_seq``) — a grid step stages and scores only the chunks that
+  hold a position its queries can see: their count is read from the
+  prefetched ``ctx_lens`` (``ceil(min(ctx + t0 + tq, table) / chunk)``,
+  :func:`_live_span`), not from the table's width, so a row that fills
+  half its table moves half the bytes. The chunks go through TWO
+  alternating VMEM buffers: while chunk ``c``'s attention contribution
+  is computed, chunk ``c+1``'s page DMAs are already in flight, and a
+  grid step's last chunk starts the NEXT grid step's first (the grid
+  runs in order), so the fetch latency hides under the matmuls. A whole
+  chunk is always copied (its dead end re-reads the row's last live
+  page: no table entry past the live count is followed, no staging row
+  is left uninitialised). The per-chunk contributions combine through
+  flash-style online softmax (running max / rescaled sum / fp32
+  accumulator), which reorders the fp32 reduction — parity vs the
+  composite is the established bounded-divergence pin (mean greedy
+  common-prefix >= 0.5), with page accounting and invariants exact; a
+  chunk left out is one the mask zeroed whole, so at one chunk size the
+  result is the table-wide loop's bit for bit; the single-chunk path
+  stays the bit-identity contract. The default chunk is the whole row
+  where that fits the VMEM gate (test sizes) and ``_CHUNK_TOKENS`` = 128
+  tokens at serving widths (a 1024-token row of 16 heads x 128 in fp32
+  is 16 MiB of K+V before any compute): the loop wastes half a chunk a
+  row in the mean, and at 8 pages that is 4 of about 34 staged.
+  :func:`pages_staged` is the same arithmetic for the host (the
+  engine's ``serving_attention_pages_staged_total``).
 - **Scalar prefetch** ``(ctx_lens, cu_q_lens, page_table)`` — the ragged
   parameterization. ``cu_q_lens[b] // s`` picks each row's query/output
   block, which makes the OUTPUT index map data-dependent: kernelcheck
@@ -41,10 +54,10 @@ one program shape:
   arguments (``index_args`` — the resolved, not suppressed,
   ``allow_data_dependent_outputs`` contract).
 - **Paged KV gather** — the pools stay in HBM (``ANY`` memory space);
-  each grid step DMAs its row's pages into VMEM scratch through the page
-  table (within a chunk, all copies started before any is awaited, so
-  the fetches overlap in the DMA queue; across chunks they overlap with
-  compute). In int8 mode the per-page-per-head dequant
+  each grid step DMAs its row's live pages into VMEM scratch through the
+  page table (within a chunk, all copies started before any is awaited,
+  so the fetches overlap in the DMA queue; across chunks and grid steps
+  they overlap with compute). In int8 mode the per-page-per-head dequant
   ``codes * scale / 127`` is FUSED into this gather.
 - **Tiling** — what the v5e compiler accepts, checked ahead of time by
   ``tests/test_tpu_compile.py``: a page copy takes ``(page_size,
@@ -82,6 +95,7 @@ gate called eligible that fails to trace or lower RAISES.
 from __future__ import annotations
 
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
@@ -91,7 +105,7 @@ from ._common import i32_index_scope, vmem_nbytes
 from .paged_attention import QMAX
 
 __all__ = ["ragged_paged_attention", "ragged_kernel_eligible",
-           "block_heads_for", "pipeline_chunk_for"]
+           "block_heads_for", "pipeline_chunk_for", "pages_staged"]
 
 #: kernelcheck certificates this module's Pallas kernel is registered
 #: under (analysis/kernelcheck.py REGISTRY; lint rule PT011's contract) —
@@ -200,6 +214,14 @@ def query_tile_for(num_query_tokens: int) -> int:
     return num_query_tokens
 
 
+#: tokens staged per DMA chunk where the row does not fit VMEM whole. The
+#: loop stages whole chunks up to the last position a query sees, so a
+#: row wastes half a chunk in the mean: small enough that this is a few
+#: pages, large enough that a loop turn's fixed cost does not show.
+#: Chosen by chip runs of the serving cell (PERF.md section 6, PR 30)
+_CHUNK_TOKENS = 128
+
+
 def pipeline_chunk_for(page_size: int, num_heads: int, head_dim: int,
                        pages_per_seq: int, *, num_query_tokens: int = 1,
                        block_heads: int | None = None,
@@ -209,12 +231,13 @@ def pipeline_chunk_for(page_size: int, num_heads: int, head_dim: int,
     still divides THIS call's page count (the validator pins it against
     the page count recorded at tune time; a call at a different
     ``pages_per_seq`` falls back rather than mis-tiling). The default is
-    the largest divisor of ``pages_per_seq`` whose working set fits the
-    VMEM gate: ``pages_per_seq`` itself (one chunk, no pipeline, the exact
+    ``pages_per_seq`` itself (one chunk, no pipeline, the exact
     gather-all-then-compute path the bit-identity tests pin) whenever the
-    whole row fits, a pipelined chunk at serving widths where it cannot
-    (16 heads x 128 x 1024 tokens of fp32 K+V is 16 MiB before any
-    compute)."""
+    whole row's working set fits the VMEM gate, and where it cannot (16
+    heads x 128 x 1024 tokens of fp32 K+V is 16 MiB before any compute)
+    the largest divisor of ``pages_per_seq`` of at most ``_CHUNK_TOKENS``
+    tokens that fits: the pipelined loop runs to the row's live length,
+    and what it copies in vain is the dead end of its last chunk."""
     tuned = _tuned_entry(page_size, num_heads,
                          head_dim).get("pipeline_chunk")
     if tuned:
@@ -223,11 +246,17 @@ def pipeline_chunk_for(page_size: int, num_heads: int, head_dim: int,
             return c
     bh = block_heads or block_heads_for(
         page_size, num_heads, head_dim, 1 if quantized else q_itemsize)
-    for c in range(pages_per_seq, 0, -1):
-        if pages_per_seq % c == 0 and _vmem_working_set(
-                head_dim, pages_per_seq * page_size, num_query_tokens, bh,
-                pages_per_seq, quantized, pipeline_chunk=c,
-                q_itemsize=q_itemsize) <= _VMEM_GATE_BYTES:
+
+    def fits(c):
+        return _vmem_working_set(
+            head_dim, pages_per_seq * page_size, num_query_tokens, bh,
+            pages_per_seq, quantized, pipeline_chunk=c,
+            q_itemsize=q_itemsize) <= _VMEM_GATE_BYTES
+
+    if fits(pages_per_seq):
+        return pages_per_seq
+    for c in range(max(1, _CHUNK_TOKENS // page_size), 0, -1):
+        if pages_per_seq % c == 0 and fits(c):
             return c
     return 1
 
@@ -351,6 +380,61 @@ def ragged_kernel_eligible(head_dim: int, pages_per_seq: int,
     return True, ""
 
 
+#: what :func:`_live_span` is written in: numpy on the host, bare lax
+#: primitives on a kernel's int32 scalars. An operator or a jnp function
+#: applied to a tracer is a nested jit to trace, and a serving program
+#: traces 24 kernels at every start: the pipelined branch's scalar path
+#: written in operators made 119 of them a kernel, in primitives 27
+_NP = types.SimpleNamespace(clip=np.clip, add=np.add, div=np.floor_divide)
+_LAX = types.SimpleNamespace(
+    clip=lambda x, lo, hi: jax.lax.clamp(np.int32(lo), x, np.int32(hi)),
+    add=lambda x, y: jax.lax.add(x, np.int32(y)),
+    div=lambda x, y: jax.lax.div(x, np.int32(y)))
+
+
+def _live_span(ctx_end, chunk_kv: int, page_size: int, total_kv: int,
+               ops=_NP):
+    """``(chunks, last page)`` of what a query tile can see when the last
+    position any of its queries sees is ``ctx_end - 1``: the chunks of
+    ``chunk_kv`` tokens that hold such a position, and the index of the
+    last page that does. The ONE piece of arithmetic that bounds the
+    kernel's DMA/compute loop (``ops=_LAX``, traced scalars) and that
+    :func:`pages_staged` counts on the host (``ops=_NP``). The clamp
+    keeps a dead slot's garbage length inside the table and a grid step
+    at one chunk or more."""
+    live = ops.clip(ctx_end, 1, total_kv)
+    return (ops.div(ops.add(live, chunk_kv - 1), chunk_kv),
+            ops.div(ops.add(live, -1), page_size))
+
+
+def pages_staged(ctx_lens, num_query_tokens: int, *, page_size: int,
+                 pages_per_seq: int, chunk_pages: int | None,
+                 query_tile: int | None = None):
+    """Pages a call stages per row, as the kernel stages them: int64
+    ``[rows]`` for ``ctx_lens [rows]`` and ``num_query_tokens`` new
+    tokens a row. Counted in whole pages (each head block copies its own
+    heads' share of a page, so the head blocks together move every staged
+    page once) and per query tile, so a prefill's repeated reads of its
+    prefix show. ``chunk_pages`` / ``query_tile`` are the launch's
+    resolved values (:func:`_launch_params`, :func:`query_tile_for`):
+    the pipelined kernel stages whole chunks up to the last position a
+    tile's queries see; the single-chunk kernel (``chunk_pages ==
+    pages_per_seq``) the whole table a tile; ``chunk_pages=None`` is the
+    composite path, which gathers the table's width once. (Not counted:
+    the one dummy chunk a call's last grid step starts for a step that
+    does not follow.)"""
+    ctx = np.asarray(ctx_lens, np.int64)
+    if chunk_pages is None:
+        return np.full(ctx.shape, pages_per_seq, np.int64)
+    tq = query_tile or query_tile_for(num_query_tokens)
+    staged = np.zeros(ctx.shape, np.int64)
+    for t0 in range(0, num_query_tokens, tq):
+        staged += chunk_pages * _live_span(
+            ctx + (t0 + tq), chunk_pages * page_size, page_size,
+            pages_per_seq * page_size)[0]
+    return staged
+
+
 def _ragged_kernel(tq, page_size, pages_per_seq, block_heads,
                    chunk_pages, scale, quant, lift_batch,
                    ctx_ref, cu_ref, tab_ref, q_ref, k_hbm, v_hbm, *rest):
@@ -363,46 +447,35 @@ def _ragged_kernel(tq, page_size, pages_per_seq, block_heads,
     op-for-op the composite ``sdpa`` formula so interpret mode is
     bit-identical to the composite path.
 
-    Pipelined (``chunk_pages < pages_per_seq``): chunks of
-    ``chunk_pages`` pages alternate through two staging buffers — chunk
-    ``c+1``'s copies are started BEFORE chunk ``c`` is awaited, so its
-    DMAs fly while chunk ``c``'s logits/softmax/PV matmuls run — and the
-    per-chunk contributions fold into a flash-style online softmax
-    (running max ``m``, rescaled denominator ``l``, fp32 accumulator)
-    finalized as ``acc / l``. The fp32 reduction order differs from the
-    composite's full-width softmax, so this path carries the
-    bounded-divergence contract, not bit-identity."""
+    Pipelined (``chunk_pages < pages_per_seq``): only the chunks that
+    hold a position this tile's queries can see are staged — their count
+    comes from the prefetched ``ctx_lens`` (:func:`_live_span`), not
+    from the table's width. Chunks of ``chunk_pages`` pages alternate
+    through two staging buffers — chunk ``c+1``'s copies are started
+    BEFORE chunk ``c`` is awaited, so its DMAs fly while chunk ``c``'s
+    logits/softmax/PV matmuls run, and a grid step's last chunk starts
+    the NEXT grid step's first (the grid runs in order; ``slot_ref``
+    carries the buffer a step begins in) — and the per-chunk
+    contributions fold into a flash-style online softmax (running max
+    ``m``, rescaled denominator ``l``, fp32 accumulator) finalized as
+    ``acc / l``. A chunk that is left out is one the mask would have
+    zeroed whole (``p == 0`` and ``alpha == 1`` exactly), so the result
+    is the table-wide loop's bit for bit. The fp32 reduction order
+    differs from the composite's full-width softmax, so this path
+    carries the bounded-divergence contract, not bit-identity."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    ksc_ref = vsc_ref = None
     if quant:
-        ksc_ref, vsc_ref, o_ref, k_s, v_s, sems = rest
-    else:
-        o_ref, k_s, v_s, sems = rest
-        ksc_ref = vsc_ref = None
+        ksc_ref, vsc_ref, *rest = rest
+    o_ref, k_s, v_s, sems, *slot_ref = rest  # slot_ref: pipelined only
     bi = pl.program_id(0)
-    h0 = pl.program_id(1) * block_heads
-    t0 = pl.program_id(2) * tq            # this tile's first query token
-    num_chunks = pages_per_seq // chunk_pages
+    hb = pl.program_id(1)
+    qt = pl.program_id(2)
+    h0 = hb * block_heads
+    t0 = qt * tq                          # this tile's first query token
     chunk_kv = chunk_pages * page_size
-
-    def _copy(page, j, slot, src, dst, sem_off):
-        # page: row-table index; j: slot-local page; reconstructing the
-        # same copy object is how wait() pairs with start(). The copied
-        # window is (page_size, block_heads, head_dim): block_heads is a
-        # whole sublane tile of the pool dtype or every head (see
-        # block_heads_for), so the slice of the pool's tiled
-        # (heads, head_dim) minor pair is tile-aligned
-        return pltpu.make_async_copy(
-            src.at[tab_ref[bi, page], :, pl.ds(h0, block_heads), :],
-            dst.at[slot, pl.ds(j * page_size, page_size)],
-            sems.at[slot, sem_off + j])
-
-    def _chunk_dma(c, slot, op):
-        for j in range(chunk_pages):
-            page = c * chunk_pages + j
-            op(_copy(page, j, slot, k_hbm, k_s, 0))
-            op(_copy(page, j, slot, v_hbm, v_s, chunk_pages))
 
     def _stage(ref, sc_ref, slot, p0):
         """One staged chunk as ``(block_heads, chunk_kv, head_dim)`` in
@@ -414,7 +487,7 @@ def _ragged_kernel(tq, page_size, pages_per_seq, block_heads,
             # (pages, 1, bh, 1): the page's per-head scale broadcasts
             # over its page_size tokens (a major axis) and head_dim (the
             # lanes) — no relayout of the scale block
-            sc = sc_ref[0, p0:p0 + chunk_pages][:, None]
+            sc = sc_ref[0, pl.ds(p0, chunk_pages)][:, None]
             x = (x.astype(jnp.float32).reshape(
                 chunk_pages, page_size, block_heads, x.shape[-1])
                 * sc).astype(q_ref.dtype).reshape(x.shape)
@@ -436,9 +509,25 @@ def _ragged_kernel(tq, page_size, pages_per_seq, block_heads,
         tpos = jax.lax.broadcasted_iota(jnp.int32, (tq, width), 0) + t0
         return (jpos <= ctx_ref[bi] + tpos)[None]
 
-    if num_chunks == 1:
-        _chunk_dma(0, 0, lambda cp: cp.start())
-        _chunk_dma(0, 0, lambda cp: cp.wait())
+    if chunk_pages == pages_per_seq:
+        def _chunk_dma(op):
+            # reconstructing the same copy object is how wait() pairs
+            # with start(). The copied window is (page_size, block_heads,
+            # head_dim): block_heads is a whole sublane tile of the pool
+            # dtype or every head (see block_heads_for), so the slice of
+            # the pool's tiled (heads, head_dim) minor pair is
+            # tile-aligned
+            for j in range(chunk_pages):
+                for src, dst, off in ((k_hbm, k_s, 0),
+                                      (v_hbm, v_s, chunk_pages)):
+                    op(pltpu.make_async_copy(
+                        src.at[tab_ref[bi, j], :, pl.ds(h0, block_heads),
+                               :],
+                        dst.at[0, pl.ds(j * page_size, page_size)],
+                        sems.at[0, off + j]))
+
+        _chunk_dma(lambda cp: cp.start())
+        _chunk_dma(lambda cp: cp.wait())
         kh = _stage(k_s, ksc_ref, 0, 0)
         vh = _stage(v_s, vsc_ref, 0, 0)
         if lift_batch:
@@ -468,24 +557,96 @@ def _ragged_kernel(tq, page_size, pages_per_seq, block_heads,
         o_ref[0] = out.astype(o_ref.dtype)
         return
 
-    # ---- double-buffered pipeline: warm up chunk 0, then per chunk
-    # start c+1's DMAs before waiting on c — fetch hides under compute
-    _chunk_dma(0, 0, lambda cp: cp.start())
-    m = jnp.full((block_heads, tq, 1), np.float32(-1e30), jnp.float32)
-    l = jnp.zeros((block_heads, tq, 1), jnp.float32)
-    acc = jnp.zeros((block_heads, tq, d), jnp.float32)
-    for c in range(num_chunks):
-        slot = c % 2
-        if c + 1 < num_chunks:
-            _chunk_dma(c + 1, (c + 1) % 2, lambda cp: cp.start())
-        _chunk_dma(c, slot, lambda cp: cp.wait())
-        khc = _stage(k_s, ksc_ref, slot, c * chunk_pages)
-        vhc = _stage(v_s, vsc_ref, slot, c * chunk_pages)
+    # ---- double-buffered pipeline over the LIVE chunks
+    (slot_ref,) = slot_ref
+    n_b, n_hb, n_qt = (pl.num_programs(i) for i in range(3))
+    total_kv = pages_per_seq * page_size
+
+    # int32 scalar arithmetic below is bare lax primitives (see _LAX)
+    lax, i32 = jax.lax, np.int32
+
+    def _span(row, tile):
+        # (live chunks, last live page) of grid step (row, ., tile): one
+        # past the last position any of its queries sees is ctx + t0 + tq
+        t_end = lax.add(lax.mul(tile, i32(tq)), i32(tq))
+        return _live_span(lax.add(ctx_ref[row], t_end), chunk_kv,
+                          page_size, total_kv, _LAX)
+
+    def _start(row, head0, last, c, slot):
+        """Start chunk ``c``'s page copies into buffer ``slot``. A whole
+        chunk is copied, so no staging row is ever left uninitialised (a
+        masked position's ``p`` is 0, and ``0 * NaN`` is NaN); a page
+        past ``last``, the row's last live one, re-reads that page, so no
+        table entry beyond the live count is ever followed. The copied
+        window is (page_size, block_heads, head_dim): block_heads is a
+        whole sublane tile of the pool dtype or every head (see
+        block_heads_for), so the slice of the pool's tiled
+        (heads, head_dim) minor pair is tile-aligned. All of a buffer's
+        K copies signal one semaphore, its V copies another."""
+        first = lax.mul(c, i32(chunk_pages))
+        for j in range(chunk_pages):
+            page = tab_ref[row, lax.min(lax.add(first, i32(j)), last)]
+            for kv, (src, dst) in enumerate(((k_hbm, k_s), (v_hbm, v_s))):
+                pltpu.make_async_copy(
+                    src.at[page, :, pl.ds(head0, block_heads), :],
+                    dst.at[slot, pl.ds(j * page_size, page_size)],
+                    sems.at[slot, kv]).start()
+
+    def _wait(slot):
+        # a wait a page copy, each for one page's bytes of its buffer's
+        # semaphore (the semaphore counts bytes, whichever copy brought
+        # them): after the last, every copy of the buffer has landed
+        for j in range(chunk_pages):
+            rows = pl.ds(j * page_size, page_size)
+            for kv, buf in enumerate((k_s, v_s)):
+                pltpu.make_async_copy(buf.at[slot, rows], buf.at[slot, rows],
+                                      sems.at[slot, kv]).wait()
+
+    n_chunks, last = _span(bi, qt)
+
+    @pl.when(lax.eq(lax.bitwise_or(lax.bitwise_or(bi, hb), qt), i32(0)))
+    def _():
+        slot_ref[0] = i32(0)
+        _start(bi, h0, last, i32(0), 0)
+
+    # the grid step after this one, in the order the grid runs; behind
+    # the last, the first again: its copies are a dummy, landed below
+    qt_1, hb_1, bi_1 = (lax.add(x, i32(1)) for x in (qt, hb, bi))
+    wrap_qt = lax.eq(qt_1, n_qt)
+    qt_n = lax.select(wrap_qt, i32(0), qt_1)
+    hb_n = lax.select(wrap_qt, hb_1, hb)
+    wrap_hb = lax.eq(hb_n, n_hb)
+    hb_n = lax.select(wrap_hb, i32(0), hb_n)
+    bi_n = lax.select(wrap_hb, bi_1, bi)
+    is_last = lax.eq(bi_n, n_b)
+    bi_n = lax.select(is_last, i32(0), bi_n)
+    h0_n = lax.mul(hb_n, i32(block_heads))
+    last_n = _span(bi_n, qt_n)[1]
+
+    slot0 = slot_ref[0]
+
+    def body(c, carry):
+        m, l, acc = carry
+        slot = lax.rem(lax.add(slot0, c), i32(2))
+        # under this chunk's compute fly the copies of this step's next
+        # chunk or, behind its last, of the next grid step's first. One
+        # unconditional start with selected operands: a pl.when in the
+        # loop's body cost 0.23 s a kernel to trace on the chip's host
+        # (24 kernels a program, at every start; PERF.md, PR 30)
+        c_1 = lax.add(c, i32(1))
+        more = lax.lt(c_1, n_chunks)
+        _start(lax.select(more, bi, bi_n), lax.select(more, h0, h0_n),
+               lax.select(more, last, last_n), lax.select(more, c_1, i32(0)),
+               lax.sub(i32(1), slot))
+        _wait(slot)
+        p0 = lax.mul(c, i32(chunk_pages))
+        khc = _stage(k_s, ksc_ref, slot, p0)
+        vhc = _stage(v_s, vsc_ref, slot, p0)
         logits = jax.lax.dot_general(
             qh, khc, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * sc
-        logits = jnp.where(_mask(chunk_kv, np.int32(c * chunk_kv)), logits,
-                           np.float32(-1e30))
+        logits = jnp.where(_mask(chunk_kv, lax.mul(c, i32(chunk_kv))),
+                           logits, np.float32(-1e30))
         # online-softmax fold, all fp32: rescale the running sum and
         # accumulator by exp(m - m_new) and add this chunk's terms
         # (m / l keep a trailing unit axis so they broadcast over lanes
@@ -497,7 +658,19 @@ def _ragged_kernel(tq, page_size, pages_per_seq, block_heads,
         acc = acc * alpha + jax.lax.dot_general(
             p.astype(vhc.dtype), vhc, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        m = m_new
+        return m_new, l, acc
+
+    m0 = jnp.full((block_heads, tq, 1), np.float32(-1e30), jnp.float32)
+    l0 = jnp.zeros((block_heads, tq, 1), jnp.float32)
+    acc0 = jnp.zeros((block_heads, tq, d), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(np.int32(0), n_chunks, body,
+                                  (m0, l0, acc0))
+    slot_n = lax.rem(lax.add(slot0, n_chunks), i32(2))
+    slot_ref[0] = slot_n
+
+    @pl.when(is_last)
+    def _():
+        _wait(slot_n)    # no step follows: land the dummy copies
     # chunk 0 always holds the row's position 0 (unmasked for every
     # query: jpos 0 <= ctx + tpos), so l > 0 — the division is safe
     o_ref[0] = (acc / l).astype(o_ref.dtype)
@@ -517,9 +690,9 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, ctx_lens, *,
     ``k_scale``/``v_scale`` — ``[num_pages, heads]`` f32 — are given);
     ``ctx_lens [batch]`` tokens resident per row BEFORE this call's new
     tokens (already written to the pool). ``pipeline_chunk`` (pages per
-    DMA chunk; default tuned-or-``pages_per_seq``) < ``pages_per_seq``
-    turns on the double-buffered DMA/compute pipeline. Returns
-    ``[batch, heads, s, head_dim]`` — at the single-chunk default,
+    DMA chunk; default :func:`pipeline_chunk_for`) < ``pages_per_seq``
+    turns on the double-buffered DMA/compute pipeline over the row's
+    live chunks. Returns ``[batch, heads, s, head_dim]`` — single-chunk,
     bit-identical in interpret mode to the composite gather +
     ragged-masked sdpa; pipelined, bounded-divergence (the online
     softmax reorders the fp32 reduction)."""
@@ -569,36 +742,56 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, ctx_lens, *,
         # paged_gather_quant divisor, laid out [batch, pps, heads, 1]:
         # heads on sublanes and a unit lane axis, the shape the in-kernel
         # dequant broadcasts over (page_size, head_dim) with no relayout
-        ksc = (k_scale[tab] / QMAX)[..., None]
-        vsc = (v_scale[tab] / QMAX)[..., None]
+        sc_tab = tab
+        if chunk < pps:
+            # the pipelined kernel follows no table entry past a row's
+            # last live page (_start re-reads that page): its scale
+            # stands in there too, so a masked position dequantizes to a
+            # finite value whatever the dead entries point at
+            last = _live_span(ctx + np.int32(s), chunk * ps, ps, pps * ps,
+                              _LAX)[1]
+            sc_tab = jnp.take_along_axis(
+                tab, jnp.minimum(jnp.arange(pps, dtype=jnp.int32)[None],
+                                 last[:, None]), axis=1)
+        ksc = (k_scale[sc_tab] / QMAX)[..., None]
+        vsc = (v_scale[sc_tab] / QMAX)[..., None]
         sc_spec = pl.BlockSpec((1, pps, bh, 1),
                                lambda bi, hb, qt, *_: (bi, 0, hb, 0))
         in_specs += [sc_spec, sc_spec]
         operands += [ksc, vsc]
 
+    scratch_shapes = [
+        # staging buffers: (n_bufs, chunk_kv, ...) — at n_bufs == 2
+        # the leading axis IS the double-buffer price kernelcheck's
+        # scratch model charges at face value
+        pltpu.VMEM((n_bufs, chunk * ps, bh, d), k_pool.dtype),
+        pltpu.VMEM((n_bufs, chunk * ps, bh, d), v_pool.dtype),
+    ]
+    if chunk < pps:
+        scratch_shapes += [
+            pltpu.SemaphoreType.DMA((2, 2)),   # [buffer, K or V]
+            pltpu.SMEM((1,), jnp.int32),       # the buffer a step begins in
+        ]
+    else:
+        scratch_shapes.append(pltpu.SemaphoreType.DMA((1, 2 * chunk)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, h // bh, s // tq),
         in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=[
-            # staging buffers: (n_bufs, chunk_kv, ...) — at n_bufs == 2
-            # the leading axis IS the double-buffer price kernelcheck's
-            # scratch model charges at face value
-            pltpu.VMEM((n_bufs, chunk * ps, bh, d), k_pool.dtype),
-            pltpu.VMEM((n_bufs, chunk * ps, bh, d), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((n_bufs, 2 * chunk)),
-        ])
+        scratch_shapes=scratch_shapes)
     kernel = functools.partial(_ragged_kernel, tq, ps, pps, bh, chunk,
                                None if scale is None else float(scale),
                                quant, s == 1 and bh == 1 and b * h >= 2)
+    # pipelined, a grid step starts the copies of the next: in order
+    semantics = "arbitrary" if chunk < pps else "parallel"
     with i32_index_scope():  # kernel index math assumes int32 defaults
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel")),
+                dimension_semantics=(semantics,) * 3),
             interpret=interpret,
             name="ragged_paged_attention",
         )(*operands)

@@ -653,6 +653,9 @@ class ServingEngine:
         # kernel's ragged_kernel_eligible), read once
         self._decode_pallas_eligible = model.decode_kernel_eligible(
             pages_per_seq, cfg.page_size, self.cache.cfg.quantized)
+        # ctx_lens -> pages the attention stages, by query count a row
+        # (the model's answer, asked once a launch shape)
+        self._pages_staged: dict = {}
 
         self._fault_injector = fault_injector
         if fault_injector is not None and self.cache.host_tier is not None:
@@ -1236,6 +1239,7 @@ class ServingEngine:
             self.metrics.on_failed()
             return None
         self.cache.pools = pools
+        self._count_attention_pages(start, bucket, n)
         req.prefilled_tokens = start + n
         self.metrics.on_prefill_chunk(n)
         # stamped AFTER the dispatch succeeded, so the trace's chunk
@@ -1662,6 +1666,7 @@ class ServingEngine:
             self.metrics.on_failed()
             return None
         self.cache.pools = pools
+        self._count_attention_pages(cached, bucket, len(tail))
         # the prefill's sanctioned device->host sync: its first-token
         # fetch, routed through the same np.asarray site PT005 polices
         # (a bare int() coercion would sync invisibly to the linter)
@@ -1736,6 +1741,30 @@ class ServingEngine:
             if self.scheduler.running:
                 self._preempt_one(self.scheduler.pick_victim())
 
+    def _count_attention_pages(self, ctx, s: int, tokens: int | None = None,
+                               live_rows=None) -> None:
+        """One launch's attention, counted on the host from the
+        ``ctx_lens`` it uploads: the pages that hold what a row's
+        ``tokens`` real new tokens attend to (``s`` but for a padded
+        bucket; ``live_rows``: the rows that are real, all by default),
+        and the pages the attention copies out of the pool for the ``s``
+        queries of every row it runs, padding and dead slots too
+        (``PagedCacheSpec.pages_staged``; a model that does not say is not
+        counted)."""
+        fn = self._pages_staged.get(s)
+        if fn is None:
+            make = self._cache_spec.pages_staged
+            if make is None:
+                return
+            fn = self._pages_staged[s] = make(
+                s, self.cache.cfg.pages_per_seq, self.config.page_size)
+        ctx = np.atleast_1d(ctx)
+        live = -(-(ctx + (s if tokens is None else tokens))
+                 // self.config.page_size)
+        if live_rows is not None:
+            live = live[live_rows]
+        self.metrics.on_attention_pages(int(live.sum()), int(fn(ctx).sum()))
+
     def _decode_args(self, active=None, override=None) -> tuple:
         """The decode program's operands as a launch uploads them: the
         whole page table and the five per-slot vectors from the host, and
@@ -1792,6 +1821,7 @@ class ServingEngine:
                 self.cache.pools = pools
                 self._prev_toks = toks
                 self.metrics.on_decode_step(overlapped=prev is not None)
+                self._count_attention_pages(self._ctx, 1, live_rows=active)
                 for slot, req in launched:
                     req.tokens_in_flight += 1
                     self._ctx[slot] += 1
@@ -1888,6 +1918,8 @@ class ServingEngine:
             self._audit_step(self._verify_jit, args, "verify")
         pools, packed = self._verify_jit(*args)
         self.cache.pools = pools
+        self._count_attention_pages(self._ctx, K + 1,
+                                    live_rows=self._active)
         # the step's ONE sanctioned device->host sync: the packed
         # (target tokens, accept count) fetch
         packed = np.asarray(packed)  # lint: disable=PT005

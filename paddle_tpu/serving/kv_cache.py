@@ -373,12 +373,17 @@ class PagedCacheSpec:
     what is asked raises ValueError with the reason). ``dtype`` is the
     weights'. ``counters`` names what each layer's returned cache may
     carry under ``"counters"`` (an int32 vector, summed over the layers
-    of a launch and fetched with its tokens)."""
+    of a launch and fetched with its tokens). ``pages_staged``, where the
+    model can say it: ``(num_query_tokens, pages_per_seq, page_size) ->
+    (ctx_lens [rows] -> pages [rows])``, the pages one layer's attention
+    copies out of the pool for a launch, by the path its dispatch takes
+    (the engine's ``serving_attention_pages_staged_total``)."""
     num_layers: int
     max_seq_len: int
     dtype: object
     leaves: tuple
     counters: tuple = ()
+    pages_staged: object = None
 
 
 def kv_heads_leaves(num_heads: int, head_dim: int, dtype=None,

@@ -25,6 +25,20 @@ them with no new plumbing):
                             that needed the host's view whole (preempt /
                             cancel / deadline / fault / debug_checks /
                             flight_record / fatal / run_end)
+- serving_attention_pages_live_total counter: pool pages that hold a
+                            context the queries of a launch attend to,
+                            ``ceil((ctx + s) / page_size)`` summed over
+                            the launch's real rows (decode, prefill,
+                            chunk and verify launches; a layer's worth)
+- serving_attention_pages_staged_total counter: pool pages the attention
+                            of those launches copies out of the pool, as
+                            its dispatch stages them: the ragged kernel's
+                            whole chunks up to the last position a query
+                            tile sees, per query tile (a prefill re-reads
+                            its prefix a tile), every row it runs; the
+                            table's width a row where the composite
+                            gathers. live / staged is the share of the
+                            attention's KV traffic that a context needs
 - serving_preemptions_total counter
 
 Resilience counters (pre-seeded to 0 so they always appear in snapshots):
@@ -274,6 +288,7 @@ PREFIX = "serving_"
 _SEEDED = ("tokens_total", "prefills_total", "prefill_tokens_total",
            "prefill_chunks_total", "chunk_limit", "slo_throttles_total",
            "decode_steps", "decode_overlapped_total", "preemptions_total",
+           "attention_pages_live_total", "attention_pages_staged_total",
            "rejected", "shed", "expired", "cancelled", "failed",
            "swap_outs", "swap_ins",
            "prefix_hits", "prefix_misses", "prefix_tokens_saved",
@@ -574,6 +589,12 @@ class ServingMetrics:
         monitor.stat_add(PREFIX + "decode_steps", 1)
         if overlapped:
             monitor.stat_add(PREFIX + "decode_overlapped_total", 1)
+
+    def on_attention_pages(self, live: int, staged: int) -> None:
+        """One launch's attention: pages its contexts hold, and pages it
+        copied out of the pool (a layer's worth of each)."""
+        monitor.stat_add(PREFIX + "attention_pages_live_total", live)
+        monitor.stat_add(PREFIX + "attention_pages_staged_total", staged)
 
     def on_decode_drain(self, reason: str) -> None:
         """One decode in flight fetched before the next launch."""

@@ -437,14 +437,26 @@ class GPTForCausalLM(nn.Layer):
         ``[heads, head_dim]`` a token in the weights' dtype (int8 codes
         beside per-page-per-head scales under ``kv_dtype="int8"``). Every
         mode the engine has is supported."""
+        from ..kernels.paged_attention import pages_staged_fn
         from ..serving.kv_cache import PagedCacheSpec, kv_heads_leaves
 
         c = self.cfg
         dtype = self.gpt.wte.weight._value.dtype
+        quantized = kv_dtype == "int8"
+        head_dim = c.hidden_size // c.num_heads
+
+        def pages_staged(num_query_tokens, pages_per_seq, page_size):
+            # a device's own heads: what its kernel launch resolves from
+            return pages_staged_fn(
+                head_dim, c.num_heads // tensor_parallel, page_size,
+                pages_per_seq, num_query_tokens, quantized=quantized,
+                q_itemsize=dtype.itemsize)
+
         return PagedCacheSpec(
             num_layers=c.num_layers, max_seq_len=c.max_seq_len, dtype=dtype,
-            leaves=kv_heads_leaves(c.num_heads, c.hidden_size // c.num_heads,
-                                   dtype, quantized=kv_dtype == "int8"))
+            leaves=kv_heads_leaves(c.num_heads, head_dim, dtype,
+                                   quantized=quantized),
+            pages_staged=pages_staged)
 
     def decode_kernel_eligible(self, pages_per_seq: int, page_size: int,
                                quantized: bool = False) -> bool:

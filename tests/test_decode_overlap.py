@@ -279,6 +279,77 @@ def test_pipelined_chunks_match_composite(mode, quant):
             err_msg=f"{mode}/{'int8' if quant else 'fp32'} chunk={chunk}")
 
 
+def _table_wide_chunks(q, kp, vp, tab, ctx, chunk_kv, k_scale=None,
+                       v_scale=None):
+    """The composite masked chunk by chunk over the WHOLE table, in the
+    pipelined kernel's order and operations (online softmax, -1e30 for a
+    masked logit): what the kernel computed before its loop was bounded
+    by ctx_lens, written in plain jnp."""
+    b, h, s, d = q.shape
+    if k_scale is not None:
+        k_all = pa.paged_gather_quant(kp, k_scale, tab, q.dtype)
+        v_all = pa.paged_gather_quant(vp, v_scale, tab, q.dtype)
+    else:
+        k_all, v_all = pa.paged_gather(kp, tab), pa.paged_gather(vp, tab)
+    mask = pa.ragged_mask(ctx, k_all.shape[2], s)    # [b, 1, s, S]
+    sc = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    m = jnp.full((b, h, s, 1), -1e30, jnp.float32)
+    l = jnp.zeros((b, h, s, 1), jnp.float32)
+    acc = jnp.zeros((b, h, s, d), jnp.float32)
+    for j0 in range(0, k_all.shape[2], chunk_kv):
+        kc, vc = k_all[:, :, j0:j0 + chunk_kv], v_all[:, :, j0:j0 + chunk_kv]
+        logits = jnp.einsum("bhsd,bhjd->bhsj", q, kc,
+                            preferred_element_type=jnp.float32) * sc
+        logits = jnp.where(mask[..., j0:j0 + chunk_kv], logits,
+                           jnp.float32(-1e30))
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(logits - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.einsum(
+            "bhsj,bhjd->bhsd", p.astype(vc.dtype), vc,
+            preferred_element_type=jnp.float32)
+        m = m_new
+    return (acc / l).astype(q.dtype)
+
+
+# mode -> (heads, s, head_dim, page_size, pages_per_seq, chunk pages)
+_BOUND_SHAPES = {
+    "decode": (2, 1, 8, 4, 8, 2),
+    "verify": (4, 5, 16, 4, 8, 2),
+    "chunk": (2, 8, 8, 4, 8, 4),
+    "prefill-2-tiles": (2, 256, 8, 16, 32, 4),
+}
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["fp32", "int8"])
+@pytest.mark.parametrize("mode", sorted(_BOUND_SHAPES))
+def test_live_chunk_loop_matches_the_table_wide_one(mode, quant):
+    """Same chunk, same bits. The kernel's loop runs to the last chunk a
+    query tile can see, and a chunk it leaves out is one the mask zeroes
+    whole (p == 0, alpha == 1 exactly): at a fixed ``pipeline_chunk`` its
+    output EQUALS the table-wide chunk loop's, and stays within the
+    pipelined path's tolerance of the composite. Rows: a live length on,
+    one under and one over a chunk boundary, ctx 0, ctx + s at the
+    table's end, and a dead slot whose garbage length lies beyond the
+    table (clamped inside the kernel, every position visible)."""
+    h, s, d, ps, pps, chunk = _BOUND_SHAPES[mode]
+    ck, total = chunk * ps, ps * pps
+    on = (-(-(s + 1) // ck) + 1) * ck     # a chunk boundary past s
+    ctx_vals = [on - s, on - s - 1, on - s + 1, 0, total - s, 10 ** 6]
+    b = len(ctx_vals)
+    args, kw = _args(17 + int(quant), b, h, s, d, ps, pps, b * pps + 1,
+                     ctx_vals, quant=quant)
+    out = jax.jit(lambda *a: rp.ragged_paged_attention(
+        *a, interpret=True, pipeline_chunk=chunk, **kw))(*args)
+    wide = jax.jit(lambda *a: _table_wide_chunks(*a, ck, **kw))(*args)
+    assert np.array_equal(np.asarray(out), np.asarray(wide)), \
+        f"{mode}: the bounded loop changed a bit"
+    ref = jax.jit(lambda *a: _composite(*a, **kw))(*args)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
 def test_single_chunk_is_bit_identical_to_default():
     """chunk == pages_per_seq is the exact pre-pipeline path: same DMA
     plan, same op-for-op compute — bit-identical to calling without the

@@ -152,9 +152,16 @@ def test_ragged_kernel_eligible_gates():
     # page too large to stage one at a time trips the VMEM gate
     ok, why = rp.ragged_kernel_eligible(128, 64, 4096)
     assert not ok and "VMEM" in why
-    assert rp.pipeline_chunk_for(16, 16, 128, 64, block_heads=8) == 32
+    # a 64-page fp32 row of 8 heads x 128 does not fit VMEM whole: the
+    # default is then the chunk of _CHUNK_TOKENS (128 tokens = 8 pages),
+    # not the largest that fits (32 for decode, 16 for a prefill tile,
+    # until PR 30): the loop runs to the row's live length and wastes
+    # half a chunk a row, so a small chunk wins (PERF.md section 6)
+    assert rp.pipeline_chunk_for(16, 16, 128, 64, block_heads=8) == 8
     assert rp.pipeline_chunk_for(16, 16, 128, 64, block_heads=8,
-                                 num_query_tokens=512) == 16
+                                 num_query_tokens=512) == 8
+    # a row that fits VMEM whole stays the exact single-chunk path
+    assert rp.pipeline_chunk_for(16, 8, 128, 32) == 32
 
 
 def test_validate_ragged_tuned():
@@ -199,6 +206,94 @@ def test_ragged_tuned_load_rejects_bad_entry(tmp_path, monkeypatch):
     assert rp.block_heads_for(16, 16, 128, pool_itemsize=1) == 16
     assert rp.block_heads_for(16, 4, 128) == 4
     monkeypatch.setattr(rp, "_TUNED", None)
+
+
+# ------------------------------------------- the loop bounded by ctx_lens
+# (mode, batch, heads, s, head_dim, page_size, pages_per_seq, ctx_lens,
+#  chunk): every serving contract through the PIPELINED kernel, with live
+# lengths that end inside a chunk so its last pages are dead
+_LIVE_MODES = [
+    ("decode", 3, 2, 1, 8, 4, 8, [5, 9, 0], 2),
+    ("prefill", 1, 2, 8, 8, 4, 8, [0], 4),
+    ("chunk", 2, 2, 8, 8, 4, 8, [4, 13], 4),
+    ("verify", 3, 4, 5, 16, 4, 8, [10, 4, 17], 2),
+]
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["fp32", "int8"])
+@pytest.mark.parametrize("mode", [m[0] for m in _LIVE_MODES])
+def test_dead_pages_are_never_read(mode, quant):
+    """Every table entry past a row's live pages points at a POISONED
+    pool page (NaN keys and values; NaN scales under int8, whose codes
+    cannot hold one) and nothing zeroes the staging buffers: the
+    pipelined kernel's output is finite and the composite's over the
+    same pool with those entries on the null page. A masked position's
+    probability is exactly 0, and 0 * NaN is NaN: the kernel may not
+    stage what it has not copied, nor copy through a dead entry."""
+    _, b, h, s, d, ps, pps, ctx_vals, chunk = next(
+        m for m in _LIVE_MODES if m[0] == mode)
+    npages = b * pps + 2
+    (q, kp, vp, tab, ctx), kw = _args(21 + int(quant), b, h, s, d, ps, pps,
+                                      npages, ctx_vals, quant=quant)
+    # keep the null page and the poison page out of every live entry
+    poison = npages - 1
+    tab = np.array(1 + np.asarray(tab) % (npages - 2))
+    live = -(-(np.asarray(ctx_vals) + s) // ps)
+    dead = np.arange(pps)[None, :] >= live[:, None]
+    assert dead.any(axis=1).all() and (live % chunk).any()
+    clean = jnp.asarray(np.where(dead, 0, tab).astype(np.int32))
+    dirty = jnp.asarray(np.where(dead, poison, tab).astype(np.int32))
+    if quant:
+        kw = {k: v.at[poison].set(jnp.nan) for k, v in kw.items()}
+    else:
+        kp, vp = kp.at[poison].set(jnp.nan), vp.at[poison].set(jnp.nan)
+    ref_kw = ({k: v.at[poison].set(1.0) for k, v in kw.items()}
+              if quant else {})
+    ref = jax.jit(lambda *a: _composite(*a, **ref_kw))(
+        q, jnp.nan_to_num(kp) if not quant else kp,
+        jnp.nan_to_num(vp) if not quant else vp, clean, ctx)
+    out = jax.jit(lambda *a: rp.ragged_paged_attention(
+        *a, interpret=True, pipeline_chunk=chunk, **kw))(
+            q, kp, vp, dirty, ctx)
+    assert np.isfinite(np.asarray(out)).all(), "a dead page was staged"
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+def _staged_brute(ctx, s, ps, pps, chunk, tq):
+    """Pages staged for one row, counted the slow way: per query tile,
+    every chunk that holds a position some query of the tile can see
+    (query t sees positions <= ctx + t), clamped to the table."""
+    n = 0
+    for t0 in range(0, s, tq):
+        seen = min(max(ctx + t0 + tq, 1), ps * pps)  # positions 0..seen-1
+        n += chunk * sum(1 for c in range(pps // chunk)
+                         if c * chunk * ps < seen)
+    return n
+
+
+@pytest.mark.parametrize("s,tq", [(1, 1), (5, 5), (256, 128)],
+                         ids=["decode", "verify", "prefill-2-tiles"])
+def test_pages_staged_matches_brute_force(s, tq):
+    """The exported arithmetic of the kernel's loop bound, on its edges:
+    ctx on, one under and one over a chunk boundary, 0, the table's end,
+    and a dead slot's garbage beyond it."""
+    ps, pps, chunk = 16, 64, 8
+    ck, total = chunk * ps, ps * pps
+    edges = [0, 1, ck - s - 1, ck - s, ck - s + 1, ck - 1, ck, ck + 1,
+             3 * ck, total - s - 1, total - s, total, 10 ** 6]
+    edges = [max(0, e) for e in edges]
+    assert rp.query_tile_for(s) == tq
+    got = rp.pages_staged(edges, s, page_size=ps, pages_per_seq=pps,
+                          chunk_pages=chunk)
+    want = [_staged_brute(c, s, ps, pps, chunk, tq) for c in edges]
+    assert got.tolist() == want
+    # the single-chunk kernel stages the table a tile, the composite once
+    assert rp.pages_staged(edges, s, page_size=ps, pages_per_seq=pps,
+                           chunk_pages=pps).tolist() == \
+        [pps * (s // tq)] * len(edges)
+    assert rp.pages_staged(edges, s, page_size=ps, pages_per_seq=pps,
+                           chunk_pages=None).tolist() == [pps] * len(edges)
 
 
 # ------------------------------------------------------------- engine level
@@ -276,6 +371,60 @@ def test_engine_kernel_on_int8_bit_identical(ragged_interpret):
     for a, b in zip(off, on):
         assert np.array_equal(a, b), "int8 kernel-on output diverged"
     assert eng.metrics.snapshot()["serving_pallas_fallback_total"] == 0
+
+
+def test_engine_counts_attention_pages(ragged_interpret, monkeypatch):
+    """serving_attention_pages_{live,staged}_total after a short run with
+    the kernel pipelined (a tuned 2-page chunk over the 16-page table):
+    every launch adds ceil((ctx + tokens) / page_size) over its real rows
+    and ``pages_staged`` of the ctx_lens it uploaded over all its rows;
+    live <= staged, and staged stays under the table-wide count. The
+    composite engine (no kernel on the CPU) stages the table's width."""
+    monkeypatch.setattr(rp, "_TUNED", {
+        "4,2,8": {"block_heads": 2, "pipeline_chunk": 2,
+                  "pages_per_seq": 16}})
+    eng = _mk_engine()
+    ps, pps = 4, eng.cache.cfg.pages_per_seq
+    assert pps == 16
+    launches = []
+    count = eng._count_attention_pages
+
+    def spy(ctx, s, tokens=None, live_rows=None):
+        launches.append((np.array(ctx, copy=True).reshape(-1), s,
+                         s if tokens is None else tokens,
+                         None if live_rows is None else live_rows.copy()))
+        count(ctx, s, tokens, live_rows)
+
+    monkeypatch.setattr(eng, "_count_attention_pages", spy)
+    pre = eng.metrics.snapshot()
+    _drive(eng, budget=6)
+    snap = eng.metrics.snapshot()
+    live = snap["serving_attention_pages_live_total"] \
+        - pre["serving_attention_pages_live_total"]
+    staged = snap["serving_attention_pages_staged_total"] \
+        - pre["serving_attention_pages_staged_total"]
+    want_live = want_staged = table_wide = 0
+    for ctx, s_, tokens, rows in launches:
+        per_row = -(-(ctx + tokens) // ps)
+        want_live += int((per_row if rows is None else per_row[rows]).sum())
+        want_staged += int(rp.pages_staged(
+            ctx, s_, page_size=ps, pages_per_seq=pps, chunk_pages=2).sum())
+        table_wide += pps * len(ctx)
+    # decodes, and the two prompts' prefill buckets
+    assert {s_ for _, s_, _, _ in launches} == {1, 8, 16}
+    assert (live, staged) == (want_live, want_staged)
+    assert 0 < live <= staged < table_wide
+
+    set_flags({"FLAGS_ragged_interpret": False})
+    eng = _mk_engine()
+    pre = eng.metrics.snapshot()
+    _drive(eng, budget=4)
+    snap = eng.metrics.snapshot()
+    steps = snap["serving_decode_steps"] - pre["serving_decode_steps"]
+    prefills = snap["serving_prefills_total"] - pre["serving_prefills_total"]
+    assert snap["serving_attention_pages_staged_total"] \
+        - pre["serving_attention_pages_staged_total"] \
+        == pps * (2 * steps + prefills)
 
 
 def test_engine_ineligible_stays_composite_with_zero_fallbacks():

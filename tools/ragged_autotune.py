@@ -22,12 +22,22 @@ BEFORE writing — the same validator the kernel runs at load time (incl.
 the stale-chunk rule: a pipeline_chunk must divide the pages_per_seq it
 was tuned at), so load can never see an entry bank rejected.
 
+The kernel stages a row's live chunks only, so its time depends on the
+context lengths it is timed at: ``--ctx LO HI`` draws each row's
+``ctx_lens`` uniformly from ``[LO, HI]`` (clamped to the shape's table),
+and a winner is only worth banking when tuned on what serving runs. The
+default is the whole table; ``--ctx 449 640`` is the benchmark's
+``gpt3-1.3b-serve.batch-unshared`` (prompts of 449-512 tokens and up to
+128 generated); ``--ctx 1023 1023`` a table-full row, the case in which
+the bounded loop saves nothing.
+
 TPU only (the compiled kernel; the CPU interpreter's timings are
 meaningless): exits non-zero otherwise. The sweep records are printed
 with the table.
 
-Usage: python tools/ragged_autotune.py
+Usage: python tools/ragged_autotune.py [--ctx LO HI]
 """
+import argparse
 import json
 import os
 import sys
@@ -82,6 +92,14 @@ def _time_config(q, kp, vp, tab, ctx, block_heads, pipeline_chunk):
 def main():
     import jax
 
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument(
+        "--ctx", nargs=2, type=int, metavar=("LO", "HI"), default=None,
+        help="draw each row's context length uniformly from [LO, HI] "
+             "tokens, clamped to the shape's table (default: the whole "
+             "table); e.g. --ctx 449 640, what the serving cell "
+             "gpt3-1.3b-serve.batch-unshared sends")
+    opts = parser.parse_args()
     if jax.default_backend() != "tpu":
         sys.exit("[ragged_autotune] needs a TPU: the compiled kernel is "
                  "what is tuned")
@@ -100,7 +118,11 @@ def main():
         vp = jnp.asarray(rng.rand(npages, ps, h, d), jnp.float32)
         tab = jnp.asarray(
             np.arange(1, 1 + b * pps, dtype=np.int32).reshape(b, pps))
-        ctx = jnp.asarray(rng.randint(ps, ps * pps - 1, (b,)), jnp.int32)
+        # the new token is in the pool already: ctx <= table - 1
+        lo, hi = opts.ctx or (ps, ps * pps - 1)
+        lo, hi = (min(max(v, 0), ps * pps - 1) for v in (lo, hi))
+        ctx = jnp.asarray(rng.randint(lo, max(lo, hi) + 1, (b,)),
+                          jnp.int32)
         results = {}
         for bh, chunk in _candidates(h, d, ps, pps):
             try:
@@ -142,6 +164,7 @@ def main():
             "device_kind": getattr(dev, "device_kind", "?"),
             "config": {"batch": b, "heads": h, "head_dim": d,
                        "page_size": ps, "pages_per_seq": pps,
+                       "ctx_range": [lo, hi],
                        "best_block_heads": best_bh,
                        "best_pipeline_chunk": best_chunk,
                        "sweep_ms": {f"{kk[0]},{kk[1]}": round(vv * 1e3, 4)
