@@ -642,7 +642,6 @@ def _build_engine_step(which: str, tensor_parallel: int = 1,
     target's own 2L+1 all-reduces (and not one more: the proposer adds
     no collectives) under tensor parallelism, donated pools aliased
     either way."""
-    import jax.numpy as jnp
     import numpy as np
 
     import paddle_tpu as paddle
@@ -670,36 +669,24 @@ def _build_engine_step(which: str, tensor_parallel: int = 1,
         # on chip, where the latency-hiding scheduler must deliver)
         tp_overlap_scheduler=tensor_parallel > 1,
         tp_quantized_logits=quantized_logits))
+    # the operands are the engine's own, as a launch builds them: the
+    # order of a step program's operands is known to engine.py alone
     if which == "verify_spec":
-        args = (eng._p, eng.cache.pools,
-                jnp.asarray(eng.cache.page_table), jnp.asarray(eng._ctx),
-                jnp.asarray(eng._last_tok), jnp.asarray(eng._active),
-                jnp.asarray(eng._rids), jnp.asarray(eng._gen),
-                jnp.asarray(eng._spec_hist()))
-        return eng._verify_jit, args, None, eng._step_budget("verify")
-    if which in ("prefill", "prefill_chunk"):
-        bucket = eng.prefill_buckets[0]
-        padded = np.zeros(bucket, np.int32)
-        if which == "prefill":
-            padded[:3] = (5, 7, 11)
-            tail, ctx0 = 3, 0
-        else:
-            # chunked prefill: a MID-PROMPT chunk — queries enter at
-            # ctx0 > 0 against already-resident KV, through the SAME
-            # prefill program shape (chunk padded to its bucket). Audited
-            # separately so the registry certifies the exact call
-            # signature the chunk phase dispatches, not just the cold
-            # ctx0 = 0 case.
-            padded[:4] = (3, 5, 7, 11)
-            tail, ctx0 = 4, 4
-        args = (eng._p, eng.cache.pools, jnp.asarray(padded),
-                jnp.asarray(tail, jnp.int32), jnp.asarray(ctx0, jnp.int32),
-                jnp.asarray(eng.cache.page_table[0]),
-                jnp.asarray(1, jnp.int32))
-        return (eng._prefill_jit, args, None,
-                eng._step_budget(f"prefill[{bucket}]"))
-    return (eng._decode_jit, eng._decode_args(), None,
-            eng._step_budget("decode"))
+        prog, args = eng._programs["verify"], eng._verify_args()
+    elif which in ("prefill", "prefill_chunk"):
+        # prefill: a cold prompt. prefill_chunk: a MID-PROMPT chunk —
+        # queries enter at start > 0 against already-resident KV, through
+        # the SAME prefill program shape (chunk padded to its bucket).
+        # Audited separately so the registry certifies the exact call
+        # signature the chunk phase dispatches, not just the cold case.
+        ids, start = (((5, 7, 11), 0) if which == "prefill"
+                      else ((3, 5, 7, 11), 4))
+        prog = eng._prefill_program(len(ids))
+        args = eng._prefill_args(prog, 0, 1, np.asarray(ids, np.int32),
+                                 start)
+    else:
+        prog, args = eng._programs["decode"], eng._decode_args()
+    return eng.guards[prog.phase], args, None, eng._step_budget(prog.label)
 
 
 def _build_cache_step(which: str, tensor_parallel: int = 1,

@@ -347,7 +347,8 @@ def _pt004(tree, path):
                    "sleeping. Use engine.now() / the injected clock.")
 
 
-_HOT_NAMES = ("step", "_step")
+# the engine's step, and what every launch of a step program goes through
+_HOT_NAMES = ("step", "_step", "_launch", "_fetch")
 
 
 def _pt005(tree, path):

@@ -150,6 +150,29 @@ def _identifier(x) -> str:
     return re.sub(r"\W+", "_", "_".join(str(p) for p in parts)).strip("_")
 
 
+def _on_a_chunk_of_its_own(fn, args, kwargs):
+    """``fn(*args, **kwargs)``, called from a frame so large that CPython
+    gives it a chunk of the thread's data stack to itself with half a
+    megabyte to spare, so that no call below it crosses a chunk boundary.
+
+    CPython (3.11 on) keeps interpreter frames in 16 KiB chunks; a call
+    that does not fit the current chunk maps a new one and the matching
+    return unmaps it at once. Tracing a program is a few hundred thousand
+    nested calls: where one of its inner loops happens to straddle a
+    boundary, every turn pays a map and an unmap. On a sandboxed host a
+    system call is 50-100 us, and a serving program's trace went from 7.5
+    to 12 s because frames ABOVE it had grown by 40 slots (PERF.md section
+    6, PR 31). Which loop straddles is decided by the sizes of all frames
+    below: this takes the caller's out of it."""
+    return fn(*args, **kwargs)
+
+
+# a frame of just over 2**16 slots (512 KiB) is given a chunk of 1 MiB: the
+# callee's frames have the other half
+_on_a_chunk_of_its_own.__code__ = _on_a_chunk_of_its_own.__code__.replace(
+    co_stacksize=2 ** 16 + 64)
+
+
 class CompileGuard:
     """``jax.jit`` with an audit trail: trace counting, per-trace abstract
     signatures, compile budgets, retrace explanation, and donation checks.
@@ -324,7 +347,11 @@ class CompileGuard:
                 raise RetraceError(self._explain(
                     sig, group if regroup else None))
         before = self.traces
-        out = self._jit_for(group)(*args, **kwargs)
+        jitted = self._jit_for(group)
+        if group in self._groups:  # built already: the hot path
+            out = jitted(*args, **kwargs)
+        else:  # this call traces and lowers the program
+            out = _on_a_chunk_of_its_own(jitted, args, kwargs)
         if self.traces > before:
             # shape/dtype metadata stays readable on donated-and-deleted
             # arrays (only the data is gone), so post-call recording is safe

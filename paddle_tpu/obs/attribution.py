@@ -14,7 +14,9 @@ site writes both:
   the part of the step that the host was in. With no profiler session the
   TraceMe records nothing (under a microsecond).
 
-The span tree (every span carries ``step=<engine step index>``, the
+Every ``*.dispatch`` span is opened by the engine's one launch
+(``ServingEngine._launch``) and every ``*.fetch`` span holds its one fetch
+(``_fetch``), whichever program runs. The span tree (every span carries ``step=<engine step index>``, the
 per-request ones ``rid=``; a span takes its attributes when it opens, so
 counts known only at its end stay on the ``StepRecord`` and join by
 ``step``):
@@ -25,13 +27,16 @@ span                    extent                                attributes
 ``serve.step``          all of ``ServingEngine.step()``       step
 ``serve.admit``         deadline sweep, ``scheduler.admit``,  queue_depth
                         restore failures
-``serve.prefill``       one per prefilled request             rid, bucket,
+``serve.prefill``       one per request prefilled whole       rid, bucket,
                                                               cached, tail
-``serve.prefill.upload``    building the padded ids and the   bytes
-                            five device operands
-``serve.prefill.dispatch``  the call of the jitted program
-``serve.prefill.fetch``     the first-token fetch (blocks)
 ``serve.chunk_prefill``  the chunk loop                       chunks
+``serve.prefill.upload``    inside either, one a launch of    rid, bytes
+                            the one prefill path: the padded
+                            ids and the five device operands
+``serve.prefill.dispatch``  the call of the jitted program    rid
+``serve.prefill.fetch``     the first-token fetch of a        rid
+                            launch that completed a prompt
+                            (blocks)
 ``serve.evict``         fault sites, decode-page pressure,
                         preemption
 ``serve.decode``        the decode phase                      batch
@@ -43,6 +48,9 @@ span                    extent                                attributes
 ``serve.drain``         an early fetch + emit of the decode   reason
                         in flight
 ``serve.verify``        the speculative verify phase          batch
+``serve.verify.dispatch``   the call of the jitted program
+``serve.verify.fetch``      the packed fetch of that same
+                            launch (blocks)
 ``serve.account``       cache stats, gauges, the step record,
                         watchdogs, the SLO controller
 ``serve.add_request``   ``ServingEngine.add_request``         rid, prompt_len
@@ -60,8 +68,11 @@ and is no part of that sum (``StepRecord.span_s``).
 
 ZERO device syncs either way (clock reads and TraceMe events only — the
 SyncTally decode-loop certification is byte-identical with attribution
-on). With ``enable_tracing=False`` the engine holds no accumulator and
-every site costs one ``is not None`` check: no span object is made.
+on). Whether tracing is on is decided HERE, once: the engine (and the
+cache) always hold an accumulator and every site reads ``with
+att.span(...):``. A disabled one (``PhaseAccumulator()``, what
+``enable_tracing=False`` builds) hands back the shared :data:`NO_SPAN`
+and does nothing else: no span object, no clock read, no record.
 
 Imports nothing from ``paddle_tpu.serving`` (serving imports us) and
 touches no device state.
@@ -85,9 +96,8 @@ PHASES = ("admit", "swap", "prefill", "chunk_prefill", "decode", "verify",
 #: what every span's name starts with in the profiler's trace
 SPAN_PREFIX = "serve."
 
-#: what a span site enters with tracing off — ``with (att.span(...) if att
-#: is not None else NO_SPAN):`` — one shared do-nothing context: no span
-#: object is made, the site costs its ``is not None`` check
+#: what a disabled accumulator's ``span()`` hands back: one shared
+#: do-nothing context, so no span object is made
 NO_SPAN = contextlib.nullcontext()
 
 
@@ -143,13 +153,18 @@ class PhaseAccumulator:
     ``serve.account``, which outlives the record: what of it lies before
     ``finish()`` is in ``span_s["account"]``, the rest is in the
     profiler's trace only.
+
+    Without a clock the accumulator is disabled (``enabled`` False):
+    ``span()`` is :data:`NO_SPAN`, every other method returns at once, a
+    record never opens.
     """
 
-    __slots__ = ("_clock", "open", "t0", "_last", "_acc", "_spans", "step",
-                 "_step_ann", "_account")
+    __slots__ = ("_clock", "enabled", "open", "t0", "_last", "_acc",
+                 "_spans", "step", "_step_ann", "_account")
 
-    def __init__(self, clock):
+    def __init__(self, clock=None):
         self._clock = clock
+        self.enabled = clock is not None
         self.open = False
         self.t0 = 0.0
         self._last = 0.0
@@ -163,6 +178,8 @@ class PhaseAccumulator:
     def enter_step(self, step: int) -> None:
         """Open ``serve.step`` (a step event: the profiler groups what the
         device ran by it). No clock read: the record opens at ``begin``."""
+        if not self.enabled:
+            return
         self.step = step
         self._step_ann = StepTraceAnnotation(
             SPAN_PREFIX + "step", step_num=step, step=step)
@@ -177,15 +194,18 @@ class PhaseAccumulator:
             self._step_ann.__exit__(None, None, None)
             self._step_ann = None
 
-    def span(self, name: str, **attrs) -> _Span:
+    def span(self, name: str, **attrs):
         """``with att.span("decode.fetch"):`` — see the class docstring.
         ``step`` is the open step's unless given."""
+        if not self.enabled:
+            return NO_SPAN
         attrs.setdefault("step", self.step)
         return _Span(self, name, attrs)
 
     def account(self) -> None:
         """Open ``serve.account``; ``exit_step`` closes it."""
-        self._account = self.span("account").__enter__()
+        if self.enabled:
+            self._account = self.span("account").__enter__()
 
     @property
     def span_s(self) -> dict:
@@ -194,6 +214,8 @@ class PhaseAccumulator:
 
     # ------------------------------------------------------------ seconds
     def begin(self, t: float | None = None) -> float:
+        if not self.enabled:
+            return 0.0
         t = self._clock() if t is None else t
         self.open = True
         self.t0 = self._last = t
@@ -203,6 +225,8 @@ class PhaseAccumulator:
 
     def mark(self, phase: str, t: float | None = None) -> float:
         """Charge now - last_mark to ``phase``; returns the interval."""
+        if not self.enabled:
+            return 0.0
         t = self._clock() if t is None else t
         dt = t - self._last
         if dt:
@@ -213,6 +237,8 @@ class PhaseAccumulator:
     def finish(self, t: float | None = None) -> tuple[float, dict]:
         """Close the step's record: residual time goes to ``"other"``;
         returns ``(t_end, phases)``."""
+        if not self.enabled:
+            return 0.0, {}
         t = self._clock() if t is None else t
         self.mark("other", t)
         if self._account is not None and self._account._t0 is not None:

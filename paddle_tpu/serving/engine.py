@@ -17,6 +17,34 @@ the smallest bucket in a fixed power-of-two set capped at
 ``max_prompt_len``, so short prompts stop paying max-length prefill FLOPs
 and the bucket set is the only source of prefill compiles.
 
+How a step program is run. The engine has three kinds of compiled
+program (prefill, one a pad bucket; decode; with ``spec=``, verify) and
+runs all of them the same way, through code that exists once:
+
+- a program's description (``_Program``: phase, audit label, rows x tokens;
+  ``engine._programs`` by label) and its operands (``_prefill_args``,
+  ``_decode_args``, ``_verify_args``: the order of a program's operands is
+  known here and nowhere else — the hlocheck registry asks for them);
+- ONE launch (``_launch``): the ``debug_checks`` audit, the dispatch inside
+  the ``serve.<phase>.dispatch`` span, the classification of a failure
+  (a strict-guard refusal and consumed pools are engine-fatal; anything
+  else retires the one request a prefill launch serves), the rebind of
+  the donated pools, the count of the launch's attention pages. A fact
+  that is true of every launch is written there;
+- ONE fetch (``_fetch``): the single sanctioned device->host copy of a
+  program's output, inside the caller's ``*.fetch`` span, with the model's
+  counters split off the tokens. Decode fetches the launch of the step
+  before (below); a prefill and a verify fetch their own;
+- ONE prefill path (``_prefill``): advance a request's prefill by ``n``
+  tokens from where it stands; if that completes the prompt, fetch the
+  first token and seat the request (``_seat``, which a swap-resume uses
+  too). A whole uncached tail is the one chunk that is final.
+
+``_step`` is then the schedule and nothing else: sweep and admit, seat or
+prefill what was admitted, advance the chunked prefills, fault sites and
+page pressure, decode or verify; the step's accounts follow it
+(``_step_and_account``).
+
 Automatic prefix caching: admission matches the prompt against the paged
 cache's content index in whole pages (kv_cache.py), maps the hit pages
 into the new slot's page-table row by refcount bump, and prefills ONLY the
@@ -30,15 +58,16 @@ the bytes a cold prefill would recompute.
 Chunked prefill (``ServingConfig(chunk_size=N)``): a long prompt no longer
 monopolizes an engine step at its full pad bucket. An admitted request
 enters a PREFILLING state and advances N prompt tokens per step through
-the SAME prefill program — each chunk's queries enter at ``ctx_lens =
-tokens already prefilled``, the exact ragged mechanism the prefix-cache
-tail prefill already rides, with the chunk padded into the existing bucket
-set (the bucket set stays the only source of prefill compiles, whatever
-the chunk size or count). Decode for the running batch proceeds in the
+the SAME prefill path and program — each chunk's queries enter at
+``ctx_lens = tokens already prefilled``, the exact ragged mechanism the
+prefix-cache tail prefill rides, with the chunk padded into the existing
+bucket set (the bucket set stays the only source of prefill compiles,
+whatever the chunk size or count). Decode for the running batch proceeds in the
 same step, so TPOT stays bounded while whales prefill and newcomer TTFT
 stops queueing behind them. Intermediate chunks never fetch their sampled
-token, so the sync-free decode certification is unchanged: one fetch per
-decode step plus one per COMPLETED prefill. Outputs are bit-identical
+token (nor the model's counters behind it: ``serving_moe_*`` leave those
+launches out), so the sync-free decode certification is unchanged: one
+fetch per decode step plus one per COMPLETED prefill. Outputs are bit-identical
 chunked or not — same KV bytes, same last-token logits (the PR 3
 exact-zero ragged masking argument, applied inductively per chunk).
 
@@ -121,8 +150,8 @@ two of them. What it takes:
   in flight and nothing to launch only fetches. A device error of decode
   k surfaces at its fetch in step k+1, with a note naming step k;
 - speculative decoding (``_verify_phase``) replaces plain decode
-  wholesale, keeps its one packed fetch in the same step, and never has
-  a decode in flight.
+  wholesale, fetches its own launch in the same step, and never has a
+  decode in flight.
 
 ``serving_decode_overlapped_total`` over ``serving_decode_steps`` is the
 share of launches made under a decode in flight;
@@ -210,14 +239,18 @@ span                                  extent; attributes
                                       included
 ``serve.admit``                       deadline sweep, ``scheduler.admit``,
                                       restore failures; ``queue_depth``
-``serve.prefill``                     one per prefilled request; ``rid``,
-                                      ``bucket``, ``cached``, ``tail``
-``serve.prefill.upload``              the padded ids and the five device
-                                      operands; ``bytes``
-``serve.prefill.dispatch``            the call of the jitted program
-``serve.prefill.fetch``               the first-token fetch (blocks: the
-                                      device time lands here)
+``serve.prefill``                     one per request prefilled whole;
+                                      ``rid``, ``bucket``, ``cached``,
+                                      ``tail``
 ``serve.chunk_prefill``               the chunk loop; ``chunks``
+``serve.prefill.upload``              inside either, one a launch: the
+                                      padded ids and the five device
+                                      operands; ``rid``, ``bytes``
+``serve.prefill.dispatch``            the call of the jitted program;
+                                      ``rid``
+``serve.prefill.fetch``               the first-token fetch of a launch
+                                      that completed a prompt (blocks: the
+                                      device time lands here); ``rid``
 ``serve.evict``                       fault sites, decode-page pressure,
                                       preemption
 ``serve.decode``                      the decode phase; ``batch`` (the
@@ -234,8 +267,11 @@ span                                  extent; attributes
 ``serve.drain``                       an early fetch + emit of the decode
                                       in flight (``decode.fetch`` and
                                       ``decode.emit`` inside); ``reason``
-``serve.verify``                      the speculative verify phase, one
-                                      span; ``batch``
+``serve.verify``                      the speculative verify phase;
+                                      ``batch``
+``serve.verify.dispatch``             the call of the jitted program
+``serve.verify.fetch``                the packed fetch of that same launch
+                                      (blocks)
 ``serve.account``                     cache stats, gauges, the step
                                       record, watchdogs, SLO controller:
                                       the obs layer's own cost per step
@@ -254,9 +290,11 @@ are named by their CompileGuards (``jit_serve_decode``,
 ``jit_serve_prefill_<bucket>``, ``jit_serve_verify``) and the model's
 regions by ``jax.named_scope`` (``embed``, ``block/attn``, ``block/mlp``,
 ``final_norm``, ``kv_write``, ``sample``). With no profiler session an
-annotation records nothing; with ``enable_tracing=False`` no span object
-is made and each site costs one ``is not None`` check. Zero added device
-syncs either way. Anomaly watchdogs (``enable_watchdogs``, default on)
+annotation records nothing; with ``enable_tracing=False`` the accumulator
+is disabled: the same sites run, each gets the one shared do-nothing
+context and no span object is made (the decision lives behind
+``PhaseAccumulator``, not at the call sites). Zero added device syncs
+either way. Anomaly watchdogs (``enable_watchdogs``, default on)
 evaluate edge-triggered rules over host-resident ints at each step
 boundary — retrace-after-warmup, Pallas fallback, speculative-acceptance
 collapse, eviction thrash, queue stall — each firing a structured Alert
@@ -289,6 +327,7 @@ import dataclasses
 import itertools
 import time
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -298,7 +337,7 @@ from ..analysis import hlocheck
 from ..analysis.tracecheck import (CompileGuard, DonationViolation,
                                    RetraceError, SyncTally, donation_audit)
 from ..core.tensor import Tensor
-from ..obs import (ALERT_RULES, NO_SPAN, JourneyBook, PhaseAccumulator,
+from ..obs import (ALERT_RULES, JourneyBook, PhaseAccumulator,
                    StepRecord, StepTimeline, TenantLedger, TenantSLO, Tracer,
                    Watchdog, WatchdogConfig, build_flight_record,
                    check_tenant_name, chrome_trace, write_chrome_trace)
@@ -446,6 +485,21 @@ def prefill_buckets(max_prompt_len: int) -> list[int]:
     return buckets
 
 
+class _Program(NamedTuple):
+    """One compiled step program, as a launch, an audit and a gauge need
+    it described. ``engine._programs`` holds one a prefill pad bucket, one
+    for decode and, with ``spec=``, one for verify, by ``label``; the
+    operands of a launch come from ``_prefill_args`` / ``_decode_args`` /
+    ``_verify_args``."""
+    phase: str  # "prefill" | "decode" | "verify": its CompileGuard
+    # (engine.guards[phase], launched as engine._<phase>_jit) and its
+    # serve.<phase>.dispatch span
+    label: str  # its audit label: "prefill[<bucket>]", "decode", "verify"
+    rows: int   # what one launch computes: rows x tokens a row, padding
+    tokens: int  # and dead slots too
+    counters: int  # the model's counters behind its tokens (_with_counters)
+
+
 class ServingEngine:
     """Continuous-batching engine over any model with the paged-cache
     contract (text/gpt.py's ``GPTForCausalLM``, text/kimi_k2.py's
@@ -566,6 +620,15 @@ class ServingEngine:
             self._p = self._tp.shard_params(self._p)
         self._clock = clock or time.monotonic
         self._skew = 0.0  # virtual seconds injected by slow_step faults
+        # goodput attribution (obs/attribution.py): the one span mechanism
+        # inside step() — each boundary writes its seconds on the engine
+        # clock and a serve.* TraceAnnotation into the profiler's trace;
+        # clock reads and TraceMe events only, zero device syncs (the
+        # SyncTally certification is pinned unchanged). The engine always
+        # holds one: with tracing off it is disabled and every site is a
+        # no-op behind it
+        self._attr = PhaseAccumulator(self.now if cfg.enable_tracing
+                                      else None)
         # obs layer: request tracer + step timeline run off the engine
         # clock (virtual-clock testable, zero host syncs); None when off —
         # every event site costs one attribute check and nothing else
@@ -582,13 +645,6 @@ class ServingEngine:
             # the per-tenant goodput/badput ledger (obs/tenant.py) —
             # observe-only, fed once per retirement in _trace_retire
             self._tenants = TenantLedger(cfg.tenants)
-            # goodput attribution (obs/attribution.py): the one span
-            # mechanism inside step() — each boundary writes its seconds
-            # on the engine clock and a serve.* TraceAnnotation into the
-            # profiler's trace; clock reads and TraceMe events only, zero
-            # device syncs (the SyncTally certification is pinned
-            # unchanged)
-            self._attr = PhaseAccumulator(self.now)
             # anomaly watchdogs: edge-triggered rules over the step
             # record + host counter totals, evaluated at step boundaries
             self._watchdog = (Watchdog(cfg.watchdog or WatchdogConfig(),
@@ -597,7 +653,6 @@ class ServingEngine:
         else:
             self._tracer = None
             self._timeline = None
-            self._attr = None
             self._watchdog = None
             self._journeys = None
             self._tenants = None
@@ -706,7 +761,7 @@ class ServingEngine:
                                self._last_tok, self._active, self._rids,
                                self._gen))
         # the cache's copy-on-write page copy is a span of the same
-        # mechanism (serve.cow_copy); None with tracing off
+        # mechanism (serve.cow_copy)
         self.cache.spans = self._attr
         self._finished: dict[int, np.ndarray] = {}
         self._retired: dict[int, Request] = {}  # cancelled/expired/failed/shed
@@ -781,6 +836,16 @@ class ServingEngine:
             self.guards["verify"] = self._verify_jit
         else:
             self._verify_jit = None
+        # every compiled program's description, by audit label (buckets
+        # in rising order: _prefill_program takes the first that fits)
+        nc = self._n_counters
+        programs = [_Program("prefill", f"prefill[{n}]", 1, n, nc)
+                    for n in self.prefill_buckets]
+        programs.append(_Program("decode", "decode", cfg.max_batch, 1, nc))
+        if cfg.spec is not None:  # _verify_impl drops the counters
+            programs.append(_Program("verify", "verify", cfg.max_batch,
+                                     cfg.spec.depth + 1, 0))
+        self._programs = {p.label: p for p in programs}
 
     # --------------------------------------------------------- jitted steps
     def _req_key(self, rid, t):
@@ -1029,10 +1094,8 @@ class ServingEngine:
         # serve.add_request opens once the request has its id (a span
         # takes its attributes when it opens): the queueing, the shed
         # and the trace's first stamp; step = the step that runs next
-        att = self._attr
-        with (att.span("add_request", step=self._step_idx, rid=req.rid,
-                       prompt_len=req.prompt_len)
-              if att is not None else NO_SPAN):
+        with self._attr.span("add_request", step=self._step_idx,
+                             rid=req.rid, prompt_len=req.prompt_len):
             try:
                 # validates against pool capacity
                 shed = self.scheduler.add(req)
@@ -1126,6 +1189,17 @@ class ServingEngine:
             self._failed_count += 1
         self._trace_retire(req, state)
 
+    def _fail(self, req: Request, error: BaseException) -> None:
+        """Retire one request FAILED with what failed it, and count it;
+        everything else keeps being served."""
+        self._retire(req, FAILED, error)
+        self.metrics.on_failed()
+
+    def _fail_injected(self, point: str, step_idx: int,
+                       req: Request) -> None:
+        self._fail(req, InjectedFault(
+            f"{point} injected (step {step_idx}, rid {req.rid})"))
+
     def _sweep_deadlines(self) -> None:
         with_deadline = [r for r in self._requests.values()
                          if r.deadline is not None]
@@ -1148,6 +1222,21 @@ class ServingEngine:
         self._gen[slot] = 0
         if self._hist is not None:
             self._hist[slot] = 0
+
+    def _seat(self, req: Request) -> None:
+        """Put a request whose last token the host knows into the decode
+        batch: the five per-slot arrays, from the request itself. A
+        prefill that has just fetched its first token and a swap-resume
+        (its KV came back with ``admit``) seat alike."""
+        slot = req.slot
+        self._ctx[slot] = req.prompt_len + len(req.generated) - 1
+        self._last_tok[slot] = req.generated[-1]
+        self._active[slot] = True
+        self._rids[slot] = req.rid
+        self._gen[slot] = len(req.generated)
+        req.state = RUNNING
+        req.fresh = True  # no decode yet: spared while a seasoned victim is
+        self._hist_sync(req)
 
     def _hist_sync(self, req: Request) -> None:
         """Mirror a request's known tokens (prompt + generated) into its
@@ -1195,94 +1284,6 @@ class ServingEngine:
         self.metrics.on_preempt()
         if self.config.preemption_mode == "swap":
             self.metrics.on_swap_out()
-
-    def _prefill_chunk(self, req: Request) -> int | None:
-        """Advance one PREFILLING request by one chunk through the SAME
-        jitted prefill step: queries enter at ``ctx_lens =
-        req.prefilled_tokens`` (exactly the ragged contract the
-        prefix-cache tail prefill rides), the chunk is padded into the
-        existing bucket set, so the bucket set stays the only source of
-        prefill compiles. Intermediate chunks never touch the host — the
-        step's sampled token is discarded undelivered, keeping the
-        dispatch pipeline async and the SyncTally certification formula
-        (one fetch per decode step + one per COMPLETED prefill)
-        unchanged. Returns the first generated token when this chunk
-        completed the prefill, else None; a request-local failure retires
-        the request FAILED here (engine-fatal failures re-raise)."""
-        cfg = self.config
-        start = req.prefilled_tokens
-        n = min(cfg.chunk_size, req.prompt_len - start)
-        final = start + n >= req.prompt_len
-        bucket = next(b for b in self.prefill_buckets if b >= n)
-        padded = np.full(bucket, cfg.pad_token_id, np.int32)
-        padded[:n] = req.prompt[start:start + n]
-        tr = self._tracer
-        args = (self._p, self.cache.pools, jnp.asarray(padded),
-                jnp.asarray(n, jnp.int32), jnp.asarray(start, jnp.int32),
-                jnp.asarray(self.cache.page_table[req.slot]),
-                jnp.asarray(req.rid, jnp.int32))
-        if cfg.debug_checks:
-            self._audit_step(self._prefill_jit, args, f"prefill[{bucket}]")
-        try:
-            pools, tok = self._prefill_jit(*args)
-        except Exception as e:  # noqa: BLE001 — isolate the request
-            if isinstance(e, (RetraceError, DonationViolation)):
-                # a strict-guard refusal is an AUDIT failure, not a
-                # request fault — surface it
-                raise
-            if any(arr.is_deleted() for pl in self.cache.pools
-                   for arr in pl.values()):
-                # donation consumed the pools before the failure:
-                # every sequence's KV is gone — engine-fatal
-                raise
-            self._retire(req, FAILED, e)
-            self.metrics.on_failed()
-            return None
-        self.cache.pools = pools
-        self._count_attention_pages(start, bucket, n)
-        req.prefilled_tokens = start + n
-        self.metrics.on_prefill_chunk(n)
-        # stamped AFTER the dispatch succeeded, so the trace's chunk
-        # count, the Chrome-export chunk spans, and the
-        # serving_prefill_chunks_total counter can never disagree about
-        # a chunk whose jit call failed
-        if tr is not None:
-            tr.event(req.rid, "prefill_chunk", start=start, tokens=n,
-                     bucket=bucket, final=final)
-        if not final:
-            return None
-        # the chunked prefill's ONE sanctioned device->host sync: the
-        # final chunk's first-token fetch (the same np.asarray site
-        # PT005 polices on the unchunked path)
-        tok = int(np.asarray(tok))  # lint: disable=PT005
-        req.generated.append(tok)
-        req.tokens_emitted += 1
-        slot = req.slot
-        self._ctx[slot] = req.prompt_len
-        self._last_tok[slot] = tok
-        self._active[slot] = True
-        self._rids[slot] = req.rid
-        self._gen[slot] = 1
-        req.state = RUNNING
-        req.fresh = True
-        self._hist_sync(req)
-        if tr is not None:
-            # accounting reads prefix_hit_tokens, not cached_tokens: a
-            # mid-prefill swap restore zeroes the latter, but this
-            # prefill attempt's cache hit still served those tokens
-            tr.event(req.rid, "prefill_end",
-                     tokens=req.prompt_len - req.prefix_hit_tokens)
-            tr.event(req.rid, "first_token")
-        # every full prompt page is now resident: index it for reuse
-        self.cache.register_prefix(slot, req.prompt)
-        self.metrics.on_prefill(0)  # chunk tokens were counted per chunk
-        if cfg.enable_prefix_caching:
-            if req.prefix_hit_tokens > 0:
-                self.metrics.on_prefix_hit(req.prefix_hit_tokens)
-            else:
-                self.metrics.on_prefix_miss()
-        self.metrics.on_tokens(1)
-        return tok
 
     def _maybe_finish(self, req: Request, tok: int) -> bool:
         eos = self.config.eos_token_id
@@ -1333,8 +1334,6 @@ class ServingEngine:
         With tracing on, the whole call is the ``serve.step`` span of the
         profiler's trace (module docstring, "Goodput attribution")."""
         att = self._attr
-        if att is None:
-            return self._step_and_account()
         att.enter_step(self._step_idx)
         try:
             return self._step_and_account()
@@ -1342,16 +1341,23 @@ class ServingEngine:
             att.exit_step()  # closes serve.account, then serve.step
 
     def _step_and_account(self) -> list[int]:
+        """The schedule (``_step``), then the step's accounts: the state
+        roll-up and the step's record (``_close_record``), the debug
+        sweep, the timeline, the watchdogs, the flight recorder's edge
+        and the SLO controller."""
+        debug = self.config.debug_checks
+        syncs = None
         try:
-            if self.config.debug_checks:
+            if debug:
                 with SyncTally() as tally:
-                    finished = self._step()
+                    finished, counts = self._step()
                 self._host_syncs += tally.count
-                self.cache.check_invariants()
                 syncs = tally.count
             else:
-                finished = self._step()
-                syncs = None
+                finished, counts = self._step()
+            finished = self._close_record(finished, counts)
+            if debug:
+                self.cache.check_invariants()
         except Exception as e:
             # engine-fatal: flush the half-built step into the timeline
             # ring and dump the flight record BEFORE re-raising — the
@@ -1359,13 +1365,13 @@ class ServingEngine:
             self._on_fatal(e)
             raise
         # from here to the end of step() is the rest of serve.account
-        # (opened in _step): in the profiler's trace only, the step's
-        # record is closed
+        # (opened in _close_record): in the profiler's trace only, the
+        # step's record is closed
         retraces = sum(g.retraces for g in
                        (*self.guards.values(), *self.cache.guards.values()))
         # the counters are pre-seeded at 0, so the non-debug hot loop only
         # pays the two monitor stat_sets when something actually changed
-        if self.config.debug_checks or retraces != self._retraces_emitted:
+        if debug or retraces != self._retraces_emitted:
             self.metrics.on_analysis(retraces=retraces,
                                      host_syncs=self._host_syncs)
             self._retraces_emitted = retraces
@@ -1404,187 +1410,14 @@ class ServingEngine:
                 self.metrics.on_chunk_limit(new, throttled=new < old)
         return finished
 
-    def _step(self) -> list[int]:
-        # the ONLY injector read of the step (pinned by a test): the
-        # uninstalled path costs one attribute lookup and None-checks
-        inj = self._fault_injector
-        step_idx = self._step_idx
-        self._now_step = step_idx  # the restore_fail probe reads this
-        self._step_idx += 1
-        if inj is not None:
-            slow = inj.hit("slow_step", step=step_idx)
-            if slow is not None:
-                self._skew += slow.delay_s
-
-        # goodput attribution: every boundary below is ONE site that
-        # writes both records (obs/attribution.py) — a serve.* span in
-        # the profiler's trace and seconds on the engine clock; the
-        # phases' seconds sum EXACTLY to the step's wall time. None with
-        # tracing off: one check per site, no span object.
+    def _close_record(self, finished: list, counts: dict) -> list[int]:
+        """After the schedule: open ``serve.account`` (the obs layer's
+        own cost per step; it outlives the record and ends with
+        ``step()``), roll the cache's and the scheduler's state up into
+        the gauges, and close the step's record into ``_step_stats`` for
+        ``_step_and_account`` to append."""
         att = self._attr
-        preempt0 = self.scheduler.preemption_count
-        n_prefills = n_active = 0
-        finished_now = []
-        with (att.span("admit", queue_depth=self.scheduler.queue_depth)
-              if att is not None else NO_SPAN):
-            self._sweep_deadlines()
-            # the step's record opens here (after the sweep, as it always
-            # has: a deadline is read off the clock before the record is)
-            t_start = att.begin() if att is not None else 0.0
-            # a paused engine (run(budget_s=) drain) admits no NEWCOMERS,
-            # but still resumes preemption victims — they are in-flight
-            # work. Under SLO degradation, warm prefix-cache waiters jump
-            # cold ones (their uncached tail barely touches the throttled
-            # chunk budget).
-            admitted = self.scheduler.admit(
-                resume_only=self.admit_paused,
-                prefer_cached=self._slo is not None and self._slo.degraded)
-            # a failed host-tier restore (restore_fail injection or a real
-            # scatter error) aborted that request's admission cleanly —
-            # the stale tier entries are dropped, the pool state is the
-            # pre-admit state: retire it FAILED and keep serving everyone
-            # else
-            for req, err in self.scheduler.pop_restore_failures():
-                self._retire(req, FAILED, err)
-                self.metrics.on_failed()
-        for req in admitted:
-            if req.generated:  # swap-resume: KV restored by admit(); there
-                slot = req.slot   # is no prefill here for prefill_fail to hit
-                req.resumed_from_swap = False
-                self._ctx[slot] = req.prompt_len + len(req.generated) - 1
-                self._last_tok[slot] = req.generated[-1]
-                self._active[slot] = True
-                self._rids[slot] = req.rid
-                self._gen[slot] = len(req.generated)
-                req.fresh = True
-                self._hist_sync(req)
-                self.metrics.on_swap_in()
-                tr = self._tracer
-                if tr is not None:
-                    tr.event(req.rid, "swap_in", tokens=len(req.generated))
-                    tr.event(req.rid, "resumed", tokens=len(req.generated))
-                if att is not None:
-                    att.mark("swap")  # seconds only: a few host writes
-                continue
-            if inj is not None and \
-                    inj.hit("prefill_fail", step=step_idx, rid=req.rid):
-                # consulted before the jitted prefill touches the pools:
-                # undoing the admission IS the pre-step state, minus req
-                self._retire(req, FAILED, InjectedFault(
-                    f"prefill_fail injected (step {step_idx}, "
-                    f"rid {req.rid})"))
-                self.metrics.on_failed()
-                if att is not None:
-                    att.mark("admit")
-                continue
-            if self.config.chunk_size:
-                # chunked prefill: hold the slot in PREFILLING and let the
-                # chunk phase below stream the prompt, chunk_size tokens
-                # per step. fresh=True spares the in-flight prefill from
-                # preemption while any decoded victim exists.
-                req.state = PREFILLING
-                req.fresh = True
-                tr = self._tracer
-                if req.resumed_from_swap:
-                    # a mid-prefill swap victim: its restored pages hold
-                    # prefilled_tokens of KV — chunking continues there,
-                    # no second prefill_start (the trace shows the swap)
-                    req.resumed_from_swap = False
-                    self.metrics.on_swap_in()
-                    if tr is not None:
-                        tr.event(req.rid, "swap_in",
-                                 tokens=req.prefilled_tokens)
-                        tr.event(req.rid, "resumed",
-                                 tokens=req.prefilled_tokens)
-                else:
-                    # cold or recompute-readmitted: start (over) from the
-                    # prefix-cache hit the admission just mapped
-                    req.prefilled_tokens = req.cached_tokens
-                    req.prefix_hit_tokens = req.cached_tokens
-                    if tr is not None:
-                        tr.event(req.rid, "prefill_start",
-                                 tokens=req.prompt_len - req.prefilled_tokens,
-                                 cached=req.cached_tokens, chunked=True)
-                if att is not None:
-                    att.mark("admit")  # PREFILLING handoff is admission
-                continue
-            # prefix-cache hit: only the uncached tail is prefilled,
-            # padded to the smallest bucket that holds it. This
-            # iteration's interval is this request's prefill (a failed
-            # attempt's time too).
-            cached = req.cached_tokens
-            tail = req.prompt[cached:]
-            bucket = next(b for b in self.prefill_buckets if b >= len(tail))
-            with (att.span("prefill", rid=req.rid, bucket=bucket,
-                           cached=cached, tail=len(tail))
-                  if att is not None else NO_SPAN):
-                tok = self._prefill_request(req, cached, tail, bucket)
-                if tok is not None:
-                    n_prefills += 1
-                    if self._maybe_finish(req, tok):
-                        finished_now.append(req.rid)
-
-        # ---- chunked prefill phase: every PREFILLING request advances one
-        # chunk through the SAME prefill program, oldest admitted first,
-        # capped at the SLO controller's chunks-per-step limit. Decode for
-        # the running batch proceeds below in this same step — a whale
-        # prompt can no longer monopolize an iteration.
-        n_chunks = 0
-        if self.config.chunk_size:
-            limit = (self._slo.chunk_limit if self._slo is not None
-                     else self.config.max_batch)
-            prefilling = sorted(
-                (r for r in self.scheduler.running.values()
-                 if r.state == PREFILLING),
-                key=lambda r: r.admit_seq)
-            if prefilling:
-                with (att.span("chunk_prefill",
-                               chunks=len(prefilling[:limit]))
-                      if att is not None else NO_SPAN):
-                    for req in prefilling[:limit]:
-                        if inj is not None and inj.hit(
-                                "chunk_fail", step=step_idx, rid=req.rid):
-                            # before the chunk touches the pools: the
-                            # partial prefill's pages drain with the
-                            # retirement, survivors keep prefilling /
-                            # decoding this very step
-                            self._retire(req, FAILED, InjectedFault(
-                                f"chunk_fail injected (step {step_idx}, "
-                                f"rid {req.rid})"))
-                            self.metrics.on_failed()
-                            continue
-                        tok = self._prefill_chunk(req)
-                        n_chunks += 1
-                        if tok is not None:  # final chunk: first token
-                            n_prefills += 1
-                            if self._maybe_finish(req, tok):
-                                finished_now.append(req.rid)
-
-        # injected faults + decode-page pressure: preemption, swap-out and
-        # eviction sweeps all happen in this window
-        with (att.span("evict") if att is not None else NO_SPAN):
-            if inj is not None:
-                self._inject_decode_faults(inj, step_idx)
-            for req, slot in self.scheduler.ensure_decode_pages():
-                self._preempt_one(req, slot)
-
-        n_accepted = 0
-        if self._active.any() or self._inflight is not None:
-            if self._spec is not None:
-                # speculative decoding: the verify step replaces plain
-                # decode wholesale — one batched K+1-token ragged pass,
-                # one packed fetch, 1..K+1 tokens emitted per slot (one
-                # span, no parts: no benchmark cell runs it yet)
-                with (att.span("verify", batch=int(self._active.sum()))
-                      if att is not None else NO_SPAN):
-                    n_active, n_accepted = self._verify_phase(finished_now)
-            else:
-                n_active = self._decode_phase(finished_now)
-
-        # serve.account: the obs layer's own cost per step. It outlives
-        # the record (closed a few lines down) and ends with step().
-        if att is not None:
-            att.account()
+        att.account()
         cs = self.cache.stats()
         self.metrics.on_state(
             queue_depth=self.scheduler.queue_depth,
@@ -1601,107 +1434,266 @@ class ServingEngine:
             host_tier_spills=cs["host_tier_spills"],
             host_tier_restores=cs["host_tier_restores"])
         if self._drained_finished:
-            finished_now = self._take_drained() + finished_now
+            finished = self._take_drained() + finished
         if self._timeline is not None:
-            # close the attribution: the residual (state roll-up, this
+            # close the attribution: the residual (the roll-up above, this
             # very bookkeeping) lands in "other", and the phase dict sums
             # to t_end - t_start exactly by the mark construction
             t_end, phase_s = att.finish()
-            self._step_stats = {
-                "step": step_idx, "t_start": t_start, "t_end": t_end,
-                "admitted": len(admitted), "prefills": n_prefills,
-                "chunks": n_chunks, "batch": n_active,
-                "accepted": n_accepted,
-                "finished": len(finished_now),
-                "preemptions": self.scheduler.preemption_count - preempt0,
-                "queue_depth": self.scheduler.queue_depth,
-                "pages_in_use": cs["pages_in_use"],
-                "phase_s": phase_s, "span_s": att.span_s}
-        return finished_now
+            self._step_stats = dict(
+                counts, t_start=att.t0, t_end=t_end,
+                finished=len(finished),
+                queue_depth=self.scheduler.queue_depth,
+                pages_in_use=cs["pages_in_use"],
+                phase_s=phase_s, span_s=att.span_s)
+        return finished
 
-    def _prefill_request(self, req: Request, cached: int, tail, bucket: int
-                         ) -> int | None:
-        """One admitted request's whole uncached tail through the prefill
-        program of its pad bucket, inside the caller's ``serve.prefill``
-        span: upload, dispatch, the sanctioned first-token fetch (where
-        the device time lands), then the slot's bookkeeping. Returns the
-        first generated token; a request-local failure retires the
-        request FAILED and returns None (engine-fatal failures
-        re-raise)."""
-        att, tr = self._attr, self._tracer
-        page_row = self.cache.page_table[req.slot]
-        with (att.span("prefill.upload", rid=req.rid,
-                       bytes=4 * bucket + page_row.nbytes + 12)
-              if att is not None else NO_SPAN):
-            padded = np.full(bucket, self.config.pad_token_id, np.int32)
-            padded[:len(tail)] = tail
-            args = (self._p, self.cache.pools, jnp.asarray(padded),
-                    jnp.asarray(len(tail), jnp.int32),
-                    jnp.asarray(cached, jnp.int32),
-                    jnp.asarray(page_row),
-                    jnp.asarray(req.rid, jnp.int32))
+    def _step(self) -> tuple[list[int], dict]:
+        """The schedule of one step: sweep and admit; seat or prefill
+        what was admitted; advance the chunked prefills; fault sites and
+        page pressure; decode or verify. Returns (the requests whose
+        finish it saw, the step's counts for its record)."""
+        # the ONLY injector read of the step (pinned by a test): the
+        # uninstalled path costs one attribute lookup and None-checks
+        inj = self._fault_injector
+        step_idx = self._step_idx
+        self._now_step = step_idx  # the restore_fail probe reads this
+        self._step_idx += 1
+        if inj is not None:
+            slow = inj.hit("slow_step", step=step_idx)
+            if slow is not None:
+                self._skew += slow.delay_s
+
+        # goodput attribution: every boundary below is ONE site that
+        # writes both records (obs/attribution.py) — a serve.* span in
+        # the profiler's trace and seconds on the engine clock; the
+        # phases' seconds sum EXACTLY to the step's wall time
+        att = self._attr
+        preempt0 = self.scheduler.preemption_count
+        finished_now = []
+        with att.span("admit", queue_depth=self.scheduler.queue_depth):
+            self._sweep_deadlines()
+            # the step's record opens here (after the sweep, as it always
+            # has: a deadline is read off the clock before the record is)
+            att.begin()
+            # a paused engine (run(budget_s=) drain) admits no NEWCOMERS,
+            # but still resumes preemption victims — they are in-flight
+            # work. Under SLO degradation, warm prefix-cache waiters jump
+            # cold ones (their uncached tail barely touches the throttled
+            # chunk budget).
+            admitted = self.scheduler.admit(
+                resume_only=self.admit_paused,
+                prefer_cached=self._slo is not None and self._slo.degraded)
+            # a failed host-tier restore (restore_fail injection or a real
+            # scatter error) aborted that request's admission cleanly —
+            # the stale tier entries are dropped, the pool state is the
+            # pre-admit state: retire it FAILED and keep serving everyone
+            # else
+            for req, err in self.scheduler.pop_restore_failures():
+                self._fail(req, err)
+        n_prefills = 0
+        for req in admitted:
+            n_prefills += self._seat_or_prefill(req, inj, step_idx,
+                                                finished_now)
+        n_chunks, done = self._advance_chunks(inj, step_idx, finished_now)
+        n_prefills += done
+
+        # injected faults + decode-page pressure: preemption, swap-out and
+        # eviction sweeps all happen in this window
+        with att.span("evict"):
+            if inj is not None:
+                self._inject_decode_faults(inj, step_idx)
+            for req, slot in self.scheduler.ensure_decode_pages():
+                self._preempt_one(req, slot)
+
+        n_active = n_accepted = 0
+        if self._active.any() or self._inflight is not None:
+            if self._spec is not None:
+                # speculative decoding: the verify step replaces plain
+                # decode wholesale — one batched K+1-token ragged pass,
+                # one packed fetch, 1..K+1 tokens emitted per slot
+                with att.span("verify", batch=int(self._active.sum())):
+                    n_active, n_accepted = self._verify_phase(finished_now)
+            else:
+                n_active = self._decode_phase(finished_now)
+        return finished_now, {
+            "step": step_idx, "admitted": len(admitted),
+            "prefills": n_prefills, "chunks": n_chunks, "batch": n_active,
+            "accepted": n_accepted,
+            "preemptions": self.scheduler.preemption_count - preempt0}
+
+    def _seat_or_prefill(self, req: Request, inj, step_idx: int,
+                         finished_now: list) -> bool:
+        """What an admitted request does in the step that admitted it. A
+        swap-resume (``admit`` restored its KV) takes its seat. Anything
+        else starts its prefill from the prefix-cache hit that the
+        admission mapped: whole, here, inside its ``serve.prefill`` span;
+        or, under ``chunk_size``, held PREFILLING for ``_advance_chunks``
+        to stream. True when a prefill completed."""
+        att = self._attr
+        if req.generated:  # swap-resume: there is no prefill here for
+            self._seat(req)                     # prefill_fail to hit
+            self._swapped_in(req, len(req.generated))
+            att.mark("swap")  # seconds only: a few host writes
+            return False
+        if inj is not None and \
+                inj.hit("prefill_fail", step=step_idx, rid=req.rid):
+            # consulted before the jitted prefill touches the pools:
+            # undoing the admission IS the pre-step state, minus req
+            self._fail_injected("prefill_fail", step_idx, req)
+            att.mark("admit")
+            return False
+        chunked = bool(self.config.chunk_size)
+        if req.resumed_from_swap:
+            # a mid-prefill swap victim: its restored pages hold
+            # prefilled_tokens of KV — chunking continues there, no second
+            # prefill_start (the trace shows the swap)
+            self._swapped_in(req, req.prefilled_tokens)
+        else:
+            # cold or recompute-readmitted: start (over) from the
+            # prefix-cache hit the admission just mapped
+            req.prefilled_tokens = req.prefix_hit_tokens = req.cached_tokens
+            # a chunked prefill's start is its admission; a whole one's
+            # is stamped at its launch (_prefill)
+            if chunked and (tr := self._tracer) is not None:
+                tr.event(req.rid, "prefill_start",
+                         tokens=req.prompt_len - req.prefilled_tokens,
+                         cached=req.cached_tokens, chunked=True)
+        if chunked:
+            # hold the slot in PREFILLING and let the chunk phase stream
+            # the prompt, chunk_size tokens per step. fresh=True spares
+            # the in-flight prefill from preemption while any decoded
+            # victim exists.
+            req.state = PREFILLING
+            req.fresh = True
+            att.mark("admit")  # PREFILLING handoff is admission
+            return False
+        # only the uncached tail is prefilled, padded to the smallest
+        # bucket that holds it. This iteration's interval is this
+        # request's prefill (a failed attempt's time too).
+        cached = req.prefilled_tokens
+        tail = req.prompt_len - cached
+        with att.span("prefill", rid=req.rid, cached=cached, tail=tail,
+                      bucket=self._prefill_program(tail).tokens):
+            return self._prefill(req, tail, finished_now)
+
+    def _swapped_in(self, req: Request, tokens: int) -> None:
+        """Account a swap-resume: the victim is back with ``tokens`` of
+        its own (generated, or prefilled so far) intact."""
+        req.resumed_from_swap = False
+        self.metrics.on_swap_in()
+        tr = self._tracer
         if tr is not None:
-            tr.event(req.rid, "prefill_start", tokens=len(tail),
-                     cached=cached, bucket=bucket)
-        if self.config.debug_checks:
-            self._audit_step(self._prefill_jit, args, f"prefill[{bucket}]")
-        try:
-            with (att.span("prefill.dispatch", rid=req.rid)
-                  if att is not None else NO_SPAN):
-                pools, tok = self._prefill_jit(*args)
-        except Exception as e:  # noqa: BLE001 — isolate the request
-            if isinstance(e, (RetraceError, DonationViolation)):
-                # a strict-guard refusal is an AUDIT failure — the
-                # contract debug_checks exists to surface — not a
-                # request-level fault to retire and serve past
-                raise
-            if any(arr.is_deleted() for pl in self.cache.pools
-                   for arr in pl.values()):
-                # the failure landed after donation consumed the pools:
-                # every sequence's KV is gone, so "retire one request and
-                # keep serving" would hand the rest deleted buffers —
-                # engine-fatal, not isolable
-                raise
-            self._retire(req, FAILED, e)
-            self.metrics.on_failed()
-            return None
-        self.cache.pools = pools
-        self._count_attention_pages(cached, bucket, len(tail))
-        # the prefill's sanctioned device->host sync: its first-token
-        # fetch, routed through the same np.asarray site PT005 polices
-        # (a bare int() coercion would sync invisibly to the linter)
-        with (att.span("prefill.fetch", rid=req.rid)
-              if att is not None else NO_SPAN):
-            tok = np.asarray(tok)  # lint: disable=PT005
-        if self._n_counters:
-            self.metrics.on_model_counters(self._cache_spec.counters,
-                                           tok[1:])
-            tok = tok[0]
-        tok = int(tok)
+            tr.event(req.rid, "swap_in", tokens=tokens)
+            tr.event(req.rid, "resumed", tokens=tokens)
+
+    def _advance_chunks(self, inj, step_idx: int,
+                        finished_now: list) -> tuple[int, int]:
+        """The chunked prefill phase: every PREFILLING request advances
+        one chunk through the prefill path, oldest admitted first, capped
+        at the SLO controller's chunks-per-step limit. Decode for the
+        running batch proceeds in this same step — a whale prompt cannot
+        monopolize an iteration. Returns (chunks launched, prefills
+        completed)."""
+        cfg = self.config
+        if not cfg.chunk_size:
+            return 0, 0
+        limit = (self._slo.chunk_limit if self._slo is not None
+                 else cfg.max_batch)
+        prefilling = sorted(
+            (r for r in self.scheduler.running.values()
+             if r.state == PREFILLING),
+            key=lambda r: r.admit_seq)[:limit]
+        if not prefilling:
+            return 0, 0
+        n_chunks = done = 0
+        with self._attr.span("chunk_prefill", chunks=len(prefilling)):
+            for req in prefilling:
+                if inj is not None and inj.hit(
+                        "chunk_fail", step=step_idx, rid=req.rid):
+                    # before the chunk touches the pools: the partial
+                    # prefill's pages drain with the retirement,
+                    # survivors keep prefilling / decoding this very step
+                    self._fail_injected("chunk_fail", step_idx, req)
+                    continue
+                n_chunks += 1
+                done += self._prefill(
+                    req, min(cfg.chunk_size,
+                             req.prompt_len - req.prefilled_tokens),
+                    finished_now)
+        return n_chunks, done
+
+    def _prefill(self, req: Request, n: int, finished_now: list) -> bool:
+        """THE prefill path: advance this request's prefill by ``n``
+        prompt tokens from ``req.prefilled_tokens`` through the prefill
+        program of the smallest pad bucket that holds them — the queries
+        enter at ``ctx_lens = tokens already resident``, the ragged
+        contract a prefix-cache tail and a chunk share — and, if that
+        completes the prompt, fetch the first token and seat the request.
+        A whole tail (``chunk_size == 0``) is the one chunk that is final.
+        A chunk that is not final never touches the host: its sampled
+        token (and its model counters) stay on the device, unfetched, so
+        the sync-free certification holds (one fetch per decode step +
+        one per COMPLETED prefill). A failure of the request's own
+        retires it FAILED (engine-fatal ones raise). True when the
+        prefill completed."""
+        att, tr = self._attr, self._tracer
+        chunked = bool(self.config.chunk_size)
+        start = req.prefilled_tokens
+        final = start + n >= req.prompt_len
+        prog = self._prefill_program(n)
+        bucket = prog.tokens
+        with att.span("prefill.upload", rid=req.rid, bytes=4 * bucket + 12
+                      + self.cache.page_table[req.slot].nbytes):
+            args = self._prefill_args(prog, req.slot, req.rid,
+                                      req.prompt[start:start + n], start)
+        if tr is not None and not chunked:  # a chunked one: at admission
+            tr.event(req.rid, "prefill_start", tokens=n, cached=start,
+                     bucket=bucket)
+        out = self._launch(prog, args, start, tokens=n, isolate=req,
+                           rid=req.rid)
+        if out is None:
+            return False
+        req.prefilled_tokens = start + n
+        if chunked:
+            self.metrics.on_prefill_chunk(n)
+            # stamped AFTER the dispatch succeeded, so the trace's chunk
+            # count, the Chrome-export chunk spans, and the
+            # serving_prefill_chunks_total counter can never disagree
+            # about a chunk whose jit call failed
+            if tr is not None:
+                tr.event(req.rid, "prefill_chunk", start=start, tokens=n,
+                         bucket=bucket, final=final)
+        if not final:
+            return False
+        # a completed prefill's ONE sanctioned device->host sync: its
+        # first-token fetch (where the device time lands)
+        with att.span("prefill.fetch", rid=req.rid):
+            tok = int(self._fetch(prog, out).flat[0])
         req.generated.append(tok)
         req.tokens_emitted += 1
-        self._ctx[req.slot] = req.prompt_len
-        self._last_tok[req.slot] = tok
-        self._active[req.slot] = True
-        self._rids[req.slot] = req.rid
-        self._gen[req.slot] = 1
-        req.fresh = True
-        self._hist_sync(req)
+        self._seat(req)
         if tr is not None:
             # prefill_end IS first-token time: the prefill pass samples
-            # the request's first output token from its last logit
-            tr.event(req.rid, "prefill_end", tokens=len(tail))
+            # the request's first output token from its last logit.
+            # Accounting reads prefix_hit_tokens, not cached_tokens: a
+            # mid-prefill swap restore zeroes the latter, but this
+            # prefill attempt's cache hit still served those tokens
+            tr.event(req.rid, "prefill_end",
+                     tokens=req.prompt_len - req.prefix_hit_tokens)
             tr.event(req.rid, "first_token")
         # every full prompt page is now resident: index it for reuse
         self.cache.register_prefix(req.slot, req.prompt)
-        self.metrics.on_prefill(len(tail))
+        self.metrics.on_prefill(0 if chunked else n)  # chunks counted theirs
         if self.config.enable_prefix_caching:
-            if cached > 0:
-                self.metrics.on_prefix_hit(cached)
+            if req.prefix_hit_tokens > 0:
+                self.metrics.on_prefix_hit(req.prefix_hit_tokens)
             else:
                 self.metrics.on_prefix_miss()
         self.metrics.on_tokens(1)
-        return tok
+        if self._maybe_finish(req, tok):
+            finished_now.append(req.rid)
+        return True
 
     def _inject_decode_faults(self, inj, step_idx: int) -> None:
         """The armed injector's step-boundary consults before the decode
@@ -1716,12 +1708,8 @@ class ServingEngine:
                 # with every token it has (the drain may even finish it),
                 # the rest of the batch decodes normally this very step
                 self._drain("fault")
-                if req.state != RUNNING:
-                    continue
-                self._retire(req, FAILED, InjectedFault(
-                    f"decode_fail injected (step {step_idx}, "
-                    f"rid {req.rid})"))
-                self.metrics.on_failed()
+                if req.state == RUNNING:
+                    self._fail_injected("decode_fail", step_idx, req)
                 continue
             if self._spec is not None and \
                     inj.hit("verify_fail", step=step_idx, rid=req.rid):
@@ -1731,10 +1719,7 @@ class ServingEngine:
                 # normal evict path (the draft proposer holds no
                 # per-request state to clean); survivors verify this
                 # very step
-                self._retire(req, FAILED, InjectedFault(
-                    f"verify_fail injected (step {step_idx}, "
-                    f"rid {req.rid})"))
-                self.metrics.on_failed()
+                self._fail_injected("verify_fail", step_idx, req)
         if self.scheduler.running and \
                 inj.hit("pool_exhausted", step=step_idx):
             self._drain("fault")
@@ -1765,6 +1750,28 @@ class ServingEngine:
             live = live[live_rows]
         self.metrics.on_attention_pages(int(live.sum()), int(fn(ctx).sum()))
 
+    # -------------------------------------- a step program: operands, launch
+    def _prefill_program(self, n: int) -> _Program:
+        """The prefill program of the smallest pad bucket that holds ``n``
+        tokens."""
+        return next(p for p in self._programs.values()
+                    if p.phase == "prefill" and p.tokens >= n)
+
+    def _prefill_args(self, prog: _Program, slot: int, rid: int, ids,
+                      start: int) -> tuple:
+        """The prefill program's operands: ``ids`` (the prompt tokens this
+        launch computes, for the request in ``slot``) right-padded to the
+        program's bucket, their count, the tokens already resident
+        (``start``: the queries enter there), the slot's page-table row
+        and the request id (its PRNG stream)."""
+        padded = np.full(prog.tokens, self.config.pad_token_id, np.int32)
+        padded[:len(ids)] = ids
+        return (self._p, self.cache.pools, jnp.asarray(padded),
+                jnp.asarray(len(ids), jnp.int32),
+                jnp.asarray(start, jnp.int32),
+                jnp.asarray(self.cache.page_table[slot]),
+                jnp.asarray(rid, jnp.int32))
+
     def _decode_args(self, active=None, override=None) -> tuple:
         """The decode program's operands as a launch uploads them: the
         whole page table and the five per-slot vectors from the host, and
@@ -1780,6 +1787,71 @@ class ServingEngine:
                 up(self._last_tok if override is None else override),
                 up(self._active if active is None else active),
                 up(self._rids), up(self._gen))
+
+    def _verify_args(self) -> tuple:
+        """The verify program's operands: the page table, the five
+        per-slot vectors, the proposers' token history and, for the draft
+        proposer, its parameters. No copies: the verify phase fetches its
+        own launch before the host writes any of them."""
+        args = (self._p, self.cache.pools,
+                jnp.asarray(self.cache.page_table),
+                jnp.asarray(self._ctx), jnp.asarray(self._last_tok),
+                jnp.asarray(self._active), jnp.asarray(self._rids),
+                jnp.asarray(self._gen), jnp.asarray(self._spec_hist()))
+        if self._spec.method == "draft":
+            args += (self._draft_p,)
+        return args
+
+    def _launch(self, prog: _Program, args: tuple, ctx, tokens=None,
+                live_rows=None, isolate: Request | None = None, **attrs):
+        """THE launch of a compiled step program; what is true of every
+        launch is written here. Audit it under ``debug_checks``, dispatch
+        it inside its ``serve.<phase>.dispatch`` span (``attrs``), rebind
+        the donated pools and count the launch's attention pages from the
+        ``ctx`` (ctx_lens) it uploaded (``tokens``, ``live_rows``: as
+        ``_count_attention_pages`` takes them). Returns the program's
+        output, still on the device. A failed dispatch raises — but for
+        the launch of one request (``isolate``) whose failure is its own:
+        that request retires FAILED, None is returned and the rest keep
+        being served."""
+        # read at the launch: a test, and the benchmark's correctness
+        # check, put a callable of their own in the guard's place
+        guard = getattr(self, f"_{prog.phase}_jit")
+        if self.config.debug_checks:
+            self._audit_step(prog, guard, args)
+        try:
+            with self._attr.span(prog.phase + ".dispatch", **attrs):
+                pools, out = guard(*args)
+        except Exception as e:  # noqa: BLE001 — isolate the request
+            # a strict-guard refusal is an AUDIT failure — the contract
+            # debug_checks exists to surface — not a request's fault; and
+            # once donation has consumed the pools every sequence's KV is
+            # gone, so "retire one request and keep serving" would hand
+            # the rest deleted buffers: both engine-fatal
+            if isolate is None \
+                    or isinstance(e, (RetraceError, DonationViolation)) \
+                    or any(arr.is_deleted() for pl in self.cache.pools
+                           for arr in pl.values()):
+                raise
+            self._fail(isolate, e)
+            return None
+        self.cache.pools = pools
+        self._count_attention_pages(ctx, prog.tokens, tokens, live_rows)
+        return out
+
+    def _fetch(self, prog: _Program, out) -> np.ndarray:
+        """THE device->host copy of a step program's output, inside the
+        caller's ``*.fetch`` span: the one sanctioned sync (PT005 polices
+        this function; a bare ``int()`` coercion would sync invisibly to
+        the linter). Blocks for what is left of the launch. What the model
+        counted rides behind the tokens in the same array: split off here
+        and fed to the metrics. Returns the launch's tokens."""
+        out = np.asarray(out)  # lint: disable=PT005
+        if prog.counters:
+            self.metrics.on_model_counters(self._cache_spec.counters,
+                                           out[-prog.counters:])
+            out = out[:-prog.counters]
+        return out
 
     def _decode_phase(self, finished_now: list) -> int:
         """Launch one decode step for the whole batch, THEN fetch and emit
@@ -1806,22 +1878,15 @@ class ServingEngine:
             if req.tokens_in_flight:
                 override[slot] = -1  # its last token is in _prev_toks
             launched.append((int(slot), req))
-        with (att.span("decode", batch=len(launched))
-              if att is not None else NO_SPAN):
+        with att.span("decode", batch=len(launched)):
             if launched:
-                with (att.span("decode.upload",
-                               bytes=self._decode_upload_bytes)
-                      if att is not None else NO_SPAN):
+                with att.span("decode.upload",
+                              bytes=self._decode_upload_bytes):
                     args = self._decode_args(active, override)
-                if self.config.debug_checks:
-                    self._audit_step(self._decode_jit, args, "decode")
-                with (att.span("decode.dispatch")
-                      if att is not None else NO_SPAN):
-                    pools, toks = self._decode_jit(*args)
-                self.cache.pools = pools
+                toks = self._launch(self._programs["decode"], args,
+                                    self._ctx, live_rows=active)
                 self._prev_toks = toks
                 self.metrics.on_decode_step(overlapped=prev is not None)
-                self._count_attention_pages(self._ctx, 1, live_rows=active)
                 for slot, req in launched:
                     req.tokens_in_flight += 1
                     self._ctx[slot] += 1
@@ -1838,26 +1903,22 @@ class ServingEngine:
         return len(launched)
 
     def _fetch_and_emit(self, inflight: tuple, finished_now: list) -> None:
-        """Fetch one launch's tokens and hand them over: append, count,
-        trace, retire finishers. A slot whose request has left it since
-        the launch (finish by EOS is known one step late) computed a
+        """Fetch one decode launch's tokens and hand them over: append,
+        count, trace, retire finishers. A slot whose request has left it
+        since the launch (finish by EOS is known one step late) computed a
         surplus token: dropped here, never appended, counted or indexed;
         its KV write went to a page of the request's own, freed with it."""
         att, tr = self._attr, self._tracer
         toks, step, launched = inflight
-        with (att.span("decode.fetch", of_step=step)
-              if att is not None else NO_SPAN):
+        with att.span("decode.fetch", of_step=step):
             try:
-                toks = np.asarray(toks)  # lint: disable=PT005
+                toks = self._fetch(self._programs["decode"], toks)
             except Exception as e:
                 e.add_note(f"raised at the fetch of the decode that step "
                            f"{step} launched")
                 raise
-        if self._n_counters:
-            self.metrics.on_model_counters(self._cache_spec.counters,
-                                           toks[-self._n_counters:])
         n_new = 0
-        with (att.span("decode.emit") if att is not None else NO_SPAN):
+        with att.span("decode.emit"):
             for slot, req in launched:
                 req.tokens_in_flight -= 1
                 if self.scheduler.running.get(slot) is not req:
@@ -1885,9 +1946,7 @@ class ServingEngine:
         inflight, self._inflight = self._inflight, None
         if inflight is None:
             return False
-        att = self._attr
-        with (att.span("drain", reason=reason)
-              if att is not None else NO_SPAN):
+        with self._attr.span("drain", reason=reason):
             self._fetch_and_emit(inflight, self._drained_finished)
         self.metrics.on_decode_drain(reason)
         return True
@@ -1897,32 +1956,23 @@ class ServingEngine:
         return done
 
     def _verify_phase(self, finished_now: list) -> tuple[int, int]:
-        """The speculative twin of the decode phase: ONE verify dispatch
-        for the whole batch, ONE packed fetch (the decode token fetch,
-        renamed — the SyncTally formula is unchanged), then each slot
-        emits its accepted candidates plus the target's own next token
-        (1..K+1 tokens) and the pages its rejected span over-reserved
-        recycle through the refcounted allocator. Returns (active slots,
+        """The speculative twin of the decode phase, inside the caller's
+        ``serve.verify`` span: ONE verify launch for the whole batch, ONE
+        packed fetch of that same launch (the decode token fetch, renamed
+        — the SyncTally formula is unchanged), then each slot emits its
+        accepted candidates plus the target's own next token (1..K+1
+        tokens) and the pages its rejected span over-reserved recycle
+        through the refcounted allocator. Returns (active slots,
         candidates accepted)."""
-        cfg = self.config
         K = self._spec.depth
         tr = self._tracer
-        args = (self._p, self.cache.pools,
-                jnp.asarray(self.cache.page_table),
-                jnp.asarray(self._ctx), jnp.asarray(self._last_tok),
-                jnp.asarray(self._active), jnp.asarray(self._rids),
-                jnp.asarray(self._gen), jnp.asarray(self._spec_hist()))
-        if self._spec.method == "draft":
-            args = args + (self._draft_p,)
-        if cfg.debug_checks:
-            self._audit_step(self._verify_jit, args, "verify")
-        pools, packed = self._verify_jit(*args)
-        self.cache.pools = pools
-        self._count_attention_pages(self._ctx, K + 1,
-                                    live_rows=self._active)
-        # the step's ONE sanctioned device->host sync: the packed
-        # (target tokens, accept count) fetch
-        packed = np.asarray(packed)  # lint: disable=PT005
+        prog = self._programs["verify"]
+        out = self._launch(prog, self._verify_args(), self._ctx,
+                           live_rows=self._active)
+        # the step's ONE sanctioned device->host sync: the packed (target
+        # tokens, accept count) fetch
+        with self._attr.span("verify.fetch"):
+            packed = self._fetch(prog, out)
         self.metrics.on_decode_step()
         n_slots = n_new = n_accepted = 0
         for slot in np.nonzero(self._active)[0]:
@@ -2113,7 +2163,7 @@ class ServingEngine:
             fatal = {"fatal": "; ".join(
                 [f"{type(exc).__name__}: {exc}",
                  *getattr(exc, "__notes__", ())])}
-            if self._timeline is not None and att is not None and att.open:
+            if self._timeline is not None and att.open:
                 t_end, phase_s = att.finish()
                 self._timeline.append(StepRecord(
                     step=self._step_idx - 1, t_start=att.t0, t_end=t_end,
@@ -2154,11 +2204,12 @@ class ServingEngine:
                 + "; ".join(dead))
         self._donation_audits[guard.name] = reports
 
-    def _audit_step(self, guard: CompileGuard, args, label: str) -> None:
-        """debug_checks: the pre-dispatch audits for one step call. The
+    def _audit_step(self, prog: _Program, guard: CompileGuard,
+                    args) -> None:
+        """debug_checks: the pre-dispatch audits for one launch. The
         jaxpr-level donation audit runs once per GUARD (at its first
         trace); the hlocheck compiled-artifact audit runs once per
-        COMPILED PROGRAM (per prefill bucket + decode, keyed by ``label``)
+        COMPILED PROGRAM (per prefill bucket + decode, keyed by its label)
         — the step is AOT-lowered and its optimized HLO enforced against
         the single-chip budget: zero collectives, zero host transfers,
         every donated pool honored with input-output aliasing. Violations
@@ -2167,6 +2218,7 @@ class ServingEngine:
         reports land in ``hlo_audits`` and the ``serving_hlo_*``
         metrics. One extra AOT compile per program, never a serving-path
         cost."""
+        label = prog.label
         if not guard.traces:
             self._audit_donation(guard, args)
         if label in self._hlo_audits:
@@ -2183,10 +2235,10 @@ class ServingEngine:
             # collective ops per step and collective bytes per token this
             # program advances (decode: max_batch tokens; prefill[N]: up
             # to N prompt tokens)
-            b, s = self._step_shape(label)
+            n_tokens = prog.rows * prog.tokens
             self.metrics.on_tp_audit(
                 collective_ops=len(report.collectives),
-                bytes_per_token=report.collective_bytes / (b * s),
+                bytes_per_token=report.collective_bytes / n_tokens,
                 overlap_frac=report.overlap_frac)
             # meshcheck placement: attribute every collective to its mesh
             # axis on the declared topology (default: single-host over
@@ -2210,20 +2262,17 @@ class ServingEngine:
                         max_dcn_bytes=0, max_dcn_ops=0)
                 mesh_report.check(budget)
             self.metrics.on_mesh_audit(
-                ici_bytes_per_token=mesh_report.ici_bytes / (b * s),
-                dcn_bytes_per_token=mesh_report.dcn_bytes / (b * s),
+                ici_bytes_per_token=mesh_report.ici_bytes / n_tokens,
+                dcn_bytes_per_token=mesh_report.dcn_bytes / n_tokens,
                 predicted_s=mesh_report.predicted_s)
 
     def _step_shape(self, label: str) -> tuple[int, int]:
-        """(batch, seq) of a compiled engine program, from its audit label
-        — ``decode`` runs the whole batch one token wide, ``verify`` the
-        whole batch depth + 1 tokens wide, ``prefill[N]`` one request N
-        padded tokens wide."""
-        if label == "decode":
-            return self.config.max_batch, 1
-        if label == "verify":
-            return self.config.max_batch, self.config.spec.depth + 1
-        return 1, int(label[label.index("[") + 1:-1])
+        """(rows, tokens a row) of the compiled program under an audit
+        label, off its record — ``decode`` runs the whole batch one token
+        wide, ``verify`` the whole batch depth + 1 tokens wide,
+        ``prefill[N]`` one request N padded tokens wide."""
+        prog = self._programs[label]
+        return prog.rows, prog.tokens
 
     def _step_budget(self, label: str) -> hlocheck.CollectiveBudget:
         """The per-program hlocheck budget ``debug_checks`` enforces:

@@ -97,7 +97,7 @@ from .metrics import PREFIX as _METRIC_PREFIX
 from .metrics import TENANT_CLASSES
 from .wire import (encode_digests, encode_page, encode_rehome,
                    WIRE_ERROR_KINDS)
-from .scheduler import (EXPIRED, FAILED, SHED, WAITING, EngineOverloaded,
+from .scheduler import (EXPIRED, SHED, WAITING, EngineOverloaded,
                         _rid_counter)
 from .scheduler import Request as _Request
 
@@ -623,11 +623,9 @@ class FleetRouter:
                             spill=True)
                 self._pending.append(pend)
             else:
-                eng._retire(req, FAILED, fault)
-                eng.metrics.on_failed()
+                eng._fail(req, fault)
         for req in list(eng.scheduler.running.values()):
-            eng._retire(req, FAILED, fault)
-            eng.metrics.on_failed()
+            eng._fail(req, fault)
         self.metrics.on_fleet_replicas(len(self._live()))
         # a replica death is exactly what the cluster flight recorder
         # exists for — capture the fleet's state at the boundary

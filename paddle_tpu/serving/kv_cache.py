@@ -73,7 +73,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from ..obs import NO_SPAN
+from ..obs import PhaseAccumulator
 
 NULL_PAGE = 0
 _RESERVED_PAGES = 1  # page 0 = null page
@@ -551,10 +551,10 @@ class PagedKVCache:
         # restore (the ``restore_fail`` fault point); None costs one
         # attribute check per admission that would restore
         self.restore_fault = None
-        # engine-installed span source (obs.PhaseAccumulator): the
-        # copy-on-write page copy is the serve.cow_copy span of the
-        # engine's step. None (tracing off, or no engine) costs one check.
-        self.spans = None
+        # the span source (the engine installs its own): the copy-on-write
+        # page copy is the serve.cow_copy span of the engine's step. A
+        # cache with no engine holds a disabled one.
+        self.spans = PhaseAccumulator()
         self._build_jits()
 
     @property
@@ -934,9 +934,7 @@ class PagedKVCache:
         """Jitted donated single-page pool copy (the COW data move)."""
         import jax.numpy as jnp
 
-        spans = self.spans
-        with (spans.span("cow_copy", pages=1) if spans is not None
-              else NO_SPAN):
+        with self.spans.span("cow_copy", pages=1):
             self.pools = self._copy_jit(
                 self.pools, jnp.asarray(src, jnp.int32),
                 jnp.asarray(dst, jnp.int32))

@@ -26,3 +26,21 @@ def _seed():
     paddle.seed(102)
     np.random.seed(102)
     yield
+
+
+#: the upstream source tree whose ``__all__`` lists the API-parity gates
+#: read; not part of this repository, and absent from some sandboxes
+REFERENCE_TREE = "/root/reference"
+
+
+def pytest_collection_modifyitems(config, items):
+    """``@pytest.mark.needs_reference``: skip, with the reason, where the
+    reference tree is absent — such a test can only fail on the missing
+    file, and a run that cannot end rc 0 hides a real failure."""
+    if os.path.isdir(REFERENCE_TREE):
+        return
+    skip = pytest.mark.skip(
+        reason=f"reads the reference tree, and {REFERENCE_TREE} is absent")
+    for item in items:
+        if "needs_reference" in item.keywords:
+            item.add_marker(skip)

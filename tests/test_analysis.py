@@ -47,6 +47,34 @@ def test_guard_counts_traces_not_calls():
     assert len(g.signatures) == 2
 
 
+def test_guard_gives_a_programs_first_call_a_stack_chunk_of_its_own():
+    """CPython keeps frames in 16 KiB chunks and maps / unmaps one at
+    every call that crosses a boundary: a loop that straddles one pays a
+    system call pair a turn (found on the chip's host, where the caller's
+    frame sizes decided how long a program takes to trace). A guard's
+    first call of a program runs below a frame big enough to get a chunk
+    to itself; later calls go straight through."""
+    from paddle_tpu.analysis import tracecheck
+
+    own = tracecheck._on_a_chunk_of_its_own
+    assert own.__code__.co_stacksize > 2 ** 16
+    assert own(lambda a, b=1: (a, b), (2,), {"b": 3}) == (2, 3)
+    with pytest.raises(ZeroDivisionError):
+        own(lambda: 1 / 0, (), {})
+
+    calls = []
+    real = tracecheck._on_a_chunk_of_its_own
+    g = CompileGuard(lambda x: x * 2, "double", group_by=lambda x: x.shape[0])
+    try:
+        tracecheck._on_a_chunk_of_its_own = \
+            lambda f, a, k: (calls.append(a[0].shape), real(f, a, k))[1]
+        for n in (4, 4, 8, 4):
+            g(jnp.zeros((n,)))
+    finally:
+        tracecheck._on_a_chunk_of_its_own = real
+    assert calls == [(4,), (8,)] and g.traces == 2
+
+
 def test_guard_budget_counts_overage_when_not_strict():
     g = CompileGuard(lambda x: x + 1, "inc", budget=1)
     g(jnp.zeros((2,)))

@@ -17,6 +17,7 @@ def _t(x):
     return paddle.to_tensor(x)
 
 
+@pytest.mark.needs_reference
 def test_root_surface_complete():
     import ast
 

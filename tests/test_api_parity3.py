@@ -10,6 +10,8 @@ import pytest
 
 import paddle_tpu as paddle
 
+pytestmark = pytest.mark.needs_reference
+
 _REF = "/root/reference/python/paddle"
 
 

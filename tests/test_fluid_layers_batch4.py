@@ -227,6 +227,7 @@ def test_codegen_helpers():
     assert L.templatedoc()(test_codegen_helpers) is test_codegen_helpers
 
 
+@pytest.mark.needs_reference
 def test_full_name_coverage_vs_reference():
     """Every name in the reference fluid.layers __all__ resolves here."""
     import ast

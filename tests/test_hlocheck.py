@@ -34,6 +34,7 @@ from paddle_tpu.analysis.hlocheck import (REGISTRY, SINGLE_CHIP,
                                           HostTransferError, audit, census,
                                           run_step)
 from paddle_tpu.serving import ServingConfig, ServingEngine
+from paddle_tpu.serving.spec import SpecConfig
 from paddle_tpu.text.gpt import GPTConfig, GPTForCausalLM
 
 pytestmark = pytest.mark.hlocheck
@@ -269,6 +270,45 @@ def test_registry_cache_steps_audit_clean():
     assert scatter.donated_leaves == 4 == scatter.aliased_leaves
     cow = run_step("cow_copy")
     assert cow.donated_leaves == 4 == cow.aliased_leaves
+
+
+@pytest.mark.parametrize("which, label, live", [
+    ("prefill", "prefill[8]", {}),
+    ("prefill_chunk", "prefill[8]", {"chunk_size": 4}),
+    ("decode", "decode", {}),
+    ("verify_spec", "verify",
+     {"spec": SpecConfig(method="ngram", depth=2)}),
+])
+def test_registry_audits_the_engine_s_own_operands(monkeypatch, which,
+                                                   label, live):
+    """What ``_build_engine_step`` hands the audit is what the engine's
+    own builders make (hlocheck builds no operand tuple): the same tree,
+    shapes and dtypes as a live launch of that program uploads, for the
+    program's own guard."""
+    launches = {}
+    real = ServingEngine._launch
+
+    def spy(self, prog, args, *a, **k):
+        launches.setdefault(prog.label, (prog, args))
+        return real(self, prog, args, *a, **k)
+
+    monkeypatch.setattr(ServingEngine, "_launch", spy)
+    paddle.seed(7)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=97, hidden_size=32, num_layers=2, num_heads=2,
+        max_seq_len=32, dropout=0.0))
+    eng = ServingEngine(model, ServingConfig(
+        max_batch=2, num_pages=16, page_size=4, max_prompt_len=8, **live))
+    eng.add_request(np.arange(1, 8, dtype=np.int32), 3)
+    eng.run()
+    prog, args = launches[label]
+
+    target, audited, _, budget = hlocheck._build_engine_step(which)
+    assert target.name == prog.phase and budget == SINGLE_CHIP
+    sig = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: (a.shape, a.dtype), tree)
+    assert jax.tree.structure(audited) == jax.tree.structure(args)
+    assert sig(audited) == sig(args)
 
 
 def test_run_step_unknown_name_raises():

@@ -276,23 +276,47 @@ def serve(model, prompts, n_new, **config):
     return eng, [np.asarray(out[r]) for r in sorted(out)]
 
 
-def test_engine_serves_the_reference_s_tokens_and_counts(interpret):
+_SERVED = {}
+
+
+def served(chunk_size):
+    """(the engine, its served sequences, the prompts, what the run added
+    to each counter, the configuration, the model's leaves) of one run of
+    the parity traffic under ``chunk_size``, made once a process."""
+    if chunk_size not in _SERVED:
+        model, cfg, p = build(held_experts=(4, 8))
+        rng = np.random.default_rng(8)
+        first = [ids_of(rng, n) for n in (5, 11, 16)]
+        second = [np.concatenate([first[2][:12], ids_of(rng, 3)])]
+        snap0 = ServingEngine(model, ServingConfig(
+            max_batch=4, num_pages=8, page_size=4,
+            max_prompt_len=16)).metrics.snapshot()
+        eng, seqs = serve(model, [first, second], 9, chunk_size=chunk_size)
+        snap = eng.metrics.snapshot()  # one registry a process: read now
+        counts = {k: v - snap0[k] for k, v in snap.items() if k in snap0}
+        _SERVED[chunk_size] = (eng, seqs, first + second, counts, cfg, p)
+    return _SERVED[chunk_size]
+
+
+@pytest.mark.parametrize("chunk_size", [0, 4])
+def test_engine_serves_the_reference_s_tokens_and_counts(interpret,
+                                                         chunk_size):
     """Through ``ServingEngine``'s own add_request / step path: every
     served token is the reference's best at its position (gap 0 in
     float32), a second request hits the first one's cached prefix, the
-    programs compile once, and the counters add up."""
+    programs compile once, and the counters add up. A prompt prefilled
+    four tokens a step comes to the same tokens as one prefilled whole:
+    the final chunk's output carries the model's counters behind its
+    token like any launch's (it raised ``TypeError`` at that fetch,
+    engine-fatal, before the prefill path was one)."""
     interpret(True)
-    model, cfg, p = build(held_experts=(4, 8))
-    rng = np.random.default_rng(8)
-    first = [ids_of(rng, n) for n in (5, 11, 16)]
-    second = [np.concatenate([first[2][:12], ids_of(rng, 3)])]
-    snap0 = ServingEngine(model, ServingConfig(
-        max_batch=4, num_pages=8, page_size=4,
-        max_prompt_len=16)).metrics.snapshot()
-    eng, seqs = serve(model, [first, second], 9)
-    assert eng.compile_counts == {"prefill": 2, "decode": 1}
+    eng, seqs, prompts, counts, cfg, p = served(chunk_size)
+    # chunks of 4 all pad into the bucket of 8; whole tails use both
+    assert eng.compile_counts == {"prefill": 1 if chunk_size else 2,
+                                  "decode": 1}
     assert eng._decode_pallas_eligible
-    for prompt, seq in zip(first + second, seqs):
+    for prompt, seq, whole in zip(prompts, seqs, served(0)[1]):
+        assert np.array_equal(seq, whole)
         toks = seq[len(prompt):]
         assert len(toks) == 9
         logits = ref.forward(p, jnp.asarray(seq[:-1])[None], cfg,
@@ -300,11 +324,14 @@ def test_engine_serves_the_reference_s_tokens_and_counts(interpret):
         at = len(prompt) - 1 + np.arange(9)
         gap = jnp.max(logits[at], -1) - logits[at, toks]
         assert float(gap.max()) < TOL
-    snap = eng.metrics.snapshot()
-    count = lambda k: snap[k] - snap0[k]  # noqa: E731
+    count = counts.__getitem__
     assert count("serving_prefix_tokens_saved") == 12
-    # tokens computed: the uncached tails, and 8 decoded a request
-    tokens = 5 + 11 + 16 + 3 + 4 * 8
+    assert count("serving_prefill_tokens_total") == 5 + 11 + 16 + 3
+    # tokens counted: the uncached tails, and 8 decoded a request. A
+    # chunk that is not final is never fetched, and its counters with it
+    # (ROADMAP D15): of a chunked tail only the last chunk is counted
+    tails = (1 + 3 + 4 + 3) if chunk_size else (5 + 11 + 16 + 3)
+    tokens = tails + 4 * 8
     moe_layers = 2
     assert count("serving_moe_assignments_total") == tokens * 4 * moe_layers
     launches = count("serving_prefills_total") + count("serving_decode_steps")
@@ -314,7 +341,8 @@ def test_engine_serves_the_reference_s_tokens_and_counts(interpret):
     assert 0 < count("serving_moe_local_assignments_total") \
         < count("serving_moe_assignments_total")
     # six... the pool's own figure: 3 layers x 128 padded values x 4 bytes
-    assert snap["serving_kv_bytes_per_token"] == 3 * 128 * 4
+    assert eng.metrics.snapshot()["serving_kv_bytes_per_token"] \
+        == 3 * 128 * 4
 
 
 def test_swap_preemption_round_trips_the_latent_pool():

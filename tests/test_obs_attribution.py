@@ -29,7 +29,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.analysis import SyncTally
-from paddle_tpu.obs import (ALERT_RULES, PHASES, PhaseAccumulator,
+from paddle_tpu.obs import (ALERT_RULES, NO_SPAN, PHASES, PhaseAccumulator,
                             StepRecord, Watchdog, WatchdogConfig,
                             validate_flight_record)
 from paddle_tpu.obs.__main__ import main as obs_main
@@ -257,12 +257,39 @@ def test_attribution_and_watchdogs_add_zero_host_syncs(model):
 
 def test_obs_off_surfaces_are_none_and_watchdog_off(model):
     engine = _engine(model, enable_tracing=False)
-    assert engine._attr is None and engine._watchdog is None
+    assert not engine._attr.enabled and engine._watchdog is None
     assert engine.alerts() == []
     engine.add_request(_prompt(5), 3)
     engine.run()
     rec = engine.flight_record()
     assert rec["steps"] == []  # documented: no ring with tracing off
+
+
+def test_disabled_accumulator_is_no_span_and_records_nothing(model):
+    # whether tracing is on is decided behind the accumulator, once: a
+    # disabled one (no clock) hands every site the one shared do-nothing
+    # context and keeps no seconds, and an engine with tracing off steps
+    # through the same call sites without opening a record or reading
+    # the clock for one
+    acc = PhaseAccumulator()
+    assert not acc.enabled
+    assert acc.span("decode.fetch", of_step=3) is NO_SPAN
+    acc.enter_step(7)
+    assert acc.begin() == 0.0 and not acc.open
+    assert acc.mark("admit") == 0.0
+    acc.account()
+    assert acc.finish() == (0.0, {}) and acc.span_s == {}
+    acc.exit_step()
+    assert acc._step_ann is None and acc._account is None
+
+    clock = VirtualClock()
+    engine = _engine(model, clock=clock, enable_tracing=False)
+    engine.add_request(_prompt(5), 3)
+    reads = clock.t
+    engine.run()
+    assert clock.t == reads  # not one clock read for a span or a phase
+    assert engine.timeline is None and engine._step_stats is None
+    assert not engine._attr.open and engine._attr.span_s == {}
 
 
 # ---------------------------------------------------------- flight recorder

@@ -180,7 +180,14 @@ def cow(tmp_path_factory):
     ("plain", "decode.emit", "decode"),
     ("plain", "account", "step"),
     ("chunked", "chunk_prefill", "step"),
+    # a chunk goes through the one prefill path: the same parts
+    ("chunked", "prefill.upload", "chunk_prefill"),
+    ("chunked", "prefill.dispatch", "chunk_prefill"),
+    ("chunked", "prefill.fetch", "chunk_prefill"),
     ("spec", "verify", "step"),
+    # and a verify through the one launch and the one fetch
+    ("spec", "verify.dispatch", "verify"),
+    ("spec", "verify.fetch", "verify"),
     ("cow", "cow_copy", "admit"),
 ])
 def test_span_nests_under_its_parent(request, scenario, child, parent):
@@ -271,6 +278,20 @@ def test_attribute_counts(chunked, spec, cow):
     # 5 and 12 prompt tokens in chunks of 4: 2 + 3 chunks over the steps
     assert sum(s.stats["chunks"] for s in chunks) == 5
     assert not [s for s in chunked["spans"] if s.name == "prefill"]
+    # every chunk uploads and dispatches; only the chunk that completes a
+    # prompt fetches (its first token), after its own dispatch
+    parts = {k: [s for s in chunked["spans"] if s.name == "prefill." + k]
+             for k in ("upload", "dispatch", "fetch")}
+    by_rid = lambda k: sorted(s.stats["rid"] for s in parts[k])  # noqa: E731
+    small, big = chunked["rids"]
+    assert by_rid("upload") == by_rid("dispatch") == [small] * 2 + [big] * 3
+    assert by_rid("fetch") == [small, big]
+    for fetch in parts["fetch"]:
+        assert max(s.end for s in parts["dispatch"]
+                   if s.stats["rid"] == fetch.stats["rid"]) <= fetch.start
+    # a chunk of 4 pads into the bucket of 8
+    row = chunked["engine"].cache.page_table[0].nbytes
+    assert {s.stats["bytes"] for s in parts["upload"]} == {4 * 8 + row + 12}
     verifies = [s for s in spec["spans"] if s.name == "verify"]
     assert verifies and all(s.stats["batch"] in (1, 2) for s in verifies)
     assert not [s for s in spec["spans"] if s.name.startswith("decode")]
@@ -315,7 +336,7 @@ def test_phases_still_sum_and_spans_are_no_part_of_the_sum(plain):
 
 def test_tracing_off_leaves_no_span_and_no_accumulator(model, tmp_path):
     engine = _engine(model, enable_tracing=False)
-    assert engine._attr is None and engine.cache.spans is None
+    assert not engine._attr.enabled and engine.cache.spans is engine._attr
 
     def run():
         engine.add_request(_prompt(5), 3)

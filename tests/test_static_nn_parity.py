@@ -161,6 +161,7 @@ def test_spectral_norm_unit_sigma():
     assert sigma == pytest.approx(1.0, rel=1e-2)
 
 
+@pytest.mark.needs_reference
 def test_static_surface_complete():
     import ast
 
