@@ -741,14 +741,18 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _build_flash():
+def _flash_args():
     import jax.numpy as jnp
 
+    b, h, s, d = 1, 2, 1024, 128
+    return (b, h, s, d), _sds((b, h, s, d), jnp.float32)
+
+
+def _build_flash():
     from ..kernels import flash_attention as fa
     from ..kernels.attention import sdpa_reference
 
-    b, h, s, d = 1, 2, 1024, 128
-    q = _sds((b, h, s, d), jnp.float32)
+    (b, h, s, d), q = _flash_args()
     blk = fa._block(s, d)
     constraints = (
         ("supports_shape", fa.supports_shape((b, h, s, d), (b, h, s, d)),
@@ -773,6 +777,39 @@ def _build_flash():
         composite=lambda q, k, v: sdpa_reference(q, k, v, is_causal=True,
                                                  scale=0.125),
         composite_args=(q, q, q))
+
+
+def _build_flash_bwd():
+    import jax
+    import jax.numpy as jnp
+
+    from ..kernels import flash_attention as fa
+    from ..kernels.attention import sdpa_reference
+
+    (b, h, s, d), q = _flash_args()
+    lse = _sds((b, h, 1, s), jnp.float32)
+
+    def composite(q, k, v, do):
+        return jax.vjp(lambda q, k, v: sdpa_reference(
+            q, k, v, is_causal=True, scale=0.125), q, k, v)[1](do)
+
+    return dict(
+        fn=lambda q, k, v, o, lse, do: fa._flash_bwd_call(
+            q, k, v, o, lse, do, True, 0.125, fa._edges(q, k),
+            fa._dq_resident(q), False),
+        args=(q, q, q, q, lse, q),
+        # dK and dV are revisited across the q grid dim and a head's whole
+        # dQ across both: the accumulators of the one fused backward
+        budget=KernelBudget(allow_output_revisits=True),
+        constraints=(
+            ("dq_resident", fa._dq_resident(q),
+             "a head's dQ (fp32 accumulator + two fp32 output buffers) "
+             "stays in VMEM at the certified shape (above "
+             "_DQ_RESIDENT_BYTES it leaves as per-kv-block partials)"),),
+        # five products (k.qT, pT.dO, v.dOT, dsT.q, ds.k) over the causal
+        # half, x2 flops/MAC
+        flops=float(5 * b * h * s * s * d),
+        composite=composite, composite_args=(q, q, q, q))
 
 
 def _build_splash():
@@ -1078,6 +1115,11 @@ REGISTRY: dict[str, KernelSpec] = {s.name: s for s in (
     KernelSpec("flash_fwd", "dense-block flash attention forward (causal, "
                "seq 1024, head_dim 128) — output revisited across the KV "
                "grid dim by declaration", _build_flash),
+    KernelSpec("flash_bwd", "dense-block flash attention backward, one "
+               "fused kernel (scores recomputed transposed, lse and di "
+               "one fp32 number a row) — dK/dV revisited across the q "
+               "grid dim and dQ across both by declaration",
+               _build_flash_bwd),
     KernelSpec("splash_fwd", "causal splash attention forward (tile-"
                "skipping mask, seq 1024) — same accumulation contract",
                _build_splash),

@@ -63,7 +63,7 @@ def test_flash_and_splash_certify_with_declared_revisits():
     """The attention kernels revisit their output across the KV grid dim
     (online-softmax accumulation) — legal exactly because their budgets
     declare allow_output_revisits."""
-    for name in ("flash_fwd", "splash_fwd"):
+    for name in ("flash_fwd", "flash_bwd", "splash_fwd"):
         report, record = _run(name)
         assert report.ok, (name, [str(f) for f in report.all_findings()])
         assert sum(c.output_revisits for c in report.calls) > 0, name

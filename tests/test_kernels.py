@@ -83,3 +83,115 @@ def test_splash_grad_matches_reference():
     for gs, gr in zip(g_s, g_r):
         np.testing.assert_allclose(np.asarray(gs), np.asarray(gr),
                                    rtol=5e-3, atol=5e-3)
+
+
+# ------------------------------------------- in-tree flash forward/backward
+from paddle_tpu.kernels import flash_attention as fa  # noqa: E402
+
+#: largest error allowed, as a share of the reference's largest entry: a
+#: bf16 run rounds p, dS and each result once to 8 bits (eps 2**-7); an
+#: fp32 run only accumulates fp32 rounding over sums of up to 1024 terms
+_FLASH_TOL = {jnp.bfloat16: 2.0 ** -6, jnp.float32: 2.0 ** -17}
+
+FLASH_PARITY = [
+    pytest.param(s, d, causal, dtype,
+                 id=f"s{s}-d{d}-{'causal' if causal else 'full'}-"
+                    f"{jnp.dtype(dtype).name}")
+    for s in (256, 1024) for d in (64, 128) for causal in (True, False)
+    for dtype in (jnp.bfloat16, jnp.float32)
+] + [
+    # the pad route: 640 is causal attention padded to 1024 by the dispatch
+    pytest.param(640, d, True, dtype, id=f"pad640-d{d}-{jnp.dtype(dtype).name}")
+    for d in (64, 128) for dtype in (jnp.bfloat16, jnp.float32)
+]
+
+
+def _close(got, want, tol):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.abs(got - want).max() <= tol * np.abs(want).max()
+
+
+@pytest.mark.parametrize("s,d,causal,dtype", FLASH_PARITY)
+def test_flash_kernels_match_reference(monkeypatch, s, d, causal, dtype):
+    """The in-tree forward and the fused backward, through the Pallas
+    interpreter, against the composite: the output and dQ, dK, dV. s 256
+    is one block (the mask inside it), s 1024 is two by two at the shipped
+    edge of 512 (a dead step, a full one, two on the diagonal), 640 goes
+    through ``sdpa``'s pad route."""
+    from paddle_tpu.kernels import _common, attention
+
+    scale = 1.0 / d ** 0.5
+    q, k, v = (x.astype(dtype) for x in _qkv(1, 2, s, s, d, seed=s + d))
+    w = _qkv(1, 2, s, s, d, seed=7)[0]
+
+    if fa.flash_route(q.shape, k.shape, causal) == "direct":
+        def attn(q, k, v):
+            return fa._flash(q, k, v, causal, scale, True)
+    else:
+        assert fa.flash_route(q.shape, k.shape, causal) == "pad"
+        monkeypatch.setattr(_common, "on_tpu_backend", lambda: True)
+        real = fa._flash
+        monkeypatch.setattr(
+            fa, "_flash", lambda q, k, v, c, sc: real(q, k, v, c, sc, True))
+
+        def attn(q, k, v):
+            return attention.sdpa(q, k, v, is_causal=True)
+
+    def ref(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        return sdpa_reference(q, k, v, is_causal=causal, scale=scale)
+
+    out, vjp = jax.vjp(attn, q, k, v)
+    want, ref_vjp = jax.vjp(ref, q, k, v)
+    assert out.dtype == dtype and out.shape == q.shape
+    tol = _FLASH_TOL[dtype]
+    _close(out, want, tol)
+    for got, exp in zip(vjp(w.astype(dtype)), ref_vjp(w)):
+        assert got.dtype == dtype
+        _close(got, exp, tol)
+
+
+def test_flash_causal_rectangular_bottom_right_aligned():
+    """s_q < s_k, as the splash path: query i sees keys up to i + s_k - s_q."""
+    q, k, v = _qkv(1, 2, 256, 1024, 64, seed=3)
+    out, vjp = jax.vjp(lambda q, k, v: fa._flash(q, k, v, True, 0.125, True),
+                       q, k, v)
+    want, ref_vjp = jax.vjp(
+        lambda q, k, v: sdpa_reference(q, k, v, is_causal=True), q, k, v)
+    _close(out, want, _FLASH_TOL[jnp.float32])
+    for got, exp in zip(vjp(q), ref_vjp(q)):
+        _close(got, exp, _FLASH_TOL[jnp.float32])
+    # the rows above every key have nothing to attend: no kernel route
+    assert fa.flash_route((1, 2, 1024, 64), (1, 2, 256, 64), True) == ""
+
+
+def test_flash_dq_leaves_as_partials_when_a_head_does_not_fit(monkeypatch):
+    """Above ``_DQ_RESIDENT_BYTES`` the backward writes each kv block's
+    share of dQ as an fp32 partial and XLA sums them: the same numbers."""
+    q, k, v = _qkv(1, 2, 1024, 1024, 64, seed=4)
+
+    def grads():
+        _, vjp = jax.vjp(
+            lambda q, k, v: fa._flash(q, k, v, True, 0.125, True), q, k, v)
+        return vjp(v)
+
+    resident = grads()
+    monkeypatch.setattr(fa, "_DQ_RESIDENT_BYTES", 0)
+    partial = grads()
+    for a, b in zip(resident, partial):
+        _close(a, b, _FLASH_TOL[jnp.float32])
+
+
+def test_flash_residual_statistic_is_one_number_a_row():
+    """What the forward keeps for the backward: q, k, v, o and ONE fp32
+    statistic, ``lse`` as ``[b, h, 1, s]`` — never a lane-broadcast
+    ``[b, h, s, 128]`` (the library kept two, and a third in its
+    backward)."""
+    b, h, s, d = 2, 2, 256, 64
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(b, h, s, s, d))
+    _, vjp = jax.vjp(lambda q, k, v: fa._flash(q, k, v, True, 0.125, True),
+                     q, k, v)
+    shapes = sorted((x.shape, x.dtype.name)
+                    for x in jax.tree_util.tree_leaves(vjp))
+    assert shapes == sorted([((b, h, s, d), "bfloat16")] * 4
+                            + [((b, h, 1, s), "float32")])
