@@ -1028,6 +1028,50 @@ def _build_mla_decode():
         composite=composite, composite_args=args)
 
 
+def _build_ssm_decode_update():
+    """The decode state-update kernel at a canonical serving shape: 4
+    slots of 8 heads x 16 x 128 float32 state, all heads a block, so a slot
+    is one grid step. Its block index is the slot's LIVE ROW (a scalar-
+    prefetched vector: a dead slot names the live slot before it, whose
+    block the pipeline holds already): data-dependent by declaration,
+    resolved at ``index_args`` with every slot live, where it is the
+    identity; a run of dead slots revisits one block in sequence, by
+    declaration too (nothing is written in those steps). The state is
+    aliased in place; the HBM model counts it in and out once a slot."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ..kernels import ssm_state_update as su
+
+    slots, heads, p, n = 4, 8, 16, 128
+    f32 = jnp.float32
+    state = _sds((slots, heads, p, n), f32)
+    x = _sds((slots, heads, p), f32)
+    dt = _sds((slots, heads), f32)
+    a = _sds((heads,), f32)
+    row = _sds((slots, n), f32)
+    active = _sds((slots,), jnp.bool_)
+    ok, why = su.ssm_kernel_eligible(heads, p, n)
+    constraints = (
+        ("ssm_kernel_eligible", ok, why or
+         "the canonical shape must pass the decode update's gate"),
+        ("lane_rows_only", not su.ssm_kernel_eligible(heads, p, 96)[0],
+         "a state whose minor axis is not whole 128-lane rows must take "
+         "the plain recurrence"),
+    )
+    args = (state, x, dt, a, row, row, active)
+    return dict(
+        fn=su.ssm_decode_update, args=args,
+        budget=KernelBudget(allow_data_dependent_outputs=True,
+                            allow_output_revisits=True),
+        constraints=constraints,
+        index_args=(np.arange(slots, dtype=np.int32),
+                    np.ones(slots, np.int32)),
+        # decay, outer product and add; multiply and add for the readout
+        flops=float(5 * slots * heads * p * n),
+        composite=su.ssm_update_reference, composite_args=args)
+
+
 def _build_ln(which: str):
     import jax.numpy as jnp
 
@@ -1149,6 +1193,11 @@ REGISTRY: dict[str, KernelSpec] = {s.name: s for s in (
                "a latent paged pool: one grid step a row, the row's live "
                "pages staged once for all heads through two alternating "
                "buffers, online softmax", _build_mla_decode),
+    KernelSpec("ssm_decode_update", "Mamba-2 decode state update: a grid "
+               "step a slot brings the slot's float32 state to VMEM once, "
+               "advances it in place and reads it out; dead slots name "
+               "the live block before them and move nothing",
+               _build_ssm_decode_update),
     KernelSpec("fused_layernorm_fwd", "fused LayerNorm forward (one HBM "
                "pass per row block, stats saved for the backward)",
                lambda: _build_ln("fwd")),

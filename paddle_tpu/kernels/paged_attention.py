@@ -306,6 +306,14 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
     """
     s = q.shape[2]
     quantized = k_scale is not None
+    if k_pool.ndim == 3 or q.shape[1] != k_pool.shape[2]:
+        # grouped KV heads: the ragged kernel has no such path yet
+        # (ROADMAP R1), the composite folds a group's queries onto its KV
+        # head
+        if quantized:
+            raise ValueError("grouped KV heads have no int8 path")
+        return _grouped_composite(q, k_pool, v_pool, page_table, ctx_lens,
+                                  scale)
     use_kernel, interpret = _use_ragged_kernel(q, k_pool, page_table,
                                                quantized)
     if use_kernel:
@@ -324,3 +332,34 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
         v_all = paged_gather(v_pool, page_table)
     mask = ragged_mask(ctx_lens, k_all.shape[2], s)
     return sdpa(q, k_all, v_all, mask=mask, scale=scale)
+
+
+def _grouped_composite(q, k_pool, v_pool, page_table, ctx_lens, scale):
+    """Composite attention of ``g`` query heads to each KV head: q ``[b,
+    kv_heads * g, s, d]`` (query head ``kv * g + j`` attends KV head
+    ``kv``) against pools of ``[pages, page_size, kv_heads, d]`` or, lane-
+    dense, ``[pages, page_size, kv_heads * d]`` (a head size under 128:
+    whole lane rows a token, and nothing tempts the compiler to lay the
+    whole pool out anew). A row's pages are gathered first and the
+    gathered rows split into heads, a KV head's keys are read once for its
+    whole group, and the ragged mask puts exact zeros beyond ``ctx_lens +
+    t``. float32 scores and softmax."""
+    import jax
+
+    b, heads, s, d = q.shape
+    pages = k_pool[page_table]             # [b, pages_per_seq, page, ...]
+    total = pages.shape[1] * pages.shape[2]
+    k_seq = pages.reshape(b, total, -1, d)
+    v_seq = v_pool[page_table].reshape(b, total, -1, d)
+    g = heads // k_seq.shape[2]
+    if g * k_seq.shape[2] != heads:
+        raise ValueError(f"{heads} query heads do not group over "
+                         f"{k_seq.shape[2]} KV heads")
+    scale = scale if scale is not None else d ** -0.5
+    qg = q.reshape(b, -1, g, s, d)
+    scores = jnp.einsum("bkgqd,btkd->bkgqt", qg, k_seq,
+                        preferred_element_type=jnp.float32) * scale
+    seen = ragged_mask(ctx_lens, total, s)[:, :, None]   # [b,1,1,s,total]
+    probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
+    out = jnp.einsum("bkgqt,btkd->bkgqd", probs.astype(q.dtype), v_seq)
+    return out.reshape(b, heads, s, d)

@@ -503,12 +503,16 @@ class _Program(NamedTuple):
 class ServingEngine:
     """Continuous-batching engine over any model with the paged-cache
     contract (text/gpt.py's ``GPTForCausalLM``, text/kimi_k2.py's
-    ``KimiK2ForCausalLM``): ``functional_state`` / ``functional_call``,
-    ``forward(ids, caches=[{<its pool leaves>, page_table, ctx_lens,
-    valid, kv_limit}, ...]) -> (logits, new_caches)``, and
-    ``paged_cache_spec(...)``, by which the model states what it keeps a
-    layer (``kv_cache.PagedCacheSpec``) and refuses what it cannot do.
-    The engine reads no model by its field names."""
+    ``KimiK2ForCausalLM``, text/granite_hybrid.py's
+    ``GraniteHybridForCausalLM``): ``functional_state`` /
+    ``functional_call``, ``forward(ids, caches=[{<that layer's leaves>,
+    page_table, ctx_lens, valid, kv_limit}, ...]) -> (logits,
+    new_caches)``, and ``paged_cache_spec(...)``, by which the model
+    states what it keeps, layer by layer (``kv_cache.PagedCacheSpec``),
+    and refuses what it cannot do. A model that keeps something a SLOT (a
+    recurrent state) also finds ``slots`` in every layer's cache: the slot
+    of each row of the launch, None where row i is slot i (decode). The
+    engine reads no model by its field names."""
 
     def __init__(self, model, config: ServingConfig | None = None,
                  clock=None, fault_injector=None, draft_model=None):
@@ -585,10 +589,15 @@ class ServingEngine:
                 quantized_logits=cfg.tp_quantized_logits)
         else:
             self._tp = None
+        if cfg.enable_prefix_caching and spec.no_prefix_sharing:
+            raise ValueError(
+                "enable_prefix_caching=True is not supported by this "
+                f"model: {spec.no_prefix_sharing}")
         pages_per_seq = cfg.pages_per_seq or \
             -(-spec.max_seq_len // cfg.page_size)
         self.cache = PagedKVCache(PagedCacheConfig(
-            num_layers=spec.num_layers, leaves=spec.leaves,
+            num_layers=spec.num_layers, leaves=spec.leaves or None,
+            leaves_by_layer=spec.leaves_by_layer,
             num_pages=cfg.num_pages, page_size=cfg.page_size,
             max_batch=cfg.max_batch, pages_per_seq=pages_per_seq,
             dtype=spec.dtype,
@@ -596,8 +605,14 @@ class ServingEngine:
             debug_checks=cfg.debug_checks, tp=self._tp,
             kv_dtype=cfg.kv_dtype, host_tier_bytes=cfg.host_tier_bytes))
         # the jitted steps thread every pool leaf through — the model's
-        # own (scale leaves ride beside the codes in quantized mode)
-        self._pool_keys = self.cache.cfg.pool_leaf_keys
+        # own, layer by layer (scale leaves ride beside the codes in
+        # quantized mode, a recurrent layer's state a slot beside nothing)
+        self._layer_keys = tuple(tuple(lf.name for lf in ls)
+                                 for ls in self.cache.cfg.layer_leaves)
+        # a model that keeps something a SLOT is told which slot each row
+        # of a launch is (``slots`` in every layer's cache; None: row i is
+        # slot i, as in decode)
+        self._slot_state = bool(self.cache.cfg.slot_leaf_keys)
         # what the model counts a launch (an expert layer's assignments):
         # an int32 vector behind the launch's tokens, in the same fetch
         self._n_counters = len(spec.counters)
@@ -605,6 +620,8 @@ class ServingEngine:
         self.metrics = ServingMetrics()
         self.metrics.on_tp_degree(cfg.tensor_parallel)
         self.metrics.on_kv_bytes_per_token(self.cache.cfg.kv_bytes_per_token)
+        self.metrics.on_state_bytes_per_slot(
+            self.cache.cfg.state_bytes_per_slot)
         self.metrics.on_spec_depth(cfg.spec.depth if cfg.spec else 0)
         # labeled-family presence: the watchdog rule counters read 0
         # before anything happens, the same contract _SEEDED gives the
@@ -796,7 +813,7 @@ class ServingEngine:
             # wrap the sharded callables, so compile counts, budgets, and
             # the retrace/donation audits are identical to single-chip
             prefill_impl = self._tp.wrap_step(
-                prefill_impl, spec.num_layers, n_rest=5,
+                prefill_impl, spec.num_layers, n_rest=6,
                 quantized=self.cache.cfg.quantized)
             decode_impl = self._tp.wrap_step(
                 decode_impl, spec.num_layers, n_rest=7,
@@ -862,20 +879,25 @@ class ServingEngine:
                              cfg.top_k, cfg.top_p)[0]
 
     def _run_model(self, p_arrays, pools, table, ctx, valid, ids,
-                   kv_limit=None):
+                   kv_limit=None, slots=None):
         """(logits, new_pools, counters): one paged call of the model.
         ``kv_limit`` is a static bound on the positions this call can
         reach (a prefill stays inside ``max_prompt_len``), for a model
         whose prefill reads its context back from the pool; None is the
-        whole page table. ``counters`` is what the layers counted, summed
-        (int32 [len(spec.counters)]), or None for a model that counts
-        nothing."""
-        caches = [dict(pl, page_table=table, ctx_lens=ctx, valid=valid,
-                       kv_limit=kv_limit) for pl in pools]
+        whole page table. ``slots`` [rows] names the slot of each row, for
+        a model that keeps something a slot (None: row i is slot i).
+        ``counters`` is what the layers counted, summed (int32
+        [len(spec.counters)]), or None for a model that counts nothing."""
+        shared = dict(page_table=table, ctx_lens=ctx, valid=valid,
+                      kv_limit=kv_limit)
+        if self._slot_state:
+            shared["slots"] = None if slots is None else jnp.reshape(
+                slots.astype(jnp.int32), (-1,))
+        caches = [dict(pl, **shared) for pl in pools]
         (logits, new_caches), _ = self.model.functional_call(
             p_arrays, {}, Tensor(ids), caches=caches)
-        new_pools = [{k: c[k] for k in self._pool_keys}
-                     for c in new_caches]
+        new_pools = [{k: c[k] for k in keys}
+                     for c, keys in zip(new_caches, self._layer_keys)]
         counters = None
         if self._n_counters:
             counters = sum(c["counters"] for c in new_caches
@@ -892,11 +914,13 @@ class ServingEngine:
         return jnp.concatenate([jnp.atleast_1d(tok), counters])
 
     def _prefill_impl(self, p_arrays, pools, padded_ids, tail_len, ctx0,
-                      page_row, rid):
+                      page_row, rid, slot):
         """One request's uncached prompt tail in one pass: padded_ids
         [bucket], tail_len scalar (real tail tokens), ctx0 scalar (tokens
-        already resident from the prefix cache; 0 on a cold prefill),
-        page_row [pages_per_seq]. The tail's queries enter at positions
+        already resident from the prefix cache or an earlier chunk; 0 on
+        a cold prefill), page_row [pages_per_seq], slot scalar (the
+        request's slot: the row of what a model keeps a slot; a model of
+        pages alone never reads it). The tail's queries enter at positions
         ``ctx0 .. ctx0 + tail_len - 1`` against the slot's page table —
         the cached prefix is attended through the same ragged-masked
         gather decode uses. Returns (new_pools, first sampled token).
@@ -907,7 +931,7 @@ class ServingEngine:
         valid = (jnp.arange(n, dtype=jnp.int32) < tail_len)[None, :]
         logits, new_pools, counters = self._run_model(
             p_arrays, pools, table, ctx, valid, padded_ids[None, :],
-            kv_limit=self.config.max_prompt_len)
+            kv_limit=self.config.max_prompt_len, slots=slot)
         with jax.named_scope("sample"):
             last = logits[0, tail_len - 1, :]
             if self.config.do_sample:
@@ -1643,7 +1667,7 @@ class ServingEngine:
         final = start + n >= req.prompt_len
         prog = self._prefill_program(n)
         bucket = prog.tokens
-        with att.span("prefill.upload", rid=req.rid, bytes=4 * bucket + 12
+        with att.span("prefill.upload", rid=req.rid, bytes=4 * bucket + 16
                       + self.cache.page_table[req.slot].nbytes):
             args = self._prefill_args(prog, req.slot, req.rid,
                                       req.prompt[start:start + n], start)
@@ -1762,15 +1786,15 @@ class ServingEngine:
         """The prefill program's operands: ``ids`` (the prompt tokens this
         launch computes, for the request in ``slot``) right-padded to the
         program's bucket, their count, the tokens already resident
-        (``start``: the queries enter there), the slot's page-table row
-        and the request id (its PRNG stream)."""
+        (``start``: the queries enter there), the slot's page-table row,
+        the request id (its PRNG stream) and the slot itself."""
         padded = np.full(prog.tokens, self.config.pad_token_id, np.int32)
         padded[:len(ids)] = ids
         return (self._p, self.cache.pools, jnp.asarray(padded),
                 jnp.asarray(len(ids), jnp.int32),
                 jnp.asarray(start, jnp.int32),
                 jnp.asarray(self.cache.page_table[slot]),
-                jnp.asarray(rid, jnp.int32))
+                jnp.asarray(rid, jnp.int32), jnp.asarray(slot, jnp.int32))
 
     def _decode_args(self, active=None, override=None) -> tuple:
         """The decode program's operands as a launch uploads them: the

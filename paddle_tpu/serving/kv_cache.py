@@ -8,7 +8,15 @@ page_size, heads, head_dim]``, a latent-attention model one ``kv_pool`` of
 functionally (``.at[]`` scatters in kernels/paged_attention.py and
 kernels/latent_paged_attention.py) so the whole cache threads through the
 engine's jitted step. Nothing here reads a leaf by name: the movers (swap,
-copy-on-write, spill) thread ``cfg.pool_leaf_keys`` in order. The host side is bookkeeping only: a refcounted block allocator
+copy-on-write, spill) thread ``cfg.pool_leaf_keys`` in order. A model whose
+layers keep different things states its leaves BY LAYER
+(``leaves_by_layer``), and a third kind of leaf beside a token's and a
+page's: what a SLOT keeps whatever its length (``CacheLeaf.per_slot``: a
+recurrent layer's state, ``[max_batch, ...]``), which no allocator hands
+out, which the model zeroes in the program when a request starts at
+position 0, which ``swap_out`` / ``swap_in`` carry in the ``SwapHandle``
+beside the slot's pages, and which makes the pages unshareable by prefix.
+The host side is bookkeeping only: a refcounted block allocator
 and per-slot page tables, mirrored into a dense ``[max_batch,
 pages_per_seq]`` int32 array each step — static shape, so table churn never
 recompiles.
@@ -227,6 +235,10 @@ class SwapHandle:
     v: np.ndarray | None = None
     k_scale: np.ndarray | None = None
     v_scale: np.ndarray | None = None
+    # what the SLOT kept beside its pages (a recurrent model's state): one
+    # array a per-slot leaf, in ``slot_leaf_keys`` order, each stacked over
+    # the layers that keep it; empty for a pool of pages alone
+    state: tuple = ()
 
     @property
     def arrays(self) -> tuple:
@@ -234,7 +246,7 @@ class SwapHandle:
 
     @property
     def nbytes(self) -> int:
-        return sum(a.nbytes for a in self.arrays)
+        return sum(a.nbytes for a in self.arrays + tuple(self.state))
 
 
 @dataclass(eq=False)  # ndarray fields: identity semantics (lint rule PT001)
@@ -352,13 +364,19 @@ def prefix_digest(tokens, page_size: int) -> tuple:
 @dataclass(frozen=True)
 class CacheLeaf:
     """One leaf of a layer's pool, as the MODEL states it: what a token
-    keeps (``shape``, behind the pool's ``[num_pages, page_size]``), or
-    with ``per_page`` what a page keeps (behind ``[num_pages]``: the int8
-    pool's scales)."""
+    keeps (``shape``, behind the pool's ``[num_pages, page_size]``), with
+    ``per_page`` what a page keeps (behind ``[num_pages]``: the int8
+    pool's scales), or with ``per_slot`` what a SLOT keeps whatever its
+    length (behind ``[max_batch]``: a recurrent layer's state). No
+    allocator hands a per-slot leaf out and no page table names it: row
+    ``s`` is slot ``s``'s, the model zeroes it in the program when a
+    request starts at position 0, and a swap carries it with the slot's
+    pages."""
     name: str
     shape: tuple
     dtype: object
     per_page: bool = False
+    per_slot: bool = False
 
     @property
     def nbytes(self) -> int:
@@ -377,13 +395,23 @@ class PagedCacheSpec:
     model can say it: ``(num_query_tokens, pages_per_seq, page_size) ->
     (ctx_lens [rows] -> pages [rows])``, the pages one layer's attention
     copies out of the pool for a launch, by the path its dispatch takes
-    (the engine's ``serving_attention_pages_staged_total``)."""
+    (the engine's ``serving_attention_pages_staged_total``).
+
+    ``leaves`` is what every layer keeps; a model whose layers differ by
+    kind gives ``leaves_by_layer`` instead, one tuple of leaves a layer
+    (a hybrid: ``k_pool`` / ``v_pool`` in its attention layers, a state a
+    slot in its recurrent ones). ``no_prefix_sharing``, where the model's
+    pages cannot be shared by token prefix, is the reason (a page of keys
+    without the state that went with its last token is a wrong answer):
+    the engine refuses ``enable_prefix_caching`` with it."""
     num_layers: int
     max_seq_len: int
     dtype: object
-    leaves: tuple
+    leaves: tuple = ()
     counters: tuple = ()
     pages_staged: object = None
+    leaves_by_layer: tuple | None = None
+    no_prefix_sharing: str = ""
 
 
 def kv_heads_leaves(num_heads: int, head_dim: int, dtype=None,
@@ -431,6 +459,9 @@ class PagedCacheConfig:
     leaves: tuple | None = None  # CacheLeaf a pool leaf, as the model's
     # PagedCacheSpec states them; None: the keys-and-values pool of
     # num_heads x head_dim in ``dtype`` (int8 + scales when quantized)
+    leaves_by_layer: tuple | None = None  # one tuple of CacheLeaf a layer,
+    # for a model whose layers keep different things (``leaves`` is then
+    # not read)
 
     @property
     def quantized(self) -> bool:
@@ -438,30 +469,55 @@ class PagedCacheConfig:
 
     @property
     def layer_leaves(self) -> tuple:
-        """The CacheLeaf of every leaf of one layer's pool, in the fixed
-        order that the engine and the movers thread them in."""
-        if self.leaves is not None:
-            return self.leaves
-        return kv_heads_leaves(self.num_heads, self.head_dim, self.dtype,
-                               self.quantized)
+        """One tuple of CacheLeaf a layer: what each layer's pool holds,
+        in the fixed order that the engine and the movers thread them
+        in."""
+        if self.leaves_by_layer is not None:
+            if len(self.leaves_by_layer) != self.num_layers:
+                raise ValueError(
+                    f"leaves_by_layer states {len(self.leaves_by_layer)} "
+                    f"layers, num_layers is {self.num_layers}")
+            return tuple(tuple(ls) for ls in self.leaves_by_layer)
+        one = self.leaves if self.leaves is not None else kv_heads_leaves(
+            self.num_heads, self.head_dim, self.dtype, self.quantized)
+        return (tuple(one),) * self.num_layers
+
+    def _leaf_keys(self, per_slot: bool) -> tuple:
+        names = [lf.name for ls in self.layer_leaves for lf in ls
+                 if lf.per_slot == per_slot]
+        return tuple(dict.fromkeys(names))
 
     @property
     def pool_leaf_keys(self) -> tuple:
-        """The per-layer pool dict's leaf names, in a fixed order — the
-        engine and the movers use this to stay layout-agnostic."""
-        return tuple(leaf.name for leaf in self.layer_leaves)
+        """The names of the PAGED leaves (a token's and a page's) of the
+        layers that have them, in a fixed order — the movers (swap,
+        copy-on-write, spill) thread these and stay layout-agnostic."""
+        return self._leaf_keys(per_slot=False)
+
+    @property
+    def slot_leaf_keys(self) -> tuple:
+        """The names of the per-slot leaves, in a fixed order; empty for a
+        pool of pages alone."""
+        return self._leaf_keys(per_slot=True)
 
     @property
     def kv_bytes_per_token(self) -> int:
-        """Device bytes one resident token costs across all layers (every
-        per-token leaf, plus the per-page leaves amortized per token) —
-        the ``serving_kv_bytes_per_token`` gauge."""
-        leaves = self.layer_leaves
+        """Device bytes one resident token costs across the layers that
+        page (every per-token leaf, plus the per-page leaves amortized per
+        token) — the ``serving_kv_bytes_per_token`` gauge."""
+        leaves = [lf for ls in self.layer_leaves for lf in ls
+                  if not lf.per_slot]
         per_token = sum(lf.nbytes for lf in leaves if not lf.per_page)
         per_page = sum(lf.nbytes for lf in leaves if lf.per_page)
-        return self.num_layers * per_token + (
-            self.num_layers * per_page + self.page_size - 1
-        ) // self.page_size
+        return per_token + (per_page + self.page_size - 1) // self.page_size
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Device bytes a slot keeps whatever its length, across the
+        layers that keep a state — the ``serving_state_bytes_per_slot``
+        gauge; 0 for a pool of pages alone."""
+        return sum(lf.nbytes for ls in self.layer_leaves for lf in ls
+                   if lf.per_slot)
 
     @property
     def max_tokens_per_seq(self) -> int:
@@ -474,8 +530,9 @@ class PagedCacheConfig:
 
 def init_pools(cfg: PagedCacheConfig) -> list[dict]:
     """Per-layer dicts of the pool's leaves (``cfg.layer_leaves``: GPT's
-    {k_pool, v_pool}, a latent model's {kv_pool}), device arrays,
-    zero-filled; quantized pools add the zero-initialized {k_scale,
+    {k_pool, v_pool}, a latent model's {kv_pool}, a recurrent layer's
+    per-slot {ssm_state, conv_state} behind ``[max_batch]``), device
+    arrays, zero-filled; quantized pools add the zero-initialized {k_scale,
     v_scale} leaves (a zero scale marks an all-zero page — the write path
     substitutes 1.0 before any division). Under tensor parallelism every
     leaf is CREATED under its heads-axis sharding — each device allocates
@@ -487,14 +544,16 @@ def init_pools(cfg: PagedCacheConfig) -> list[dict]:
     pool_sh, scale_sh = (cfg.tp.pool_shardings() if cfg.tp is not None
                          else (None, None))
 
-    def layer():
-        return {lf.name: jnp.zeros(
+    def leaf(lf):
+        if lf.per_slot:
+            return jnp.zeros((cfg.max_batch,) + tuple(lf.shape), lf.dtype)
+        return jnp.zeros(
             (cfg.num_pages,) + (() if lf.per_page else (cfg.page_size,))
             + tuple(lf.shape), lf.dtype,
             device=scale_sh if lf.per_page else pool_sh)
-            for lf in cfg.layer_leaves}
 
-    return [layer() for _ in range(cfg.num_layers)]
+    return [{lf.name: leaf(lf) for lf in leaves}
+            for leaves in cfg.layer_leaves]
 
 
 class PagedKVCache:
@@ -513,6 +572,14 @@ class PagedKVCache:
             raise ValueError(
                 "host_tier_bytes spills INDEXED prefix pages — it needs "
                 "enable_prefix_caching=True (nothing would ever spill)")
+        if cfg.slot_leaf_keys and cfg.enable_prefix_caching:
+            raise ValueError(
+                f"a pool with per-slot leaves {cfg.slot_leaf_keys} cannot "
+                "share pages by prefix: no snapshot of the slot's state at "
+                "a page boundary goes with a shared page")
+        if cfg.slot_leaf_keys and cfg.tp is not None:
+            raise ValueError("per-slot leaves have no placement under "
+                             "tensor parallelism")
         self.cfg = cfg
         self.allocator = PageAllocator(cfg.num_pages)
         # under tensor parallelism the pools' heads axis is sharded across
@@ -564,6 +631,19 @@ class PagedKVCache:
         shapes mean each compiles exactly once for the cache's lifetime."""
         return {k: g.traces for k, g in self.guards.items()}
 
+    def _stack_rows(self, keys) -> list:
+        """For each layer ``{leaf: its row}`` among the layers that keep
+        that leaf of ``keys``: where a layer's leaf stands in the array a
+        mover stacks over those layers."""
+        count = dict.fromkeys(keys, 0)
+        rows = []
+        for leaves in self.cfg.layer_leaves:
+            names = {lf.name for lf in leaves}
+            rows.append({k: count[k] for k in keys if k in names})
+            for k in rows[-1]:
+                count[k] += 1
+        return rows
+
     def _build_jits(self) -> None:
         import jax.numpy as jnp
 
@@ -571,26 +651,39 @@ class PagedKVCache:
 
         quantized = self.cfg.quantized
         keys = self.cfg.pool_leaf_keys
+        # the movers move PAGED leaves, of the layers that have them: a
+        # stacked array's row ``rows[i][k]`` is layer i's leaf k. Where
+        # every layer keeps every leaf (GPT, a latent model) row i is
+        # layer i and these are the programs they always were; a layer's
+        # per-slot leaves pass through untouched
+        rows = self._stack_rows(keys)
 
-        def gather(pools, idx):
-            # one stacked array a pool leaf, in ``pool_leaf_keys`` order.
-            # Index each layer BEFORE stacking: stacking whole pools would
-            # materialize an O(pool) concatenate per swap event — the exact
-            # cost this jit exists to avoid; this way only the gathered
-            # pages ([layers, pages_per_seq, ...]) are ever copied.
-            # Quantized pools move their raw codes + the touched pages'
-            # scale rows — never dequantized, so a round-trip is bit-exact.
-            return tuple(jnp.stack([pl[k][idx] for pl in pools])
-                         for k in keys)
+        def gather_of(keys):
+            # one stacked array a leaf of ``keys``, over the layers that
+            # keep it. Index each layer BEFORE stacking: stacking whole
+            # pools would materialize an O(pool) concatenate per swap event
+            # — the exact cost this jit exists to avoid; this way only the
+            # gathered rows are ever copied. Quantized pools move their raw
+            # codes + the touched pages' scale rows — never dequantized, so
+            # a round-trip is bit-exact.
+            return lambda pools, idx: tuple(
+                jnp.stack([pl[k][idx] for pl in pools if k in pl])
+                for k in keys)
 
-        def scatter(pools, idx, *stacked):
-            return [{k: pl[k].at[idx].set(a[i])
-                     for k, a in zip(keys, stacked)}
-                    for i, pl in enumerate(pools)]
+        def scatter_of(keys, rows):
+            def scatter(pools, idx, *stacked):
+                by_key = dict(zip(keys, stacked))
+                return [dict(pl, **{k: pl[k].at[idx].set(by_key[k][r])
+                                    for k, r in at.items()})
+                        for pl, at in zip(pools, rows)]
+            return scatter
+
+        gather, scatter = gather_of(keys), scatter_of(keys, rows)
 
         def copy_page(pools, src, dst):
-            return [{k: pl[k].at[dst].set(pl[k][src]) for k in keys}
-                    for pl in pools]
+            return [dict(pl, **{k: pl[k].at[dst].set(pl[k][src])
+                                for k in at})
+                    for pl, at in zip(pools, rows)]
 
         # gather READS the pools — donation would delete the other
         # sequences' live KV; scatter and COW consume them: without
@@ -621,6 +714,21 @@ class PagedKVCache:
         self.guards = {"swap_gather": self._gather_jit,
                        "swap_scatter": self._scatter_jit,
                        "cow_copy": self._copy_jit}
+        slot_keys = self.cfg.slot_leaf_keys
+        if not slot_keys:
+            return
+        # what a SLOT keeps rides with its pages through a swap: row
+        # ``slot`` of every per-slot leaf, stacked over the layers that
+        # keep it (the slot is an operand, so one trace serves them all)
+        state_gather = gather_of(slot_keys)
+        state_scatter = scatter_of(slot_keys, self._stack_rows(slot_keys))
+        self._state_gather_jit = CompileGuard(  # lint: disable=PT006
+            state_gather, "state_gather", budget=1, strict=strict)
+        self._state_scatter_jit = CompileGuard(
+            state_scatter, "state_scatter", budget=1, strict=strict,
+            donate_argnums=(0,))
+        self.guards.update(state_gather=self._state_gather_jit,
+                           state_scatter=self._state_scatter_jit)
 
     # ------------------------------------------------------------- sizing
     def pages_for(self, num_tokens: int) -> int:
@@ -781,6 +889,11 @@ class PagedKVCache:
         from the root."""
         import jax.numpy as jnp
 
+        if self.cfg.slot_leaf_keys:
+            raise ValueError(
+                "a page of a pool with per-slot leaves "
+                f"{self.cfg.slot_leaf_keys} cannot cross the wire: it "
+                "would need the slot's state at its last token with it")
         pages = self.match_prefix(tokens)
         parent = self._page_serial[pages[-1]] if pages else 0
         spilled = self._match_host_tail(tokens, parent, len(pages),
@@ -821,7 +934,7 @@ class PagedKVCache:
             raise ValueError(
                 "import_spilled_chain needs the host tier "
                 "(host_tier_bytes > 0) as its landing zone")
-        want_dtype = np.dtype(self.cfg.layer_leaves[0].dtype)
+        want_dtype = np.dtype(self.cfg.layer_leaves[0][0].dtype)
         n_leaves = len(self.cfg.pool_leaf_keys)
         by_parent: dict[int, SpilledPage] = {}
         for e in entries:
@@ -1137,7 +1250,11 @@ class PagedKVCache:
         n = len(pages)
         got = self._gather_jit(self.pools,
                                jnp.asarray(self._padded_idx(pages)))
-        handle = SwapHandle(n_pages=n, **_by_field(
+        state = ()
+        if self.cfg.slot_leaf_keys:
+            state = tuple(np.asarray(a) for a in self._state_gather_jit(
+                self.pools, jnp.asarray(slot, jnp.int32)))
+        handle = SwapHandle(n_pages=n, state=state, **_by_field(
             [np.asarray(a)[:, :n].copy() for a in got]))
         self.release(slot)
         return handle
@@ -1163,6 +1280,12 @@ class PagedKVCache:
             args.append(jnp.asarray(full))
         # pad rows scatter zeros into the null page — never read unmasked
         self.pools = self._scatter_jit(self.pools, *args)
+        if handle.state:
+            # the slot may be another than the one swapped out: the state
+            # goes where the pages' table row now is
+            self.pools = self._state_scatter_jit(
+                self.pools, jnp.asarray(slot, jnp.int32),
+                *(jnp.asarray(a) for a in handle.state))
         self._slot_pages[slot] = pages
         self.page_table[slot, :] = NULL_PAGE
         self.page_table[slot, :len(pages)] = pages
@@ -1198,7 +1321,11 @@ class PagedKVCache:
                 "host_tier_bytes": t.bytes if t is not None else 0,
                 "host_tier_hits": self.host_tier_hits,
                 "host_tier_spills": self.spills,
-                "host_tier_restores": self.restores}
+                "host_tier_restores": self.restores,
+                # what the slots keep beside their pages (0 a slot for a
+                # pool of pages alone)
+                "slots_live": len(self._slot_pages),
+                "state_bytes_per_slot": self.cfg.state_bytes_per_slot}
 
     # --------------------------------------------------------- invariants
     def check_invariants(self) -> None:
@@ -1227,6 +1354,14 @@ class PagedKVCache:
         holds = Counter(held)
         assert all(holds[p] <= a.refcount(p) for p in holds), \
             "a page table may never hold more references than its refcount"
+        assert all(0 <= s < self.cfg.max_batch for s in self._slot_pages), \
+            "a live slot is a row of the per-slot leaves"
+        for pl, leaves in zip(self.pools, self.cfg.layer_leaves):
+            for lf in leaves:
+                if lf.per_slot:
+                    assert pl[lf.name].shape == (self.cfg.max_batch,) \
+                        + tuple(lf.shape), \
+                        f"per-slot leaf {lf.name} is not [max_batch, ...]"
         if self.host_tier is not None:
             t = self.host_tier
             assert t.bytes == sum(e.nbytes for e in t._entries.values()), \

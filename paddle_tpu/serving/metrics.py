@@ -71,10 +71,22 @@ KV quantization + host cache tier (pre-seeded like everything else):
                                   x top-k; those routed to experts held
                                   here; held experts x layers x launches;
                                   of those, the ones that got a token
+- serving_ssm_state_rows_live_total, serving_ssm_state_rows_moved_total
+                                  a recurrent-state model's counts, summed
+                                  over its state layers a launch and
+                                  fetched with the launch's tokens: slots
+                                  whose state the launch advanced, and the
+                                  slots' state rows the update read and
+                                  wrote (equal where dead slots are
+                                  skipped)
 - serving_kv_bytes_per_token      gauge: device bytes one resident token
-                                  costs across layers (codes + amortized
-                                  scales), set at construction — 4x lower
-                                  under kv_dtype="int8"
+                                  costs across the layers that page (codes
+                                  + amortized scales), set at construction
+                                  — 4x lower under kv_dtype="int8"
+- serving_state_bytes_per_slot    gauge: device bytes a slot keeps whatever
+                                  its length (a recurrent model's state
+                                  leaves over its state layers; 0 for a
+                                  pool of pages alone), set at construction
 - serving_host_tier_pages         gauge: spilled prefix pages resident in
                                   the host tier now
 - serving_host_tier_bytes         gauge: host bytes the tier holds now
@@ -296,11 +308,13 @@ _SEEDED = ("tokens_total", "prefills_total", "prefill_tokens_total",
            "prefix_cow_copies", "prefix_evictions",
            "spec_depth", "spec_proposed_tokens_total",
            "spec_accepted_tokens_total", "spec_acceptance_rate",
-           "kv_bytes_per_token", "host_tier_pages", "host_tier_bytes",
+           "kv_bytes_per_token", "state_bytes_per_slot",
+           "host_tier_pages", "host_tier_bytes",
            "host_tier_hits_total", "host_tier_spills_total",
            "host_tier_restores_total",
            "moe_assignments_total", "moe_local_assignments_total",
            "moe_expert_slots_total", "moe_expert_hits_total",
+           "ssm_state_rows_live_total", "ssm_state_rows_moved_total",
            "pallas_fallback_total",
            "flash_pad_total", "flash_edge_fallback_total",
            "analysis_retraces_total", "analysis_host_syncs_total",
@@ -632,6 +646,11 @@ class ServingMetrics:
         construction — a static consequence of kv_dtype + the model
         shape, the denominator capacity dashboards divide HBM by)."""
         monitor.stat_set(PREFIX + "kv_bytes_per_token", int(nbytes))
+
+    def on_state_bytes_per_slot(self, nbytes: int) -> None:
+        """Device bytes a slot keeps whatever its length (set once at
+        engine construction, from the pool's per-slot leaves)."""
+        monitor.stat_set(PREFIX + "state_bytes_per_slot", int(nbytes))
 
     def on_state(self, queue_depth: int, active: int, pages_used: int,
                  usable_pages: int, shared_pages: int = 0,

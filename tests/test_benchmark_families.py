@@ -33,6 +33,12 @@ TINY_KIMI = dict(vocab_size=96, hidden_size=64, intermediate_size=160,
                  kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16,
                  qk_rope_head_dim=8, v_head_dim=16,
                  max_position_embeddings=64)
+TINY_GRANITE = dict(vocab_size=96, hidden_size=32, num_hidden_layers=8,
+                    layer_types=["mamba", "mamba", "attention", "mamba"] * 2,
+                    num_attention_heads=4, num_key_value_heads=2,
+                    shared_intermediate_size=48, mamba_n_heads=4,
+                    mamba_d_head=16, mamba_d_state=8, mamba_chunk_size=8,
+                    max_position_embeddings=64)
 
 
 def load(path):
@@ -41,7 +47,8 @@ def load(path):
 
 
 def tiny_model(config: dict) -> dict:
-    tiny = {"gpt3": TINY_GPT3, "kimi_k2": TINY_KIMI}[config["family"]]
+    tiny = {"gpt3": TINY_GPT3, "kimi_k2": TINY_KIMI,
+            "granite_hybrid": TINY_GRANITE}[config["family"]]
     return dict(config["model"], **tiny)
 
 
@@ -57,6 +64,13 @@ def program_model(config: dict, model: dict):
                 num_layers=model["num_layers"],
                 num_heads=model["num_heads"],
                 max_seq_len=model["max_seq_len"]))
+    if config["family"] == "granite_hybrid":
+        from benchmark.families import granite_hybrid
+        from paddle_tpu.text.granite_hybrid import GraniteHybridForCausalLM
+
+        with paddle.LazyGuard():
+            return GraniteHybridForCausalLM(
+                granite_hybrid.program_config(model))
     from benchmark.families import kimi_k2
     from paddle_tpu.text.kimi_k2 import KimiK2ForCausalLM
 
@@ -151,6 +165,51 @@ def test_kimi_k2_copy_gives_an_expert_its_tokens_or_raises():
     with pytest.raises(ValueError, match="an expert was routed"):
         kimi_k2.logits_at(leaves_of, jnp.asarray(ids),
                           jnp.asarray([[5, 1535]] * 3, jnp.int32), model)
+
+
+def test_granite_hybrid_copy_of_the_reference_gives_the_repos_logits():
+    """The same float32 leaves, with the family's initialisation added
+    (``as_used``), through ``benchmark/families/granite_hybrid.py``'s
+    ``logits_at`` (a layer's leaves at a time, the recurrence stopped at
+    the block's last position asked for, zeros padded behind the rows) and
+    through ``tests/refs/granite_hybrid_reference.py``: the same logits,
+    to float32 round-off of values of order 0.1 (2e-6)."""
+    import granite_hybrid_reference as ref
+
+    from benchmark.families import granite_hybrid
+    from benchmark.lib import weights
+
+    config = {"model": dict(load(os.path.join(
+        ROOT, "benchmark", "configs",
+        "granite-4.0-h-micro-serve.json"))["model"], **TINY_GRANITE),
+        "precision": {"parameters": "bfloat16"}}
+    model = config["model"]
+    leaves_of = weights.for_reference(granite_hybrid, config,
+                                      seed=2147483659)
+    rng = np.random.default_rng(0)
+    ids = np.zeros((2, 40), np.int32)
+    ids[:, :24] = rng.integers(1, 96, (2, 24))
+    positions = jnp.asarray([[3, 10, 23], [0, 5, 22]], jnp.int32)
+    got = granite_hybrid.logits_at(leaves_of, jnp.asarray(ids), positions,
+                                   model)
+    cfg = granite_hybrid.program_config(model)
+    used = granite_hybrid.as_used(leaves_of())
+    want = ref.forward(used, jnp.asarray(ids[:, :24]), cfg)
+    want = jnp.take_along_axis(want, positions[..., None], axis=1)
+    assert got.shape == want.shape == (2, 3, 96)
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+    # the initialisation the family adds: A over [1, 16], steps over
+    # [0.001, 0.1], the convolution's taps at a standard deviation of 0.29
+    pre = "model.layers.0.mamba."
+    a = np.exp(np.asarray(used[pre + "A_log"]))
+    assert 0.9 < a[0] < 1.1 and 15 < a[-1] < 17
+    step = np.log1p(np.exp(np.asarray(used[pre + "dt_bias"])))
+    assert 0.9e-3 < step[0] < 1.1e-3 and 0.09 < step[-1] < 0.11
+    assert 0.2 < float(np.std(np.asarray(used[pre + "conv1d.weight"]))) < 0.4
+    # the control's policy runs, and is not the reference
+    low = granite_hybrid.logits_at(leaves_of, jnp.asarray(ids), positions,
+                                   model, "fp8")
+    assert 1e-4 < float(jnp.max(jnp.abs(low - want))) < 1.0
 
 
 def test_gpt3_copy_of_the_reference_gives_the_programs_logits():

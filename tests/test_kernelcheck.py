@@ -439,12 +439,13 @@ def test_kernelcheck_certs_declarations_match_registry():
     PT011's declaration can't go stale in either direction."""
     from paddle_tpu.kernels import (flash_attention, fused_layernorm,
                                     fused_optimizer, latent_paged_attention,
-                                    paged_attention, ragged_paged_attention)
+                                    paged_attention, ragged_paged_attention,
+                                    ssm_state_update)
 
     declared = []
     for mod in (flash_attention, fused_layernorm, fused_optimizer,
                 latent_paged_attention, paged_attention,
-                ragged_paged_attention):
+                ragged_paged_attention, ssm_state_update):
         certs = mod.KERNELCHECK_CERTS
         assert certs, mod.__name__
         declared.extend(certs)

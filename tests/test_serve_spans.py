@@ -233,8 +233,8 @@ def test_request_spans_carry_rid_and_their_attributes(plain):
         assert hops["prefill_start"] == s.stats["step"]
     up = [s for s in spans if s.name == "prefill.upload"]
     row = engine.cache.page_table[0].nbytes
-    assert [s.stats["bytes"] for s in up] == [4 * 8 + row + 12,
-                                              4 * 16 + row + 12]
+    assert [s.stats["bytes"] for s in up] == [4 * 8 + row + 16,
+                                              4 * 16 + row + 16]
     decodes = [s for s in spans if s.name == "decode"]
     # batch = the slots launched: none in the last, which only fetches
     assert [s.stats["batch"] for s in decodes][-1] == 0
@@ -291,7 +291,7 @@ def test_attribute_counts(chunked, spec, cow):
                    if s.stats["rid"] == fetch.stats["rid"]) <= fetch.start
     # a chunk of 4 pads into the bucket of 8
     row = chunked["engine"].cache.page_table[0].nbytes
-    assert {s.stats["bytes"] for s in parts["upload"]} == {4 * 8 + row + 12}
+    assert {s.stats["bytes"] for s in parts["upload"]} == {4 * 8 + row + 16}
     verifies = [s for s in spec["spans"] if s.name == "verify"]
     assert verifies and all(s.stats["batch"] in (1, 2) for s in verifies)
     assert not [s for s in spec["spans"] if s.name.startswith("decode")]

@@ -219,3 +219,23 @@ def test_flash_runs_per_shard_under_a_training_mesh(topo, monkeypatch):
     for coll in ("all-reduce", "all-gather", "all-to-all",
                  "collective-permute"):
         assert f" {coll}(" not in text and f" {coll}-start(" not in text
+
+
+# ------------------------------------------------- the decode state update
+def test_ssm_decode_update_compiles_for_v5e_in_place(v5e):
+    """The Mamba-2 decode state update at granite-4.0-h-micro's widths
+    (64 slots x 64 heads x 64 x 128 float32): Mosaic takes its one-lane
+    column slices, and the 134 MB state is aliased, not copied."""
+    from paddle_tpu.kernels import ssm_state_update as su
+
+    slots, heads, p, n = 64, 64, 64, 128
+    f32 = jnp.float32
+    shapes = [((slots, heads, p, n), f32), ((slots, heads, p), f32),
+              ((slots, heads), f32), ((heads,), f32), ((slots, n), f32),
+              ((slots, n), f32), ((slots,), jnp.bool_)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    compiled = jax.jit(su.ssm_decode_update, donate_argnums=(0,)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == slots * heads * p * n * 4
