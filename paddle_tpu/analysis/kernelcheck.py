@@ -843,6 +843,11 @@ def _build_splash():
 _PAGED_SHAPE = dict(batch=2, heads=2, head_dim=128, num_pages=64,
                     page_size=16, pages_per_seq=32)
 
+#: granite-4.0-h-micro's attention: 32 query heads over 8 KV heads of 64,
+#: pages of 16, a table of 96 (the coverage report's grouped-head rows)
+_GQA_SHAPE = dict(heads=32, kv_heads=8, head_dim=64, page_size=16,
+                  pages_per_seq=96)
+
 
 def _build_paged_decode():
     import jax.numpy as jnp
@@ -1028,6 +1033,54 @@ def _build_mla_decode():
         composite=composite, composite_args=args)
 
 
+def _build_gqa_decode():
+    """The grouped-head decode kernel at a canonical serving shape: 2 rows
+    of 8 query heads over 2 KV heads of 64 (a lane-dense pool row of 128),
+    32 pages a row staged a chunk at a time, keys and values in the two
+    halves of one staging buffer. The same pipeline as ``mla_decode``,
+    given a values pool. What is certified is the kernel call with the
+    block-diagonal queries built and each head's own columns picked off
+    around it, as the dispatch runs it; the composite is
+    ``_grouped_composite`` on the same pools."""
+    import jax.numpy as jnp
+
+    from ..kernels import paged_attention as pa
+    from ..kernels import paged_decode as pd
+
+    b, heads, kv, d, ps, pps, npages = 2, 8, 2, 64, 16, 32, 64
+    q = _sds((b, heads, 1, d), jnp.float32)
+    pool = _sds((npages, ps, kv * d), jnp.float32)
+    table = _sds((b, pps), jnp.int32)
+    ctx = _sds((b,), jnp.int32)
+    ok, why = pd.gqa_kernel_eligible(heads, kv, d, ps, pps, itemsize=4)
+    constraints = (
+        ("gqa_kernel_eligible", ok, why or
+         "the canonical shape must pass the grouped-head kernel's gate"),
+        ("decode_only",
+         not pd.gqa_kernel_eligible(heads, kv, d, ps, pps, 64)[0],
+         "a call of several tokens a row must take the composite"),
+        ("lane_dense_pool_only",
+         not pd.gqa_kernel_eligible(heads, kv, d, ps, pps,
+                                    flat_pool=False)[0],
+         "a pool with a heads axis must take the composite"),
+    )
+
+    def fn(q, kp, vp, t, c):
+        return pd.gqa_decode_attention(q, kp, vp, t, c, 0.125)
+
+    def composite(q, kp, vp, t, c):
+        return pa._grouped_composite(q, kp, vp, t, c, 0.125)
+
+    args = (q, pool, pool, table, ctx)
+    return dict(
+        fn=fn, args=args, budget=KernelBudget(), constraints=constraints,
+        # what the attention needs: scores and values over head_dim, all
+        # heads, the whole gathered width, x2 flops/MAC (the kernel's
+        # block-diagonal products do kv times that)
+        flops=float(4 * b * heads * ps * pps * d),
+        composite=composite, composite_args=args)
+
+
 def _build_ssm_decode_update():
     """The decode state-update kernel at a canonical serving shape: 4
     slots of 8 heads x 16 x 128 float32 state, all heads a block, so a slot
@@ -1193,6 +1246,11 @@ REGISTRY: dict[str, KernelSpec] = {s.name: s for s in (
                "a latent paged pool: one grid step a row, the row's live "
                "pages staged once for all heads through two alternating "
                "buffers, online softmax", _build_mla_decode),
+    KernelSpec("gqa_decode", "grouped-head decode attention over a lane-"
+               "dense paged pool: the mla_decode pipeline given a values "
+               "pool, a row's live pages staged once for all query heads, "
+               "block-diagonal queries so that no slice is narrower than a "
+               "lane row", _build_gqa_decode),
     KernelSpec("ssm_decode_update", "Mamba-2 decode state update: a grid "
                "step a slot brings the slot's float32 state to VMEM once, "
                "advances it in place and reads it out; dead slots name "
@@ -1354,6 +1412,22 @@ def coverage_report() -> dict:
                            f"mode={mode}"),
                 "path": "pallas" if ok else "composite",
                 "blocked_by": why})
+    # grouped KV heads over a lane-dense pool (granite-4.0-h-micro's
+    # attention layers): the grouped branch's own gate, the predicate
+    # paged_attention's dispatch asks
+    from ..kernels import paged_decode as pd
+
+    g = _GQA_SHAPE
+    for mode, nq in (("decode", 1), ("prefill[512]", 512)):
+        ok, why = pd.gqa_kernel_eligible(
+            g["heads"], g["kv_heads"], g["head_dim"], g["page_size"],
+            g["pages_per_seq"], nq)
+        rows.append({
+            "family": "gqa_decode",
+            "config": f"platform=tpu pallas_flag=on kv_dtype=bfloat16 "
+                      f"mode={mode}",
+            "path": "pallas" if ok else "composite",
+            "blocked_by": why})
     for s in (1024, 640, 512):
         shape = (1, 8, s, 128)
         route = fa.flash_route(shape, shape, causal=True)

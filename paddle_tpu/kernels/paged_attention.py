@@ -18,6 +18,12 @@ paths, dispatched like kernels/attention.py:
    legacy reference (kernelcheck ``paged_decode``) but no longer serves
    dispatch.
 
+Grouped KV heads (more query heads than KV heads, or a lane-dense pool
+``[num_pages, page_size, kv_heads * head_dim]``) take a branch of their
+own: one new token a row runs the grouped-head decode kernel of
+:mod:`.paged_decode` behind its gate ``gqa_kernel_eligible``, anything
+else ``_grouped_composite``.
+
 Pool layout is ``[num_pages, page_size, num_heads, head_dim]`` per layer
 (serving/kv_cache.py owns allocation). Page 0 is reserved as the null page:
 writes from padding/inactive rows are routed there so a scatter can stay
@@ -42,7 +48,8 @@ import jax.numpy as jnp
 
 __all__ = ["paged_write", "paged_write_quant", "paged_gather",
            "paged_gather_quant", "paged_attention", "ragged_mask",
-           "decode_kernel_eligible", "pages_staged_fn", "QMAX"]
+           "decode_kernel_eligible", "pages_staged_fn",
+           "grouped_pages_staged_fn", "QMAX"]
 
 #: symmetric int8 code range: codes in [-127, 127], dequant = code*scale/127
 QMAX = 127.0
@@ -204,6 +211,35 @@ def _use_ragged_kernel(q, k_pool, page_table,
                             q.dtype.itemsize)
 
 
+def _gqa_dispatch(heads: int, kv_heads: int, head_dim: int,
+                  page_size: int, pages_per_seq: int, num_query_tokens: int,
+                  itemsize: int, flat_pool: bool) -> tuple[bool, bool]:
+    """Runtime dispatch gate of the grouped-head decode kernel:
+    ``(eligible, interpret)`` for a call at these shapes
+    (``paged_decode.gqa_kernel_eligible``, the one gate)."""
+    from ..utils.flags import flag
+    from ._common import on_tpu_backend
+    from .paged_decode import gqa_kernel_eligible
+
+    interp = bool(flag("FLAGS_ragged_interpret", False))
+    ok, _ = gqa_kernel_eligible(
+        heads, kv_heads, head_dim, page_size, pages_per_seq,
+        num_query_tokens, itemsize=itemsize, flat_pool=flat_pool,
+        on_tpu=on_tpu_backend(),
+        flags_on=bool(flag("FLAGS_use_pallas_kernels", True)),
+        interpret=interp)
+    return ok, interp
+
+
+def _use_gqa_kernel(q, k_pool, page_table) -> tuple[bool, bool]:
+    flat = k_pool.ndim == 3
+    d = q.shape[-1]
+    kv_heads = k_pool.shape[2] // d if flat else k_pool.shape[2]
+    return _gqa_dispatch(q.shape[1], kv_heads, d, k_pool.shape[1],
+                         page_table.shape[1], q.shape[2],
+                         k_pool.dtype.itemsize, flat)
+
+
 def pages_staged_fn(head_dim: int, num_heads: int, page_size: int,
                     pages_per_seq: int, num_query_tokens: int, *,
                     quantized: bool = False, q_itemsize: int = 4):
@@ -230,6 +266,27 @@ def pages_staged_fn(head_dim: int, num_heads: int, page_size: int,
         _rp.pages_staged, num_query_tokens=num_query_tokens,
         page_size=page_size, pages_per_seq=pages_per_seq,
         chunk_pages=chunk)
+
+
+def grouped_pages_staged_fn(heads: int, kv_heads: int, head_dim: int,
+                            page_size: int, pages_per_seq: int,
+                            num_query_tokens: int, *, itemsize: int = 2):
+    """:func:`pages_staged_fn` for a call with grouped KV heads over a
+    lane-dense pool: the decode kernel's live chunks where the call takes
+    it (one new token a row and the gate holds), the table's width where
+    the composite gathers it."""
+    import functools
+
+    from . import ragged_paged_attention as _rp
+    from .paged_decode import gqa_chunk_pages
+
+    ok, _ = _gqa_dispatch(heads, kv_heads, head_dim, page_size,
+                          pages_per_seq, num_query_tokens, itemsize, True)
+    return functools.partial(
+        _rp.pages_staged, num_query_tokens=num_query_tokens,
+        page_size=page_size, pages_per_seq=pages_per_seq,
+        chunk_pages=gqa_chunk_pages(page_size, pages_per_seq) if ok
+        else None, query_tile=1)
 
 
 def _pages_per_block(page_size: int) -> int:
@@ -294,7 +351,11 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
     path dequantizes through :func:`paged_gather_quant` instead. Either
     way nothing downstream of the gather knows the pool was compressed.
 
-    Dispatch: EVERY mode — prefill, chunked-prefill tail, decode,
+    Dispatch: grouped KV heads first (module docstring): a decode call
+    over a lane-dense pool runs ``gqa_decode_attention`` where
+    ``gqa_kernel_eligible`` holds, every other grouped call
+    ``_grouped_composite``. With a KV head a query head, EVERY mode —
+    prefill, chunked-prefill tail, decode,
     spec-verify, fp32 AND int8 — routes through the ONE unified ragged
     kernel (:mod:`.ragged_paged_attention`) when
     ``ragged_kernel_eligible`` holds; anything else (flag off, CPU
@@ -307,11 +368,22 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
     s = q.shape[2]
     quantized = k_scale is not None
     if k_pool.ndim == 3 or q.shape[1] != k_pool.shape[2]:
-        # grouped KV heads: the ragged kernel has no such path yet
-        # (ROADMAP R1), the composite folds a group's queries onto its KV
-        # head
+        # grouped KV heads: one new token a row over a lane-dense pool
+        # runs the grouped-head decode kernel (paged_decode.py); anything
+        # else (a prefill, a chunk's tail, a pool with a heads axis) the
+        # composite, which folds a group's queries onto its KV head. The
+        # ragged kernel has no such path yet (ROADMAP R1)
         if quantized:
             raise ValueError("grouped KV heads have no int8 path")
+        use_kernel, interpret = _use_gqa_kernel(q, k_pool, page_table)
+        if use_kernel:
+            from .paged_decode import gqa_decode_attention
+
+            d = q.shape[-1]
+            return gqa_decode_attention(
+                q, k_pool, v_pool, page_table, ctx_lens,
+                scale if scale is not None else d ** -0.5,
+                interpret=interpret)
         return _grouped_composite(q, k_pool, v_pool, page_table, ctx_lens,
                                   scale)
     use_kernel, interpret = _use_ragged_kernel(q, k_pool, page_table,

@@ -387,8 +387,9 @@ class GraniteAttention(nn.Layer):
                 k_pool, v_pool = pa.paged_write(
                     k_pool, v_pool, k.reshape(b, s, -1),
                     v.reshape(b, s, -1), page_ids, offsets)
-            # the dispatch as it stands: grouped heads take the composite
-            # path (kernels/paged_attention.py)
+            # grouped heads over the flat pool: a decode step runs the
+            # grouped-head kernel, a call of several tokens a row the
+            # composite (kernels/paged_attention.py's dispatch)
             o = pa.paged_attention(q, k_pool, v_pool, table, ctx,
                                    scale=scale)
             new_cache = dict(cache, k_pool=k_pool, v_pool=v_pool,
@@ -494,8 +495,11 @@ class GraniteHybridForCausalLM(nn.Layer):
     def decode_kernel_eligible(self, pages_per_seq: int, page_size: int,
                                quantized: bool = False) -> bool:
         """Whether the decode step's state update reaches its Pallas
-        kernel (``ssm_state_update.ssm_kernel_eligible``, the one gate);
-        the four attention layers take the composite path either way."""
+        kernel (``ssm_state_update.ssm_kernel_eligible``, the one gate).
+        The attention layers reach theirs by a gate of their own
+        (``paged_decode.gqa_kernel_eligible``, asked by
+        ``paged_attention``'s dispatch at every call; what they stage is
+        counted through ``paged_cache_spec``'s ``pages_staged``)."""
         from ..kernels._common import on_tpu_backend
         from ..utils.flags import flag
 
@@ -518,6 +522,7 @@ class GraniteHybridForCausalLM(nn.Layer):
         ``conv_state`` ``[d_conv - 1, conv_width]`` a SLOT. Its cache
         cannot be shared by prefix. Refuses, with the reason, what a model
         with a recurrent state cannot do yet."""
+        from ..kernels.paged_attention import grouped_pages_staged_fn
         from ..serving.kv_cache import CacheLeaf, PagedCacheSpec
 
         c = self.cfg
@@ -552,12 +557,20 @@ class GraniteHybridForCausalLM(nn.Layer):
                       per_slot=True),
             CacheLeaf("conv_state", (c.mamba_d_conv - 1, c.conv_width),
                       dtype, per_slot=True))
+
+        def pages_staged(num_query_tokens, pages_per_seq, page_size):
+            # an attention layer's worth, by the path the call takes
+            return grouped_pages_staged_fn(
+                c.num_attention_heads, c.num_key_value_heads, c.head_dim,
+                page_size, pages_per_seq, num_query_tokens,
+                itemsize=dtype.itemsize)
+
         return PagedCacheSpec(
             num_layers=c.num_hidden_layers,
             max_seq_len=c.max_position_embeddings, dtype=dtype,
             leaves_by_layer=tuple(mamba if t == "mamba" else attention
                                   for t in c.layer_types),
-            counters=SSM_COUNTERS,
+            counters=SSM_COUNTERS, pages_staged=pages_staged,
             no_prefix_sharing=(
                 "granite_hybrid: a shared page of keys and values needs "
                 "the recurrent state that went with its last token, and "

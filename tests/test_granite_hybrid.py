@@ -71,7 +71,13 @@ def ids_of(rng, *shape):
 
 
 @pytest.fixture
-def interpret():
+def interpret(monkeypatch):
+    """Both decode kernels (the state update and the attention layers'
+    grouped-head kernel) through the interpreter; the attention kernel's
+    chunk at 8 tokens, two pages, so that its loop turns."""
+    from paddle_tpu.kernels import paged_decode
+
+    monkeypatch.setattr(paged_decode, "_GQA_CHUNK_TOKENS", 2 * PAGE)
     before = flag("FLAGS_ragged_interpret", False)
     yield lambda on: set_flags({"FLAGS_ragged_interpret": on})
     set_flags({"FLAGS_ragged_interpret": before})
@@ -258,6 +264,69 @@ def test_engine_serves_the_reference_s_tokens_and_counts(interpret,
     eng.cache.check_invariants()
     st = eng.cache.stats()
     assert st["slots_live"] == 0 and st["state_bytes_per_slot"] == 18048
+    # the attention layers' pages are counted for this model: the decode
+    # launches through the kernel's live chunks, so under the table's
+    # width for every row of every launch
+    live = count("serving_attention_pages_live_total")
+    staged = count("serving_attention_pages_staged_total")
+    launches = prefills + count("serving_decode_steps")
+    assert 0 < live <= staged < PPS * (
+        prefills + SLOTS * count("serving_decode_steps"))
+    assert staged >= launches
+
+
+@pytest.mark.parametrize("kernel", [True, False],
+                         ids=["kernel", "composite"])
+def test_engine_counts_the_attention_layers_pages(interpret, monkeypatch,
+                                                  kernel):
+    """``serving_attention_pages_{live,staged}_total`` for this model: every
+    launch adds ``ceil((ctx + tokens) / page_size)`` over its real rows and
+    what an attention layer copies out of a pool for all its rows, by the
+    path the call takes: with the kernel a decode row stages its live
+    chunks of two pages (a dead slot one chunk), without it the table's
+    width; a prefill the table's width either way."""
+    from paddle_tpu.kernels import ragged_paged_attention as rp
+
+    interpret(kernel)
+    model, cfg, _ = build(**PLAIN)
+    eng = ServingEngine(model, ServingConfig(
+        max_batch=SLOTS, num_pages=PAGES, page_size=PAGE, max_prompt_len=32,
+        enable_prefix_caching=False))
+    launches = []
+    count = eng._count_attention_pages
+
+    def spy(ctx, s, tokens=None, live_rows=None):
+        launches.append((np.array(ctx, copy=True).reshape(-1), s,
+                         s if tokens is None else tokens,
+                         None if live_rows is None else live_rows.copy()))
+        count(ctx, s, tokens, live_rows)
+
+    monkeypatch.setattr(eng, "_count_attention_pages", spy)
+    pre = eng.metrics.snapshot()
+    rng = np.random.default_rng(9)
+    rids = [eng.add_request(ids_of(rng, n), 7) for n in (19, 6)]
+    out = {}
+    while len(out) < len(rids):
+        eng.step()
+        out.update(eng.pop_finished())
+    snap = eng.metrics.snapshot()
+    live, staged = (snap[k] - pre[k] for k in (
+        "serving_attention_pages_live_total",
+        "serving_attention_pages_staged_total"))
+    want_live = want_staged = 0
+    for ctx, s_, tokens, rows in launches:
+        per_row = -(-(ctx + tokens) // PAGE)
+        want_live += int((per_row if rows is None else per_row[rows]).sum())
+        want_staged += int(rp.pages_staged(
+            ctx, s_, page_size=PAGE, pages_per_seq=PPS,
+            chunk_pages=2 if kernel and s_ == 1 else None,
+            query_tile=1).sum())
+    assert {s_ for _, s_, _, _ in launches} == {1, 8, 32}
+    assert (live, staged) == (want_live, want_staged)
+    decode = [ctx for ctx, s_, _, _ in launches if s_ == 1]
+    assert max(int(c.max()) for c in decode) > 2 * PAGE   # past one chunk
+    if kernel:
+        assert 0 < live <= staged < PPS * sum(len(c) for c, *_ in launches)
 
 
 @pytest.mark.parametrize("mode", ["swap", "recompute"])

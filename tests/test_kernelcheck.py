@@ -40,7 +40,8 @@ def _run(name):
 
 FAST_FAMILIES = ("fused_layernorm_fwd", "fused_layernorm_dx", "fused_adam",
                  "paged_decode", "ragged_paged", "ragged_paged_q8",
-                 "ragged_paged_verify", "ragged_paged_prefill")
+                 "ragged_paged_verify", "ragged_paged_prefill",
+                 "mla_decode", "gqa_decode")
 
 
 # ------------------------------------------------------------ certification
@@ -333,6 +334,47 @@ def test_coverage_int8_decode_covered_and_head_dim_64_declared():
     assert not any("flash" in k and "640" in k for k in cov["kernel_less"])
 
 
+def test_coverage_names_the_grouped_branch_s_gate():
+    """Grouped KV heads over a lane-dense pool at granite-4.0-h-micro's
+    shape: a decode step reaches the grouped-head kernel, a prefill is
+    kernel-less and says why, in the words of the gate
+    ``paged_attention``'s grouped branch asks."""
+    from paddle_tpu.kernels import paged_decode as pd
+
+    cov = kc.coverage_report()
+    rows = {r["config"].split("mode=")[1]: r for r in cov["rows"]
+            if r["family"] == "gqa_decode"}
+    assert rows["decode"]["path"] == "pallas"
+    assert not rows["decode"]["blocked_by"]
+    g = kc._GQA_SHAPE
+    prefill = rows["prefill[512]"]
+    assert prefill["path"] == "composite"
+    assert prefill["blocked_by"] == pd.gqa_kernel_eligible(
+        g["heads"], g["kv_heads"], g["head_dim"], g["page_size"],
+        g["pages_per_seq"], 512)[1]
+    assert any(prefill["blocked_by"] in k for k in cov["kernel_less"])
+
+
+def test_gqa_decode_is_certified_against_the_grouped_composite():
+    """The grouped-head kernel's certificate: the gate's three constraints
+    hold, the banked record is the fresh one, and the composite it is
+    measured against materializes more than the kernel moves."""
+    import json
+
+    report, record = _run("gqa_decode")
+    assert report.ok
+    spec = kc.REGISTRY["gqa_decode"].build()
+    assert {c[0]: c[1] for c in spec["constraints"]} == {
+        "gqa_kernel_eligible": True, "decode_only": True,
+        "lane_dense_pool_only": True}
+    with open(kc.bank_path()) as fh:
+        banked = json.load(fh)
+    assert kc.diff_banked({"gqa_decode": record,
+                           "mla_decode": _run("mla_decode")[1]},
+                          banked) == []
+    assert record["predicted_speedup"] > 1.0
+
+
 def test_coverage_predicate_is_the_runtime_gate():
     """The coverage rows come from decode_kernel_eligible — now the
     unified ragged_kernel_eligible gate the dispatch calls, so the table
@@ -438,13 +480,13 @@ def test_kernelcheck_certs_declarations_match_registry():
     entries, and every registry entry is declared by exactly one module —
     PT011's declaration can't go stale in either direction."""
     from paddle_tpu.kernels import (flash_attention, fused_layernorm,
-                                    fused_optimizer, latent_paged_attention,
-                                    paged_attention, ragged_paged_attention,
+                                    fused_optimizer, paged_attention,
+                                    paged_decode, ragged_paged_attention,
                                     ssm_state_update)
 
     declared = []
     for mod in (flash_attention, fused_layernorm, fused_optimizer,
-                latent_paged_attention, paged_attention,
+                paged_attention, paged_decode,
                 ragged_paged_attention, ssm_state_update):
         certs = mod.KERNELCHECK_CERTS
         assert certs, mod.__name__
@@ -494,8 +536,9 @@ def test_cli_coverage_and_violation_exit(tmp_path, capsys):
     """A drifted bank fails the default sweep loudly (the PR 6 contract);
     the coverage table shows the int8 flip, and its kernel-less
     production section holds exactly the declared head_dim-64 row with
-    the compiler's refusal (every other TPU-flags-on serving config
-    reaches a kernel or a counted fallback)."""
+    the compiler's refusal and the grouped heads' prefill, which has no
+    kernel yet (every other TPU-flags-on serving config reaches a kernel
+    or a counted fallback)."""
     profile = tmp_path / "kernelcheck.json"
     bad = {name: {"grid": [], "vmem_bytes": 0, "flops": -1,
                   "hbm_bytes": 0} for name in kc.REGISTRY}
@@ -505,6 +548,7 @@ def test_cli_coverage_and_violation_exit(tmp_path, capsys):
     assert rc == 1
     assert "drifted from the banked contract" in out
     less = out.split("kernel-less production configs")[1].split("\n\n")[0]
-    assert less.count("!!") == 1 and "head_dim=64" in less
+    assert less.count("!!") == 2 and "head_dim=64" in less
+    assert "gqa_decode" in less and "mode=prefill[512]" in less
     assert "aligned to tiling (128), but is 64" in less
     assert "kv_dtype=int8" in out  # the flipped row still prints, as pallas

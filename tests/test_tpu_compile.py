@@ -239,3 +239,64 @@ def test_ssm_decode_update_compiles_for_v5e_in_place(v5e):
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().alias_size_in_bytes \
         == slots * heads * p * n * 4
+
+
+# ------------------------------------- the decode pipeline's two instances
+def test_gqa_decode_kernel_compiles_for_v5e_at_cell_g_s_shape(v5e,
+                                                              monkeypatch):
+    """Grouped heads over the lane-dense pool at granite-4.0-h-micro's
+    widths (64 rows, 32 query heads over 8 KV heads of 64, 96 pages of 16,
+    bf16): a page copy is 16 whole rows of four lane tiles, no slice of
+    the head size appears in the kernel, Mosaic takes it, and nothing of
+    the table's width (64 x 1,536 positions) is left around it."""
+    import re
+
+    from paddle_tpu.kernels import _common
+    from paddle_tpu.kernels import paged_attention as pa
+
+    # the dispatch asks the backend, which is the CPU here: steer it
+    monkeypatch.setattr(_common, "on_tpu_backend", lambda: True)
+    bf = jnp.bfloat16
+    pool = ((12902, 16, 8 * 64), bf)
+    text = _compiled_text(
+        lambda q, k, v, t, c: pa.paged_attention(q, k, v, t, c,
+                                                 scale=1 / 64),
+        ((64, 32, 1, 64), bf), pool, pool, ((64, 96), jnp.int32),
+        ((64,), jnp.int32), device=v5e)
+    assert text.count("tpu_custom_call") == 1
+    assert "gqa_decode_attention" in text
+    assert not re.findall(r"\[64,(?:\d+,)*1536(?:,\d+)*\]", text)
+
+
+def test_gqa_prefill_takes_the_composite_on_the_same_pools(v5e,
+                                                           monkeypatch):
+    """A call of 512 tokens a row (cell G's prefill) has no kernel: the
+    gate says so and the compiled call holds none."""
+    from paddle_tpu.kernels import _common
+    from paddle_tpu.kernels import paged_attention as pa
+
+    monkeypatch.setattr(_common, "on_tpu_backend", lambda: True)
+    bf = jnp.bfloat16
+    pool = ((12902, 16, 8 * 64), bf)
+    text = _compiled_text(
+        lambda q, k, v, t, c: pa.paged_attention(q, k, v, t, c,
+                                                 scale=1 / 64),
+        ((1, 32, 512, 64), bf), pool, pool, ((1, 96), jnp.int32),
+        ((1,), jnp.int32), device=v5e)
+    assert "tpu_custom_call" not in text
+
+
+def test_mla_decode_kernel_still_compiles_for_v5e_at_cell_k_s_shape(v5e):
+    """The latent kernel is the same pipeline given no values pool: at
+    Kimi's serving shape (256 rows, 64 heads, a 640-wide bf16 latent pool,
+    160 pages of 16) it lowers under its own name."""
+    from paddle_tpu.kernels import latent_paged_attention as lp
+
+    bf = jnp.bfloat16
+    text = _compiled_text(
+        lambda q, pool, t, c: lp.mla_decode_kernel_call(
+            q, pool, t, c, rank=512, scale=0.1),
+        ((256, 64, 640), bf), ((34402, 16, 640), bf),
+        ((256, 160), jnp.int32), ((256,), jnp.int32), device=v5e)
+    assert text.count("tpu_custom_call") == 1
+    assert "mla_decode_attention" in text
