@@ -622,10 +622,23 @@ def _tiny_latent_model():
         held_experts=(2, 4)))
 
 
+def _tiny_window_model():
+    """A toy Mellum (text/mellum.py): window layers beside a full one in
+    two page groups, a softmax-routed expert layer held whole."""
+    from ..text.mellum import MellumConfig, MellumForCausalLM
+
+    return MellumForCausalLM(MellumConfig(
+        vocab_size=97, hidden_size=32, moe_intermediate_size=16,
+        num_hidden_layers=4, layer_types=["sliding_attention"] * 3
+        + ["full_attention"], num_attention_heads=4, num_key_value_heads=2,
+        head_dim=8, num_experts=8, num_experts_per_tok=2, sliding_window=8,
+        max_position_embeddings=32))
+
+
 def _build_engine_step(which: str, tensor_parallel: int = 1,
                        kv_dtype: str = "float32",
                        quantized_logits: bool = False,
-                       latent: bool = False):
+                       latent: bool = False, window: bool = False):
     """Engine-step audit targets. ``tensor_parallel=2`` builds the SAME
     step on a 2-device mesh (Megatron weight + KV-pool shards via
     serving/tp.py shard_map) with the budget the engine itself declares:
@@ -654,14 +667,20 @@ def _build_engine_step(which: str, tensor_parallel: int = 1,
     # ``latent``: the same steps over a latent-attention model's one-leaf
     # pool (the model's own counters ride behind the tokens) — single
     # chip, zero collectives, the donated pool aliased
-    model = _tiny_latent_model() if latent else GPTForCausalLM(GPTConfig(
-        vocab_size=97, hidden_size=32, num_layers=2, num_heads=2,
-        max_seq_len=32, dropout=0.0))
+    # ``window``: the same steps over a model of two page groups (the
+    # groups' tables uploaded stacked, each layer handed its own; the
+    # prefill's head at the last real token alone)
+    model = _tiny_latent_model() if latent else _tiny_window_model() \
+        if window else GPTForCausalLM(GPTConfig(
+            vocab_size=97, hidden_size=32, num_layers=2, num_heads=2,
+            max_seq_len=32, dropout=0.0))
     model.eval()
     spec = (SpecConfig(method="ngram", depth=2)
             if which == "verify_spec" else None)
     eng = ServingEngine(model, ServingConfig(
         max_batch=2, num_pages=16, page_size=4, max_prompt_len=8,
+        group_pages={"window": 12} if window else None,
+        enable_prefix_caching=not window,
         tensor_parallel=tensor_parallel, kv_dtype=kv_dtype, spec=spec,
         # the tp2 entries certify WITH the overlap contract declared:
         # min_overlap_frac=1.0 over async collectives (vacuous where the
@@ -858,6 +877,18 @@ REGISTRY: dict[str, StepSpec] = {s.name: s for s in (
              "attention expert model (absorbed MLA over the latent pool, "
              "dropless expert layer; budget: zero collectives)",
              lambda: _build_engine_step("decode", latent=True)),
+    # ---- a model of window layers beside full ones (text/mellum.py): two
+    # page groups, their tables one stacked operand; both groups' donated
+    # pools aliased, zero collectives
+    StepSpec("engine_prefill_window", "serving prefill step of the model "
+             "of window and full layers (two page groups, the head at the "
+             "last real token alone; budget: zero collectives)",
+             lambda: _build_engine_step("prefill", window=True)),
+    StepSpec("engine_decode_window", "serving decode step of the model of "
+             "window and full layers (each layer its own group's table, "
+             "a dropless expert layer held whole; budget: zero "
+             "collectives)",
+             lambda: _build_engine_step("decode", window=True)),
     # ---- quantized logits all-reduce (tp_quantized_logits=True): the
     # b*s*V f32 logits payload ships as int8 codes + a 4-byte shared
     # scale — budget 2L+2 all-reduces with the logits byte term counted

@@ -1033,7 +1033,56 @@ def _build_mla_decode():
         composite=composite, composite_args=args)
 
 
-def _build_gqa_decode():
+def _build_flash_grouped():
+    """The grouped flash forward of a serving prefill at a canonical
+    shape: 8 query heads over 2 KV heads of 128, a sequence of 2,048 from
+    position 0 in blocks of 512, a window of 512: the in-tree ``flash_fwd``
+    body with query head ``h`` reading KV head ``h // g`` and the kv
+    blocks wholly behind a q block's window skipped like those above the
+    diagonal (9 of 16 grid steps a head compute). The composite is the
+    banded masked attention with the KV heads repeated."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..kernels import flash_attention as fa
+
+    b, h, kv, s, d, window = 1, 8, 2, 2048, 128, 512
+    q = _sds((b, h, s, d), jnp.bfloat16)
+    k = _sds((b, kv, s, d), jnp.bfloat16)
+    live = fa.grouped_live_steps(s, window)
+    constraints = (
+        ("grouped_supported", fa.grouped_supported(s, d),
+         f"seq {s} must be whole blocks of {fa.grouped_edge(s)}"),
+        ("window_skips_blocks", live == 7 < fa.grouped_live_steps(s) == 10,
+         "a window of one block leaves the diagonal block and the one "
+         "before it: 7 of the causal 10 live steps a head"),
+        ("composite_below_a_block",
+         not fa.grouped_supported(64, d),
+         "a sequence under 128 tokens must take the composite"),
+    )
+
+    def composite(q, k, v):
+        g = h // kv
+        kk, vv = (jnp.repeat(t, g, axis=1) for t in (k, v))
+        sc = jnp.einsum("bhqd,bhkd->bhqk", q, kk,
+                        preferred_element_type=jnp.float32) * 0.125
+        i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+        seen = (j <= i) & (i - j < window)
+        pr = jax.nn.softmax(jnp.where(seen, sc, -1e30), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", pr.astype(q.dtype), vv)
+
+    return dict(
+        fn=lambda q, k, v: fa.flash_fwd_grouped(q, k, v, 0.125, window),
+        args=(q, k, k),
+        budget=KernelBudget(allow_output_revisits=True),
+        constraints=constraints,
+        # the band's pairs: window x s less the corner, x2 matmuls, x2
+        flops=float(4 * b * h * d * (window * s - window * (window - 1)
+                                     // 2)),
+        composite=composite, composite_args=(q, k, k))
+
+
+def _build_gqa_decode(window: int | None = None):
     """The grouped-head decode kernel at a canonical serving shape: 2 rows
     of 8 query heads over 2 KV heads of 64 (a lane-dense pool row of 128),
     32 pages a row staged a chunk at a time, keys and values in the two
@@ -1041,7 +1090,9 @@ def _build_gqa_decode():
     given a values pool. What is certified is the kernel call with the
     block-diagonal queries built and each head's own columns picked off
     around it, as the dispatch runs it; the composite is
-    ``_grouped_composite`` on the same pools."""
+    ``_grouped_composite`` on the same pools. ``window``: the instance of
+    a window layer, whose loop starts at the first chunk that holds a
+    position inside the window."""
     import jax.numpy as jnp
 
     from ..kernels import paged_attention as pa
@@ -1066,10 +1117,11 @@ def _build_gqa_decode():
     )
 
     def fn(q, kp, vp, t, c):
-        return pd.gqa_decode_attention(q, kp, vp, t, c, 0.125)
+        return pd.gqa_decode_attention(q, kp, vp, t, c, 0.125,
+                                       window=window)
 
     def composite(q, kp, vp, t, c):
-        return pa._grouped_composite(q, kp, vp, t, c, 0.125)
+        return pa._grouped_composite(q, kp, vp, t, c, 0.125, window)
 
     args = (q, pool, pool, table, ctx)
     return dict(
@@ -1251,6 +1303,15 @@ REGISTRY: dict[str, KernelSpec] = {s.name: s for s in (
                "pool, a row's live pages staged once for all query heads, "
                "block-diagonal queries so that no slice is narrower than a "
                "lane row", _build_gqa_decode),
+    KernelSpec("gqa_decode_window", "the grouped-head decode kernel of a "
+               "WINDOW layer (window 128 of 512 positions): the loop runs "
+               "over the chunks of [ctx + 1 - window, ctx] only and the "
+               "positions behind the window are masked exactly",
+               lambda: _build_gqa_decode(window=128)),
+    KernelSpec("flash_fwd_grouped", "grouped-head flash forward of a "
+               "serving prefill from position 0 (8 query heads over 2 KV "
+               "heads of 128, seq 2048, window 512): the flash_fwd body, "
+               "blocks behind the window skipped", _build_flash_grouped),
     KernelSpec("ssm_decode_update", "Mamba-2 decode state update: a grid "
                "step a slot brings the slot's float32 state to VMEM once, "
                "advances it in place and reads it out; dead slots name "

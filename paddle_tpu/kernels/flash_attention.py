@@ -28,7 +28,8 @@ from ._common import i32_index_scope
 #: under (analysis/kernelcheck.py REGISTRY) — lint rule PT011 requires
 #: every pallas-kernel module to carry this declaration, and a tier-1
 #: test pins each name to a live registry entry
-KERNELCHECK_CERTS = ("flash_fwd", "flash_bwd", "splash_fwd")
+KERNELCHECK_CERTS = ("flash_fwd", "flash_bwd", "splash_fwd",
+                     "flash_fwd_grouped")
 
 _TUNED = None
 
@@ -175,23 +176,40 @@ def _causal(x, q0, k0, q_axis: int):
     return jnp.where(key <= qry, x, _MASK)
 
 
-def _visible_steps(step, causal: bool, i, j, bq: int, bk: int, off: int):
+def _banded(x, q0, k0, window: int):
+    """Scores ``x`` (queries along axis 0) with every pair outside the
+    band ``query - window < key <= query`` at ``_MASK``: a query sees
+    itself and the ``window - 1`` keys before it."""
+    qry = q0 + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    key = k0 + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((key <= qry) & (key > qry - window), x, _MASK)
+
+
+def _visible_steps(step, causal: bool, i, j, bq: int, bk: int, off: int,
+                   window: int | None = None):
     """Run ``step(masked)`` for q block ``i`` against kv block ``j``:
     not at all where the causal mask (key <= query + ``off``) hides every
     pair, with ``masked`` only where it hides some. Returns whether the
-    block is live."""
+    block is live. ``window``: the mask also hides the keys at or behind
+    ``query + off - window``; a block wholly behind the window of its q
+    block's first query is skipped like one above the diagonal."""
     if not causal:
         step(False)
         return True
     live = j * bk <= i * bq + (bq - 1) + off
     full = j * bk + (bk - 1) <= i * bq + off
+    if window is not None:
+        # the block's last key inside the first query's window; its first
+        # key inside the last query's
+        live &= j * bk + (bk - 1) > i * bq + off - window
+        full &= j * bk > i * bq + (bq - 1) + off - window
     pl.when(full)(lambda: step(False))
     pl.when(live & jnp.logical_not(full))(lambda: step(True))
     return live
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
-                      acc_sc, *, causal, scale, off, nk):
+                      acc_sc, *, causal, scale, off, nk, window=None):
     """One (q block, kv block) step of the online softmax. ``m`` and ``l``
     live in VMEM as 128 equal lanes a row (a ``[rows, 1]`` column takes the
     same tiles and half as many bundles again in the compiler's schedule);
@@ -211,7 +229,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
         s = jax.lax.dot_general(q_ref[...], k_ref[...], _NT,
                                 preferred_element_type=jnp.float32) * scale
         if masked:
-            s = _causal(s, i * bq + off, j * bk, 0)
+            s = _causal(s, i * bq + off, j * bk, 0) if window is None \
+                else _banded(s, i * bq + off, j * bk, window)
         m_prev = m_sc[...]
         m_next = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - jnp.tile(m_next, (1, bk // _LANES)))
@@ -222,7 +241,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
                      preferred_element_type=jnp.float32)
         acc_sc[...] = acc_sc[...] * _lanes_to(alpha, d) + pv
 
-    _visible_steps(step, causal, i, j, bq, bk, off)
+    _visible_steps(step, causal, i, j, bq, bk, off, window)
 
     @pl.when(j == nk - 1)
     def _():
@@ -275,6 +294,104 @@ def _flash_fwd_call(q, k, v, causal, sm_scale, edges, interpret):
             interpret=interpret,
             name="flash_fwd",
         )(q, k, v)
+
+
+#: q and kv block edge of the grouped forward (a prefill of thousands of
+#: tokens at head size 128; not in flash_tuned.json, which is keyed by the
+#: training shapes). A sequence shorter than it is one block
+_GROUPED_BLOCK = 512
+
+
+def grouped_edge(s: int) -> int:
+    """The block edge :func:`flash_fwd_grouped` runs a sequence of ``s``
+    at: ``_GROUPED_BLOCK``, or ``s`` itself when shorter."""
+    return min(_GROUPED_BLOCK, s)
+
+
+def grouped_supported(s: int, d: int, interpret: bool = False) -> bool:
+    """Whether :func:`flash_fwd_grouped` tiles a sequence of ``s`` at
+    head size ``d``: whole blocks, whose keys fill whole 128-lane rows of
+    the scores (the statistics are 128 equal lanes a row); on the chip the
+    head size a multiple of the 64-lane tile."""
+    b = grouped_edge(s)
+    return s % b == 0 and b % _LANES == 0 and (interpret or d % 64 == 0)
+
+
+def grouped_live_steps(s: int, window: int | None = None) -> int:
+    """(q block, kv block) pairs that :func:`flash_fwd_grouped` computes
+    for one head of a sequence of ``s``: the host's count of the kernel's
+    ``_visible_steps``. The rest of the ``(s / block) ** 2`` grid steps
+    are skipped, above the diagonal or behind the window."""
+    b = grouped_edge(s)
+    n = s // b
+    live = 0
+    for i in range(n):
+        for j in range(n):
+            ok = j * b <= i * b + (b - 1)
+            if window is not None:
+                ok = ok and j * b + (b - 1) > i * b - window
+            live += ok
+    return live
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _flash_fwd_grouped_call(q, k, v, sm_scale, window, interpret):
+    """The forward of :func:`flash_fwd_grouped`: ``_flash_fwd_kernel``
+    with query head ``h`` reading KV head ``h // g`` and, with a window,
+    the band's lower edge in the mask, the block skip and the block
+    fetch."""
+    b, h, s, d = q.shape
+    g = h // k.shape[1]
+    bq = bk = grouped_edge(s)
+    n = s // bq
+
+    def kv_map(bi, hi, i, j):
+        # a skipped step names a block it holds (or will want next), so
+        # it fetches nothing new
+        last = (i * bq + (bq - 1)) // bk
+        if window is None:
+            return bi, hi // g, jnp.minimum(j, last), 0
+        first = jnp.maximum(i * bq - window + 1, 0) // bk
+        return bi, hi // g, jnp.clip(j, first, last), 0
+
+    q_spec = pl.BlockSpec((None, None, bq, d),
+                          lambda bi, hi, i, j: (bi, hi, i, 0))
+    kv_spec = pl.BlockSpec((None, None, bk, d), kv_map)
+    kernel = functools.partial(_flash_fwd_kernel, causal=True,
+                               scale=sm_scale, off=0, nk=n, window=window)
+    with i32_index_scope():
+        return pl.pallas_call(
+            kernel,
+            grid=(b, h, n, n),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[q_spec,
+                       pl.BlockSpec((None, None, 1, bq),
+                                    lambda bi, hi, i, j: (bi, hi, 0, i))],
+            out_shape=[pltpu.HBM(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bq, _LANES), jnp.float32),
+                            pltpu.VMEM((bq, _LANES), jnp.float32),
+                            pltpu.VMEM((bq, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="flash_fwd_grouped" if window is None
+            else "flash_fwd_grouped_window",
+        )(q, k, v)[0]
+
+
+def flash_fwd_grouped(q, k, v, scale: float, window: int | None = None,
+                      interpret: bool = False):
+    """Causal self-attention of a whole sequence from position 0 with
+    grouped KV heads, forward only (a serving prefill): q ``[b, kv_heads *
+    g, s, d]`` (query head ``kv * g + j`` reads KV head ``kv``), k and v
+    ``[b, kv_heads, s, d]``. ``window``: a query sees itself and the
+    ``window - 1`` keys before it; kv blocks wholly behind a q block's
+    window are skipped, not masked (:func:`grouped_live_steps` counts what
+    is left). The in-tree ``flash_fwd`` kernel body, statistics in
+    float32. ``grouped_supported`` is its gate."""
+    return _flash_fwd_grouped_call(q, k, v, float(scale), window,
+                                   interpret).astype(q.dtype)
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,

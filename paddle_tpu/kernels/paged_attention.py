@@ -270,11 +270,13 @@ def pages_staged_fn(head_dim: int, num_heads: int, page_size: int,
 
 def grouped_pages_staged_fn(heads: int, kv_heads: int, head_dim: int,
                             page_size: int, pages_per_seq: int,
-                            num_query_tokens: int, *, itemsize: int = 2):
+                            num_query_tokens: int, *, itemsize: int = 2,
+                            window: int | None = None):
     """:func:`pages_staged_fn` for a call with grouped KV heads over a
     lane-dense pool: the decode kernel's live chunks where the call takes
-    it (one new token a row and the gate holds), the table's width where
-    the composite gathers it."""
+    it (one new token a row and the gate holds; with a ``window`` the
+    chunks from the first that holds a position inside it), the table's
+    width where the composite gathers it."""
     import functools
 
     from . import ragged_paged_attention as _rp
@@ -286,7 +288,7 @@ def grouped_pages_staged_fn(heads: int, kv_heads: int, head_dim: int,
         _rp.pages_staged, num_query_tokens=num_query_tokens,
         page_size=page_size, pages_per_seq=pages_per_seq,
         chunk_pages=gqa_chunk_pages(page_size, pages_per_seq) if ok
-        else None, query_tile=1)
+        else None, query_tile=1, window=window)
 
 
 def _pages_per_block(page_size: int) -> int:
@@ -328,7 +330,7 @@ def _pallas_decode(q, k_pool, v_pool, page_table, ctx_lens, scale):
 
 
 def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
-                    k_scale=None, v_scale=None):
+                    k_scale=None, v_scale=None, window: int | None = None):
     """Attention of new-token queries against a row's paged KV prefix.
 
     q: [batch, heads, s, head_dim] — queries for s new tokens at positions
@@ -364,9 +366,17 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
     gather + masked-sdpa path — the gate's reason string says which. A
     kernel the gate called eligible that fails to trace or lower RAISES:
     nothing here turns a kernel failure into a composite result.
+
+    ``window`` (grouped KV heads only): query ``t`` sees itself and the
+    ``window - 1`` positions before it, ``ctx + t - window < j <= ctx +
+    t``; the decode kernel starts its loop inside the window, the
+    composite masks behind it.
     """
     s = q.shape[2]
     quantized = k_scale is not None
+    if window is not None and not (k_pool.ndim == 3
+                                   or q.shape[1] != k_pool.shape[2]):
+        raise ValueError("a window is taken by the grouped-head paths only")
     if k_pool.ndim == 3 or q.shape[1] != k_pool.shape[2]:
         # grouped KV heads: one new token a row over a lane-dense pool
         # runs the grouped-head decode kernel (paged_decode.py); anything
@@ -383,9 +393,9 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
             return gqa_decode_attention(
                 q, k_pool, v_pool, page_table, ctx_lens,
                 scale if scale is not None else d ** -0.5,
-                interpret=interpret)
+                interpret=interpret, window=window)
         return _grouped_composite(q, k_pool, v_pool, page_table, ctx_lens,
-                                  scale)
+                                  scale, window)
     use_kernel, interpret = _use_ragged_kernel(q, k_pool, page_table,
                                                quantized)
     if use_kernel:
@@ -406,7 +416,16 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
     return sdpa(q, k_all, v_all, mask=mask, scale=scale)
 
 
-def _grouped_composite(q, k_pool, v_pool, page_table, ctx_lens, scale):
+#: a grouped composite call of more than ``_COMPOSITE_WHOLE`` queries a row
+#: (a prefill of thousands of tokens behind cached ones) scores them
+#: ``_COMPOSITE_Q_BLOCK`` at a time, so that ``heads x s x total`` float32
+#: scores never stand whole (2.1 GB a layer at 32 heads and 4,096 tokens)
+_COMPOSITE_WHOLE = 1024
+_COMPOSITE_Q_BLOCK = 256
+
+
+def _grouped_composite(q, k_pool, v_pool, page_table, ctx_lens, scale,
+                       window: int | None = None):
     """Composite attention of ``g`` query heads to each KV head: q ``[b,
     kv_heads * g, s, d]`` (query head ``kv * g + j`` attends KV head
     ``kv``) against pools of ``[pages, page_size, kv_heads, d]`` or, lane-
@@ -415,7 +434,8 @@ def _grouped_composite(q, k_pool, v_pool, page_table, ctx_lens, scale):
     whole pool out anew). A row's pages are gathered first and the
     gathered rows split into heads, a KV head's keys are read once for its
     whole group, and the ragged mask puts exact zeros beyond ``ctx_lens +
-    t``. float32 scores and softmax."""
+    t`` (and, with a ``window``, at and behind ``ctx_lens + t - window``).
+    float32 scores and softmax."""
     import jax
 
     b, heads, s, d = q.shape
@@ -429,9 +449,28 @@ def _grouped_composite(q, k_pool, v_pool, page_table, ctx_lens, scale):
                          f"{k_seq.shape[2]} KV heads")
     scale = scale if scale is not None else d ** -0.5
     qg = q.reshape(b, -1, g, s, d)
-    scores = jnp.einsum("bkgqd,btkd->bkgqt", qg, k_seq,
-                        preferred_element_type=jnp.float32) * scale
-    seen = ragged_mask(ctx_lens, total, s)[:, :, None]   # [b,1,1,s,total]
-    probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
-    out = jnp.einsum("bkgqt,btkd->bkgqd", probs.astype(q.dtype), v_seq)
+
+    def attend(qb, t0=None):
+        """Queries ``qb`` [b, kv, g, n, d], the first of them query
+        ``t0`` of its row (None: query 0)."""
+        scores = jnp.einsum("bkgqd,btkd->bkgqt", qb, k_seq,
+                            preferred_element_type=jnp.float32) * scale
+        first = ctx_lens if t0 is None \
+            else ctx_lens.astype(jnp.int32) + t0
+        seen = ragged_mask(first, total, qb.shape[3])[:, :, None]
+        if window is not None:
+            # hidden: the positions at and behind ``query - window``
+            seen &= ~ragged_mask(first - window, total,
+                                 qb.shape[3])[:, :, None]
+        probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
+        return jnp.einsum("bkgqt,btkd->bkgqd", probs.astype(q.dtype), v_seq)
+
+    n = _COMPOSITE_Q_BLOCK
+    if s <= _COMPOSITE_WHOLE or s % n:
+        out = attend(qg)
+    else:
+        blocks = jnp.moveaxis(qg.reshape(b, -1, g, s // n, n, d), 3, 0)
+        out = jax.lax.map(lambda xs: attend(*xs),
+                          (blocks, jnp.arange(0, s, n)))
+        out = jnp.moveaxis(out, 0, 3).reshape(b, -1, g, s, d)
     return out.reshape(b, heads, s, d)

@@ -48,7 +48,7 @@ __all__ = ["decode_kernel_call", "gqa_decode_attention",
 
 #: kernelcheck certificates this module's Pallas kernel is registered
 #: under (analysis/kernelcheck.py REGISTRY; lint rule PT011's contract)
-KERNELCHECK_CERTS = ("mla_decode", "gqa_decode")
+KERNELCHECK_CERTS = ("mla_decode", "gqa_decode", "gqa_decode_window")
 
 #: a pool row is a whole number of 128-lane rows for the compiled kernel
 LANES = 128
@@ -82,7 +82,7 @@ def gqa_chunk_pages(page_size: int, pages_per_seq: int) -> int:
 
 
 def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
-                   n_pools, ctx_ref, tab_ref, q_ref, *refs):
+                   n_pools, window, ctx_ref, tab_ref, q_ref, *refs):
     """One row: its live pages through two staging buffers, chunk c + 1's
     copies started before chunk c is awaited, every chunk scored for all
     heads at once and folded into a running (max, sum, accumulator). The
@@ -91,7 +91,12 @@ def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
     row begins in). ``refs``: the ``n_pools`` pools in HBM (keys, then
     values if a second pool holds them), the output block, the staging
     buffer ``[2, n_pools * chunk_kv, width]``, its two semaphores and
-    ``slot_ref``."""
+    ``slot_ref``. ``window`` (None: every position): the new token sees
+    itself and the ``window - 1`` positions before it; the loop then
+    starts at the first chunk that holds one of them
+    (:func:`.ragged_paged_attention._live_start`) and the positions behind
+    the window are masked exactly, so a page the cache has freed there
+    (its table column reads the null page) is neither copied nor read."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -106,6 +111,16 @@ def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
                            np.int32(total_kv))
     n_chunks, _ = _rp._live_span(length, chunk_kv, page_size, total_kv,
                                  ops=_rp._LAX)
+
+    def _first(row):
+        """The first chunk a row's loop stages, and its first position
+        seen: (0, 0) without a window."""
+        if window is None:
+            return 0, 0
+        return _rp._live_start(ctx_ref[row] + np.int32(1), chunk_kv, window,
+                               total_kv, ops=_rp._LAX)
+
+    c0, lo = _first(bi)
 
     def _start(row, c, slot):
         # a chunk's page copies all signal the buffer's one semaphore
@@ -127,7 +142,7 @@ def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
     @pl.when(bi == 0)
     def _():
         slot_ref[0] = np.int32(0)
-        _start(bi, 0, 0)
+        _start(bi, c0, 0)
 
     slot0 = slot_ref[0]
     q = q_ref[0]                                   # (heads, width)
@@ -135,7 +150,7 @@ def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
 
     def body(c, carry):
         m, l, acc = carry
-        slot = (slot0 + c) % 2
+        slot = (slot0 + c) % 2 if window is None else (slot0 + c - c0) % 2
 
         @pl.when(c + 1 < n_chunks)
         def _():
@@ -143,7 +158,8 @@ def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
 
         @pl.when((c + 1 == n_chunks) & (bi + 1 < rows))
         def _():
-            _start(bi + 1, 0, 1 - slot)
+            nxt = bi + 1
+            _start(nxt, _first(nxt)[0], 1 - slot)
 
         _wait(slot)
         if n_pools == 1:
@@ -156,7 +172,9 @@ def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * np.float32(scale)
         pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + c * chunk_kv
-        s = jnp.where(pos < length, s, np.float32(-1e30))
+        seen = pos < length if window is None \
+            else (pos < length) & (pos >= lo)
+        s = jnp.where(seen, s, np.float32(-1e30))
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
@@ -169,22 +187,27 @@ def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
     m0 = jnp.full((heads, 1), np.float32(-1e30), jnp.float32)
     l0 = jnp.zeros((heads, 1), jnp.float32)
     acc0 = jnp.zeros((heads, out_width), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(np.int32(0), n_chunks, body,
-                                  (m0, l0, acc0))
-    slot_ref[0] = (slot0 + n_chunks) % 2
-    # position 0 is seen by every row, so l > 0
+    _, l, acc = jax.lax.fori_loop(
+        np.int32(0) if window is None else c0, n_chunks, body,
+        (m0, l0, acc0))
+    slot_ref[0] = (slot0 + n_chunks) % 2 if window is None \
+        else (slot0 + n_chunks - c0) % 2
+    # the new token's own position is seen by every row, so l > 0
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 def decode_kernel_call(q, k_pool, v_pool, page_table, ctx_lens, *,
                        out_width: int, scale: float, chunk_tokens: int,
-                       name: str, interpret: bool = False):
+                       name: str, interpret: bool = False,
+                       window: int | None = None):
     """The decode kernel. q [batch, heads, width]; k_pool [num_pages,
     page_size, width]; ``v_pool`` of the same shape, or None where the
     value is the key row's first ``out_width`` columns (then ``out_width <=
     width``; with a values pool ``out_width == width``). Returns [batch,
     heads, out_width] in q's dtype. ``name`` is the kernel's name in a
-    trace."""
+    trace. ``window``: the new token sees itself and the ``window - 1``
+    positions before it (None: every position, and the kernel that traces
+    is the one without the argument, operand for operand)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -208,7 +231,8 @@ def decode_kernel_call(q, k_pool, v_pool, page_table, ctx_lens, *,
             pltpu.SMEM((1,), jnp.int32),     # the buffer a row begins in
         ])
     kernel = functools.partial(_decode_kernel, ps, pps, chunk, out_width,
-                               float(scale), len(pools))
+                               float(scale), len(pools),
+                               None if window is None else int(window))
     with i32_index_scope():  # kernel index math assumes int32 defaults
         return pl.pallas_call(
             kernel,
@@ -274,14 +298,17 @@ def gqa_kernel_eligible(heads: int, kv_heads: int, head_dim: int,
 
 
 def gqa_decode_attention(q, k_pool, v_pool, page_table, ctx_lens,
-                         scale: float, *, interpret: bool = False):
+                         scale: float, *, interpret: bool = False,
+                         window: int | None = None):
     """Attention of ONE new token a row (already written to the pools) of
     ``g`` query heads to each KV head. q ``[batch, kv_heads * g, 1, d]``
     (query head ``kv * g + j`` attends KV head ``kv``); pools ``[pages,
     page_size, kv_heads * d]``. Returns ``[batch, heads, 1, d]`` in q's
     dtype. The queries are laid out block-diagonally before the kernel and
     each head's own ``d`` columns of the kernel's output are picked off
-    behind it: two small fusions."""
+    behind it: two small fusions. ``window``: a layer whose query sees
+    itself and the ``window - 1`` tokens before it; the kernel stages the
+    chunks of ``[max(0, ctx + 1 - window), ctx]`` only."""
     b, heads, _, d = q.shape
     width = k_pool.shape[-1]
     kv_heads = width // d
@@ -294,6 +321,8 @@ def gqa_decode_attention(q, k_pool, v_pool, page_table, ctx_lens,
     o = decode_kernel_call(
         q_bd, k_pool, v_pool, page_table, ctx_lens, out_width=width,
         scale=scale, chunk_tokens=_GQA_CHUNK_TOKENS,
-        name="gqa_decode_attention", interpret=interpret)
+        name="gqa_decode_attention" if window is None
+        else "gqa_decode_attention_window", interpret=interpret,
+        window=window)
     o = jnp.sum(jnp.where(own, o.reshape(b, heads, kv_heads, d), 0), axis=2)
     return o[:, :, None, :].astype(q.dtype)

@@ -407,9 +407,21 @@ def _live_span(ctx_end, chunk_kv: int, page_size: int, total_kv: int,
             ops.div(ops.add(live, -1), page_size))
 
 
+def _live_start(ctx_end, chunk_kv: int, window: int, total_kv: int,
+                ops=_NP):
+    """``(first chunk, first position)`` of what the query at ``ctx_end -
+    1`` sees through a window of ``window`` tokens (itself and the
+    ``window - 1`` before it): the start beside :func:`_live_span`'s end,
+    in the same two arithmetics. Positions behind the first are masked
+    exactly; chunks behind the first chunk are not staged."""
+    live = ops.clip(ctx_end, 1, total_kv)
+    lo = ops.clip(ops.add(live, -window), 0, total_kv)
+    return ops.div(lo, chunk_kv), lo
+
+
 def pages_staged(ctx_lens, num_query_tokens: int, *, page_size: int,
                  pages_per_seq: int, chunk_pages: int | None,
-                 query_tile: int | None = None):
+                 query_tile: int | None = None, window: int | None = None):
     """Pages a call stages per row, as the kernel stages them: int64
     ``[rows]`` for ``ctx_lens [rows]`` and ``num_query_tokens`` new
     tokens a row. Counted in whole pages (each head block copies its own
@@ -422,16 +434,21 @@ def pages_staged(ctx_lens, num_query_tokens: int, *, page_size: int,
     pages_per_seq``) the whole table a tile; ``chunk_pages=None`` is the
     composite path, which gathers the table's width once. (Not counted:
     the one dummy chunk a call's last grid step starts for a step that
-    does not follow.)"""
+    does not follow.) ``window``: the decode kernel's loop starts at the
+    first chunk that holds a position inside it (:func:`_live_start`)."""
     ctx = np.asarray(ctx_lens, np.int64)
     if chunk_pages is None:
         return np.full(ctx.shape, pages_per_seq, np.int64)
     tq = query_tile or query_tile_for(num_query_tokens)
     staged = np.zeros(ctx.shape, np.int64)
+    chunk_kv, total_kv = chunk_pages * page_size, pages_per_seq * page_size
     for t0 in range(0, num_query_tokens, tq):
-        staged += chunk_pages * _live_span(
-            ctx + (t0 + tq), chunk_pages * page_size, page_size,
-            pages_per_seq * page_size)[0]
+        chunks = _live_span(ctx + (t0 + tq), chunk_kv, page_size,
+                            total_kv)[0]
+        if window is not None:
+            chunks = chunks - _live_start(ctx + (t0 + tq), chunk_kv, window,
+                                          total_kv)[0]
+        staged += chunk_pages * chunks
     return staged
 
 

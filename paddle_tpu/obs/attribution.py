@@ -55,6 +55,8 @@ span                    extent                                attributes
                         watchdogs, the SLO controller
 ``serve.add_request``   ``ServingEngine.add_request``         rid, prompt_len
 ``serve.cow_copy``      one copy-on-write page copy           pages
+``serve.window_release``  window-group pages freed behind
+                        their slots' windows (host only)
 ======================  ====================================  ==============
 
 The seconds come in two kinds. A span named after one of :data:`PHASES`
@@ -63,7 +65,7 @@ mark is charged to it when it closes, so the phases plus the residual
 ``"other"`` SUM EXACTLY to the step's wall time by construction — no
 sampling, no double counting (``StepRecord.phase_s``). Every other span
 (the dotted ``*.upload`` / ``*.dispatch`` / ``*.fetch`` / ``*.emit``,
-``cow_copy``, ``account``) measures its own extent, lies inside a phase
+``cow_copy``, ``window_release``, ``account``) measures its own extent, lies inside a phase
 and is no part of that sum (``StepRecord.span_s``).
 
 ZERO device syncs either way (clock reads and TraceMe events only — the

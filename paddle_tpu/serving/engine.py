@@ -359,6 +359,10 @@ from .spec import SpecConfig, accept_counts, draft_window, propose_ngram
 class ServingConfig:
     max_batch: int = 4
     num_pages: int = 64
+    group_pages: dict | None = None  # {group name: pages}: the pages (null
+    # page included) of each page group behind the model's first, for a
+    # model that states several (kv_cache.PageGroup: window layers beside
+    # full ones); ``num_pages`` is then the first group's
     page_size: int = 16
     pages_per_seq: int = 0  # 0 -> ceil(max_seq_len / page_size)
     max_prompt_len: int = 32  # prefill pad bucket (one compile for all prompts)
@@ -595,9 +599,15 @@ class ServingEngine:
                 f"model: {spec.no_prefix_sharing}")
         pages_per_seq = cfg.pages_per_seq or \
             -(-spec.max_seq_len // cfg.page_size)
+        rest = [g.name for g in spec.groups[1:]]
+        if sorted(cfg.group_pages or {}) != sorted(rest):
+            raise ValueError(
+                f"group_pages {cfg.group_pages} does not give the pages of "
+                f"the model's page groups behind its first: {rest}")
         self.cache = PagedKVCache(PagedCacheConfig(
             num_layers=spec.num_layers, leaves=spec.leaves or None,
-            leaves_by_layer=spec.leaves_by_layer,
+            leaves_by_layer=spec.leaves_by_layer, groups=spec.groups,
+            group_pages=tuple(cfg.group_pages[n] for n in rest),
             num_pages=cfg.num_pages, page_size=cfg.page_size,
             max_batch=cfg.max_batch, pages_per_seq=pages_per_seq,
             dtype=spec.dtype,
@@ -613,6 +623,10 @@ class ServingEngine:
         # of a launch is (``slots`` in every layer's cache; None: row i is
         # slot i, as in decode)
         self._slot_state = bool(self.cache.cfg.slot_leaf_keys)
+        # several page groups: a launch uploads their tables stacked, and
+        # each layer is handed its own group's
+        self._layer_group = (self.cache.cfg.group_of_layer
+                             if len(self.cache.groups) > 1 else None)
         # what the model counts a launch (an expert layer's assignments):
         # an int32 vector behind the launch's tokens, in the same fetch
         self._n_counters = len(spec.counters)
@@ -774,7 +788,7 @@ class ServingEngine:
         # what a decode step uploads, from the operands' shapes: the whole
         # page table and the five per-slot vectors (serve.decode.upload)
         self._decode_upload_bytes = sum(
-            a.nbytes for a in (self.cache.page_table, self._ctx,
+            a.nbytes for a in (self.cache.tables, self._ctx,
                                self._last_tok, self._active, self._rids,
                                self._gen))
         # the cache's copy-on-write page copy is a span of the same
@@ -879,13 +893,18 @@ class ServingEngine:
                              cfg.top_k, cfg.top_p)[0]
 
     def _run_model(self, p_arrays, pools, table, ctx, valid, ids,
-                   kv_limit=None, slots=None):
+                   kv_limit=None, slots=None, head_at=None):
         """(logits, new_pools, counters): one paged call of the model.
         ``kv_limit`` is a static bound on the positions this call can
         reach (a prefill stays inside ``max_prompt_len``), for a model
         whose prefill reads its context back from the pool; None is the
         whole page table. ``slots`` [rows] names the slot of each row, for
         a model that keeps something a slot (None: row i is slot i).
+        ``table`` is the page table of the launch's rows, or, for a model
+        of several page groups, one a group, stacked: each layer gets its
+        own group's. ``head_at`` [rows], for a model that takes it
+        (``PagedCacheSpec.head_at_positions``): the one position a row
+        whose logits are read; the logits are then ``[rows, 1, vocab]``.
         ``counters`` is what the layers counted, summed (int32
         [len(spec.counters)]), or None for a model that counts nothing."""
         shared = dict(page_table=table, ctx_lens=ctx, valid=valid,
@@ -893,7 +912,12 @@ class ServingEngine:
         if self._slot_state:
             shared["slots"] = None if slots is None else jnp.reshape(
                 slots.astype(jnp.int32), (-1,))
+        if head_at is not None:
+            shared["head_at"] = head_at
         caches = [dict(pl, **shared) for pl in pools]
+        if self._layer_group is not None:
+            for c, g in zip(caches, self._layer_group):
+                c["page_table"] = table[g]
         (logits, new_caches), _ = self.model.functional_call(
             p_arrays, {}, Tensor(ids), caches=caches)
         new_pools = [{k: c[k] for k in keys}
@@ -926,14 +950,23 @@ class ServingEngine:
         gather decode uses. Returns (new_pools, first sampled token).
         Compiles once per pad bucket (padded_ids shape)."""
         n = padded_ids.shape[0]
-        table = page_row[None, :]
+        # one row; of a model of several page groups one row a group
+        table = page_row[None, :] if page_row.ndim == 1 \
+            else page_row[:, None, :]
         ctx = jnp.reshape(ctx0.astype(jnp.int32), (1,))
         valid = (jnp.arange(n, dtype=jnp.int32) < tail_len)[None, :]
+        # a model that takes it computes its head at the last real token
+        # alone: at a bucket of thousands the head at every position is
+        # most of the program's FLOPs and an array of which one row is read
+        at_last = self._cache_spec.head_at_positions
         logits, new_pools, counters = self._run_model(
             p_arrays, pools, table, ctx, valid, padded_ids[None, :],
-            kv_limit=self.config.max_prompt_len, slots=slot)
+            kv_limit=self.config.max_prompt_len, slots=slot,
+            head_at=jnp.reshape(tail_len.astype(jnp.int32) - 1, (1,))
+            if at_last else None)
         with jax.named_scope("sample"):
-            last = logits[0, tail_len - 1, :]
+            last = logits[0, 0, :] if at_last \
+                else logits[0, tail_len - 1, :]
             if self.config.do_sample:
                 tok = self._sample_row(last, self._req_key(rid, 0))
             else:
@@ -1527,6 +1560,10 @@ class ServingEngine:
         with att.span("evict"):
             if inj is not None:
                 self._inject_decode_faults(inj, step_idx)
+            # every slot's next query is one position on since the last
+            # launch: a window page may have fallen behind it
+            self._release_behind((int(slot), int(self._ctx[slot]))
+                                 for slot in np.nonzero(self._active)[0])
             for req, slot in self.scheduler.ensure_decode_pages():
                 self._preempt_one(req, slot)
 
@@ -1668,7 +1705,7 @@ class ServingEngine:
         prog = self._prefill_program(n)
         bucket = prog.tokens
         with att.span("prefill.upload", rid=req.rid, bytes=4 * bucket + 16
-                      + self.cache.page_table[req.slot].nbytes):
+                      + self.cache.tables[..., req.slot, :].nbytes):
             args = self._prefill_args(prog, req.slot, req.rid,
                                       req.prompt[start:start + n], start)
         if tr is not None and not chunked:  # a chunked one: at admission
@@ -1679,6 +1716,9 @@ class ServingEngine:
         if out is None:
             return False
         req.prefilled_tokens = start + n
+        # the launch that read them is dispatched: what lies behind the
+        # window of the request's next query goes back to its group
+        self._release_behind([(req.slot, start + n)])
         if chunked:
             self.metrics.on_prefill_chunk(n)
             # stamped AFTER the dispatch succeeded, so the trace's chunk
@@ -1750,6 +1790,21 @@ class ServingEngine:
             if self.scheduler.running:
                 self._preempt_one(self.scheduler.pick_victim())
 
+    def _release_behind(self, slots) -> None:
+        """For each ``(slot, position of its next query)``: free the
+        slot's window-group pages that this query and every later one
+        cannot see (``PagedKVCache.release_behind``). The
+        ``serve.window_release`` span and
+        ``serving_kv_window_pages_released_total``; one attribute check
+        for a model without a window group."""
+        if not self.cache.has_windows:
+            return
+        with self._attr.span("window_release"):
+            freed = sum(self.cache.release_behind(slot, pos)
+                        for slot, pos in slots)
+        if freed:
+            self.metrics.on_window_release(freed)
+
     def _count_attention_pages(self, ctx, s: int, tokens: int | None = None,
                                live_rows=None) -> None:
         """One launch's attention, counted on the host from the
@@ -1768,8 +1823,13 @@ class ServingEngine:
             fn = self._pages_staged[s] = make(
                 s, self.cache.cfg.pages_per_seq, self.config.page_size)
         ctx = np.atleast_1d(ctx)
-        live = -(-(ctx + (s if tokens is None else tokens))
-                 // self.config.page_size)
+        new = s if tokens is None else tokens
+        if self._cache_spec.pages_live is not None:
+            # over the layers that pages_staged counts, by their kinds
+            live = self._cache_spec.pages_live(
+                s, self.config.page_size)(ctx, new)
+        else:
+            live = -(-(ctx + new) // self.config.page_size)
         if live_rows is not None:
             live = live[live_rows]
         self.metrics.on_attention_pages(int(live.sum()), int(fn(ctx).sum()))
@@ -1793,7 +1853,7 @@ class ServingEngine:
         return (self._p, self.cache.pools, jnp.asarray(padded),
                 jnp.asarray(len(ids), jnp.int32),
                 jnp.asarray(start, jnp.int32),
-                jnp.asarray(self.cache.page_table[slot]),
+                jnp.asarray(self.cache.tables[..., slot, :]),
                 jnp.asarray(rid, jnp.int32), jnp.asarray(slot, jnp.int32))
 
     def _decode_args(self, active=None, override=None) -> tuple:
@@ -1806,7 +1866,7 @@ class ServingEngine:
         # jnp.asarray returns, and the host writes _ctx and _gen right
         # after the dispatch, the page table and the rest while it runs
         up = lambda a: jnp.asarray(a.copy())  # noqa: E731
-        return (self._p, self.cache.pools, up(self.cache.page_table),
+        return (self._p, self.cache.pools, up(self.cache.tables),
                 up(self._ctx), self._prev_toks,
                 up(self._last_tok if override is None else override),
                 up(self._active if active is None else active),
@@ -1818,7 +1878,7 @@ class ServingEngine:
         proposer, its parameters. No copies: the verify phase fetches its
         own launch before the host writes any of them."""
         args = (self._p, self.cache.pools,
-                jnp.asarray(self.cache.page_table),
+                jnp.asarray(self.cache.tables),
                 jnp.asarray(self._ctx), jnp.asarray(self._last_tok),
                 jnp.asarray(self._active), jnp.asarray(self._rids),
                 jnp.asarray(self._gen), jnp.asarray(self._spec_hist()))
@@ -1911,6 +1971,8 @@ class ServingEngine:
                                     self._ctx, live_rows=active)
                 self._prev_toks = toks
                 self.metrics.on_decode_step(overlapped=prev is not None)
+                if self._layer_group is not None:
+                    self.metrics.on_kv_residency(*self.cache.residency())
                 for slot, req in launched:
                     req.tokens_in_flight += 1
                     self._ctx[slot] += 1

@@ -21,6 +21,19 @@ and per-slot page tables, mirrored into a dense ``[max_batch,
 pages_per_seq]`` int32 array each step — static shape, so table churn never
 recompiles.
 
+Pages come in GROUPS by layer kind (``PageGroup``, data the model states):
+each group has its own page count, allocator, page table and LIFETIME. A
+model of one group (GPT, a latent model, a hybrid's attention layers) is
+what it always was: ``cache.allocator`` and ``cache.page_table`` are group
+0's. A group with a ``window`` (layers whose query sees itself and the
+``window - 1`` tokens before it) returns a page to its allocator as soon
+as no later query of the slot can see it (``release_behind``): its table
+row keeps a position's column, so column ``i`` is still tokens ``i *
+page_size ..``, and the columns behind the window read the null page. The
+groups share ``pages_per_seq`` (a table row's width) and the slot; a
+request is admitted, grown, swapped and released in all of them together,
+and exhaustion of any one is exhaustion.
+
 Page 0 is reserved (never allocated): it is the null/trash page that padding
 tokens and inactive slots write to, keeping the jitted scatter branch-free.
 
@@ -239,6 +252,11 @@ class SwapHandle:
     # array a per-slot leaf, in ``slot_leaf_keys`` order, each stacked over
     # the layers that keep it; empty for a pool of pages alone
     state: tuple = ()
+    # a pool of several page groups: ``(pages, first table column)`` of
+    # each group behind the first, whose pages are ``n_pages`` from column
+    # 0. The arrays' page axis is as wide as the widest group; a layer's
+    # row holds its own group's pages from column 0 of that axis
+    rest: tuple = ()
 
     @property
     def arrays(self) -> tuple:
@@ -362,6 +380,19 @@ def prefix_digest(tokens, page_size: int) -> tuple:
 
 
 @dataclass(frozen=True)
+class PageGroup:
+    """The layers that share a page table and a page's lifetime, as the
+    MODEL states them: ``layers`` (indices into the model's layers; every
+    layer that pages is in exactly one group) and, for layers whose query
+    sees only itself and the ``window - 1`` tokens before it, ``window``:
+    the cache then frees a slot's page once every later query's window has
+    passed it. ``window=None`` keeps a context's every page."""
+    name: str
+    layers: tuple
+    window: int | None = None
+
+
+@dataclass(frozen=True)
 class CacheLeaf:
     """One leaf of a layer's pool, as the MODEL states it: what a token
     keeps (``shape``, behind the pool's ``[num_pages, page_size]``), with
@@ -412,6 +443,20 @@ class PagedCacheSpec:
     pages_staged: object = None
     leaves_by_layer: tuple | None = None
     no_prefix_sharing: str = ""
+    # the page groups by layer kind (``PageGroup``); () is one group of
+    # every layer, which keeps every page: GPT, a latent model, a hybrid
+    groups: tuple = ()
+    # where ``pages_staged`` counts more than one layer's worth (layers of
+    # different kinds stage different spans): ``(num_query_tokens,
+    # page_size) -> ((ctx_lens [rows], tokens [rows]) -> pages [rows])``,
+    # the pages that hold what a launch's real tokens attend to, counted
+    # over the same layers. None: one layer's, ``ceil((ctx + tokens) /
+    # page_size)``
+    pages_live: object = None
+    # the model computes its head only at the positions the engine names
+    # (``head_at`` [rows] int32 in every layer's cache of a prefill; the
+    # logits come back ``[rows, 1, vocab]``): a prefill reads one row
+    head_at_positions: bool = False
 
 
 def kv_heads_leaves(num_heads: int, head_dim: int, dtype=None,
@@ -462,10 +507,55 @@ class PagedCacheConfig:
     leaves_by_layer: tuple | None = None  # one tuple of CacheLeaf a layer,
     # for a model whose layers keep different things (``leaves`` is then
     # not read)
+    groups: tuple = ()  # PageGroup a page group, as the model's
+    # PagedCacheSpec states them; () is one group "full" of every layer
+    group_pages: tuple = ()  # the pages (null page included) of
+    # groups[1:], in order; groups[0] has ``num_pages``
 
     @property
     def quantized(self) -> bool:
         return self.kv_dtype == "int8"
+
+    @property
+    def page_groups(self) -> tuple:
+        """The groups, resolved: one ``full`` group of every layer where
+        the model stated none."""
+        if not self.groups:
+            return (PageGroup("full", tuple(range(self.num_layers))),)
+        seen = [i for g in self.groups for i in g.layers]
+        if sorted(seen) != sorted(set(seen)) or any(
+                not 0 <= i < self.num_layers for i in seen):
+            raise ValueError("page groups must name each layer at most "
+                             f"once, inside the model: {self.groups}")
+        if len(self.group_pages) != len(self.groups) - 1:
+            raise ValueError(
+                f"{len(self.groups)} page groups need {len(self.groups) - 1}"
+                f" entries of group_pages, got {self.group_pages}")
+        for g in self.groups:
+            if g.window is not None and g.window < 1:
+                raise ValueError(f"group {g.name}: window {g.window} < 1")
+        if self.groups[0].window is not None:
+            raise ValueError(
+                "the first page group keeps a context's every page (the "
+                "prefix index, copy-on-write and the spill tier are its); "
+                "a model of window layers alone states an empty first "
+                "group")
+        return tuple(self.groups)
+
+    @property
+    def group_num_pages(self) -> tuple:
+        """The pages of each group, the null page included."""
+        return (self.num_pages,) + tuple(self.group_pages)
+
+    @property
+    def group_of_layer(self) -> tuple:
+        """The group of each layer (0 for a layer in none: it keeps no
+        page, and nothing reads its table)."""
+        of = [0] * self.num_layers
+        for gi, g in enumerate(self.page_groups):
+            for i in g.layers:
+                of[i] = gi
+        return tuple(of)
 
     @property
     def layer_leaves(self) -> tuple:
@@ -544,16 +634,51 @@ def init_pools(cfg: PagedCacheConfig) -> list[dict]:
     pool_sh, scale_sh = (cfg.tp.pool_shardings() if cfg.tp is not None
                          else (None, None))
 
-    def leaf(lf):
+    def leaf(lf, num_pages):
         if lf.per_slot:
             return jnp.zeros((cfg.max_batch,) + tuple(lf.shape), lf.dtype)
         return jnp.zeros(
-            (cfg.num_pages,) + (() if lf.per_page else (cfg.page_size,))
+            (num_pages,) + (() if lf.per_page else (cfg.page_size,))
             + tuple(lf.shape), lf.dtype,
             device=scale_sh if lf.per_page else pool_sh)
 
-    return [{lf.name: leaf(lf) for lf in leaves}
-            for leaves in cfg.layer_leaves]
+    # a layer's pool has its group's pages
+    pages = cfg.group_num_pages
+    return [{lf.name: leaf(lf, pages[g]) for lf in leaves}
+            for leaves, g in zip(cfg.layer_leaves, cfg.group_of_layer)]
+
+
+class _Group:
+    """One page group's host state: its allocator, its table (a view of
+    the cache's stacked tables) and each live slot's pages in position
+    order, the first of them at table column ``first[slot]``: 0 but in a
+    window group, whose dead pages leave from the front."""
+
+    def __init__(self, spec: PageGroup, num_pages: int, table):
+        self.name, self.window = spec.name, spec.window
+        self.layers = tuple(spec.layers)
+        self.allocator = PageAllocator(num_pages)
+        self.table = table
+        self.pages: dict[int, list[int]] = {}
+        self.first: dict[int, int] = {}
+        self.released = 0  # pages freed behind the window, ever
+
+    def window_span(self, page_size: int) -> int:
+        """The most pages that hold a window's positions, a page's edge
+        anywhere: ``ceil(window / page_size) + 1``."""
+        return -(-self.window // page_size) + 1
+
+    def map(self, slot: int, pages: list, first: int = 0) -> None:
+        self.pages[slot], self.first[slot] = pages, first
+        self.table[slot, :] = NULL_PAGE
+        self.table[slot, first:first + len(pages)] = pages
+
+    def drop(self, slot: int) -> None:
+        pages = self.pages.pop(slot, None)
+        self.first.pop(slot, None)
+        if pages:
+            self.allocator.free(pages)
+        self.table[slot, :] = NULL_PAGE
 
 
 class PagedKVCache:
@@ -580,16 +705,40 @@ class PagedKVCache:
         if cfg.slot_leaf_keys and cfg.tp is not None:
             raise ValueError("per-slot leaves have no placement under "
                              "tensor parallelism")
+        specs = cfg.page_groups
+        if len(specs) > 1 and cfg.enable_prefix_caching:
+            raise ValueError(
+                f"a pool of {len(specs)} page groups cannot share pages by "
+                "prefix: the index names one page a block, and a window "
+                "group's page of a shared prefix is freed behind the window "
+                "of whoever holds it")
+        if len(specs) > 1 and cfg.tp is not None:
+            raise ValueError("page groups have no placement under tensor "
+                             "parallelism")
         self.cfg = cfg
-        self.allocator = PageAllocator(cfg.num_pages)
+        # one table a group, stacked: a launch uploads them as one array.
+        # The FIRST group's allocator, table and slot pages are the
+        # cache's own (``allocator``, ``page_table``, ``_slot_pages``): a
+        # model of one group is what it always was, and the prefix index,
+        # copy-on-write and the spill tier below are the first group's
+        self._tables = np.full(
+            (len(specs), cfg.max_batch, cfg.pages_per_seq), NULL_PAGE,
+            np.int32)
+        self.groups = [_Group(g, n, self._tables[i]) for i, (g, n)
+                       in enumerate(zip(specs, cfg.group_num_pages))]
+        self._rest = self.groups[1:]
+        self.has_windows = any(g.window is not None for g in self._rest)
+        self.allocator = self.groups[0].allocator
         # under tensor parallelism the pools' heads axis is sharded across
         # the mesh; the page ids in the (host-side) table stay logical, so
         # every allocator/prefix-cache/COW decision below is
         # sharding-agnostic
         self.pools = init_pools(cfg)
-        self.page_table = np.full((cfg.max_batch, cfg.pages_per_seq),
-                                  NULL_PAGE, np.int32)
-        self._slot_pages: dict[int, list[int]] = {}
+        self.page_table = self.groups[0].table
+        self._slot_pages = self.groups[0].pages
+        # slot -> the position of its next query, as release_behind was
+        # last told (window groups only)
+        self._slot_pos: dict[int, int] = {}
         # ---- prefix cache: exact token-chain -> full immutable page.
         # Keys are LINKED, not flat: (parent_serial, block_tokens), where
         # parent_serial is the registration serial of the page holding the
@@ -631,6 +780,14 @@ class PagedKVCache:
         shapes mean each compiles exactly once for the cache's lifetime."""
         return {k: g.traces for k, g in self.guards.items()}
 
+    @property
+    def tables(self) -> np.ndarray:
+        """What a launch uploads: the one group's ``[max_batch,
+        pages_per_seq]`` table, or the groups' tables stacked ``[groups,
+        max_batch, pages_per_seq]`` (layer ``i`` reads row
+        ``cfg.group_of_layer[i]``)."""
+        return self.page_table if not self._rest else self._tables
+
     def _stack_rows(self, keys) -> list:
         """For each layer ``{leaf: its row}`` among the layers that keep
         that leaf of ``keys``: where a layer's leaf stands in the array a
@@ -657,8 +814,13 @@ class PagedKVCache:
         # layer i and these are the programs they always were; a layer's
         # per-slot leaves pass through untouched
         rows = self._stack_rows(keys)
+        # the page ids a mover is given: one vector, or with several
+        # groups one a group, stacked; layer i moves its own group's
+        gof = self.cfg.group_of_layer
+        at = (lambda idx, i: idx) if not self._rest \
+            else (lambda idx, i: idx[gof[i]])
 
-        def gather_of(keys):
+        def gather_of(keys, at=at):
             # one stacked array a leaf of ``keys``, over the layers that
             # keep it. Index each layer BEFORE stacking: stacking whole
             # pools would materialize an O(pool) concatenate per swap event
@@ -667,15 +829,16 @@ class PagedKVCache:
             # codes + the touched pages' scale rows — never dequantized, so
             # a round-trip is bit-exact.
             return lambda pools, idx: tuple(
-                jnp.stack([pl[k][idx] for pl in pools if k in pl])
+                jnp.stack([pl[k][at(idx, i)] for i, pl in enumerate(pools)
+                           if k in pl])
                 for k in keys)
 
-        def scatter_of(keys, rows):
+        def scatter_of(keys, rows, at=at):
             def scatter(pools, idx, *stacked):
                 by_key = dict(zip(keys, stacked))
-                return [dict(pl, **{k: pl[k].at[idx].set(by_key[k][r])
-                                    for k, r in at.items()})
-                        for pl, at in zip(pools, rows)]
+                return [dict(pl, **{k: pl[k].at[at(idx, i)].set(
+                    by_key[k][r]) for k, r in row.items()})
+                        for i, (pl, row) in enumerate(zip(pools, rows))]
             return scatter
 
         gather, scatter = gather_of(keys), scatter_of(keys, rows)
@@ -720,8 +883,10 @@ class PagedKVCache:
         # what a SLOT keeps rides with its pages through a swap: row
         # ``slot`` of every per-slot leaf, stacked over the layers that
         # keep it (the slot is an operand, so one trace serves them all)
-        state_gather = gather_of(slot_keys)
-        state_scatter = scatter_of(slot_keys, self._stack_rows(slot_keys))
+        whole = lambda idx, i: idx  # noqa: E731  (a slot, not page ids)
+        state_gather = gather_of(slot_keys, whole)
+        state_scatter = scatter_of(slot_keys, self._stack_rows(slot_keys),
+                                   whole)
         self._state_gather_jit = CompileGuard(  # lint: disable=PT006
             state_gather, "state_gather", budget=1, strict=strict)
         self._state_scatter_jit = CompileGuard(
@@ -734,7 +899,8 @@ class PagedKVCache:
     def pages_for(self, num_tokens: int) -> int:
         return max(1, math.ceil(num_tokens / self.cfg.page_size))
 
-    def fits_ever(self, total_tokens: int) -> bool:
+    def fits_ever(self, total_tokens: int,
+                  prompt_tokens: int | None = None) -> bool:
         """Could a request of total_tokens run with the whole pool to
         itself? The admission-time check that makes preemption loops
         terminate (a lone running request can always grow). Reusable
@@ -742,8 +908,21 @@ class PagedKVCache:
         the request runs, so the guarantee must hold cold — but they don't
         tighten it either: every reclaimable page is evictable on demand,
         so the full ``usable_pages`` capacity always counts."""
+        need = self.pages_for(total_tokens)
+
+        def most(g: _Group) -> int:
+            # a window group holds the whole prompt while it is prefilled
+            # (the whole request's pages where the caller does not say how
+            # much of it is prompt) and a window's pages after
+            if g.window is None or prompt_tokens is None:
+                return need
+            # (a window's span and the page a step grows into)
+            return min(need, max(self.pages_for(prompt_tokens),
+                                 g.window_span(self.cfg.page_size) + 1))
+
         return (total_tokens <= self.cfg.max_tokens_per_seq
-                and self.pages_for(total_tokens) <= self.cfg.usable_pages)
+                and all(most(g) <= g.allocator.num_usable
+                        for g in self.groups))
 
     # ----------------------------------------------------- prefix caching
     def _block_key(self, parent_serial: int, tokens, i: int) -> tuple:
@@ -894,6 +1073,11 @@ class PagedKVCache:
                 "a page of a pool with per-slot leaves "
                 f"{self.cfg.slot_leaf_keys} cannot cross the wire: it "
                 "would need the slot's state at its last token with it")
+        if self._rest:
+            raise ValueError(
+                f"a page of a pool of {len(self.groups)} page groups cannot "
+                "cross the wire: the index names the first group's pages, "
+                "and a window group's may be gone")
         pages = self.match_prefix(tokens)
         parent = self._page_serial[pages[-1]] if pages else 0
         spilled = self._match_host_tail(tokens, parent, len(pages),
@@ -1132,6 +1316,11 @@ class PagedKVCache:
         if slot in self._slot_pages:
             raise ValueError(f"slot {slot} already admitted")
         total = self.pages_for(num_tokens)
+        # the groups behind the first: a prompt's pages in each, all or
+        # nothing (a window group holds the whole prompt until its prefill
+        # is launched: release_behind then frees what lies behind)
+        if not self._map_rest(slot, [(total, 0)] * len(self._rest)):
+            return False
         shared: list[int] = []
         spilled: list[SpilledPage] = []
         if tokens is not None and self.cfg.enable_prefix_caching:
@@ -1156,6 +1345,7 @@ class PagedKVCache:
                                        + (1 if need_cow else 0))
         if private is None:
             self._release_pages(shared)
+            self._drop_rest(slot)
             return False
         if spilled:
             try:
@@ -1206,6 +1396,12 @@ class PagedKVCache:
             self.page_table[slot, len(pages)] = NULL_PAGE
             self.allocator.decref(page)
             freed += 1
+        for g in self._rest:
+            mine = g.pages.get(slot, [])
+            while mine and g.first[slot] + len(mine) > keep:
+                g.table[slot, g.first[slot] + len(mine) - 1] = NULL_PAGE
+                g.allocator.decref(mine.pop())
+                freed += 1
         return freed
 
     def grow(self, slot: int, num_tokens: int) -> bool:
@@ -1225,7 +1421,83 @@ class PagedKVCache:
                 return False
             self.page_table[slot, len(pages)] = got[0]
             pages.extend(got)
+        for g in self._rest:
+            mine = g.pages[slot]
+            while g.first[slot] + len(mine) < need:
+                got = g.allocator.alloc(1)
+                if got is None:
+                    return False
+                g.table[slot, g.first[slot] + len(mine)] = got[0]
+                mine.extend(got)
         return True
+
+    # ------------------------------------------------ groups behind the first
+    def _map_rest(self, slot: int, wants: list) -> bool:
+        """``(pages, first column)`` a group behind the first, allocated
+        and mapped into the slot's rows; False (and no state change) when
+        any group cannot give its share."""
+        got = []
+        for g, (n, _) in zip(self._rest, wants):
+            pages = g.allocator.alloc(n)
+            if pages is None:
+                for h, ps in got:
+                    h.allocator.free(ps)
+                return False
+            got.append((g, pages))
+        for (g, pages), (_, first) in zip(got, wants):
+            g.map(slot, pages, first)
+        return True
+
+    def _drop_rest(self, slot: int) -> None:
+        for g in self._rest:
+            g.drop(slot)
+        self._slot_pos.pop(slot, None)
+
+    def release_behind(self, slot: int, next_pos: int) -> int:
+        """Return to their allocators the slot's window-group pages that
+        no later query can see. The slot's next query enters at position
+        ``next_pos`` (every later one further on) and sees position ``j``
+        while ``next_pos - j < window``: a page whose LAST position is at
+        or behind ``next_pos - window`` is dead. Its table column reads the
+        null page from now on; the kernels start past it and mask behind
+        the window exactly, so what the page holds next, for whom, changes
+        nothing for this slot. The engine calls this once a launch has
+        been dispatched with the table that still named the page (the
+        device runs launches in order). Returns the pages freed."""
+        ps, freed = self.cfg.page_size, 0
+        for g in self._rest:
+            mine = g.pages.get(slot)
+            if g.window is None or not mine:
+                continue
+            # pages 0 .. dead - 1 end at or behind next_pos - window
+            dead = max(0, (next_pos - g.window + 1) // ps)
+            k = min(dead - g.first[slot], len(mine))
+            if k <= 0:
+                continue
+            g.allocator.free(mine[:k])
+            del mine[:k]
+            g.table[slot, g.first[slot]:g.first[slot] + k] = NULL_PAGE
+            g.first[slot] += k
+            g.released += k
+            freed += k
+        if self._rest:
+            self._slot_pos[slot] = next_pos
+        return freed
+
+    def window_pages(self, slot: int) -> dict:
+        """Pages the slot holds in each window group, by group name."""
+        return {g.name: len(g.pages.get(slot, ())) for g in self._rest
+                if g.window is not None}
+
+    def residency(self) -> tuple[int, int]:
+        """``(resident, one lifetime)`` in page-layers: the pages in use
+        in each group times the group's layers, and what the same contexts
+        would hold under ONE table and lifetime (the first group's pages,
+        which are every context's, times every layer that pages)."""
+        resident = sum(g.allocator.pages_in_use * len(g.layers)
+                       for g in self.groups)
+        return resident, self.allocator.pages_in_use * sum(
+            len(g.layers) for g in self.groups)
 
     # --------------------------------------------------------------- swap
     def _padded_idx(self, pages) -> np.ndarray:
@@ -1234,6 +1506,15 @@ class PagedKVCache:
         idx = np.full(self.cfg.pages_per_seq, NULL_PAGE, np.int32)
         idx[:len(pages)] = pages
         return idx
+
+    def _swap_idx(self, slot: int, pages) -> np.ndarray:
+        """What a swap's mover is given: the first group's padded page
+        ids, or with several groups each group's, stacked."""
+        idx = self._padded_idx(pages)
+        if not self._rest:
+            return idx
+        return np.stack([idx] + [self._padded_idx(g.pages[slot])
+                                 for g in self._rest])
 
     def swap_out(self, slot: int) -> SwapHandle:
         """Copy the slot's pages to host memory and drop its holds. One
@@ -1247,15 +1528,18 @@ class PagedKVCache:
             raise ValueError(f"slot {slot} has no pages to swap out")
         import jax.numpy as jnp
 
-        n = len(pages)
+        rest = tuple((len(g.pages[slot]), g.first[slot])
+                     for g in self._rest)
+        n = max([len(pages)] + [k for k, _ in rest])
         got = self._gather_jit(self.pools,
-                               jnp.asarray(self._padded_idx(pages)))
+                               jnp.asarray(self._swap_idx(slot, pages)))
         state = ()
         if self.cfg.slot_leaf_keys:
             state = tuple(np.asarray(a) for a in self._state_gather_jit(
                 self.pools, jnp.asarray(slot, jnp.int32)))
-        handle = SwapHandle(n_pages=n, state=state, **_by_field(
-            [np.asarray(a)[:, :n].copy() for a in got]))
+        handle = SwapHandle(n_pages=len(pages), state=state, rest=rest,
+                            **_by_field([np.asarray(a)[:, :n].copy()
+                                         for a in got]))
         self.release(slot)
         return handle
 
@@ -1269,14 +1553,20 @@ class PagedKVCache:
 
         if slot in self._slot_pages:
             raise ValueError(f"slot {slot} already admitted")
+        if len(handle.rest) != len(self._rest):
+            raise ValueError("the handle is of a pool with other page "
+                             "groups")
+        if not self._map_rest(slot, list(handle.rest)):
+            return False
         pages = self._alloc_or_evict(handle.n_pages)
         if pages is None:
+            self._drop_rest(slot)
             return False
         w = self.cfg.pages_per_seq
-        args = [jnp.asarray(self._padded_idx(pages))]
+        args = [jnp.asarray(self._swap_idx(slot, pages))]
         for a in handle.arrays:
             full = np.zeros((a.shape[0], w) + a.shape[2:], a.dtype)
-            full[:, :handle.n_pages] = a
+            full[:, :a.shape[1]] = a
             args.append(jnp.asarray(full))
         # pad rows scatter zeros into the null page — never read unmasked
         self.pools = self._scatter_jit(self.pools, *args)
@@ -1299,9 +1589,12 @@ class PagedKVCache:
         if pages:
             self._release_pages(pages)
         self.page_table[slot, :] = NULL_PAGE
+        self._drop_rest(slot)
 
     def utilization(self) -> float:
-        return self.allocator.pages_in_use / max(1, self.cfg.usable_pages)
+        """The fullest group's share in use."""
+        return max(g.allocator.pages_in_use / max(1, g.allocator.num_usable)
+                   for g in self.groups)
 
     def stats(self) -> dict:
         """One consistent host-side reading of the pool's observable state
@@ -1310,7 +1603,16 @@ class PagedKVCache:
         disagree about page pressure within a step."""
         a = self.allocator
         t = self.host_tier
-        return {"pages_in_use": a.pages_in_use,
+        by_group = {} if not self._rest else {"groups": {
+            g.name: {"pages_in_use": g.allocator.pages_in_use,
+                     "free_pages": g.allocator.num_free,
+                     "usable_pages": g.allocator.num_usable,
+                     "layers": len(g.layers), "window": g.window,
+                     "window_pages_released": g.released}
+            for g in self.groups}}
+        # the first group's, as ever: its pages are every context's
+        return {**by_group,
+                "pages_in_use": a.pages_in_use,
                 "free_pages": a.num_free,
                 "reclaimable_pages": a.num_reclaimable,
                 "usable_pages": self.cfg.usable_pages,
@@ -1328,9 +1630,45 @@ class PagedKVCache:
                 "state_bytes_per_slot": self.cfg.state_bytes_per_slot}
 
     # --------------------------------------------------------- invariants
+    def _check_group(self, g: _Group) -> None:
+        """A group behind the first: no page in two live slots, none both
+        free and mapped, the live slots those of the first group, and in a
+        window group no page a slot's next query cannot see, nor more
+        behind that query than a window takes."""
+        a, ps = g.allocator, self.cfg.page_size
+        free, live = set(a._free), set(a._ref)
+        assert not a._cached, f"group {g.name} parks no page"
+        assert not (free & live), f"group {g.name}: a page free and live"
+        assert len(free) + len(live) == a.num_usable, \
+            f"group {g.name}: every usable page is free or live"
+        held = list(itertools.chain.from_iterable(g.pages.values()))
+        assert len(held) == len(set(held)), \
+            f"group {g.name}: a page in two live slots (or twice in one)"
+        assert set(held) == live, \
+            f"group {g.name}: the live pages are the slots' pages"
+        assert set(g.pages) == set(self._slot_pages), \
+            f"group {g.name}: its live slots are not the first group's"
+        if g.window is None:
+            return
+        most = g.window_span(ps)
+        for slot, pages in g.pages.items():
+            pos = self._slot_pos.get(slot)
+            if pos is None:
+                continue        # admitted, nothing launched yet
+            first = g.first[slot]
+            assert (first + 1) * ps - 1 > pos - g.window or not pages, \
+                f"group {g.name}: slot {slot} holds a page behind the " \
+                f"window of its next query at {pos}"
+            behind = min(len(pages), pos // ps + 1 - first)
+            assert behind <= most, \
+                f"group {g.name}: slot {slot} holds {behind} pages up to " \
+                f"position {pos}, a window of {g.window} takes {most}"
+
     def check_invariants(self) -> None:
         """Structural invariants the test suite sweeps after every
         scenario; raises AssertionError with the violated relation."""
+        for g in self._rest:
+            self._check_group(g)
         a = self.allocator
         free = set(a._free)
         live = set(a._ref)
@@ -1362,6 +1700,15 @@ class PagedKVCache:
                     assert pl[lf.name].shape == (self.cfg.max_batch,) \
                         + tuple(lf.shape), \
                         f"per-slot leaf {lf.name} is not [max_batch, ...]"
+        for g in self.groups:
+            for slot, pages in g.pages.items():
+                first = g.first.get(slot, 0)
+                row = g.table[slot]
+                assert list(row[first:first + len(pages)]) == list(pages) \
+                    and not row[:first].any() \
+                    and not row[first + len(pages):].any(), \
+                    f"group {g.name}: slot {slot}'s table row is not its " \
+                    "pages at their columns and the null page elsewhere"
         if self.host_tier is not None:
             t = self.host_tier
             assert t.bytes == sum(e.nbytes for e in t._entries.values()), \

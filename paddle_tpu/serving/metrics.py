@@ -39,6 +39,19 @@ them with no new plumbing):
                             table's width a row where the composite
                             gathers. live / staged is the share of the
                             attention's KV traffic that a context needs
+- serving_kv_window_pages_released_total counter: pages of a window page
+                            group (kv_cache.PageGroup) returned to their
+                            allocator behind the window of their slot's
+                            next query
+- serving_kv_resident_page_layers_total,
+  serving_kv_one_lifetime_page_layers_total counters: at each decode
+                            launch of a model of several page groups, the
+                            pages in use in each group times its layers,
+                            and what ONE table and lifetime would hold for
+                            the same contexts (their pages times every
+                            layer that pages); the first over the second
+                            is the share of the one-lifetime cache that
+                            the groups keep resident
 - serving_preemptions_total counter
 
 Resilience counters (pre-seeded to 0 so they always appear in snapshots):
@@ -309,6 +322,9 @@ _SEEDED = ("tokens_total", "prefills_total", "prefill_tokens_total",
            "spec_depth", "spec_proposed_tokens_total",
            "spec_accepted_tokens_total", "spec_acceptance_rate",
            "kv_bytes_per_token", "state_bytes_per_slot",
+           "kv_window_pages_released_total",
+           "kv_resident_page_layers_total",
+           "kv_one_lifetime_page_layers_total",
            "host_tier_pages", "host_tier_bytes",
            "host_tier_hits_total", "host_tier_spills_total",
            "host_tier_restores_total",
@@ -609,6 +625,21 @@ class ServingMetrics:
         copied out of the pool (a layer's worth of each)."""
         monitor.stat_add(PREFIX + "attention_pages_live_total", live)
         monitor.stat_add(PREFIX + "attention_pages_staged_total", staged)
+
+    def on_window_release(self, pages: int) -> None:
+        """Pages of a window group returned to its allocator because no
+        later query of their slot can see them."""
+        monitor.stat_add(PREFIX + "kv_window_pages_released_total",
+                         int(pages))
+
+    def on_kv_residency(self, resident: int, one_lifetime: int) -> None:
+        """At one decode launch of a model of several page groups: the
+        pages resident in each group times the group's layers, and what
+        one table and one lifetime would hold for the same contexts."""
+        monitor.stat_add(PREFIX + "kv_resident_page_layers_total",
+                         int(resident))
+        monitor.stat_add(PREFIX + "kv_one_lifetime_page_layers_total",
+                         int(one_lifetime))
 
     def on_decode_drain(self, reason: str) -> None:
         """One decode in flight fetched before the next launch."""

@@ -213,11 +213,14 @@ class Scheduler:
         # the request's own total, and the lone-request growth guarantee
         # must cover that worst case too
         total = req.prompt_len + req.max_new_tokens + self.decode_reserve
-        if not self.cache.fits_ever(total):
+        if not self.cache.fits_ever(total, req.prompt_len):
             raise ValueError(
                 f"request {req.rid}: {total} tokens can never fit "
                 f"(max {self.cache.cfg.max_tokens_per_seq} per sequence, "
-                f"{self.cache.cfg.usable_pages} usable pages"
+                + ", ".join(f"{g.allocator.num_usable} usable pages"
+                            + (f" in group {g.name}"
+                               if len(self.cache.groups) > 1 else "")
+                            for g in self.cache.groups)
                 + (f", incl. the speculative decode reserve of "
                    f"{self.decode_reserve}" if self.decode_reserve else "")
                 + ")")

@@ -39,6 +39,14 @@ TINY_GRANITE = dict(vocab_size=96, hidden_size=32, num_hidden_layers=8,
                     shared_intermediate_size=48, mamba_n_heads=4,
                     mamba_d_head=16, mamba_d_state=8, mamba_chunk_size=8,
                     max_position_embeddings=64)
+TINY_MELLUM = dict(vocab_size=96, hidden_size=64, moe_intermediate_size=32,
+                   num_hidden_layers=8,
+                   layer_types=["sliding_attention"] * 3
+                   + ["full_attention"] + ["sliding_attention"] * 3
+                   + ["full_attention"],
+                   num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+                   num_experts=8, num_experts_per_tok=2, sliding_window=8,
+                   max_position_embeddings=64)
 
 
 def load(path):
@@ -48,7 +56,8 @@ def load(path):
 
 def tiny_model(config: dict) -> dict:
     tiny = {"gpt3": TINY_GPT3, "kimi_k2": TINY_KIMI,
-            "granite_hybrid": TINY_GRANITE}[config["family"]]
+            "granite_hybrid": TINY_GRANITE,
+            "mellum": TINY_MELLUM}[config["family"]]
     return dict(config["model"], **tiny)
 
 
@@ -71,6 +80,12 @@ def program_model(config: dict, model: dict):
         with paddle.LazyGuard():
             return GraniteHybridForCausalLM(
                 granite_hybrid.program_config(model))
+    if config["family"] == "mellum":
+        from benchmark.families import mellum
+        from paddle_tpu.text.mellum import MellumForCausalLM
+
+        with paddle.LazyGuard():
+            return MellumForCausalLM(mellum.program_config(model))
     from benchmark.families import kimi_k2
     from paddle_tpu.text.kimi_k2 import KimiK2ForCausalLM
 
@@ -210,6 +225,89 @@ def test_granite_hybrid_copy_of_the_reference_gives_the_repos_logits():
     low = granite_hybrid.logits_at(leaves_of, jnp.asarray(ids), positions,
                                    model, "fp8")
     assert 1e-4 < float(jnp.max(jnp.abs(low - want))) < 1.0
+
+
+def _tiny_mellum():
+    from benchmark.families import mellum
+    from benchmark.lib import weights
+
+    config = {"model": dict(load(os.path.join(
+        ROOT, "benchmark", "configs",
+        "mellum2-12b-a2.5b-serve.json"))["model"], **TINY_MELLUM),
+        "precision": {"parameters": "bfloat16"}}
+    leaves_of = weights.for_reference(mellum, config, seed=2147483659)
+    rng = np.random.default_rng(0)
+    ids = np.zeros((2, 40), np.int32)
+    ids[:, :30] = rng.integers(1, 96, (2, 30))
+    positions = jnp.asarray([[3, 10, 29], [0, 17, 28]], jnp.int32)
+    return mellum, config["model"], leaves_of, ids, positions
+
+
+def test_mellum_copy_of_the_reference_gives_the_repos_logits():
+    """The same float32 leaves through ``benchmark/families/mellum.py``'s
+    ``logits_at`` (a layer's leaves at a time, attention a block of
+    queries at a time, the experts one after another, the head at the
+    positions asked for, zeros padded behind the rows) and through
+    ``tests/refs/mellum_reference.py``: the same logits at positions
+    inside the window and three windows on, to float32 round-off of
+    values of order 0.3 (2e-6)."""
+    import mellum_reference as ref
+
+    mellum, model, leaves_of, ids, positions = _tiny_mellum()
+    got = mellum.logits_at(leaves_of, jnp.asarray(ids), positions, model)
+    cfg = mellum.program_config(model)
+    want = ref.forward(mellum.as_used(leaves_of()), jnp.asarray(ids[:, :30]),
+                       cfg)
+    want = jnp.take_along_axis(want, positions[..., None], axis=1)
+    assert got.shape == want.shape == (2, 3, 96)
+    assert float(jnp.max(jnp.abs(want))) > 0.1
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+    # the control's policy runs, and is not the reference
+    low = mellum.logits_at(leaves_of, jnp.asarray(ids), positions, model,
+                           "fp8")
+    assert 1e-4 < float(jnp.max(jnp.abs(low - want))) < 1.0
+    # the reference sees the window: with every layer's MASK full, and
+    # with every layer's windowed (the rotary tables stay each kind's),
+    # the logits inside the first window are the same and those three
+    # windows on are others
+    windowed = mellum._window_of
+    try:
+        for window_of in (lambda m, kind: None,
+                          lambda m, kind: m["sliding_window"]):
+            mellum._window_of = window_of
+            mellum._layer.clear_cache()     # traced under the other mask
+            other = mellum.logits_at(leaves_of, jnp.asarray(ids), positions,
+                                     model)
+            assert float(jnp.max(jnp.abs(other - got)[:, 0])) < 2e-6
+            assert float(jnp.max(jnp.abs(other - got)[:, 2])) > 1e-4
+    finally:
+        mellum._window_of = windowed
+        mellum._layer.clear_cache()
+
+
+def test_mellum_copy_of_the_reference_gives_the_programs_logits():
+    """The benchmark's leaves installed into the program's model
+    (``install_weights``, bfloat16 as the configuration serves them) and
+    its whole forward in float32 arithmetic against the family's
+    ``logits_at`` over the same leaves held in float32."""
+    from benchmark.lib import weights
+    from benchmark.lib.common import install_weights
+    from paddle_tpu.text.mellum import MellumForCausalLM
+
+    mellum, model, leaves_of, ids, positions = _tiny_mellum()
+    with paddle.LazyGuard():
+        program = MellumForCausalLM(mellum.program_config(model))
+    config = {"model": model, "precision": {"parameters": "float32"}}
+    install_weights(program, mellum.as_used(
+        {n: a.astype(jnp.float32) for n, a in weights.for_program(
+            mellum, dict(config, precision={"parameters": "bfloat16"}),
+            2147483659).items()}))
+    program.eval()
+    got = program(paddle.to_tensor(ids[:, :30]))._value
+    got = jnp.take_along_axis(got, positions[..., None], axis=1)
+    want = mellum.logits_at(leaves_of, jnp.asarray(ids), positions, model)
+    assert got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(got - want))) < 5e-6
 
 
 def test_gpt3_copy_of_the_reference_gives_the_programs_logits():

@@ -247,3 +247,133 @@ def test_pages_staged_is_the_table_s_width_without_the_kernel():
     a decode launch is counted as the composite stages it."""
     staged = pa.grouped_pages_staged_fn(8, KV, D, PAGE, PPS, 1, itemsize=4)
     assert staged(np.asarray([0, 9, 31])).tolist() == [PPS] * 3
+
+
+# ------------------------------------------------------- a window layer's
+WINDOW = 12                     # a chunk and a half: its edge off a page's
+
+
+@functools.lru_cache(maxsize=None)
+def windowed(window):
+    return (jax.jit(lambda q, k, v, t, c: pd.gqa_decode_attention(
+                q, k, v, t, c, SCALE, interpret=True, window=window)),
+            jax.jit(lambda q, k, v, t, c: pa._grouped_composite(
+                q, k, v, t, c, SCALE, window)))
+
+
+def plain_formula(q, k, v, table, ctx, window):
+    """Attention of the token at position ``ctx`` to positions ``ctx -
+    window + 1 .. ctx`` (from 0), each head to its KV head, float64."""
+    heads, g = q.shape[1], q.shape[1] // KV
+    rows = np.asarray(k, np.float64)[np.asarray(table[0])].reshape(
+        TOTAL, KV, D)
+    vals = np.asarray(v, np.float64)[np.asarray(table[0])].reshape(
+        TOTAL, KV, D)
+    lo = max(0, ctx + 1 - window)
+    out = np.zeros((heads, D))
+    for h in range(heads):
+        s = rows[lo:ctx + 1, h // g] @ np.asarray(q, np.float64)[0, h, 0] \
+            * SCALE
+        w = np.exp(s - s.max())
+        out[h] = (w / w.sum()) @ vals[lo:ctx + 1, h // g]
+    return out
+
+
+@pytest.mark.parametrize("ctx", [0, 5, WINDOW - 2, WINDOW - 1, WINDOW,
+                                 WINDOW + 1, 2 * CHUNK - 1, 2 * CHUNK + 3,
+                                 TOTAL - 1],
+                         ids=lambda c: f"ctx{c}")
+@pytest.mark.parametrize("g", [1, 4])
+def test_window_kernel_is_the_plain_formula(interpret, g, ctx):
+    """Contexts under, at and over the window, its first position on and
+    off a page's and a chunk's edge: the kernel, the composite and the
+    formula written out agree (float32 round-off)."""
+    q, k, v, table = operands(g, "float32", 1)
+    kernel, composite = windowed(WINDOW)
+    c = jnp.asarray([ctx], jnp.int32)
+    got = kernel(q, k, v, table, c)
+    want = plain_formula(q, k, v, table, ctx, WINDOW)
+    assert got.shape == (1, KV * g, 1, D)
+    assert np.abs(np.asarray(got)[0, :, 0] - want).max() < TOL["float32"]
+    assert gap(got, composite(q, k, v, table, c)) < TOL["float32"]
+
+
+def test_window_none_is_the_kernel_it_was(interpret):
+    """``window=None`` traces the kernel without the argument, operand for
+    operand (the same jaxpr), and a window no context reaches gives its
+    result bit for bit."""
+    q, k, v, table = operands(4, "bfloat16", 3, seed=2)
+    ctx = jnp.asarray([3, CHUNK, TOTAL - 1], jnp.int32)
+    plain = lambda *a: pd.gqa_decode_attention(  # noqa: E731
+        *a, SCALE, interpret=True)
+    none = lambda *a: pd.gqa_decode_attention(  # noqa: E731
+        *a, SCALE, interpret=True, window=None)
+    args = (q, k, v, table, ctx)
+    assert str(jax.make_jaxpr(plain)(*args)) \
+        == str(jax.make_jaxpr(none)(*args))
+    assert "gqa_decode_attention_window" not in str(
+        jax.make_jaxpr(none)(*args))
+    wide = windowed(TOTAL + 5)[0]
+    assert jnp.array_equal(wide(*args), both_paths()[0](*args))
+
+
+def test_window_rows_of_one_batch_and_what_lies_behind(interpret):
+    """Five rows whose windows start in different chunks (the pipeline
+    hands a row's last chunk over to the next row's FIRST LIVE chunk), and
+    behind every window keys of any kind and values of any finite size,
+    on freed pages' columns too (the null page): none of it reaches the
+    result."""
+    q, k, v, _ = operands(4, "float32", 5, seed=1)
+    table = np.tile(np.arange(1, PPS + 1), (5, 1))
+    ctx = np.asarray([TOTAL - 1, 2, CHUNK + 1, 3 * CHUNK - 1, 5000], np.int32)
+    kernel, composite = windowed(WINDOW)
+    clean = kernel(q, k, v, jnp.asarray(table, jnp.int32), jnp.asarray(ctx))
+    # (the last row is a dead slot's garbage length: the kernel clamps it
+    # inside the table, the composite's mask hides every position)
+    assert gap(clean[:4], composite(
+        q, k, v, jnp.asarray(table, jnp.int32),
+        jnp.asarray(ctx))[:4]) < TOL["float32"]
+    # behind each row's window: the table names the null page, as the
+    # cache leaves a freed page's column, and the null page holds dirt
+    freed = table.copy()
+    for r, c in enumerate(np.minimum(ctx, TOTAL - 1)):
+        freed[r, :max(0, c + 1 - WINDOW) // PAGE] = 0
+    dirty_k, dirty_v = np.asarray(k).copy(), np.asarray(v).copy()
+    dirty_k[0], dirty_v[0] = 1e4, -1e30
+    got = kernel(q, jnp.asarray(dirty_k), jnp.asarray(dirty_v),
+                 jnp.asarray(freed, jnp.int32), jnp.asarray(ctx))
+    assert jnp.array_equal(got, clean)
+    for r in range(5):
+        alone = kernel(q[r:r + 1], k, v, jnp.asarray(table[r:r + 1],
+                                                     jnp.int32),
+                       jnp.asarray(ctx[r:r + 1]))
+        assert jnp.array_equal(alone[0], clean[r])
+
+
+def test_pages_staged_with_a_window_is_the_kernel_s_own_loop(interpret):
+    """The host's count for a window layer's decode launch: the chunks
+    from the first that holds a position inside the window
+    (``_live_start``, traced here in the kernel's own primitives) to the
+    last live one, times the pages a chunk, for every ``ctx_lens``."""
+    ctx = np.r_[np.arange(0, TOTAL + 3), 50_000].astype(np.int32)
+    staged = pa.grouped_pages_staged_fn(8, KV, D, PAGE, PPS, 1, itemsize=4,
+                                        window=WINDOW)
+    whole = pa.grouped_pages_staged_fn(8, KV, D, PAGE, PPS, 1, itemsize=4)
+    chunk_pages = pd.gqa_chunk_pages(PAGE, PPS)
+
+    def traced(c):
+        length = jax.lax.clamp(np.int32(1), c + np.int32(1), np.int32(TOTAL))
+        end = rp._live_span(length, CHUNK, PAGE, TOTAL, ops=rp._LAX)[0]
+        start, lo = rp._live_start(c + np.int32(1), CHUNK, WINDOW, TOTAL,
+                                   ops=rp._LAX)
+        return end - start, lo
+
+    chunks, lo = (np.asarray(a) for a in jax.vmap(traced)(jnp.asarray(ctx)))
+    assert staged(ctx).tolist() == (chunks * chunk_pages).tolist()
+    assert lo[:TOTAL].tolist() == np.maximum(
+        0, ctx[:TOTAL] + 1 - WINDOW).tolist()
+    # never more than the whole context's, and at most the chunks a window
+    # can touch: 12 positions lie in at most 3 chunks of 8
+    assert (staged(ctx) <= whole(ctx)).all()
+    assert staged(ctx).max() == 3 * chunk_pages < whole(ctx).max() == PPS
+    assert staged(ctx)[0] == chunk_pages

@@ -331,7 +331,9 @@ def test_registry_names_are_stable():
                              "swap_scatter_q8", "tp2_engine_decode_q8",
                              "tp2_engine_decode_qlogits",
                              "engine_prefill_latent",
-                             "engine_decode_latent"}
+                             "engine_decode_latent",
+                             "engine_prefill_window",
+                             "engine_decode_window"}
     assert REGISTRY["tp8_decode"].min_devices == 8
     assert all(REGISTRY[n].min_devices == 2 for n in REGISTRY
                if n.startswith("tp2_"))

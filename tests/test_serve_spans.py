@@ -165,6 +165,34 @@ def cow(tmp_path_factory):
     return {"engine": engine, "spans": spans, "names": names}
 
 
+@pytest.fixture(scope="module")
+def window(tmp_path_factory):
+    """A model of window layers beside a full one (text/mellum.py): a
+    prompt of three windows and ten more tokens, so window pages go back
+    behind the prefill's launch and behind decode steps."""
+    from paddle_tpu.text.mellum import MellumConfig, MellumForCausalLM
+
+    paddle.seed(5)
+    m = MellumForCausalLM(MellumConfig(
+        vocab_size=97, hidden_size=32, moe_intermediate_size=16,
+        num_hidden_layers=2, layer_types=["sliding_attention",
+                                          "full_attention"],
+        num_attention_heads=2, num_key_value_heads=1, head_dim=16,
+        num_experts=4, num_experts_per_tok=2, sliding_window=4,
+        max_position_embeddings=32, initializer_range=0.1))
+    engine = _engine(m, group_pages={"window": 8},
+                     enable_prefix_caching=False)
+    engine.add_request(_prompt(12, 0), 3)
+    engine.run()
+
+    def run():
+        engine.add_request(_prompt(12, 1), 10)
+        engine.run()
+
+    spans, names = _traced(tmp_path_factory.mktemp("window"), run)
+    return {"engine": engine, "spans": spans, "names": names}
+
+
 # ------------------------------------------------------------ the span tree
 @pytest.mark.parametrize("scenario,child,parent", [
     ("plain", "admit", "step"),
@@ -202,7 +230,25 @@ def test_span_nests_under_its_parent(request, scenario, child, parent):
             assert s.stats["rid"] == p.stats["rid"]
 
 
-@pytest.mark.parametrize("scenario", ["plain", "chunked", "spec", "cow"])
+def test_window_release_lies_behind_a_prefill_s_launch_and_in_evict(window):
+    """``serve.window_release``: the host's freeing of window-group pages
+    behind the window: inside ``serve.prefill`` (behind the launch that
+    read them) and inside ``serve.evict`` (before a decode step grows its
+    slots), each in its step; the counter counts what they freed."""
+    spans = window["spans"]
+    mine = [s for s in spans if s.name == "window_release"]
+    parents = {_parent(s, spans).name for s in mine}
+    assert parents == {"prefill", "evict"}
+    for s in mine:
+        assert s.stats["step"] == _parent(s, spans).stats["step"]
+    freed = window["engine"].metrics.snapshot()[
+        "serving_kv_window_pages_released_total"]
+    # two prompts of 12 and 13 more tokens at a window of 4, pages of 4
+    assert freed >= 2 * 2 + 2
+
+
+@pytest.mark.parametrize("scenario", ["plain", "chunked", "spec", "cow",
+                                      "window"])
 def test_every_span_carries_step_and_lies_in_its_step(request, scenario):
     spans = request.getfixturevalue(scenario)["spans"]
     steps = {s.stats["step"]: s for s in spans if s.name == "step"}
