@@ -371,9 +371,12 @@ def program_custom_calls(engine) -> dict:
                             jax.ShapeDtypeStruct((b,), jnp.bool_),
                             i32(b), i32(b)))}
     for bucket in SERVE_BUCKETS:
+        # ids, their count, tokens resident, the page row, rid, slot, and
+        # the last launch's tokens (engine._prefill_args)
         programs[f"prefill[{bucket}]"] = (
             engine.guards["prefill"],
-            (i32(bucket), i32(), i32(), i32(pps), i32()))
+            (i32(bucket), i32(), i32(), i32(pps), i32(), i32(),
+             i32(*engine._prev_toks.shape)))
     counts = {}
     for label, (guard, rest) in programs.items():
         text = jax.jit(guard.fn, donate_argnums=guard.donate_argnums).lower(

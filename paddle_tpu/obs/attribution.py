@@ -32,11 +32,17 @@ span                    extent                                attributes
 ``serve.chunk_prefill``  the chunk loop                       chunks
 ``serve.prefill.upload``    inside either, one a launch of    rid, bytes
                             the one prefill path: the padded
-                            ids and the five device operands
+                            ids and the five operands from
+                            the host
 ``serve.prefill.dispatch``  the call of the jitted program    rid
 ``serve.prefill.fetch``     the first-token fetch of a        rid
-                            launch that completed a prompt
-                            (blocks)
+                            launch that completed a prompt:
+                            inside ``serve.decode``, behind
+                            the step's launch (or inside
+                            ``serve.drain``); inside the
+                            prefill's own span where the
+                            engine fetches at once (spec,
+                            ``debug_checks``); blocks
 ``serve.evict``         fault sites, decode-page pressure,
                         preemption
 ``serve.decode``        the decode phase                      batch
@@ -45,8 +51,8 @@ span                    extent                                attributes
 ``serve.decode.fetch``      the token fetch of the PREVIOUS   of_step
                             step's launch (blocks)
 ``serve.decode.emit``       the per-slot loop, retirements
-``serve.drain``         an early fetch + emit of the decode   reason
-                        in flight
+``serve.drain``         an early fetch + emit of what is in   reason
+                        flight (first tokens, the decode)
 ``serve.verify``        the speculative verify phase          batch
 ``serve.verify.dispatch``   the call of the jitted program
 ``serve.verify.fetch``      the packed fetch of that same
@@ -66,6 +72,7 @@ mark is charged to it when it closes, so the phases plus the residual
 sampling, no double counting (``StepRecord.phase_s``). Every other span
 (the dotted ``*.upload`` / ``*.dispatch`` / ``*.fetch`` / ``*.emit``,
 ``cow_copy``, ``window_release``, ``account``) measures its own extent, lies inside a phase
+(not always the phase of its name: ``prefill.fetch`` lies in ``decode``)
 and is no part of that sum (``StepRecord.span_s``).
 
 ZERO device syncs either way (clock reads and TraceMe events only — the

@@ -34,11 +34,13 @@ runs all of them the same way, through code that exists once:
 - ONE fetch (``_fetch``): the single sanctioned device->host copy of a
   program's output, inside the caller's ``*.fetch`` span, with the model's
   counters split off the tokens. Decode fetches the launch of the step
-  before (below); a prefill and a verify fetch their own;
+  before, a completed prefill is fetched behind the decode launch that
+  follows it (below); a verify fetches its own;
 - ONE prefill path (``_prefill``): advance a request's prefill by ``n``
-  tokens from where it stands; if that completes the prompt, fetch the
-  first token and seat the request (``_seat``, which a swap-resume uses
-  too). A whole uncached tail is the one chunk that is final.
+  tokens from where it stands; if that completes the prompt, seat the
+  request (``_seat``, which a swap-resume uses too) with its first token
+  in flight and leave the token's fetch (``_first_token``) to the decode
+  phase. A whole uncached tail is the one chunk that is final.
 
 ``_step`` is then the schedule and nothing else: sweep and admit, seat or
 prefill what was admitted, advance the chunked prefills, fault sites and
@@ -110,51 +112,69 @@ ones (their uncached tail is cheap). The current limit is mirrored in the
 ``serving_chunk_limit`` gauge.
 
 The order of a step, and when a token is handed over. ``step()`` sweeps
-deadlines, admits and prefills (a prefill blocks on its own first-token
-fetch, as it always has), makes room for the decode, LAUNCHES decode k,
-and only then fetches, emits and retires the tokens of decode k-1, which
-the previous ``step()`` launched and which has been running on the device
-while the host came round. So everything the host does between two
-launches (the fetch's tail, emit, accounting, the caller's loop, admit,
-evict, upload, dispatch) happens under a decode program and not between
-two of them. What it takes:
+deadlines, admits and LAUNCHES the prefills of what it admitted (no
+fetch), makes room for the decode, LAUNCHES decode k behind them, and
+only then fetches: first the tokens of decode k-1, which the previous
+``step()`` launched and which has been running on the device while the
+host came round (emitted and retired at once), then the first token of
+each prefill it completed, in admission order. On the device the order
+is decode k-1, the prefills, decode k, with nothing between them: all
+that the host does between two launches (a fetch's tail, emit,
+accounting, the caller's loop, admit, a prefill's upload and dispatch,
+evict, the decode's upload and dispatch) happens under a program and not
+between two of them. What it takes:
 
 - the last token stays on the device: the decode program takes the
   previous launch's token output as it is (``prev_toks``, not donated —
   it is still to be fetched) and merges it in-program with the host's
-  ``override`` for the slots whose last token the host does know (just
-  prefilled, swap-resumed, or every slot of a drained engine); no eager
-  operation runs between two steps;
+  ``override`` for the slots whose last token the host does know
+  (swap-resumed, or every slot of a drained engine). A prefill program
+  takes ``prev_toks`` too and returns it with its sampled token in the
+  request's slot: that array is the next launch's ``prev_toks`` and what
+  the host fetches for the first token. No eager operation runs between
+  two steps;
 - what does not depend on a token's value advances at the launch
-  (``_ctx``, ``_gen``, the page that ``ensure_decode_pages`` reserves);
-  what does (``req.generated``, ``tokens_emitted``, ``_last_tok``, the
-  finish, ``decode_mark``, ``on_tokens``) advances at the fetch. A
-  caller sees a decode token when it is appended to ``req.generated``:
-  one ``step()`` after the step that launched it. A prefill's first
-  token is handed over by the step that prefilled;
-- finish by length is known at the launch (tokens emitted + in flight):
-  such a slot is left out of the next launch and retires at its fetch.
-  Finish by EOS is known one step late: the surplus token of the launch
-  already made is dropped at its fetch — never appended, counted or
-  indexed; its KV write went to a page of the request's own, freed with
-  it. Outputs are token for token those of an engine that fetches every
-  step;
-- ``_drain(reason)`` fetches and emits what is in flight, now, at every
-  site that needs the host's view whole before it acts
-  (``DRAIN_REASONS``): preemption and swap-out, ``cancel`` and the
-  deadline sweep when they hit a request with a token in flight, the
-  fault injector's decode-phase hits, ``debug_checks`` (every step: the
-  invariant sweep and the sync tally read a whole step), the flight
-  record and the fatal path, the end of ``run()``. A drained engine is
-  exactly the engine that fetched every step; a ``step()`` with a decode
-  in flight and nothing to launch only fetches. A device error of decode
-  k surfaces at its fetch in step k+1, with a note naming step k;
-- speculative decoding (``_verify_phase``) replaces plain decode
-  wholesale, fetches its own launch in the same step, and never has a
-  decode in flight.
+  (``_ctx``, ``_gen``, the page that ``ensure_decode_pages`` reserves, a
+  prefilled request's seat); what does (``req.generated``,
+  ``tokens_emitted``, ``_last_tok``, the finish, ``decode_mark``,
+  ``prefill_end`` / ``first_token``, the prompt's pages in the prefix
+  index, ``on_tokens``) advances at the fetch. A caller sees a decode
+  token when it is appended to ``req.generated``: one ``step()`` after
+  the step that launched it. A prefill's first token is handed over by
+  the step that completed the prefill, behind that step's decode launch;
+- finish by length is known at the launch (tokens emitted + in flight, a
+  first token in flight among them: a request of ``max_new_tokens`` 1 is
+  never launched): such a slot is left out of the next launch and
+  retires at its fetch. Finish by EOS is known one step late (for a
+  first token: after the decode behind the prefill was launched): the
+  surplus token of the launch already made is dropped at its fetch —
+  never appended, counted or indexed; its KV write went to a page of the
+  request's own, freed with it. Outputs are token for token those of an
+  engine that fetches every step;
+- ``_drain(reason)`` fetches and emits what is in flight, now (the first
+  tokens of prefills not yet fetched, then the decode), at every site
+  that needs the host's view whole before it acts (``DRAIN_REASONS``):
+  preemption and swap-out, ``cancel`` and the deadline sweep when they
+  hit a request with a token in flight, the fault injector's
+  decode-phase hits, ``debug_checks`` (every step: the invariant sweep
+  and the sync tally read a whole step), the flight record and the fatal
+  path, the end of ``run()``. A drained engine is exactly the engine
+  that fetched every step; a ``step()`` with a decode in flight and
+  nothing to launch only fetches. A device error of decode k surfaces at
+  its fetch in step k+1, with a note naming step k;
+- three callers want a first token on the host at once and keep the
+  blocking fetch inside ``_prefill`` (one branch at the fetch, decided
+  by the engine's own state): speculative decoding (``_verify_phase``
+  replaces plain decode wholesale, mirrors every known token into the
+  proposers' history, fetches its own launch in the same step and never
+  has a launch in flight), ``debug_checks`` (every step drains), and, as
+  ever, a chunk that is not final, which fetches nothing at all.
 
 ``serving_decode_overlapped_total`` over ``serving_decode_steps`` is the
-share of launches made under a decode in flight;
+share of launches made under a decode in flight,
+``serving_prefill_overlapped_total`` over ``serving_prefills_total`` the
+share of completed prefills whose first token was fetched behind the
+decode launch that followed them;
 ``serving_decode_drains_total{reason=}`` counts the early fetches.
 
 Decode semantics match text/generation.py: prefill picks the first token
@@ -244,17 +264,29 @@ span                                  extent; attributes
                                       ``tail``
 ``serve.chunk_prefill``               the chunk loop; ``chunks``
 ``serve.prefill.upload``              inside either, one a launch: the
-                                      padded ids and the five device
-                                      operands; ``rid``, ``bytes``
+                                      padded ids and the five operands
+                                      from the host (``prev_toks`` is on
+                                      the device); ``rid``, ``bytes``
 ``serve.prefill.dispatch``            the call of the jitted program;
                                       ``rid``
 ``serve.prefill.fetch``               the first-token fetch of a launch
-                                      that completed a prompt (blocks: the
-                                      device time lands here); ``rid``
+                                      that completed a prompt: inside
+                                      ``serve.decode``, behind this step's
+                                      launch and the fetch of the step
+                                      before's (blocks for what is left of
+                                      the prefill), or inside
+                                      ``serve.drain``; inside
+                                      ``serve.prefill`` /
+                                      ``serve.chunk_prefill`` where the
+                                      engine fetches at once (spec,
+                                      ``debug_checks``); ``rid``
 ``serve.evict``                       fault sites, decode-page pressure,
                                       preemption
-``serve.decode``                      the decode phase; ``batch`` (the
-                                      slots launched)
+``serve.decode``                      the decode phase: this step's
+                                      launch, the fetch and emit of the
+                                      step before's, the first-token
+                                      fetches of this step's prefills;
+                                      ``batch`` (the slots launched)
 ``serve.decode.upload``               the six device operands, the whole
                                       page table among them; ``bytes``
 ``serve.decode.dispatch``             the call of the jitted program:
@@ -264,9 +296,11 @@ span                                  extent; attributes
                                       left of it); ``of_step``
 ``serve.decode.emit``                 the per-slot loop over the fetched
                                       tokens, retirements
-``serve.drain``                       an early fetch + emit of the decode
-                                      in flight (``decode.fetch`` and
-                                      ``decode.emit`` inside); ``reason``
+``serve.drain``                       an early fetch + emit of what is in
+                                      flight (``prefill.fetch`` of each
+                                      unfetched prefill, ``decode.fetch``
+                                      and ``decode.emit`` inside);
+                                      ``reason``
 ``serve.verify``                      the speculative verify phase;
                                       ``batch``
 ``serve.verify.dispatch``             the call of the jitted program
@@ -326,6 +360,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import time
+from collections import deque
 from dataclasses import dataclass
 from typing import NamedTuple
 
@@ -502,6 +537,15 @@ class _Program(NamedTuple):
     rows: int   # what one launch computes: rows x tokens a row, padding
     tokens: int  # and dead slots too
     counters: int  # the model's counters behind its tokens (_with_counters)
+
+
+class _FirstToken(NamedTuple):
+    """A completed prefill whose sampled token is still on the device:
+    what its fetch needs (``engine._unfetched``)."""
+    req: Request
+    prog: _Program  # the prefill program that ran
+    out: object     # its output: prev_toks with the token in req.slot
+    tokens: int     # prompt tokens the launch computed
 
 
 class ServingEngine:
@@ -775,9 +819,20 @@ class ServingEngine:
         # _gen are ahead of the host's tokens by the one in flight;
         # _last_tok is the host's mirror and lags it.
         self._inflight = None
-        # the last launch's token output, the next launch's last tokens for
-        # the slots whose token the host has not seen; until the first
-        # launch a placeholder that every slot overrides
+        # the completed prefills whose first token the host has not fetched
+        # yet, in admission order: a step launches its prefills, then its
+        # decode, and fetches them behind that launch. Empty between two
+        # steps; _drain fetches them first
+        self._unfetched: deque[_FirstToken] = deque()
+        # a speculative engine mirrors every known token into the
+        # proposers' history and never has a launch in flight, and
+        # debug_checks reads a whole step: both fetch a first token at once
+        self._first_token_at_once = cfg.spec is not None or cfg.debug_checks
+        # the last launch's token output (a decode's, or a completed
+        # prefill's: the decode's with the first token in its slot), the
+        # next launch's last tokens for the slots whose token the host has
+        # not seen; until the first launch a placeholder that every slot
+        # overrides
         prev = np.full(b + self._n_counters, cfg.pad_token_id, np.int32)
         self._prev_toks = (jnp.asarray(prev) if self._tp is None
                            else self._tp.replicated(prev))
@@ -827,7 +882,7 @@ class ServingEngine:
             # wrap the sharded callables, so compile counts, budgets, and
             # the retrace/donation audits are identical to single-chip
             prefill_impl = self._tp.wrap_step(
-                prefill_impl, spec.num_layers, n_rest=6,
+                prefill_impl, spec.num_layers, n_rest=7,
                 quantized=self.cache.cfg.quantized)
             decode_impl = self._tp.wrap_step(
                 decode_impl, spec.num_layers, n_rest=7,
@@ -938,16 +993,21 @@ class ServingEngine:
         return jnp.concatenate([jnp.atleast_1d(tok), counters])
 
     def _prefill_impl(self, p_arrays, pools, padded_ids, tail_len, ctx0,
-                      page_row, rid, slot):
+                      page_row, rid, slot, prev_toks):
         """One request's uncached prompt tail in one pass: padded_ids
         [bucket], tail_len scalar (real tail tokens), ctx0 scalar (tokens
         already resident from the prefix cache or an earlier chunk; 0 on
         a cold prefill), page_row [pages_per_seq], slot scalar (the
-        request's slot: the row of what a model keeps a slot; a model of
-        pages alone never reads it). The tail's queries enter at positions
-        ``ctx0 .. ctx0 + tail_len - 1`` against the slot's page table —
-        the cached prefix is attended through the same ragged-masked
-        gather decode uses. Returns (new_pools, first sampled token).
+        request's slot), prev_toks [max_batch (+ counters)] (the last
+        launch's token output, where it is: on the device, not donated —
+        it may still be to be fetched). The tail's queries enter at
+        positions ``ctx0 .. ctx0 + tail_len - 1`` against the slot's page
+        table — the cached prefix is attended through the same
+        ragged-masked gather decode uses. Returns (new_pools, prev_toks
+        with the sampled token in the request's slot and this launch's
+        counters behind): what the decode launch behind this one takes as
+        ITS ``prev_toks``, so the first token reaches the decode on the
+        device and the host fetches the same array when it comes round.
         Compiles once per pad bucket (padded_ids shape)."""
         n = padded_ids.shape[0]
         # one row; of a model of several page groups one row a group
@@ -972,17 +1032,20 @@ class ServingEngine:
             else:
                 tok = jnp.argmax(last, axis=-1)
             tok = tok.astype(jnp.int32)
-        return new_pools, self._with_counters(tok, counters)
+        b = self.config.max_batch
+        toks = prev_toks[:b].at[slot].set(tok)
+        return new_pools, self._with_counters(toks, counters)
 
     def _decode_impl(self, p_arrays, pools, table, ctx, prev_toks,
                      override, active, rids, gen_idx):
         """One token for every running slot. Inactive slots run the same
         computation against the null page and emit pad — branch-free, so the
         batch composition never changes the compiled program. A slot's last
-        token is ``prev_toks`` (the previous launch's output, still on the
-        device, never donated: the host fetches it after this launch) unless
-        the host knows it and says so with ``override >= 0``: a slot just
-        prefilled or swap-resumed, or every slot of a drained engine."""
+        token is ``prev_toks`` (the previous launch's output, a decode's or
+        a completed prefill's, still on the device, never donated: the host
+        fetches it after this launch) unless the host knows it and says so
+        with ``override >= 0``: a slot swap-resumed, or every slot of a
+        drained engine."""
         if self._n_counters:    # the last launch's counters ride behind
             prev_toks = prev_toks[:override.shape[0]]
         last_tok = jnp.where(override >= 0, override, prev_toks)
@@ -1281,19 +1344,26 @@ class ServingEngine:
             self._hist[slot] = 0
 
     def _seat(self, req: Request) -> None:
-        """Put a request whose last token the host knows into the decode
-        batch: the five per-slot arrays, from the request itself. A
-        prefill that has just fetched its first token and a swap-resume
-        (its KV came back with ``admit``) seat alike."""
+        """Put a request into the decode batch: the five per-slot arrays,
+        from the request itself. A swap-resume (its KV came back with
+        ``admit``) seats with the tokens the host knows; a completed
+        prefill seats WITHOUT its first token, which is in flight
+        (``tokens_in_flight`` 1: it is in the request's slot of
+        ``_prev_toks``, so the decode phase overrides nothing there and
+        ``all_launched`` counts it) and advances ``_ctx`` and ``_gen`` as
+        every token in flight does."""
         slot = req.slot
-        self._ctx[slot] = req.prompt_len + len(req.generated) - 1
-        self._last_tok[slot] = req.generated[-1]
+        known = len(req.generated) + req.tokens_in_flight
+        self._ctx[slot] = req.prompt_len + known - 1
+        self._last_tok[slot] = req.generated[-1] if req.generated \
+            else self.config.pad_token_id
         self._active[slot] = True
         self._rids[slot] = req.rid
-        self._gen[slot] = len(req.generated)
+        self._gen[slot] = known
         req.state = RUNNING
         req.fresh = True  # no decode yet: spared while a seasoned victim is
-        self._hist_sync(req)
+        if not req.tokens_in_flight:  # else at the first token's fetch
+            self._hist_sync(req)
 
     def _hist_sync(self, req: Request) -> None:
         """Mirror a request's known tokens (prompt + generated) into its
@@ -1371,13 +1441,15 @@ class ServingEngine:
                 f"{self.cache.cfg.usable_pages}")
 
     def step(self) -> list[int]:
-        """One continuous-batching iteration: sweep deadlines, admit +
-        prefill (or swap-resume) joiners, launch one decode step for the
-        whole batch, then fetch the tokens of the decode that the
-        PREVIOUS step launched and retire finishers. A decode token is
-        handed over (appended to ``req.generated``, counted, traced) one
-        ``step()`` after the step that launched it; a prefill's first
-        token in the step that prefilled it. Returns the request ids
+        """One continuous-batching iteration: sweep deadlines, admit and
+        launch the prefill of (or swap-resume) joiners, launch one decode
+        step for the whole batch behind them, then fetch the tokens of
+        the decode that the PREVIOUS step launched and retire finishers,
+        then fetch the first tokens of this step's prefills. A decode
+        token is handed over (appended to ``req.generated``, counted,
+        traced) one ``step()`` after the step that launched it; a
+        prefill's first token in the step that completed the prefill.
+        Returns the request ids
         whose finish this step saw (those that a drain between two steps
         finished among them). Injected faults retire only the requests
         they name; everything else keeps being served.
@@ -1689,13 +1761,19 @@ class ServingEngine:
         prompt tokens from ``req.prefilled_tokens`` through the prefill
         program of the smallest pad bucket that holds them — the queries
         enter at ``ctx_lens = tokens already resident``, the ragged
-        contract a prefix-cache tail and a chunk share — and, if that
-        completes the prompt, fetch the first token and seat the request.
-        A whole tail (``chunk_size == 0``) is the one chunk that is final.
-        A chunk that is not final never touches the host: its sampled
-        token (and its model counters) stay on the device, unfetched, so
-        the sync-free certification holds (one fetch per decode step +
-        one per COMPLETED prefill). A failure of the request's own
+        contract a prefix-cache tail and a chunk share. A whole tail
+        (``chunk_size == 0``) is the one chunk that is final. A chunk
+        that is not final never touches the host: its sampled token (and
+        its model counters) stay on the device, unfetched, so the
+        sync-free certification holds (one fetch per decode step + one
+        per COMPLETED prefill). A launch that completes the prompt leaves
+        its token where the decode launch behind it reads it (the
+        program's output becomes ``_prev_toks``), seats the request with
+        that token in flight, and queues the token's fetch
+        (``_unfetched``) for the decode phase to make behind its launch:
+        nothing here waits for the device. Where the engine wants the
+        token on the host at once (speculation, ``debug_checks``) the
+        same fetch is made here, blocking. A failure of the request's own
         retires it FAILED (engine-fatal ones raise). True when the
         prefill completed."""
         att, tr = self._attr, self._tracer
@@ -1730,13 +1808,38 @@ class ServingEngine:
                          bucket=bucket, final=final)
         if not final:
             return False
-        # a completed prefill's ONE sanctioned device->host sync: its
-        # first-token fetch (where the device time lands)
-        with att.span("prefill.fetch", rid=req.rid):
-            tok = int(self._fetch(prog, out).flat[0])
+        # the sampled token is in the request's slot of the program's
+        # output: the next launch's prev_toks. The request takes its seat
+        # with that token in flight
+        self._prev_toks = out
+        req.tokens_in_flight += 1
+        self._seat(req)
+        first = _FirstToken(req, prog, out, n)
+        if self._first_token_at_once:
+            self._first_token(first, finished_now, overlapped=False)
+        else:  # behind the decode launch that follows (_decode_phase)
+            self._unfetched.append(first)
+        return True
+
+    def _first_token(self, first: _FirstToken, finished_now: list,
+                     overlapped: bool) -> None:
+        """Fetch a completed prefill's first token (its ONE sanctioned
+        device->host sync) and hand it over: append, count, trace, index
+        the prompt's pages, retire a request that finishes with it.
+        ``overlapped``: a decode was launched behind the prefill before
+        this fetch, so the fetch's wait and tail lie under that decode
+        (``serving_prefill_overlapped_total``)."""
+        req, prog, out, n = first
+        tr = self._tracer
+        chunked = bool(self.config.chunk_size)
+        slot = req.slot
+        with self._attr.span("prefill.fetch", rid=req.rid):
+            tok = int(self._fetch(prog, out)[slot])
+        req.tokens_in_flight -= 1
         req.generated.append(tok)
         req.tokens_emitted += 1
-        self._seat(req)
+        self._last_tok[slot] = tok
+        self._hist_sync(req)
         if tr is not None:
             # prefill_end IS first-token time: the prefill pass samples
             # the request's first output token from its last logit.
@@ -1746,9 +1849,10 @@ class ServingEngine:
             tr.event(req.rid, "prefill_end",
                      tokens=req.prompt_len - req.prefix_hit_tokens)
             tr.event(req.rid, "first_token")
-        # every full prompt page is now resident: index it for reuse
-        self.cache.register_prefix(req.slot, req.prompt)
-        self.metrics.on_prefill(0 if chunked else n)  # chunks counted theirs
+        # every full prompt page is resident: index it for reuse
+        self.cache.register_prefix(slot, req.prompt)
+        # chunks counted their tokens themselves
+        self.metrics.on_prefill(0 if chunked else n, overlapped=overlapped)
         if self.config.enable_prefix_caching:
             if req.prefix_hit_tokens > 0:
                 self.metrics.on_prefix_hit(req.prefix_hit_tokens)
@@ -1757,7 +1861,14 @@ class ServingEngine:
         self.metrics.on_tokens(1)
         if self._maybe_finish(req, tok):
             finished_now.append(req.rid)
-        return True
+
+    def _fetch_first_tokens(self, finished_now: list,
+                            overlapped: bool) -> None:
+        """Fetch and hand over the first token of every completed prefill
+        that is still unfetched, in admission order."""
+        while self._unfetched:
+            self._first_token(self._unfetched.popleft(), finished_now,
+                              overlapped)
 
     def _inject_decode_faults(self, inj, step_idx: int) -> None:
         """The armed injector's step-boundary consults before the decode
@@ -1847,14 +1958,16 @@ class ServingEngine:
         launch computes, for the request in ``slot``) right-padded to the
         program's bucket, their count, the tokens already resident
         (``start``: the queries enter there), the slot's page-table row,
-        the request id (its PRNG stream) and the slot itself."""
+        the request id (its PRNG stream), the slot itself and the last
+        launch's tokens where they are, on the device."""
         padded = np.full(prog.tokens, self.config.pad_token_id, np.int32)
         padded[:len(ids)] = ids
         return (self._p, self.cache.pools, jnp.asarray(padded),
                 jnp.asarray(len(ids), jnp.int32),
                 jnp.asarray(start, jnp.int32),
                 jnp.asarray(self.cache.tables[..., slot, :]),
-                jnp.asarray(rid, jnp.int32), jnp.asarray(slot, jnp.int32))
+                jnp.asarray(rid, jnp.int32), jnp.asarray(slot, jnp.int32),
+                self._prev_toks)
 
     def _decode_args(self, active=None, override=None) -> tuple:
         """The decode program's operands as a launch uploads them: the
@@ -1939,16 +2052,21 @@ class ServingEngine:
 
     def _decode_phase(self, finished_now: list) -> int:
         """Launch one decode step for the whole batch, THEN fetch and emit
-        the tokens of the decode that the previous step launched: the
-        ``serve.decode`` span and its four parts (upload, dispatch, the
-        fetch of the previous launch — this step's ONE sanctioned
-        device->host sync — and the per-slot bookkeeping of those tokens).
-        What does not depend on a token's value advances at the launch
-        (``_ctx``, ``_gen``; the page was reserved by
+        the tokens of the decode that the previous step launched, THEN
+        fetch the first token of each prefill this step completed: the
+        ``serve.decode`` span and its parts (upload, dispatch, the fetch
+        of the previous launch and the per-slot bookkeeping of those
+        tokens, a ``prefill.fetch`` a completed prefill: one sanctioned
+        device->host sync a decode step and one a completed prefill, as
+        ever). What does not depend on a token's value advances at the
+        launch (``_ctx``, ``_gen``; the page was reserved by
         ``ensure_decode_pages``), what does advances at the fetch, one
-        step later. A slot whose last token is already in flight is left
-        out of the launch; with nothing to launch the phase only fetches.
-        Returns the slots launched."""
+        step later. A slot whose last token the host has not seen (a
+        decode's or a prefill's, in flight) takes it from ``_prev_toks``
+        on the device (``override`` -1); a slot whose last token is in
+        flight already (finish by length) is left out of the launch; with
+        nothing to launch the phase only fetches. Returns the slots
+        launched."""
         att = self._attr
         prev = self._inflight
         active = np.zeros_like(self._active)
@@ -1982,6 +2100,9 @@ class ServingEngine:
                 self._inflight = None
             if prev is not None:
                 self._fetch_and_emit(prev, finished_now)
+            # this step's prefills, behind its launch: their wait and tail
+            # lie under the decode just launched
+            self._fetch_first_tokens(finished_now, overlapped=bool(launched))
             if self.config.debug_checks:
                 # the invariant sweep and the sync tally that follow read
                 # a whole step: nothing stays in flight under debug_checks
@@ -2024,16 +2145,22 @@ class ServingEngine:
             self.metrics.on_tokens(n_new)
 
     def _drain(self, reason: str) -> bool:
-        """Fetch and emit what is in flight, now: for a site that needs
-        the host's view whole before it acts (``DRAIN_REASONS``). A
-        drained engine is the engine that fetched every step. Requests it
-        finishes are reported by the step that is running, or the next.
-        True when something was in flight."""
+        """Fetch and emit what is in flight, now (the first tokens of
+        the prefills this step completed and has not fetched, then the
+        decode): for a site that needs the host's view whole before it
+        acts (``DRAIN_REASONS``). A drained engine is the engine that
+        fetched every step. Requests it finishes are reported by the step
+        that is running, or the next. True when something was in
+        flight."""
         inflight, self._inflight = self._inflight, None
-        if inflight is None:
+        if inflight is None and not self._unfetched:
             return False
         with self._attr.span("drain", reason=reason):
-            self._fetch_and_emit(inflight, self._drained_finished)
+            # a first token before the decode token that follows it
+            self._fetch_first_tokens(self._drained_finished,
+                                     overlapped=False)
+            if inflight is not None:
+                self._fetch_and_emit(inflight, self._drained_finished)
         self.metrics.on_decode_drain(reason)
         return True
 

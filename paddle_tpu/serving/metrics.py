@@ -10,7 +10,17 @@ them with no new plumbing):
 - serving_page_utilization  gauge: used / usable pages (0..1)
 - serving_tokens_total      counter: generated tokens (monotonic)
 - serving_tokens_per_sec    gauge: windowed decode throughput
-- serving_prefills_total    counter
+- serving_prefills_total    counter: prefills completed (first token
+                            handed over)
+- serving_prefill_overlapped_total counter: completed prefills whose
+                            first token was fetched after the decode
+                            behind them was launched (the fetch's wait
+                            and tail lay under that decode and not in
+                            series with its upload and dispatch); over
+                            serving_prefills_total, the overlapped share.
+                            A speculative or debug_checks engine, which
+                            fetches a first token at once, and a prefill
+                            that a drain caught count none
 - serving_prefill_tokens_total counter: tokens actually prefilled (a prefix
                             cache hit prefills only the uncached tail, so
                             this is the FLOPs-weighted prefill cost)
@@ -310,7 +320,8 @@ PREFIX = "serving_"
 # event must still show the zeros — dashboards key on presence; lint rule
 # PT003 flags any stat_add of a name missing here, PT008 any
 # stat_set/stat_max)
-_SEEDED = ("tokens_total", "prefills_total", "prefill_tokens_total",
+_SEEDED = ("tokens_total", "prefills_total", "prefill_overlapped_total",
+           "prefill_tokens_total",
            "prefill_chunks_total", "chunk_limit", "slo_throttles_total",
            "decode_steps", "decode_overlapped_total", "preemptions_total",
            "attention_pages_live_total", "attention_pages_staged_total",
@@ -553,9 +564,13 @@ class ServingMetrics:
                 fam.child(p)
 
     # ------------------------------------------------------------- updates
-    def on_prefill(self, tokens: int = 0) -> None:
+    def on_prefill(self, tokens: int = 0, overlapped: bool = False) -> None:
+        """One completed prefill; ``overlapped`` when its first token was
+        fetched behind the launch of the decode that follows it."""
         monitor.stat_add(PREFIX + "prefills_total", 1)
         monitor.stat_add(PREFIX + "prefill_tokens_total", int(tokens))
+        if overlapped:
+            monitor.stat_add(PREFIX + "prefill_overlapped_total", 1)
 
     def on_prefix_hit(self, tokens_saved: int) -> None:
         monitor.stat_add(PREFIX + "prefix_hits", 1)

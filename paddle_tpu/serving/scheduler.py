@@ -111,11 +111,12 @@ class Request:        # generated dataclass __eq__ chokes on ndarray fields
     # accrues this at retirement so per-tenant goodput+badput token
     # totals reconcile exactly with serving_tokens_total (which also
     # counts re-emissions); len(generated) is the client-visible count
-    tokens_in_flight: int = 0  # decodes launched for this request and not
-    # fetched yet: 1 from one step's decode phase to the next (the engine
-    # launches decode k+1 before it fetches decode k; 2 only in between).
-    # That token exists on the device only; the next launch consumes it
-    # there
+    tokens_in_flight: int = 0  # tokens computed for this request (by its
+    # completed prefill or by a decode) and not fetched yet: 1 from one
+    # step's decode phase to the next (the engine launches decode k+1
+    # before it fetches decode k, and the decode behind a prefill before
+    # it fetches the prefill's first token; 2 only in between). Such a
+    # token exists on the device only; the next launch consumes it there
 
     @property
     def prompt_len(self) -> int:

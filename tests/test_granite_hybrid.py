@@ -461,10 +461,16 @@ def test_gpt_and_kimi_state_and_compile_what_they_did():
         assert not eng._slot_state
         args = eng._prefill_args(eng._programs["prefill[16]"], 1, 7,
                                  np.arange(5, dtype=np.int32), 0)
-        assert len(args) == 8 and int(args[-1]) == 1
+        assert len(args) == 9 and int(args[-2]) == 1
+        assert args[-1] is eng._prev_toks
         jaxpr = jax.make_jaxpr(eng._prefill_impl)(*args).jaxpr
-        slot = jaxpr.invars[-1]
-        assert not any(slot in eqn.invars for eqn in jaxpr.eqns)
+        # the model never reads the slot: only the write of the sampled
+        # token into prev_toks does, behind the sample
+        slot = jaxpr.invars[-2]
+        sampled = max(i for i, eqn in enumerate(jaxpr.eqns)
+                      if eqn.primitive.name == "argmax")
+        assert all(i > sampled for i, eqn in enumerate(jaxpr.eqns)
+                   if slot in eqn.invars)
         rid = eng.add_request(np.arange(1, 8, dtype=np.int32), 3)
         while rid not in eng.pop_finished():
             eng.step()

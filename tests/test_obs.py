@@ -515,8 +515,10 @@ def test_obs_off_is_one_attribute_check_per_event_site():
     engine.add_request(_prompt(4), 3)
     assert CountingEngine.reads == 1  # the enqueue site
     CountingEngine.reads = 0
-    engine.step()  # prefill site; the decode it launches has no site
-    assert CountingEngine.reads == 1
+    # the prefill's two sites (prefill_start at its launch, prefill_end /
+    # first_token at its fetch behind the decode launch, which has none)
+    engine.step()
+    assert CountingEngine.reads == 2
     CountingEngine.reads = 0
     engine.step()  # the emit site of the decode launched one step before
     assert CountingEngine.reads == 1

@@ -115,6 +115,15 @@ def test_engine_phase_times_sum_to_step_wall_time_exactly(model):
     assert any(rec.phase_s.get("decode", 0) > 0 for rec in records)
     assert any(rec.phase_s.get("prefill", 0) > 0 for rec in records)
     assert all(rec.phase_s.get("admit", 0) > 0 for rec in records)
+    # a first token is fetched behind the step's decode launch: its seconds
+    # lie in the decode phase, and a prefill's phase is its upload and launch
+    firsts = [rec for rec in records if "prefill.fetch" in rec.span_s]
+    assert firsts
+    for rec in firsts:
+        assert rec.span_s["prefill.fetch"] + rec.span_s["decode.dispatch"] \
+            <= rec.phase_s["decode"]
+        assert rec.span_s["prefill.upload"] + rec.span_s["prefill.dispatch"] \
+            <= rec.phase_s["prefill"]
 
 
 def test_phase_family_histograms_fed_and_pre_seeded(model):

@@ -199,7 +199,8 @@ def window(tmp_path_factory):
     ("plain", "prefill", "step"),
     ("plain", "prefill.upload", "prefill"),
     ("plain", "prefill.dispatch", "prefill"),
-    ("plain", "prefill.fetch", "prefill"),
+    # the first-token fetch lies behind the step's decode launch
+    ("plain", "prefill.fetch", "decode"),
     ("plain", "evict", "step"),
     ("plain", "decode", "step"),
     ("plain", "decode.upload", "decode"),
@@ -211,8 +212,10 @@ def window(tmp_path_factory):
     # a chunk goes through the one prefill path: the same parts
     ("chunked", "prefill.upload", "chunk_prefill"),
     ("chunked", "prefill.dispatch", "chunk_prefill"),
-    ("chunked", "prefill.fetch", "chunk_prefill"),
+    ("chunked", "prefill.fetch", "decode"),
     ("spec", "verify", "step"),
+    # a speculative engine fetches a first token at once
+    ("spec", "prefill.fetch", "prefill"),
     # and a verify through the one launch and the one fetch
     ("spec", "verify.dispatch", "verify"),
     ("spec", "verify.fetch", "verify"),
@@ -228,6 +231,11 @@ def test_span_nests_under_its_parent(request, scenario, child, parent):
         assert s.stats["step"] == p.stats["step"]
         if "rid" in p.stats:  # a request's parts carry its id
             assert s.stats["rid"] == p.stats["rid"]
+        elif child == "prefill.fetch":  # in serve.decode: its own request's
+            assert s.stats["rid"] in {
+                q.stats["rid"] for q in spans if q.name in (
+                    "prefill", "prefill.dispatch")
+                and q.stats["step"] == s.stats["step"]}
 
 
 def test_window_release_lies_behind_a_prefill_s_launch_and_in_evict(window):
@@ -294,23 +302,31 @@ def test_request_spans_carry_rid_and_their_attributes(plain):
 
 def test_fetch_ends_after_dispatch_inside_the_phase(plain):
     spans = plain["spans"]
-    for p in (s for s in spans if s.name == "prefill"):
+    prefills = [s for s in spans if s.name == "prefill"]
+    for p in prefills:
         inside = {s.name: s for s in spans if _parent(s, spans) is p}
-        up, disp, fetch = (inside[f"prefill.{k}"]
-                           for k in ("upload", "dispatch", "fetch"))
+        # a prefill launches and does not wait: no fetch inside
+        assert set(inside) == {"prefill.upload", "prefill.dispatch"}
+        up, disp = inside["prefill.upload"], inside["prefill.dispatch"]
         assert p.start <= up.start and up.end <= disp.start
-        assert disp.end <= fetch.start and fetch.end <= p.end
-    # a decode phase launches, then fetches the launch of the step before:
-    # the first has nothing to fetch, the last nothing to launch
+        assert disp.end <= p.end
+    # a decode phase launches, then fetches the launch of the step before,
+    # then the first tokens of its own step's prefills in admission order:
+    # the first has no decode to fetch, the last nothing to launch
     decodes = [s for s in spans if s.name == "decode"]
     for i, p in enumerate(decodes):
-        inside = {s.name: s for s in spans if _parent(s, spans) is p}
+        mine = [s for s in spans if _parent(s, spans) is p]
+        firsts = [s for s in mine if s.name == "prefill.fetch"]
+        assert [s.stats["rid"] for s in firsts] == [
+            q.stats["rid"] for q in prefills
+            if q.stats["step"] == p.stats["step"]]
+        inside = {s.name: s for s in mine if s.name != "prefill.fetch"}
         assert set(inside) == \
             ({"decode.upload", "decode.dispatch"} if i < len(decodes) - 1
              else set()) | ({"decode.fetch", "decode.emit"} if i else set())
         order = [inside[f"decode.{k}"]
                  for k in ("upload", "dispatch", "fetch", "emit")
-                 if f"decode.{k}" in inside]
+                 if f"decode.{k}" in inside] + firsts
         assert p.start <= order[0].start and order[-1].end <= p.end
         assert all(a.end <= b.start for a, b in zip(order, order[1:]))
         if i:
@@ -368,10 +384,14 @@ def test_phases_still_sum_and_spans_are_no_part_of_the_sum(plain):
         assert not set(rec.span_s) & set(PHASES)
         assert sum(rec.phase_s.values()) == pytest.approx(rec.duration,
                                                           rel=1e-9)
-        # a part is no longer than its phase
-        for phase in ("prefill", "decode"):
-            parts = sum(v for k, v in rec.span_s.items()
-                        if k.startswith(phase + "."))
+        # a part is no longer than its phase; a first token's fetch lies
+        # in the decode phase, behind its launch
+        in_decode = lambda k: k.startswith("decode.") \
+            or k == "prefill.fetch"  # noqa: E731
+        for phase, mine in (
+                ("prefill", lambda k: k.startswith("prefill.")
+                 and not in_decode(k)), ("decode", in_decode)):
+            parts = sum(v for k, v in rec.span_s.items() if mine(k))
             assert parts <= rec.phase_s.get(phase, 0.0) + 1e-9
         assert 0 < rec.span_s["account"] <= rec.phase_s["other"] + 1e-9
     assert any("prefill.fetch" in r.span_s for r in plain["records"])
