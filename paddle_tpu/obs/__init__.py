@@ -29,6 +29,11 @@ doing right before it died.
   ``engine.step()``: each boundary writes its seconds on the engine's
   clock (exact per-phase split + the sub-spans' own extents) and a
   ``serve.*`` ``TraceAnnotation`` into the profiler's own trace.
+- :mod:`~paddle_tpu.obs.stall` — the stall record (:class:`StallWatch`):
+  a blocking span (``*.fetch`` / ``*.upload`` / ``*.dispatch``) that
+  outlasts its name's norm is flagged on the engine's thread,
+  a sampler thread reads what the device, the process's threads and the
+  machine did meanwhile, and one word (``held_by``) sums it up.
 - :mod:`~paddle_tpu.obs.alerts` — anomaly watchdogs (:class:`Watchdog`):
   edge-triggered rules over host-resident step state — retrace after
   warmup, Pallas fallback, speculative-acceptance collapse, eviction
@@ -76,7 +81,7 @@ host syncs to the decode loop (the SyncTally certification is unchanged).
 from .alerts import RULES as ALERT_RULES  # noqa: F401
 from .alerts import Alert, Watchdog, WatchdogConfig  # noqa: F401
 from .attribution import (NO_SPAN, PHASES, SPAN_PREFIX,  # noqa: F401
-                          PhaseAccumulator)
+                          SPANS, PhaseAccumulator)
 from .export import (chrome_trace, latency_table,  # noqa: F401
                      prometheus_text, write_chrome_trace)
 from .fleetscope import (FLEET_RECORD_SCHEMA,  # noqa: F401
@@ -93,6 +98,7 @@ from .recorder import (FLIGHT_RECORD_SCHEMA,  # noqa: F401
                        FLIGHT_RECORD_SCHEMA_V1, build_flight_record,
                        dump_flight_record, format_flight_record,
                        validate_flight_record)
+from .stall import HELD_BY, StallWatch, stall_table  # noqa: F401
 from .tenant import TENANT_CLASSES  # noqa: F401
 from .tenant import (TenantLedger, TenantSLO,  # noqa: F401
                      check_tenant_name, tenant_table)
@@ -103,7 +109,8 @@ __all__ = ["Histogram", "HistogramFamily", "LATENCY_EDGES_S",
            "OCCUPANCY_EDGES", "QUANTILES", "split_labels",
            "Tracer", "RequestTrace", "TraceEvent",
            "StepTimeline", "StepRecord",
-           "PHASES", "SPAN_PREFIX", "NO_SPAN", "PhaseAccumulator",
+           "PHASES", "SPANS", "SPAN_PREFIX", "NO_SPAN", "PhaseAccumulator",
+           "HELD_BY", "StallWatch", "stall_table",
            "Alert", "ALERT_RULES", "Watchdog", "WatchdogConfig",
            "JOURNEY_SCHEMA", "Journey", "JourneyBook",
            "validate_journey", "format_journey",

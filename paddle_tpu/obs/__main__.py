@@ -14,6 +14,11 @@ the automatic fatal/failure dumps):
     python -m paddle_tpu.obs --flight-record dump.json --tenant-table
         render the dump's per-tenant roll-ups (goodput %, TTFT/TPOT
         p99, badput breakdown by class) — flight-record v2 dumps only
+    python -m paddle_tpu.obs --flight-record dump.json --stalls
+        render the dump's stall records (obs/stall.py): each blocking
+        wait of the engine's thread that outlasted its norm, with what
+        the device, the threads and the machine did meanwhile, and the
+        one word ``held_by``
     python -m paddle_tpu.obs --flight-record dump.json --journey RID
         pretty-print one request's journey out of the dump's bounded
         journey ring (hop table with engine-step refs)
@@ -46,6 +51,7 @@ import sys
 from .export import latency_table, prometheus_text
 from .journey import format_journey
 from .recorder import format_flight_record, validate_flight_record
+from .stall import stall_table
 from .tenant import tenant_table
 
 
@@ -80,7 +86,8 @@ def _fleet_main(args) -> int:
         print(f"cannot read fleet record {args.fleet_record!r}: {e}")
         return 2
 
-    if args.latency_table or args.tenant_table or args.journey is not None:
+    if args.latency_table or args.tenant_table or args.stalls \
+            or args.journey is not None:
         print("that view reads a single replica's flight record: pass "
               "--flight-record PATH (a fleet record bundles them under "
               "'replicas')")
@@ -133,6 +140,9 @@ def main(argv=None) -> int:
     view.add_argument("--tenant-table", action="store_true",
                       help="render the dump's per-tenant goodput/SLO "
                            "roll-ups (flight-record v2)")
+    view.add_argument("--stalls", action="store_true",
+                      help="render the dump's stall records: what held "
+                           "each blocking wait that outlasted its norm")
     view.add_argument("--journey", metavar="RID", type=int, default=None,
                       help="pretty-print one request's journey out of "
                            "the dump's journey ring")
@@ -188,6 +198,8 @@ def main(argv=None) -> int:
                   f"(flight-record v1, pre-tenant)")
             return 2
         print(tenant_table(tenants))
+    elif args.stalls:
+        print(stall_table(record.get("stalls", ())))
     elif args.journey is not None:
         ring = record.get("journeys")
         if ring is None:  # v1 predates journeys — don't claim eviction

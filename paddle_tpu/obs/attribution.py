@@ -63,7 +63,16 @@ span                    extent                                attributes
 ``serve.cow_copy``      one copy-on-write page copy           pages
 ``serve.window_release``  window-group pages freed behind
                         their slots' windows (host only)
+``serve.stall``         the sampled part of a blocking span   span
+                        that outlasted its norm, on the
+                        SAMPLER's thread (obs/stall.py)
 ======================  ====================================  ==============
+
+A span whose name ends in ``.fetch``, ``.upload`` or ``.dispatch`` is a
+BLOCKING span: it also reports its start (with ``awaited``, the device
+value it waits for) and its end to the accumulator's stall watch
+(obs/stall.py), which flags one that outlasts its name's norm
+and has a sampler thread read what held it.
 
 The seconds come in two kinds. A span named after one of :data:`PHASES`
 is a **phase** of the top-level split: the interval since the previous
@@ -92,7 +101,9 @@ import contextlib
 
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
-__all__ = ["PHASES", "SPAN_PREFIX", "NO_SPAN", "PhaseAccumulator"]
+from .stall import BLOCKING
+
+__all__ = ["PHASES", "SPANS", "SPAN_PREFIX", "NO_SPAN", "PhaseAccumulator"]
 
 #: the phase vocabulary — the pre-seeded label set of the
 #: ``serving_step_phase_s{phase=}`` histogram family. "admit" covers the
@@ -101,6 +112,14 @@ __all__ = ["PHASES", "SPAN_PREFIX", "NO_SPAN", "PhaseAccumulator"]
 #: decode-page eviction pressure, "other" the residual step bookkeeping.
 PHASES = ("admit", "swap", "prefill", "chunk_prefill", "decode", "verify",
           "evict", "other")
+
+#: the spans that are no phase (``StepRecord.span_s``): the pre-seeded
+#: label set of ``serving_step_span_seconds_total{span=}``; one the engine
+#: opens beside these joins the family at its first close
+SPANS = ("prefill.upload", "prefill.dispatch", "prefill.fetch",
+         "decode.upload", "decode.dispatch", "decode.fetch", "decode.emit",
+         "verify.dispatch", "verify.fetch", "drain", "account",
+         "window_release", "cow_copy")
 
 #: what every span's name starts with in the profiler's trace
 SPAN_PREFIX = "serve."
@@ -113,30 +132,42 @@ NO_SPAN = contextlib.nullcontext()
 class _Span:
     """One open span: the TraceMe event, and where its seconds go."""
 
-    __slots__ = ("_acc", "_name", "_phase", "_ann", "_t0")
+    __slots__ = ("_acc", "_name", "_phase", "_ann", "_t0", "_watch",
+                 "_awaited")
 
-    def __init__(self, acc, name: str, attrs: dict):
+    def __init__(self, acc, name: str, attrs: dict, awaited):
         self._acc = acc
         self._name = name
         self._phase = name in PHASES
         self._ann = TraceAnnotation(SPAN_PREFIX + name, **attrs)
         self._t0 = None
+        # a blocking span reports to the stall watch (obs/stall.py): the
+        # sampler thread sees what the engine's thread waits in, for what
+        self._watch = acc.stalls if name.endswith(BLOCKING) else None
+        self._awaited = awaited
 
     def __enter__(self):
         self._ann.__enter__()
         # a span that is no phase reads the clock for its own extent, and
         # only while a step's record is open to take it
-        if self._acc.open and not self._phase:
-            self._t0 = self._acc._clock()
+        acc = self._acc
+        if acc.open and not self._phase:
+            t0 = self._t0 = acc._clock()
+            if self._watch is not None:
+                self._watch.enter(self._name, t0, acc.step, self._awaited)
         return self
 
     def __exit__(self, *exc):
         acc = self._acc
         if self._phase:
             acc.mark(self._name)
-        elif self._t0 is not None and acc.open:
+        elif self._t0 is not None:
             dt = acc._clock() - self._t0
-            acc._spans[self._name] = acc._spans.get(self._name, 0.0) + dt
+            if acc.open:
+                acc._spans[self._name] = \
+                    acc._spans.get(self._name, 0.0) + dt
+            if self._watch is not None:  # a fatal step's span too
+                self._watch.exit(self._name, self._t0, dt, acc.step)
         self._ann.__exit__(*exc)
         return False
 
@@ -169,11 +200,14 @@ class PhaseAccumulator:
     """
 
     __slots__ = ("_clock", "enabled", "open", "t0", "_last", "_acc",
-                 "_spans", "step", "_step_ann", "_account")
+                 "_spans", "step", "_step_ann", "_account", "stalls")
 
-    def __init__(self, clock=None):
+    def __init__(self, clock=None, stalls=None):
         self._clock = clock
         self.enabled = clock is not None
+        #: the stall watch (obs/stall.py) that the blocking spans report
+        #: to, or None
+        self.stalls = stalls if self.enabled else None
         self.open = False
         self.t0 = 0.0
         self._last = 0.0
@@ -203,13 +237,14 @@ class PhaseAccumulator:
             self._step_ann.__exit__(None, None, None)
             self._step_ann = None
 
-    def span(self, name: str, **attrs):
+    def span(self, name: str, awaited=None, **attrs):
         """``with att.span("decode.fetch"):`` — see the class docstring.
-        ``step`` is the open step's unless given."""
+        ``step`` is the open step's unless given. ``awaited``: the device
+        value a blocking span waits for, for the stall watch."""
         if not self.enabled:
             return NO_SPAN
         attrs.setdefault("step", self.step)
-        return _Span(self, name, attrs)
+        return _Span(self, name, attrs, awaited)
 
     def account(self) -> None:
         """Open ``serve.account``; ``exit_step`` closes it."""

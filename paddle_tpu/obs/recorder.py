@@ -28,6 +28,7 @@ import json
 from dataclasses import asdict
 
 from .journey import validate_journey
+from .stall import stall_table
 
 __all__ = ["FLIGHT_RECORD_SCHEMA", "FLIGHT_RECORD_SCHEMA_V1",
            "MAX_FLIGHT_JOURNEYS", "build_flight_record",
@@ -57,7 +58,7 @@ def build_flight_record(*, reason: str, now: float, step: int,
                         alerts=(), gauges: dict | None = None,
                         programs: dict | None = None, requests=(),
                         tenants: dict | None = None, journeys=(),
-                        max_steps: int = 64,
+                        stalls=(), max_steps: int = 64,
                         max_requests: int = 64,
                         max_journeys: int = MAX_FLIGHT_JOURNEYS) -> dict:
     """Assemble one flight record (schema v2). ``timeline`` is a
@@ -65,7 +66,9 @@ def build_flight_record(*, reason: str, now: float, step: int,
     off), ``alerts`` an iterable of :class:`~paddle_tpu.obs.alerts.Alert`
     (or already-dict entries), ``requests`` latency-summary dicts,
     ``tenants`` the :meth:`TenantLedger.rollup` dict, ``journeys`` wire
-    journey dicts (the newest ``max_journeys`` are kept)."""
+    journey dicts (the newest ``max_journeys`` are kept), ``stalls`` the
+    engine's ring of stall records (obs/stall.py; those of the retained
+    steps are in their ``extra`` too)."""
     steps = timeline.records()[-max_steps:] if timeline is not None else []
     return {
         "schema": FLIGHT_RECORD_SCHEMA,
@@ -81,6 +84,7 @@ def build_flight_record(*, reason: str, now: float, step: int,
         "requests": list(requests)[-max_requests:],
         "tenants": dict(tenants or {}),
         "journeys": list(journeys)[-max_journeys:],
+        "stalls": list(stalls),
     }
 
 
@@ -149,6 +153,10 @@ def format_flight_record(record: dict) -> str:
                      f"{a['message']}")
     if not record["alerts"]:
         lines.append("  (none)")
+    stalls = record.get("stalls")  # absent from a dump older than PR 37
+    if stalls:
+        lines.append(f"\nstalls ({len(stalls)}; --stalls prints all):")
+        lines.append(stall_table(stalls[-4:]))
     steps = record["steps"]
     lines.append(f"\nsteps (last {len(steps)} retained):")
     for rec in steps[-10:]:

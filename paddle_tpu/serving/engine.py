@@ -328,7 +328,94 @@ annotation records nothing; with ``enable_tracing=False`` the accumulator
 is disabled: the same sites run, each gets the one shared do-nothing
 context and no span object is made (the decision lives behind
 ``PhaseAccumulator``, not at the call sites). Zero added device syncs
-either way. Anomaly watchdogs (``enable_watchdogs``, default on)
+either way.
+
+The step's seconds and the stall record (ride ``enable_tracing``;
+obs/stall.py). At a step's close the record's seconds go into counters
+that a reader differences over any window, on the engine's clock:
+``serving_step_seconds_total``, ``serving_step_span_seconds_total{span=}``
+(each entry of ``span_s``), ``serving_step_host_seconds_total`` (the step
+less its ``*.fetch`` spans: what the host itself does, as against waiting
+for the device; an upload or a dispatch that blocks on a full device
+queue counts as the host's, and the by-span family takes it off) and
+``serving_step_unstalled_seconds_total`` (the step less its stall
+seconds); plain floats that ``metrics.snapshot()`` mirrors, so a step
+pays additions and no lock. A BLOCKING span (its name ends in ``.fetch``,
+``.upload`` or ``.dispatch``: ``_fetch``, ``_launch`` and the upload
+sites) that outlasts its name's NORM (what nine in ten of the name's last
+64 spans stayed under) by more than ``max(50 ms, that norm)`` is a STALL:
+flagged on the engine's thread by one float compare, its excess over the
+norm the stall's seconds. What held it is read by a sampler thread (started here
+when tracing is on and the clock is the default one, ended by
+``close()`` or with the engine's collection) that sees the open span and
+the device value it awaits in one slot, samples the process and the
+machine once the span is 20 ms past its norm, asks
+``awaited.is_ready()`` every 2 ms (non-blocking, no sync) and samples
+again at the release. The record, over that sampled part:
+
+=========================  ==========================================
+field                      what
+=========================  ==========================================
+``step``, ``span``,        the engine step, the span's name, its
+``at_s``                   start on the engine's clock
+``ms``, ``excess_ms``,     the span's length, its excess over its
+``norm_ms``                name's norm, that norm
+``sampler_late_ms``        the most that a pass of the sampler
+                           itself came late (a timed wait that needs
+                           nothing of the device or the runtime): by
+                           about the wait's length, the whole
+                           process stood still
+``sampled_from_ms``,       where in the span the first sample was
+``sampled_ms``             taken; how long the sampled part is (D)
+``device_ready_after_ms``  ms into the span at which the awaited
+                           value (a fetch: the array to copy; an
+                           upload or dispatch: the newest launch's
+                           output) was first seen ready; ``None``:
+                           never
+``thread_cpu_ms``,         CPU of the engine's thread and of the
+``process_cpu_ms``         whole process
+``nvcsw``, ``nivcsw``,     the engine thread's voluntary and
+``majflt``                 involuntary switches and major faults
+``threads``                the five threads with most CPU and the
+                           five with most run-queue wait (``tid``,
+                           ``comm``, ``state``, ``cpu_ms``,
+                           ``runq_wait_ms``, ``core``)
+``psi``, ``loadavg``,      the machine: growth of PSI ``some total``
+``vmstat``,                by cpu / memory / io, the load, growth of
+``machine_cpu_ms``,        major faults and allocation stalls, of all
+``steal_ms``               cores' busy time, of the hypervisor's
+                           take; an absent file reads ``None``
+``held_by``                ``frozen`` (the sampler came late by over
+                           half the stall's excess: held from
+                           outside the process; ``runtime_busy`` if
+                           the process used over 3/4 of that
+                           lateness in CPU, ``late_cpu_ms``), then
+                           ``device`` (not ready until within 5 ms
+                           of the release), ``cpu_queue`` (a
+                           thread, or PSI cpu, over D/2 runnable and
+                           waiting for a core), ``memory``, ``io``
+                           (PSI over D/2; allocation stalls; major
+                           faults), ``runtime_busy`` (the other
+                           threads over D/2 of CPU), else ``asleep``
+                           (the wake-up itself); ``unsampled``: no
+                           evidence (no sampler on a clock of one's
+                           own)
+=========================  ==========================================
+
+It is read from ``engine.stalls`` (the newest 64),
+``StepRecord.extra["stalls"]`` of the step that held the wait (so the
+timeline ring and every flight-record dump have it),
+``python -m paddle_tpu.obs --flight-record DUMP --stalls``, the counters
+``serving_stalls_total{held_by=}`` /
+``serving_stall_seconds_total{held_by=}``, and the ``serve.stall`` event
+(``span=``, ``step=``) that the sampler's thread leaves in a profiler
+trace beside the device's ``XLA Modules`` line. The benchmark driver's
+own ``stalls`` list (``serve.steps``: every ``step()`` over 100 ms by the
+caller's clock) is the outside view of the same steps; it also lists
+steps that are long by their nature (a first step of many prefills),
+which this rule does not flag.
+
+Anomaly watchdogs (``enable_watchdogs``, default on)
 evaluate edge-triggered rules over host-resident ints at each step
 boundary — retrace-after-warmup, Pallas fallback, speculative-acceptance
 collapse, eviction thrash, queue stall — each firing a structured Alert
@@ -360,6 +447,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass
 from typing import NamedTuple
@@ -372,10 +460,11 @@ from ..analysis import hlocheck
 from ..analysis.tracecheck import (CompileGuard, DonationViolation,
                                    RetraceError, SyncTally, donation_audit)
 from ..core.tensor import Tensor
-from ..obs import (ALERT_RULES, JourneyBook, PhaseAccumulator,
-                   StepRecord, StepTimeline, TenantLedger, TenantSLO, Tracer,
-                   Watchdog, WatchdogConfig, build_flight_record,
-                   check_tenant_name, chrome_trace, write_chrome_trace)
+from ..obs import (ALERT_RULES, HELD_BY, SPANS, JourneyBook,
+                   PhaseAccumulator, StallWatch, StepRecord, StepTimeline,
+                   TenantLedger, TenantSLO, Tracer, Watchdog, WatchdogConfig,
+                   build_flight_record, check_tenant_name, chrome_trace,
+                   write_chrome_trace)
 from ..obs.recorder import MAX_FLIGHT_JOURNEYS as _MAX_FLIGHT_JOURNEYS
 from ..obs.recorder import dump_flight_record as _write_flight_record
 from ..text.generation import sample_logits
@@ -702,8 +791,21 @@ class ServingEngine:
         # SyncTally certification is pinned unchanged). The engine always
         # holds one: with tracing off it is disabled and every site is a
         # no-op behind it
+        # the stall watch (obs/stall.py) rides the blocking spans: it
+        # flags one that outlasts its name's norm, and its sampler thread
+        # reads what held the wait. The thread lives in real time, so
+        # only an engine on the default clock starts it; it references
+        # nothing of the engine and ends with it (close(), or collection)
+        self._stalls = None
+        if cfg.enable_tracing:
+            self._stalls = StallWatch(
+                clock=time.monotonic if clock is None else self.now)
+            self.metrics.seed_step_seconds(SPANS, HELD_BY)
+            if clock is None:
+                self._stalls.start()
+                weakref.finalize(self, self._stalls.stop)
         self._attr = PhaseAccumulator(self.now if cfg.enable_tracing
-                                      else None)
+                                      else None, stalls=self._stalls)
         # obs layer: request tracer + step timeline run off the engine
         # clock (virtual-clock testable, zero host syncs); None when off —
         # every event site costs one attribute check and nothing else
@@ -1508,10 +1610,15 @@ class ServingEngine:
         # debug-mode sync tally covers the whole step body it reports on
         if self._timeline is not None and self._step_stats is not None:
             st, self._step_stats = self._step_stats, None
+            stall_s = self._close_stalls(st)
             record = StepRecord(host_syncs=syncs, **st)
             self._timeline.append(record)
-            self.metrics.observe_step(st["t_end"] - st["t_start"],
-                                      st["batch"])
+            self.metrics.observe_step(record.duration, st["batch"])
+            # the step's seconds, over whatever window a reader
+            # differences them: the step, its spans, the host's part, the
+            # part that was no stall (serving_step_*_seconds_total)
+            self.metrics.on_step_seconds(record.duration, record.span_s,
+                                         stall_s)
             # per-phase attribution into the serving_step_phase_s{phase=}
             # family (zero-time phases stay unobserved — the record keeps
             # the exact split)
@@ -1538,6 +1645,26 @@ class ServingEngine:
                 old, new = change
                 self.metrics.on_chunk_limit(new, throttled=new < old)
         return finished
+
+    def _close_stalls(self, st: dict) -> float:
+        """The stall watch at a step's close: the records of the blocking
+        spans flagged in this step go into the step record's ``extra``
+        (``st``: the record's fields) as they are, and each is completed
+        in place, counted and put in the ``stalls`` ring once the
+        sampler's evidence for it is there (this step or one of the
+        next). Returns the step's stall seconds."""
+        stall_s, flagged, done = self._stalls.close_step(st["t_end"])
+        if flagged:
+            st["extra"] = dict(st.get("extra", ()), stalls=flagged)
+        for rec in done:
+            self.metrics.on_stall(rec["held_by"], 1e-3 * rec["excess_ms"])
+        return stall_s
+
+    def _settle_stalls(self) -> None:
+        """Between two steps (a flight record, ``stalls``): complete the
+        records whose evidence has come since the last step's close."""
+        if self._stalls is not None:
+            self._close_stalls({"t_end": self.now()})
 
     def _close_record(self, finished: list, counts: dict) -> list[int]:
         """After the schedule: open ``serve.account`` (the obs layer's
@@ -1782,7 +1909,8 @@ class ServingEngine:
         final = start + n >= req.prompt_len
         prog = self._prefill_program(n)
         bucket = prog.tokens
-        with att.span("prefill.upload", rid=req.rid, bytes=4 * bucket + 16
+        with att.span("prefill.upload", awaited=self._prev_toks,
+                      rid=req.rid, bytes=4 * bucket + 16
                       + self.cache.tables[..., req.slot, :].nbytes):
             args = self._prefill_args(prog, req.slot, req.rid,
                                       req.prompt[start:start + n], start)
@@ -1833,7 +1961,7 @@ class ServingEngine:
         tr = self._tracer
         chunked = bool(self.config.chunk_size)
         slot = req.slot
-        with self._attr.span("prefill.fetch", rid=req.rid):
+        with self._attr.span("prefill.fetch", awaited=out, rid=req.rid):
             tok = int(self._fetch(prog, out)[slot])
         req.tokens_in_flight -= 1
         req.generated.append(tok)
@@ -2017,7 +2145,8 @@ class ServingEngine:
         if self.config.debug_checks:
             self._audit_step(prog, guard, args)
         try:
-            with self._attr.span(prog.phase + ".dispatch", **attrs):
+            with self._attr.span(prog.phase + ".dispatch",
+                                 awaited=self._prev_toks, **attrs):
                 pools, out = guard(*args)
         except Exception as e:  # noqa: BLE001 — isolate the request
             # a strict-guard refusal is an AUDIT failure — the contract
@@ -2082,7 +2211,7 @@ class ServingEngine:
             launched.append((int(slot), req))
         with att.span("decode", batch=len(launched)):
             if launched:
-                with att.span("decode.upload",
+                with att.span("decode.upload", awaited=self._prev_toks,
                               bytes=self._decode_upload_bytes):
                     args = self._decode_args(active, override)
                 toks = self._launch(self._programs["decode"], args,
@@ -2117,7 +2246,7 @@ class ServingEngine:
         its KV write went to a page of the request's own, freed with it."""
         att, tr = self._attr, self._tracer
         toks, step, launched = inflight
-        with att.span("decode.fetch", of_step=step):
+        with att.span("decode.fetch", awaited=toks, of_step=step):
             try:
                 toks = self._fetch(self._programs["decode"], toks)
             except Exception as e:
@@ -2184,7 +2313,7 @@ class ServingEngine:
                            live_rows=self._active)
         # the step's ONE sanctioned device->host sync: the packed (target
         # tokens, accept count) fetch
-        with self._attr.span("verify.fetch"):
+        with self._attr.span("verify.fetch", awaited=out):
             packed = self._fetch(prog, out)
         self.metrics.on_decode_step()
         n_slots = n_new = n_accepted = 0
@@ -2315,6 +2444,7 @@ class ServingEngine:
         journeys — schema-versioned, JSON-ready. Drains a decode in
         flight first: the record holds every token computed."""
         self._drain("flight_record")
+        self._settle_stalls()
         cfg = self.config
         programs = {
             label: {"flops": r.flops, "peak_hbm_bytes": r.peak_bytes,
@@ -2342,7 +2472,7 @@ class ServingEngine:
             journeys=self._journeys.wire_records(
                 limit=_MAX_FLIGHT_JOURNEYS)
             if self._journeys is not None else (),
-            max_steps=cfg.flight_record_steps)
+            stalls=self.stalls, max_steps=cfg.flight_record_steps)
 
     def dump_flight_record(self, path, reason: str = "manual") -> dict:
         """Write the flight record as JSON to ``path``; returns it."""
@@ -2378,13 +2508,14 @@ class ServingEngine:
                  *getattr(exc, "__notes__", ())])}
             if self._timeline is not None and att.open:
                 t_end, phase_s = att.finish()
-                self._timeline.append(StepRecord(
-                    step=self._step_idx - 1, t_start=att.t0, t_end=t_end,
-                    admitted=0, prefills=0, batch=0, finished=0,
-                    preemptions=0,
-                    queue_depth=self.scheduler.queue_depth,
-                    pages_in_use=self.cache.allocator.pages_in_use,
-                    phase_s=phase_s, span_s=att.span_s, extra=fatal))
+                st = dict(step=self._step_idx - 1, t_start=att.t0,
+                          t_end=t_end, admitted=0, prefills=0, batch=0,
+                          finished=0, preemptions=0,
+                          queue_depth=self.scheduler.queue_depth,
+                          pages_in_use=self.cache.allocator.pages_in_use,
+                          phase_s=phase_s, span_s=att.span_s, extra=fatal)
+                self._close_stalls(st)
+                self._timeline.append(StepRecord(**st))
                 self._step_stats = None
             elif self._timeline is not None and self._step_stats is not None:
                 # _step completed (attribution closed, full stats built)
@@ -2393,7 +2524,9 @@ class ServingEngine:
                 # broke the engine must not be the one the black box
                 # misses
                 st, self._step_stats = self._step_stats, None
-                self._timeline.append(StepRecord(extra=fatal, **st))
+                st["extra"] = fatal
+                self._close_stalls(st)
+                self._timeline.append(StepRecord(**st))
             self._flight_auto(f"engine-fatal: {type(exc).__name__}")
         except Exception:  # noqa: BLE001 — the original fatal wins
             pass
@@ -2505,6 +2638,24 @@ class ServingEngine:
         ``debug_checks`` — one per prefill pad bucket (``prefill[N]``)
         plus ``decode``. Empty with debug checks off."""
         return dict(self._hlo_audits)
+
+    @property
+    def stalls(self) -> list[dict]:
+        """The newest 64 stall records (obs/stall.py), oldest first: each
+        blocking wait (a ``*.fetch``, ``*.upload`` or ``*.dispatch`` span)
+        that outlasted its name's norm, with the evidence of
+        what held it and the one word ``held_by``. Empty with
+        ``enable_tracing=False``."""
+        self._settle_stalls()
+        return list(self._stalls.ring) if self._stalls is not None else []
+
+    def close(self) -> None:
+        """End what the engine runs beside its caller's thread: the
+        stall sampler. The engine serves on without it (a stall is still
+        flagged, its record reads ``unsampled``); a collected engine's
+        sampler ends by itself."""
+        if self._stalls is not None:
+            self._stalls.stop()
 
     @property
     def timeline(self) -> StepTimeline | None:

@@ -6,7 +6,6 @@ them with no new plumbing):
 
 - serving_queue_depth       gauge: waiting requests
 - serving_active_requests   gauge: running decode slots
-- serving_page_pool_used    gauge: pages allocated out of the pool
 - serving_page_utilization  gauge: used / usable pages (0..1)
 - serving_tokens_total      counter: generated tokens (monotonic)
 - serving_tokens_per_sec    gauge: windowed decode throughput
@@ -239,6 +238,49 @@ Goodput attribution + watchdogs + flight recorder (PR 12):
                                   the PREVIOUS step's launch, which has
                                   been running since; the launch of
                                   this step is fetched by the next
+                                  (``_count`` and ``_sum`` of each
+                                  child are mirrored beside the
+                                  percentiles: sum / count is a phase's
+                                  mean)
+- serving_step_seconds_total      counter: ``t_end - t_start`` of every
+                                  step record: the seconds inside
+                                  ``engine.step()`` on the engine's
+                                  clock, over whatever window a reader
+                                  differences it
+- serving_step_span_seconds_total{span=}  counter family: each entry of
+                                  ``StepRecord.span_s``, summed
+                                  (``decode.upload`` / ``.dispatch`` /
+                                  ``.fetch`` / ``.emit``, ``prefill.*``,
+                                  ``verify.*``, ``account``,
+                                  ``window_release``, ``cow_copy``,
+                                  ``drain``)
+- serving_step_host_seconds_total counter: the step's seconds less its
+                                  ``*.fetch`` spans: what the host itself
+                                  does in a step (admit, uploads,
+                                  dispatches, emit, evict, account) as
+                                  against waiting for the device. An
+                                  upload or a dispatch that BLOCKS while
+                                  the device's queue is full (a window's
+                                  first step of 256 prefills) counts as
+                                  the host's here: take
+                                  ``{span=prefill.upload}`` off to read
+                                  without it
+- serving_step_unstalled_seconds_total  counter: the step's seconds less
+                                  its stall seconds (the excess of each
+                                  blocking span that obs/stall.py
+                                  flagged over its name's norm)
+- serving_stalls_total{held_by=},
+  serving_stall_seconds_total{held_by=}  counter families: the stall
+                                  records completed, and their excess
+                                  seconds, by what held the wait (frozen
+                                  / device / cpu_queue / memory / io /
+                                  runtime_busy / asleep / unsampled:
+                                  obs/stall.py)
+  These six ride ``enable_tracing``: the engine seeds them when tracing
+  is on (``seed_step_seconds``) and an engine without has none of them.
+  They are plain floats on ``ServingMetrics`` that ``snapshot()``
+  mirrors into the registry, so a step pays float additions and not the
+  registry's lock.
 - serving_alerts_total{rule=}     counter family: watchdog firings per
                                   rule (retrace_after_warmup /
                                   pallas_fallback /
@@ -352,7 +394,7 @@ _SEEDED = ("tokens_total", "prefills_total", "prefill_overlapped_total",
            "ici_bytes_per_token", "dcn_bytes_per_token",
            "collective_time_predicted_s",
            "tokens_per_sec", "queue_depth", "active_requests",
-           "page_pool_used", "page_utilization",
+           "page_utilization",
            "fleet_replicas", "fleet_prefix_affinity_hits_total",
            "fleet_spills_total",
            "fleet_goodput_tokens_total", "fleet_inflight_exchanges",
@@ -372,6 +414,9 @@ _SEEDED = ("tokens_total", "prefills_total", "prefill_overlapped_total",
 # registry — the dynamically-formatted-name blind spot of PT003/PT008.
 _FAMILIES = {
     "step_phase_s": "phase",              # histogram family (below)
+    "step_span_seconds_total": "span",    # counter: StepRecord.span_s
+    "stalls_total": "held_by",            # counter: stall records, and
+    "stall_seconds_total": "held_by",     # their excess (obs/stall.py)
     "alerts_total": "rule",               # counter: watchdog firings
     "decode_drains_total": "reason",      # counter: early fetches of the
     # decode in flight, by site (engine.DRAIN_REASONS)
@@ -422,7 +467,13 @@ COUNTER_STATS = frozenset(
         "failed", "swap_outs", "swap_ins", "prefix_hits", "prefix_misses",
         "prefix_tokens_saved", "prefix_cow_copies", "prefix_evictions",
         "hlo_collective_ops", "hlo_host_transfers")) \
+    | frozenset(PREFIX + k for k in (  # seeded with tracing on only
+        "step_seconds_total", "step_host_seconds_total",
+        "step_unstalled_seconds_total")) \
     | frozenset({  # labeled counter family bases
+        PREFIX + "step_span_seconds_total",
+        PREFIX + "stalls_total",
+        PREFIX + "stall_seconds_total",
         PREFIX + "alerts_total",
         PREFIX + "decode_drains_total",
         PREFIX + "tenant_goodput_tokens_total",
@@ -435,6 +486,11 @@ COUNTER_STATS = frozenset(
 #: serving_breaker_state{peer=} gauge values — the breaker state
 #: machine's three states in escalation order
 BREAKER_STATE_VALUES = {"closed": 0, "half_open": 1, "open": 2}
+
+# the step's seconds (registry keys of ServingMetrics._seconds)
+_STEP_S = PREFIX + "step_seconds_total"
+_HOST_S = PREFIX + "step_host_seconds_total"
+_UNSTALLED_S = PREFIX + "step_unstalled_seconds_total"
 
 
 class ServingMetrics:
@@ -475,6 +531,11 @@ class ServingMetrics:
         # (str, or a tuple matching a multi-label declaration;
         # seed_family records them so reset() can replay the zeros)
         self._family_values: dict[str, list] = {}
+        # the step's seconds and the stalls (seed_step_seconds): registry
+        # key -> running sum, added to without the registry's lock and
+        # mirrored by snapshot(); empty on an engine without tracing
+        self._seconds: dict[str, float] = {}
+        self._span_keys: dict[str, str] = {}  # span -> its member's key
         self.reset()
 
     def _hist_families(self):
@@ -512,6 +573,8 @@ class ServingMetrics:
             for v in values:
                 monitor.stat_set(self._family_key(base, v), 0)
         self._publish_hists()  # seed the percentile gauges at 0
+        self._seconds = dict.fromkeys(self._seconds, 0.0)
+        self._publish_seconds()
         self._samples.clear()
         self._samples.append((time.perf_counter(), 0.0))
 
@@ -707,7 +770,6 @@ class ServingMetrics:
                  host_tier_restores: int = 0) -> None:
         monitor.stat_set(PREFIX + "queue_depth", queue_depth)
         monitor.stat_set(PREFIX + "active_requests", active)
-        monitor.stat_set(PREFIX + "page_pool_used", pages_used)
         monitor.stat_set(PREFIX + "page_utilization",
                          pages_used / max(1, usable_pages))
         monitor.stat_max(PREFIX + "queue_depth_peak", queue_depth)
@@ -787,6 +849,43 @@ class ServingMetrics:
         zero-time phases are not observed — the StepRecord keeps the
         exact split)."""
         self.phase_hist.observe(phase, seconds)
+
+    def seed_step_seconds(self, spans, held_by) -> None:
+        """Bring the step's seconds counters and the stall families into
+        being at 0 (an engine with tracing on, at construction): the
+        three totals, ``step_span_seconds_total{span=}`` over ``spans``
+        and the two stall families over ``held_by``."""
+        for k in (_STEP_S, _HOST_S, _UNSTALLED_S,
+                  *(self._family_key("step_span_seconds_total", v)
+                    for v in spans),
+                  *(self._family_key(base, v) for v in held_by
+                    for base in ("stalls_total", "stall_seconds_total"))):
+            self._seconds.setdefault(k, 0.0)
+        self._publish_seconds()
+
+    def on_step_seconds(self, step_s: float, span_s: dict,
+                        stall_s: float) -> None:
+        """One step record's seconds: the step, each of its spans, the
+        step less its ``*.fetch`` spans, the step less its stalls. Float
+        additions on this object: no lock, nothing published."""
+        sec = self._seconds
+        sec[_STEP_S] += step_s
+        sec[_UNSTALLED_S] += step_s - stall_s
+        for span, dt in span_s.items():
+            key = self._span_keys.get(span)
+            if key is None:
+                key = self._span_keys[span] = self._family_key(
+                    "step_span_seconds_total", span)
+            sec[key] = sec.get(key, 0.0) + dt
+            if span.endswith(".fetch"):
+                step_s -= dt
+        sec[_HOST_S] += step_s
+
+    def on_stall(self, held_by: str, seconds: float) -> None:
+        """One completed stall record: what held it, its excess."""
+        self._seconds[self._family_key("stalls_total", held_by)] += 1
+        self._seconds[self._family_key("stall_seconds_total",
+                                       held_by)] += seconds
 
     def on_alert(self, rule: str) -> None:
         """One watchdog firing (the rule's family member is pre-seeded
@@ -948,6 +1047,7 @@ class ServingMetrics:
                 monitor.stat_set(f"{PREFIX}{name}_{suffix}",
                                  h.percentile(q))
             monitor.stat_set(f"{PREFIX}{name}_count", h.count)
+            monitor.stat_set(f"{PREFIX}{name}_sum", h.sum)
         for fam in self._hist_families():
             for value, h in fam.children().items():
                 lab = f"{{{fam.label}={value}}}"
@@ -955,10 +1055,18 @@ class ServingMetrics:
                     monitor.stat_set(f"{fam.name}_{suffix}" + lab,
                                      h.percentile(q))
                 monitor.stat_set(f"{fam.name}_count" + lab, h.count)
+                monitor.stat_set(f"{fam.name}_sum" + lab, h.sum)
+
+    def _publish_seconds(self) -> None:
+        """Mirror the step's seconds and the stall families into the
+        registry (from snapshot(), as the histograms are)."""
+        for key, value in self._seconds.items():
+            monitor.stat_set(key, value)
 
     # ------------------------------------------------------------ querying
     def snapshot(self) -> dict:
         self._publish_hists()
+        self._publish_seconds()
         return monitor.stats_with_prefix(PREFIX)
 
     def prometheus(self) -> str:
