@@ -1138,7 +1138,8 @@ def _build_ssm_decode_update():
     slots of 8 heads x 16 x 128 float32 state, all heads a block, so a slot
     is one grid step. Its block index is the slot's LIVE ROW (a scalar-
     prefetched vector: a dead slot names the live slot before it, whose
-    block the pipeline holds already): data-dependent by declaration,
+    block the pipeline holds already), and so is the block of its decays
+    (in SMEM) and of its lane-dense ``y``: data-dependent by declaration,
     resolved at ``index_args`` with every slot live, where it is the
     identity; a run of dead slots revisits one block in sequence, by
     declaration too (nothing is written in those steps). The state is
@@ -1172,8 +1173,9 @@ def _build_ssm_decode_update():
         constraints=constraints,
         index_args=(np.arange(slots, dtype=np.int32),
                     np.ones(slots, np.int32)),
-        # decay, outer product and add; multiply and add for the readout
-        flops=float(5 * slots * heads * p * n),
+        # decay, outer product and add on the VPU; the readout on the MXU,
+        # C's row on 8 sublanes against the block's rows, x2 flops/MAC
+        flops=float((3 + 2 * 8) * slots * heads * p * n),
         composite=su.ssm_update_reference, composite_args=args)
 
 
@@ -1314,8 +1316,9 @@ REGISTRY: dict[str, KernelSpec] = {s.name: s for s in (
                "blocks behind the window skipped", _build_flash_grouped),
     KernelSpec("ssm_decode_update", "Mamba-2 decode state update: a grid "
                "step a slot brings the slot's float32 state to VMEM once, "
-               "advances it in place and reads it out; dead slots name "
-               "the live block before them and move nothing",
+               "advances it in place and reads it out in one MXU product; "
+               "dead slots name the live block before them and move "
+               "nothing",
                _build_ssm_decode_update),
     KernelSpec("fused_layernorm_fwd", "fused LayerNorm forward (one HBM "
                "pass per row block, stats saved for the backward)",

@@ -21,13 +21,34 @@ grid step names the block of the live slot before it (``live_rows``), which
 is the block the pipeline already holds, so nothing of a dead slot's state
 is read or written, and its body does nothing.
 
-The small operands come laid out for the chip's vector unit, by XLA, before
-the launch: the state's minor axis is ``d_state`` (128: a whole lane row),
-and what multiplies a state ROW (``dt * x``, one number a ``head_dim``
-index) has to be a COLUMN of numbers, one a sublane. So ``dt * x`` arrives
-transposed, ``[.., head_dim, heads]``, the kernel takes head ``h``'s column
-as a one-lane slice and broadcasts it over the lanes, and ``y`` leaves the
-same way. Everything is float32.
+A grid step lays out its work so that nothing is done once a head that can
+be done once a block. The state's minor axis is ``d_state`` (128: a whole
+lane row). A head's decay ``exp(dt * A)`` is one number: it comes in SMEM
+and multiplies the head's 8 vector registers as a scalar. What multiplies
+a state ROW (``dt * x``, one number a ``head_dim`` index) has to be a
+COLUMN of numbers, one a sublane, so ``dt * x`` arrives transposed, ``[..,
+head_dim, heads]``, and head ``h``'s column is a one-lane slice broadcast
+over the lanes (forming ``(dt * x) (x) B`` on the MXU instead compiled to
+more bundles). The readout is ONE product a block on the MXU: C on 8
+sublanes against the block's ``[heads * head_dim, d_state]`` rows,
+contracting ``d_state`` on both sides, at ``HIGHEST`` precision (float32
+arithmetic), which leaves ``y`` lane-dense in the caller's ``[slots, heads,
+head_dim]`` order: no lane reduction, no one-lane store, no transpose
+after the launch. Everything is float32.
+
+Measured alone on a v5e at granite-4.0-h-micro's shape (64 slots x 64
+heads x 64 x 128, donated; ms a call of a loop of 20 in one program, which
+adds 0.04-0.047 ms of its own; PR 38), at 64 / 33 / 7 live slots: the
+kernel with a lane reduction and a one-lane store a head (PR 33) 0.508 /
+0.376-0.451 / 0.134-0.202; the same ``pallas_call`` with its body a copy
+0.462 / 0.277 / 0.122; XLA's fusion of the plain recurrence 0.463-0.470 at
+every count; this body 0.474-0.475 / 0.276-0.350 / 0.119-0.191. A plain
+in-place stream (0.456-0.466) and a copy through VMEM by manual DMA with
+two or three buffers and 1-16 copies a slot (0.460-0.471) read what the
+copy-only body reads: the HBM's stream with reads and writes in flight
+together, about 78% of 819 GB/s, is this schedule's ceiling, and this body
+is 0.013 ms above it. Only copies that never read and write at once beat
+it (8-16 slots in, then out: 0.432-0.438, copy only).
 
 Dispatch (:func:`ssm_update`): the kernel on a TPU behind
 ``FLAGS_use_pallas_kernels``, or through the Pallas interpreter under
@@ -105,10 +126,6 @@ def ssm_kernel_eligible(heads: int, head_dim: int, d_state: int, *,
                        "the state's minor axis")
     if head_dim % 8:
         return False, f"head_dim {head_dim} is not whole 8-sublane tiles"
-    bh = _block_heads(heads)
-    if bh != heads and bh % 128:
-        return False, (f"{heads} heads in blocks of {bh}: a block of the "
-                       "transposed operands is not whole lane rows")
     return True, ""
 
 
@@ -121,22 +138,28 @@ def _block_heads(heads: int) -> int:
 
 def _kernel(block_heads, rows_ref, active_ref, decay_ref, dtx_ref, b_ref,
             c_ref, state_ref, new_ref, y_ref):
-    """One slot, one block of heads. decay ``[1, bh]``, dtx and y ``[p,
-    bh]`` (a head a lane), b and c ``[1, n]``, state ``[bh, p, n]``."""
+    """One slot, one block of heads. decay ``[1, 1, 1, bh]`` in SMEM (a
+    number a head), dtx ``[p, bh]`` (a head a lane), b and c ``[1, n]``,
+    state ``[bh, p, n]``, y ``[1, bh * p]`` (lane-dense, heads major)."""
     from jax.experimental import pallas as pl
 
     s = pl.program_id(1)
 
     @pl.when(active_ref[s] == 1)
     def _():
-        b_row, c_row = b_ref[0], c_ref[0]                # [1, n]
+        b_row = b_ref[0]                                 # [1, n]
         for h in range(block_heads):
-            col = dtx_ref[0, 0, :, h:h + 1]              # [p, 1]
-            new = decay_ref[0, 0, :, h:h + 1] * state_ref[0, h] \
-                + col * b_row
-            new_ref[0, h] = new
-            y_ref[0, 0, :, h:h + 1] = jnp.sum(new * c_row, axis=-1,
-                                              keepdims=True)
+            new_ref[0, h] = decay_ref[0, 0, 0, h] * state_ref[0, h] \
+                + dtx_ref[0, 0, :, h:h + 1] * b_row
+        # the readout of the whole block as ONE product on the MXU: C
+        # (8 sublanes alike) against the block's rows, contracting d_state
+        n = b_row.shape[-1]
+        rows = new_ref[0].reshape(block_heads * new_ref.shape[2], n)
+        y = jax.lax.dot_general(
+            jnp.broadcast_to(c_ref[0], (8, n)), rows,
+            (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        y_ref[0, 0] = y[:1]
 
     # no live slot at all: this step's block (slot 0's) is written back
     # when the grid ends, so it has to hold what it held
@@ -157,7 +180,8 @@ def _launch(state, decay, dtx_t, b_in, c_out, rows, mode, *, block_heads,
     slots, heads, p, n = state.shape
     bh = block_heads
     at = lambda j, s, rows, mode: (rows[s], j, 0, 0)  # noqa: E731
-    small = lambda shape: pl.BlockSpec((1, 1) + shape, at)  # noqa: E731
+    small = lambda shape, **kw: pl.BlockSpec(  # noqa: E731
+        (1, 1) + shape, at, **kw)
     row = pl.BlockSpec((1, 1, n), lambda j, s, rows, mode: (rows[s], 0, 0))
     big = pl.BlockSpec((1, bh, p, n), at)
     with i32_index_scope():  # the package's x64 would make index maps i64
@@ -168,10 +192,12 @@ def _launch(state, decay, dtx_t, b_in, c_out, rows, mode, *, block_heads,
                 # the slot is the inner axis: a run of dead slots names
                 # one block for the whole run, and no copy is made
                 grid=(heads // bh, slots),
-                in_specs=[small((1, bh)), small((p, bh)), row, row, big],
-                out_specs=[big, small((p, bh))]),
+                in_specs=[small((1, bh), memory_space=pltpu.SMEM),
+                          small((p, bh)), row, row, big],
+                out_specs=[big, small((1, bh * p))]),
             out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
-                       jax.ShapeDtypeStruct(dtx_t.shape, jnp.float32)],
+                       jax.ShapeDtypeStruct((slots, heads // bh, 1, bh * p),
+                                            jnp.float32)],
             # operand 6 (after the two prefetched vectors) is the state
             input_output_aliases={6: 0},
             compiler_params=pltpu.CompilerParams(
@@ -201,11 +227,12 @@ def ssm_decode_update(state, x, dt, a, b_in, c_out, active, *,
     # 1: advance the slot; 0: skip it; 2: no slot is live, keep the block
     mode = active.astype(jnp.int32)
     mode = mode.at[0].set(jnp.where(jnp.any(active), mode[0], 2))
-    new, y_t = _launch(state, decay, dtx_t,
-                       b_in.astype(f32)[:, None, :],
-                       c_out.astype(f32)[:, None, :], rows, mode,
-                       block_heads=bh, interpret=interpret)
-    y = y_t.transpose(0, 1, 3, 2).reshape(slots, heads, p)
+    new, y = _launch(state, decay, dtx_t,
+                     b_in.astype(f32)[:, None, :],
+                     c_out.astype(f32)[:, None, :], rows, mode,
+                     block_heads=bh, interpret=interpret)
+    # y is heads major already: the caller's order
+    y = y.reshape(slots, heads, p)
     return new, jnp.where(active[:, None, None], y, 0.0)
 
 

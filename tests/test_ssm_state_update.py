@@ -46,9 +46,12 @@ def interpret():
     set_flags({"FLAGS_ragged_interpret": before})
 
 
+@pytest.mark.parametrize("heads", [HEADS, 128])
 @pytest.mark.parametrize("mask", MASKS, ids=list(MASKS))
-def test_kernel_is_the_recurrence_and_skips_dead_slots(mask):
-    o = operands()
+def test_kernel_is_the_recurrence_and_skips_dead_slots(mask, heads):
+    """Every slot mask, at a small layer and at one of 128 heads (another
+    Mamba-2 width: two blocks of 64 heads a slot)."""
+    o = operands(heads=heads)
     active = jnp.asarray(MASKS[mask], bool)
     want_s, want_y = su.ssm_update_reference(**o, active=active)
     got_s, got_y = su.ssm_decode_update(**o, active=active, interpret=True)
@@ -72,10 +75,14 @@ def test_a_dead_slot_names_the_live_block_the_pipeline_holds(mask, rows):
     assert got.dtype == jnp.int32 and got.tolist() == rows
 
 
-def test_kernel_takes_heads_in_blocks(monkeypatch):
-    """Blocks of fewer heads than the layer has: the grid's outer axis."""
-    monkeypatch.setitem(su._TUNED, "block_heads", 4)
-    o = operands(seed=1)
+@pytest.mark.parametrize("heads, block", [(HEADS, 4), (128, 32)])
+def test_kernel_takes_heads_in_blocks(monkeypatch, heads, block):
+    """Blocks of fewer heads than the layer has: the grid's outer axis.
+    A block's decays, ``dt * x`` columns and ``y`` are whole blocks of
+    their own arrays, so any block that divides the heads is legal."""
+    monkeypatch.setitem(su._TUNED, "block_heads", block)
+    assert su._block_heads(heads) == block
+    o = operands(seed=1, heads=heads)
     active = jnp.asarray(MASKS["dead_between"], bool)
     want_s, want_y = su.ssm_update_reference(**o, active=active)
     got_s, got_y = su.ssm_decode_update(**o, active=active, interpret=True)
@@ -85,6 +92,7 @@ def test_kernel_takes_heads_in_blocks(monkeypatch):
 
 @pytest.mark.parametrize("heads, p, n, kw, ok, reason", [
     (64, 64, 128, {}, True, ""),
+    (128, 64, 128, {}, True, ""),
     (64, 64, 128, dict(flags_on=False), False, "FLAGS_use_pallas_kernels"),
     (64, 64, 128, dict(on_tpu=False), False, "CPU backend"),
     (64, 64, 96, {}, False, "128-lane rows"),

@@ -222,13 +222,16 @@ def test_flash_runs_per_shard_under_a_training_mesh(topo, monkeypatch):
 
 
 # ------------------------------------------------- the decode state update
-def test_ssm_decode_update_compiles_for_v5e_in_place(v5e):
+@pytest.mark.parametrize("heads", [64, 128])
+def test_ssm_decode_update_compiles_for_v5e_in_place(v5e, heads):
     """The Mamba-2 decode state update at granite-4.0-h-micro's widths
-    (64 slots x 64 heads x 64 x 128 float32): Mosaic takes its one-lane
-    column slices, and the 134 MB state is aliased, not copied."""
+    (64 slots x 64 heads x 64 x 128 float32), and at 128 heads (two blocks
+    of 64 a slot): Mosaic takes its one-lane column slices, its SMEM decays
+    and its readout on the MXU, and the 134 MB state is aliased, not
+    copied."""
     from paddle_tpu.kernels import ssm_state_update as su
 
-    slots, heads, p, n = 64, 64, 64, 128
+    slots, p, n = 64, 64, 128
     f32 = jnp.float32
     shapes = [((slots, heads, p, n), f32), ((slots, heads, p), f32),
               ((slots, heads), f32), ((heads,), f32), ((slots, n), f32),
