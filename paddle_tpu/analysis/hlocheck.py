@@ -635,10 +635,22 @@ def _tiny_window_model():
         max_position_embeddings=32))
 
 
+def _tiny_looped_model():
+    """A toy Ouro (text/ouro.py): 2 layers run 3 times a token, each loop's
+    keys in its own columns of one pool row."""
+    from ..text.ouro import OuroConfig, OuroForCausalLM
+
+    return OuroForCausalLM(OuroConfig(
+        vocab_size=97, hidden_size=32, intermediate_size=48,
+        num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=2,
+        head_dim=16, total_ut_steps=3, max_position_embeddings=32))
+
+
 def _build_engine_step(which: str, tensor_parallel: int = 1,
                        kv_dtype: str = "float32",
                        quantized_logits: bool = False,
-                       latent: bool = False, window: bool = False):
+                       latent: bool = False, window: bool = False,
+                       looped: bool = False):
     """Engine-step audit targets. ``tensor_parallel=2`` builds the SAME
     step on a 2-device mesh (Megatron weight + KV-pool shards via
     serving/tp.py shard_map) with the budget the engine itself declares:
@@ -670,8 +682,12 @@ def _build_engine_step(which: str, tensor_parallel: int = 1,
     # ``window``: the same steps over a model of two page groups (the
     # groups' tables uploaded stacked, each layer handed its own; the
     # prefill's head at the last real token alone)
+    # ``looped``: the same steps over a model whose layers run several
+    # times a token, the loop one loop in the program that carries every
+    # layer's donated pools
     model = _tiny_latent_model() if latent else _tiny_window_model() \
-        if window else GPTForCausalLM(GPTConfig(
+        if window else _tiny_looped_model() if looped \
+        else GPTForCausalLM(GPTConfig(
             vocab_size=97, hidden_size=32, num_layers=2, num_heads=2,
             max_seq_len=32, dropout=0.0))
     model.eval()
@@ -889,6 +905,19 @@ REGISTRY: dict[str, StepSpec] = {s.name: s for s in (
              "a dropless expert layer held whole; budget: zero "
              "collectives)",
              lambda: _build_engine_step("decode", window=True)),
+    # ---- a model whose layers run several times a token (text/ouro.py):
+    # the loops one loop in the program, every layer's pools its carry;
+    # the donated pools aliased THROUGH the loop, zero collectives
+    StepSpec("engine_prefill_loop", "serving prefill step of the looped "
+             "model (every loop's keys into its own columns of one pool "
+             "row; budget: zero collectives, pools aliased through the "
+             "loop)",
+             lambda: _build_engine_step("prefill", looped=True)),
+    StepSpec("engine_decode_loop", "serving decode step of the looped "
+             "model (the decode kernel over loop t's columns, the loop a "
+             "scalar operand; budget: zero collectives, pools aliased "
+             "through the loop)",
+             lambda: _build_engine_step("decode", looped=True)),
     # ---- quantized logits all-reduce (tp_quantized_logits=True): the
     # b*s*V f32 logits payload ships as int8 codes + a 4-byte shared
     # scale — budget 2L+2 all-reduces with the logits byte term counted

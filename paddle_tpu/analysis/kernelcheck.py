@@ -1082,7 +1082,7 @@ def _build_flash_grouped():
         composite=composite, composite_args=(q, k, k))
 
 
-def _build_gqa_decode(window: int | None = None):
+def _build_gqa_decode(window: int | None = None, loops: int = 1):
     """The grouped-head decode kernel at a canonical serving shape: 2 rows
     of 8 query heads over 2 KV heads of 64 (a lane-dense pool row of 128),
     32 pages a row staged a chunk at a time, keys and values in the two
@@ -1092,7 +1092,11 @@ def _build_gqa_decode(window: int | None = None):
     around it, as the dispatch runs it; the composite is
     ``_grouped_composite`` on the same pools. ``window``: the instance of
     a window layer, whose loop starts at the first chunk that holds a
-    position inside the window."""
+    position inside the window. ``loops``: the instance of a model whose
+    layers run ``loops`` times a token, its pool rows ``loops`` times as
+    wide, the loop (a traced scalar) choosing the columns each page copy
+    takes; the HBM model's manual-DMA bound is the whole pool, every
+    loop's columns: ``loops`` times what a call copies."""
     import jax.numpy as jnp
 
     from ..kernels import paged_attention as pa
@@ -1100,7 +1104,7 @@ def _build_gqa_decode(window: int | None = None):
 
     b, heads, kv, d, ps, pps, npages = 2, 8, 2, 64, 16, 32, 64
     q = _sds((b, heads, 1, d), jnp.float32)
-    pool = _sds((npages, ps, kv * d), jnp.float32)
+    pool = _sds((npages, ps, loops * kv * d), jnp.float32)
     table = _sds((b, pps), jnp.int32)
     ctx = _sds((b,), jnp.int32)
     ok, why = pd.gqa_kernel_eligible(heads, kv, d, ps, pps, itemsize=4)
@@ -1116,14 +1120,19 @@ def _build_gqa_decode(window: int | None = None):
          "a pool with a heads axis must take the composite"),
     )
 
-    def fn(q, kp, vp, t, c):
-        return pd.gqa_decode_attention(q, kp, vp, t, c, 0.125,
-                                       window=window)
+    # the looped instance takes the loop as a sixth, traced operand
+    extra = (_sds((), jnp.int32),) if loops > 1 else ()
 
-    def composite(q, kp, vp, t, c):
-        return pa._grouped_composite(q, kp, vp, t, c, 0.125, window)
+    def fn(q, kp, vp, t, c, *loop):
+        return pd.gqa_decode_attention(
+            q, kp, vp, t, c, 0.125, window=window,
+            loop=loop[0] if loop else None, loops=loops)
 
-    args = (q, pool, pool, table, ctx)
+    def composite(q, kp, vp, t, c, *loop):
+        return pa._grouped_composite(q, kp, vp, t, c, 0.125, window,
+                                     loop[0] if loop else None, loops)
+
+    args = (q, pool, pool, table, ctx) + extra
     return dict(
         fn=fn, args=args, budget=KernelBudget(), constraints=constraints,
         # what the attention needs: scores and values over head_dim, all
@@ -1310,6 +1319,11 @@ REGISTRY: dict[str, KernelSpec] = {s.name: s for s in (
                "over the chunks of [ctx + 1 - window, ctx] only and the "
                "positions behind the window are masked exactly",
                lambda: _build_gqa_decode(window=128)),
+    KernelSpec("gqa_decode_loop", "the grouped-head decode kernel of a "
+               "model whose layers run 4 times a token: pool rows of 4 "
+               "loops' keys side by side, the loop a scalar operand of "
+               "each page copy's source slice, loop t's columns read in "
+               "place", lambda: _build_gqa_decode(loops=4)),
     KernelSpec("flash_fwd_grouped", "grouped-head flash forward of a "
                "serving prefill from position 0 (8 query heads over 2 KV "
                "heads of 128, seq 2048, window 512): the flash_fwd body, "

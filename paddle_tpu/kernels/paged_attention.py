@@ -46,7 +46,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-__all__ = ["paged_write", "paged_write_quant", "paged_gather",
+__all__ = ["paged_write", "paged_write_loop", "paged_write_quant",
+           "paged_gather", "loop_pages",
            "paged_gather_quant", "paged_attention", "ragged_mask",
            "decode_kernel_eligible", "pages_staged_fn",
            "grouped_pages_staged_fn", "QMAX"]
@@ -71,6 +72,74 @@ def paged_write(k_pool, v_pool, k_new, v_new, page_ids, offsets):
     k_pool = k_pool.at[page_ids, offsets].set(k_new.astype(k_pool.dtype))
     v_pool = v_pool.at[page_ids, offsets].set(v_new.astype(v_pool.dtype))
     return k_pool, v_pool
+
+
+def paged_write_loop(k_pool, v_pool, k_new, v_new, page_table, ctx_lens,
+                     valid, loop):
+    """:func:`paged_write` for lane-dense pools whose rows hold several
+    loops' keys side by side: k_new/v_new ``[batch, tokens, width]``, the
+    tokens of row ``b`` at positions ``ctx_lens[b] ..``, go to columns
+    ``[loop * width, (loop + 1) * width)`` of their rows (``loop`` an int32
+    scalar, traced or not); every other column as it was. ``valid``
+    [batch, tokens]: the real tokens (a row with none writes to the null
+    page).
+
+    Written as read, replace the loop's columns, write back, in the two
+    forms the chip's compiler keeps in place: one token a row (decode) by
+    whole ROWS; several by whole PAGES, those that positions ``ctx ..
+    ctx + tokens - 1`` touch (the padding of a bucket lands on positions
+    after the row's real tokens, which a later token writes before any
+    query reads them). A scatter of a ``width``-wide window at a traced
+    column was a serial loop over the tokens (my chip run, PR 39: a
+    prefill of 256 tokens took 530 ms), and a scatter of 256 rows had the
+    compiler stage the whole pool in on-chip memory and back a layer."""
+    return (_write_loop(k_pool, k_new, page_table, ctx_lens, valid, loop),
+            _write_loop(v_pool, v_new, page_table, ctx_lens, valid, loop))
+
+
+def _write_loop(pool, new, table, ctx, valid, loop):
+    import jax
+
+    b, s, w = new.shape
+    ps = pool.shape[1]
+    col = jnp.asarray(loop, jnp.int32) * w
+    new = new.astype(pool.dtype)
+    ctx = ctx.astype(jnp.int32)
+    if s == 1:
+        page = jnp.take_along_axis(
+            table, jnp.minimum(ctx // ps, table.shape[1] - 1)[:, None],
+            axis=1)
+        page = jnp.where(valid, page, 0)
+        off = jnp.where(valid, ctx[:, None] % ps, 0)
+        rows = jax.lax.dynamic_update_slice_in_dim(pool[page, off], new,
+                                                   col, axis=2)
+        return pool.at[page, off].set(rows)
+    # the pages that hold positions ctx .. ctx + s - 1, at most s / ps + 1;
+    # a column past the table's width, or a row with nothing real, names
+    # the null page (no two entries of a row name one real page)
+    n = s // ps + 1
+    at = ctx[:, None] // ps + jnp.arange(n, dtype=jnp.int32)[None, :]
+    pages = jnp.take_along_axis(table, jnp.minimum(at, table.shape[1] - 1),
+                                axis=1)
+    pages = jnp.where((at < table.shape[1])
+                      & jnp.any(valid, axis=1)[:, None], pages, 0)
+    blocks = pool[pages].reshape(b, n * ps, -1)
+    blocks = jax.vmap(lambda blk, rows, c: jax.lax.dynamic_update_slice(
+        blk, rows, (c % ps, col)))(blocks, new, ctx)
+    return pool.at[pages].set(blocks.reshape(b, n, ps, -1))
+
+
+def loop_pages(pool, page_table, loop, width: int):
+    """``pool[page_table][..., loop * width : (loop + 1) * width]``:
+    ``[batch, pages_per_seq, page_size, width]``. The gather takes whole
+    pages (the form the chip's compiler vectorizes; a gather of a column
+    window at a traced offset it emitted as a serial loop over the pages)
+    and the loop's columns are sliced out of them."""
+    import jax
+
+    return jax.lax.dynamic_slice_in_dim(
+        pool[page_table], jnp.asarray(loop, jnp.int32) * width, width,
+        axis=3)
 
 
 def _write_quant(pool, scale, new, page_ids, offsets):
@@ -231,10 +300,11 @@ def _gqa_dispatch(heads: int, kv_heads: int, head_dim: int,
     return ok, interp
 
 
-def _use_gqa_kernel(q, k_pool, page_table) -> tuple[bool, bool]:
+def _use_gqa_kernel(q, k_pool, page_table,
+                    loops: int = 1) -> tuple[bool, bool]:
     flat = k_pool.ndim == 3
     d = q.shape[-1]
-    kv_heads = k_pool.shape[2] // d if flat else k_pool.shape[2]
+    kv_heads = k_pool.shape[2] // (d * loops) if flat else k_pool.shape[2]
     return _gqa_dispatch(q.shape[1], kv_heads, d, k_pool.shape[1],
                          page_table.shape[1], q.shape[2],
                          k_pool.dtype.itemsize, flat)
@@ -330,7 +400,8 @@ def _pallas_decode(q, k_pool, v_pool, page_table, ctx_lens, scale):
 
 
 def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
-                    k_scale=None, v_scale=None, window: int | None = None):
+                    k_scale=None, v_scale=None, window: int | None = None,
+                    loop=None, loops: int = 1):
     """Attention of new-token queries against a row's paged KV prefix.
 
     q: [batch, heads, s, head_dim] — queries for s new tokens at positions
@@ -371,12 +442,18 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
     ``window - 1`` positions before it, ``ctx + t - window < j <= ctx +
     t``; the decode kernel starts its loop inside the window, the
     composite masks behind it.
+
+    ``loop`` of ``loops`` (grouped KV heads, lane-dense pools only): the
+    pools' rows hold ``loops`` loops' keys side by side and the call
+    attends loop ``loop``'s columns, read in place (a model that runs its
+    layers several times a token, :func:`paged_write`'s ``loop``).
     """
     s = q.shape[2]
     quantized = k_scale is not None
-    if window is not None and not (k_pool.ndim == 3
-                                   or q.shape[1] != k_pool.shape[2]):
-        raise ValueError("a window is taken by the grouped-head paths only")
+    if (window is not None or loop is not None) and not (
+            k_pool.ndim == 3 or q.shape[1] != k_pool.shape[2]):
+        raise ValueError("a window or a loop is taken by the grouped-head "
+                         "paths only")
     if k_pool.ndim == 3 or q.shape[1] != k_pool.shape[2]:
         # grouped KV heads: one new token a row over a lane-dense pool
         # runs the grouped-head decode kernel (paged_decode.py); anything
@@ -385,7 +462,8 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
         # ragged kernel has no such path yet (ROADMAP R1)
         if quantized:
             raise ValueError("grouped KV heads have no int8 path")
-        use_kernel, interpret = _use_gqa_kernel(q, k_pool, page_table)
+        use_kernel, interpret = _use_gqa_kernel(q, k_pool, page_table,
+                                                loops)
         if use_kernel:
             from .paged_decode import gqa_decode_attention
 
@@ -393,9 +471,9 @@ def paged_attention(q, k_pool, v_pool, page_table, ctx_lens, scale=None,
             return gqa_decode_attention(
                 q, k_pool, v_pool, page_table, ctx_lens,
                 scale if scale is not None else d ** -0.5,
-                interpret=interpret, window=window)
+                interpret=interpret, window=window, loop=loop, loops=loops)
         return _grouped_composite(q, k_pool, v_pool, page_table, ctx_lens,
-                                  scale, window)
+                                  scale, window, loop, loops)
     use_kernel, interpret = _use_ragged_kernel(q, k_pool, page_table,
                                                quantized)
     if use_kernel:
@@ -425,7 +503,8 @@ _COMPOSITE_Q_BLOCK = 256
 
 
 def _grouped_composite(q, k_pool, v_pool, page_table, ctx_lens, scale,
-                       window: int | None = None):
+                       window: int | None = None, loop=None,
+                       loops: int = 1):
     """Composite attention of ``g`` query heads to each KV head: q ``[b,
     kv_heads * g, s, d]`` (query head ``kv * g + j`` attends KV head
     ``kv``) against pools of ``[pages, page_size, kv_heads, d]`` or, lane-
@@ -435,14 +514,22 @@ def _grouped_composite(q, k_pool, v_pool, page_table, ctx_lens, scale,
     gathered rows split into heads, a KV head's keys are read once for its
     whole group, and the ragged mask puts exact zeros beyond ``ctx_lens +
     t`` (and, with a ``window``, at and behind ``ctx_lens + t - window``).
-    float32 scores and softmax."""
+    float32 scores and softmax. ``loop`` of ``loops``: a lane-dense pool
+    row holds each loop's keys side by side, and loop ``loop``'s columns
+    are gathered (:func:`loop_pages`)."""
     import jax
 
     b, heads, s, d = q.shape
-    pages = k_pool[page_table]             # [b, pages_per_seq, page, ...]
+    if loop is None:
+        pages = k_pool[page_table]         # [b, pages_per_seq, page, ...]
+        v_pages = v_pool[page_table]
+    else:
+        width = k_pool.shape[-1] // loops
+        pages = loop_pages(k_pool, page_table, loop, width)
+        v_pages = loop_pages(v_pool, page_table, loop, width)
     total = pages.shape[1] * pages.shape[2]
     k_seq = pages.reshape(b, total, -1, d)
-    v_seq = v_pool[page_table].reshape(b, total, -1, d)
+    v_seq = v_pages.reshape(b, total, -1, d)
     g = heads // k_seq.shape[2]
     if g * k_seq.shape[2] != heads:
         raise ValueError(f"{heads} query heads do not group over "

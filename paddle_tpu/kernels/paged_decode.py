@@ -30,6 +30,10 @@ What a call is given decides its instance at trace time:
   caller picks them off. No slice narrower than a 128-lane row appears in
   the kernel, whatever the head size (``kv_heads`` times the needed FLOPs,
   beside copies that bound the kernel anyway).
+- **a loop** (``gqa_decode_attention(loop=, loops=)``, a model that runs
+  its layers several times a token): the pools' rows hold every loop's
+  keys side by side and the loop, a third scalar operand, picks the
+  columns each page copy takes (``gqa_decode_attention_loop``).
 """
 from __future__ import annotations
 
@@ -48,7 +52,8 @@ __all__ = ["decode_kernel_call", "gqa_decode_attention",
 
 #: kernelcheck certificates this module's Pallas kernel is registered
 #: under (analysis/kernelcheck.py REGISTRY; lint rule PT011's contract)
-KERNELCHECK_CERTS = ("mla_decode", "gqa_decode", "gqa_decode_window")
+KERNELCHECK_CERTS = ("mla_decode", "gqa_decode", "gqa_decode_window",
+                     "gqa_decode_loop")
 
 #: a pool row is a whole number of 128-lane rows for the compiled kernel
 LANES = 128
@@ -82,7 +87,7 @@ def gqa_chunk_pages(page_size: int, pages_per_seq: int) -> int:
 
 
 def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
-                   n_pools, window, ctx_ref, tab_ref, q_ref, *refs):
+                   n_pools, window, looped, ctx_ref, tab_ref, *refs):
     """One row: its live pages through two staging buffers, chunk c + 1's
     copies started before chunk c is awaited, every chunk scored for all
     heads at once and folded into a running (max, sum, accumulator). The
@@ -96,10 +101,16 @@ def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
     starts at the first chunk that holds one of them
     (:func:`.ragged_paged_attention._live_start`) and the positions behind
     the window are masked exactly, so a page the cache has freed there
-    (its table column reads the null page) is neither copied nor read."""
+    (its table column reads the null page) is neither copied nor read.
+    ``looped``: a third scalar operand ``loop_ref`` comes before ``q_ref``,
+    and a page's copy takes columns ``[loop * width, (loop + 1) * width)``
+    of a pool row ``n * width`` wide (a model that runs its layers ``n``
+    times a token keeps each loop's keys side by side in one page)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    loop_ref, refs = (refs[0], refs[1:]) if looped else (None, refs)
+    q_ref, refs = refs[0], refs[1:]
     pools, (o_ref, kv_s, sems, slot_ref) = refs[:n_pools], refs[n_pools:]
     bi = pl.program_id(0)
     rows = pl.num_programs(0)
@@ -122,13 +133,19 @@ def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
 
     c0, lo = _first(bi)
 
+    def _source(pool_hbm, page):
+        if loop_ref is None:
+            return pool_hbm.at[page]
+        width = kv_s.shape[-1]
+        return pool_hbm.at[page, :, pl.ds(loop_ref[0] * width, width)]
+
     def _start(row, c, slot):
         # a chunk's page copies all signal the buffer's one semaphore
         for j in range(chunk_pages):
             page = tab_ref[row, c * chunk_pages + j]
             for p, pool_hbm in enumerate(pools):
                 pltpu.make_async_copy(
-                    pool_hbm.at[page],
+                    _source(pool_hbm, page),
                     kv_s.at[slot, pl.ds(p * chunk_kv + j * page_size,
                                         page_size)],
                     sems.at[slot]).start()
@@ -199,7 +216,7 @@ def _decode_kernel(page_size, pages_per_seq, chunk_pages, out_width, scale,
 def decode_kernel_call(q, k_pool, v_pool, page_table, ctx_lens, *,
                        out_width: int, scale: float, chunk_tokens: int,
                        name: str, interpret: bool = False,
-                       window: int | None = None):
+                       window: int | None = None, loop=None):
     """The decode kernel. q [batch, heads, width]; k_pool [num_pages,
     page_size, width]; ``v_pool`` of the same shape, or None where the
     value is the key row's first ``out_width`` columns (then ``out_width <=
@@ -207,7 +224,13 @@ def decode_kernel_call(q, k_pool, v_pool, page_table, ctx_lens, *,
     heads, out_width] in q's dtype. ``name`` is the kernel's name in a
     trace. ``window``: the new token sees itself and the ``window - 1``
     positions before it (None: every position, and the kernel that traces
-    is the one without the argument, operand for operand)."""
+    is the one without the argument, operand for operand). ``loop`` (an
+    int32 scalar, traced or not; None: no such operand, and the kernel
+    that traces is the one without it, operand for operand): the pools'
+    rows hold several loops' keys side by side, ``width`` columns a loop,
+    and the kernel copies loop ``loop``'s columns of each live page in
+    place (a scalar operand of the copy's source slice, no copy of a
+    pool)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -215,16 +238,20 @@ def decode_kernel_call(q, k_pool, v_pool, page_table, ctx_lens, *,
     ps, pps = k_pool.shape[1], page_table.shape[1]
     chunk = chunk_pages_for(ps, pps, chunk_tokens)
     pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
+    looped = loop is not None
+    scalars = (ctx_lens.astype(jnp.int32), page_table.astype(jnp.int32))
+    if looped:
+        scalars += (jnp.reshape(loop, (1,)).astype(jnp.int32),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, heads, width), lambda bi, ctx, tab: (bi, 0, 0)),
+            pl.BlockSpec((1, heads, width), lambda bi, *_: (bi, 0, 0)),
             # the pools: manual DMA
             *[pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
         ],
         out_specs=pl.BlockSpec((1, heads, out_width),
-                               lambda bi, ctx, tab: (bi, 0, 0)),
+                               lambda bi, *_: (bi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, len(pools) * chunk * ps, width), k_pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
@@ -232,7 +259,8 @@ def decode_kernel_call(q, k_pool, v_pool, page_table, ctx_lens, *,
         ])
     kernel = functools.partial(_decode_kernel, ps, pps, chunk, out_width,
                                float(scale), len(pools),
-                               None if window is None else int(window))
+                               None if window is None else int(window),
+                               looped)
     with i32_index_scope():  # kernel index math assumes int32 defaults
         return pl.pallas_call(
             kernel,
@@ -243,8 +271,7 @@ def decode_kernel_call(q, k_pool, v_pool, page_table, ctx_lens, *,
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name=name,
-        )(ctx_lens.astype(jnp.int32), page_table.astype(jnp.int32), q,
-          *pools)
+        )(*scalars, q, *pools)
 
 
 # ------------------------------------------------- grouped KV heads (GQA)
@@ -299,7 +326,8 @@ def gqa_kernel_eligible(heads: int, kv_heads: int, head_dim: int,
 
 def gqa_decode_attention(q, k_pool, v_pool, page_table, ctx_lens,
                          scale: float, *, interpret: bool = False,
-                         window: int | None = None):
+                         window: int | None = None, loop=None,
+                         loops: int = 1):
     """Attention of ONE new token a row (already written to the pools) of
     ``g`` query heads to each KV head. q ``[batch, kv_heads * g, 1, d]``
     (query head ``kv * g + j`` attends KV head ``kv``); pools ``[pages,
@@ -308,9 +336,13 @@ def gqa_decode_attention(q, k_pool, v_pool, page_table, ctx_lens,
     each head's own ``d`` columns of the kernel's output are picked off
     behind it: two small fusions. ``window``: a layer whose query sees
     itself and the ``window - 1`` tokens before it; the kernel stages the
-    chunks of ``[max(0, ctx + 1 - window), ctx]`` only."""
+    chunks of ``[max(0, ctx + 1 - window), ctx]`` only. ``loop`` (an int32
+    scalar, traced or not) of ``loops``: pools ``[pages, page_size, loops *
+    kv_heads * d]`` whose rows hold each loop's keys side by side, of which
+    the kernel reads loop ``loop``'s columns in place (the instance
+    ``gqa_decode_attention_loop``)."""
     b, heads, _, d = q.shape
-    width = k_pool.shape[-1]
+    width = k_pool.shape[-1] // loops
     kv_heads = width // d
     g = heads // kv_heads
     # own[h, kv]: the KV head that query head h attends
@@ -321,8 +353,9 @@ def gqa_decode_attention(q, k_pool, v_pool, page_table, ctx_lens,
     o = decode_kernel_call(
         q_bd, k_pool, v_pool, page_table, ctx_lens, out_width=width,
         scale=scale, chunk_tokens=_GQA_CHUNK_TOKENS,
-        name="gqa_decode_attention" if window is None
+        name="gqa_decode_attention_loop" if loop is not None
+        else "gqa_decode_attention" if window is None
         else "gqa_decode_attention_window", interpret=interpret,
-        window=window)
+        window=window, loop=loop)
     o = jnp.sum(jnp.where(own, o.reshape(b, heads, kv_heads, d), 0), axis=2)
     return o[:, :, None, :].astype(q.dtype)

@@ -2218,6 +2218,7 @@ class ServingEngine:
                                     self._ctx, live_rows=active)
                 self._prev_toks = toks
                 self.metrics.on_decode_step(overlapped=prev is not None)
+                self.metrics.on_kv_pages(*self.cache.pages_held())
                 if self._layer_group is not None:
                     self.metrics.on_kv_residency(*self.cache.residency())
                 for slot, req in launched:

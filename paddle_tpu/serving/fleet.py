@@ -207,6 +207,9 @@ class FleetRouter:
                           fault_injector=(replica_injectors[i]
                                           if replica_injectors else None))
             for i in range(cfg.num_replicas)]
+        no_wire = self.replicas[0]._cache_spec.no_wire
+        if cfg.fetch_pages and no_wire:
+            raise ValueError(f"fetch_pages is not supported: {no_wire}")
         self.metrics = self.replicas[0].metrics
         self._page_size = cfg.engine.page_size
         self._down: set[int] = set()

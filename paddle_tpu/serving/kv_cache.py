@@ -457,6 +457,9 @@ class PagedCacheSpec:
     # (``head_at`` [rows] int32 in every layer's cache of a prefill; the
     # logits come back ``[rows, 1, vocab]``): a prefill reads one row
     head_at_positions: bool = False
+    # where a page of this model cannot cross the wire (serving/wire.py's
+    # page frame), the reason: a fleet that fetches pages refuses it
+    no_wire: str = ""
 
 
 def kv_heads_leaves(num_heads: int, head_dim: int, dtype=None,
@@ -1498,6 +1501,12 @@ class PagedKVCache:
                        for g in self.groups)
         return resident, self.allocator.pages_in_use * sum(
             len(g.layers) for g in self.groups)
+
+    def pages_held(self) -> tuple[int, int]:
+        """``(in use, usable)`` over every group: the pages that live slots
+        (and shared prefixes they hold) map, and the pool's usable pages."""
+        return (sum(g.allocator.pages_in_use for g in self.groups),
+                sum(g.allocator.num_usable for g in self.groups))
 
     # --------------------------------------------------------------- swap
     def _padded_idx(self, pages) -> np.ndarray:

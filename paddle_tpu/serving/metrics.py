@@ -61,6 +61,13 @@ them with no new plumbing):
                             layer that pages); the first over the second
                             is the share of the one-lifetime cache that
                             the groups keep resident
+- serving_kv_pages_in_use_total,
+  serving_kv_pages_usable_total counters: at each decode launch, the pages
+                            that live slots map, over every page group,
+                            and the pool's usable pages; the first over the
+                            second is the share of the pool that the batch
+                            actually holds (what admitting past the worst
+                            case would be worth)
 - serving_preemptions_total counter
 
 Resilience counters (pre-seeded to 0 so they always appear in snapshots):
@@ -378,6 +385,7 @@ _SEEDED = ("tokens_total", "prefills_total", "prefill_overlapped_total",
            "kv_window_pages_released_total",
            "kv_resident_page_layers_total",
            "kv_one_lifetime_page_layers_total",
+           "kv_pages_in_use_total", "kv_pages_usable_total",
            "host_tier_pages", "host_tier_bytes",
            "host_tier_hits_total", "host_tier_spills_total",
            "host_tier_restores_total",
@@ -718,6 +726,12 @@ class ServingMetrics:
                          int(resident))
         monitor.stat_add(PREFIX + "kv_one_lifetime_page_layers_total",
                          int(one_lifetime))
+
+    def on_kv_pages(self, in_use: int, usable: int) -> None:
+        """At one decode launch: the pages live slots map, over every
+        group, and the pool's usable pages."""
+        monitor.stat_add(PREFIX + "kv_pages_in_use_total", int(in_use))
+        monitor.stat_add(PREFIX + "kv_pages_usable_total", int(usable))
 
     def on_decode_drain(self, reason: str) -> None:
         """One decode in flight fetched before the next launch."""

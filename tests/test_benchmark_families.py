@@ -47,6 +47,10 @@ TINY_MELLUM = dict(vocab_size=96, hidden_size=64, moe_intermediate_size=32,
                    num_attention_heads=4, num_key_value_heads=2, head_dim=16,
                    num_experts=8, num_experts_per_tok=2, sliding_window=8,
                    max_position_embeddings=64)
+TINY_OURO = dict(vocab_size=96, hidden_size=64, intermediate_size=80,
+                 num_hidden_layers=3, num_attention_heads=4,
+                 num_key_value_heads=4, head_dim=16, total_ut_steps=3,
+                 max_position_embeddings=64)
 
 
 def load(path):
@@ -57,7 +61,7 @@ def load(path):
 def tiny_model(config: dict) -> dict:
     tiny = {"gpt3": TINY_GPT3, "kimi_k2": TINY_KIMI,
             "granite_hybrid": TINY_GRANITE,
-            "mellum": TINY_MELLUM}[config["family"]]
+            "mellum": TINY_MELLUM, "ouro": TINY_OURO}[config["family"]]
     return dict(config["model"], **tiny)
 
 
@@ -86,6 +90,12 @@ def program_model(config: dict, model: dict):
 
         with paddle.LazyGuard():
             return MellumForCausalLM(mellum.program_config(model))
+    if config["family"] == "ouro":
+        from benchmark.families import ouro
+        from paddle_tpu.text.ouro import OuroForCausalLM
+
+        with paddle.LazyGuard():
+            return OuroForCausalLM(ouro.program_config(model))
     from benchmark.families import kimi_k2
     from paddle_tpu.text.kimi_k2 import KimiK2ForCausalLM
 
@@ -333,3 +343,69 @@ def test_gpt3_copy_of_the_reference_gives_the_programs_logits():
     got = gpt3.logits_at(lambda only=None: leaves, jnp.asarray(ids),
                          positions, model)
     assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+
+
+def _tiny_ouro():
+    from benchmark.families import ouro
+    from benchmark.lib import weights
+
+    config = {"model": dict(load(os.path.join(
+        ROOT, "benchmark", "configs", "ouro-2.6b-serve.json"))["model"],
+        **TINY_OURO), "precision": {"parameters": "bfloat16"}}
+    leaves_of = weights.for_reference(ouro, config, seed=2147483659)
+    rng = np.random.default_rng(0)
+    ids = np.zeros((2, 40), np.int32)
+    ids[:, :30] = rng.integers(1, 96, (2, 30))
+    positions = jnp.asarray([[3, 10, 29], [0, 17, 28]], jnp.int32)
+    return ouro, config["model"], leaves_of, ids, positions
+
+
+def test_ouro_copy_of_the_reference_gives_the_repos_logits():
+    """The same float32 leaves through ``benchmark/families/ouro.py``'s
+    ``logits_at`` (loop-major, a layer's leaves drawn once and kept between
+    loops, attention a row at a time, the head at the positions asked for,
+    zeros padded behind the rows) and through
+    ``tests/refs/ouro_reference.py``: the same logits to float32 round-off
+    (2e-6); the copy sees the loop count, and the control's policy runs
+    and is not the reference."""
+    import ouro_reference as ref
+
+    ouro, model, leaves_of, ids, positions = _tiny_ouro()
+    got = ouro.logits_at(leaves_of, jnp.asarray(ids), positions, model)
+    cfg = ouro.program_config(model)
+    want = ref.forward(leaves_of(), jnp.asarray(ids[:, :30]), cfg)
+    want = jnp.take_along_axis(want, positions[..., None], axis=1)
+    assert got.shape == want.shape == (2, 3, 96)
+    assert float(jnp.max(jnp.abs(want))) > 0.05
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+    low = ouro.logits_at(leaves_of, jnp.asarray(ids), positions, model,
+                         "fp8")
+    assert 1e-4 < float(jnp.max(jnp.abs(low - want))) < 1.0
+    two = ouro.logits_at(leaves_of, jnp.asarray(ids), positions,
+                         dict(model, total_ut_steps=2))
+    assert float(jnp.max(jnp.abs(two - want))) > 1e-3
+
+
+def test_ouro_copy_of_the_reference_gives_the_programs_logits():
+    """The benchmark's leaves installed into the program's model
+    (``install_weights``, bfloat16 as the configuration serves them, held
+    in float32 here) and its whole forward against the family's
+    ``logits_at`` over the same leaves."""
+    from benchmark.lib import weights
+    from benchmark.lib.common import install_weights
+    from paddle_tpu.text.ouro import OuroForCausalLM
+
+    ouro, model, leaves_of, ids, positions = _tiny_ouro()
+    with paddle.LazyGuard():
+        program = OuroForCausalLM(ouro.program_config(model))
+    install_weights(program, {n: a.astype(jnp.float32) for n, a in
+                              weights.for_program(ouro, {
+                                  "model": model, "precision": {
+                                      "parameters": "bfloat16"}},
+                                  2147483659).items()})
+    program.eval()
+    got = program(paddle.to_tensor(ids[:, :30]))._value
+    got = jnp.take_along_axis(got, positions[..., None], axis=1)
+    want = ouro.logits_at(leaves_of, jnp.asarray(ids), positions, model)
+    assert got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(got - want))) < 5e-6

@@ -377,3 +377,98 @@ def test_pages_staged_with_a_window_is_the_kernel_s_own_loop(interpret):
     assert (staged(ctx) <= whole(ctx)).all()
     assert staged(ctx).max() == 3 * chunk_pages < whole(ctx).max() == PPS
     assert staged(ctx)[0] == chunk_pages
+
+
+# ------------------------------------- a loop: several loops' keys a row
+LOOPS = 3
+
+
+@pytest.mark.parametrize("loop", [0, 1, LOOPS - 1])
+@pytest.mark.parametrize("g", [1, 4])
+def test_loop_operand_is_copy_then_kernel(interpret, g, loop):
+    """Pools whose rows hold three loops' keys side by side: the kernel
+    given the loop reads loop ``loop``'s columns of each live page in
+    place, and gives bit for bit what the kernel without the operand gives
+    over a copy of those columns; the composite over the same columns
+    agrees. Rows at contexts inside, on and past a chunk's edge, and every
+    other loop's columns hold values of any size: none reaches the
+    result."""
+    rng = np.random.default_rng(7)
+    q, _, _, table = operands(g, "bfloat16", 3, seed=4)
+    k = jnp.asarray(rng.normal(size=(PAGES, PAGE, LOOPS * KV * D)),
+                    jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(PAGES, PAGE, LOOPS * KV * D)),
+                    jnp.bfloat16)
+    cols = slice(loop * KV * D, (loop + 1) * KV * D)
+    # the other loops' columns: large, so that a wrong column shows
+    others = np.ones(LOOPS * KV * D, bool)
+    others[cols] = False
+    k = k.at[..., others].multiply(1e3)
+    v = v.at[..., others].multiply(1e3)
+    ctx = jnp.asarray([3, CHUNK, TOTAL - 1], jnp.int32)
+    looped = jax.jit(lambda q, k, v, t, c, i: pd.gqa_decode_attention(
+        q, k, v, t, c, SCALE, interpret=True, loop=i, loops=LOOPS))
+    got = looped(q, k, v, table, ctx, jnp.int32(loop))
+    copied = both_paths()[0](q, k[..., cols], v[..., cols], table, ctx)
+    assert jnp.array_equal(got, copied)
+    composite = pa._grouped_composite(q, k, v, table, ctx, SCALE, None,
+                                      jnp.int32(loop), LOOPS)
+    assert gap(got, composite) < TOL["bfloat16"]
+    assert "gqa_decode_attention_loop" in str(jax.make_jaxpr(
+        looped)(q, k, v, table, ctx, jnp.int32(loop)))
+
+
+@pytest.mark.parametrize("tokens", [1, 6], ids=["rows", "pages"])
+def test_paged_write_of_a_loop_touches_its_columns_alone(tokens):
+    """``paged_write_loop(..., loop=1)``: a token's keys land in columns
+    ``[w, 2 w)`` of its row, every other column and row as it was; one
+    token a row is written by rows, several by the pages they touch (a row
+    from position 5, across a page's edge, and a row of no real token,
+    which writes nothing but the null page)."""
+    rng = np.random.default_rng(3)
+    w = KV * D
+    k = jnp.asarray(rng.normal(size=(PAGES, PAGE, LOOPS * w)), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(3, tokens, w)), jnp.float32)
+    table = jnp.asarray(rng.permutation(np.arange(1, PAGES))[:3 * PPS]
+                        .reshape(3, PPS), jnp.int32)
+    ctx = jnp.asarray([5, 0, 9], jnp.int32)
+    valid = jnp.asarray([[True] * tokens, [True] * tokens,
+                         [False] * tokens])
+    k2, v2 = pa.paged_write_loop(k, k + 1, new, new, table, ctx, valid,
+                                 jnp.int32(1))
+    for base, got in ((k, k2), (k + 1, v2)):
+        want = np.asarray(base).copy()
+        for b in range(2):
+            for t in range(tokens):
+                pos = int(ctx[b]) + t
+                want[table[b, pos // PAGE], pos % PAGE, w:2 * w] = new[b, t]
+        # the null page aside
+        assert np.array_equal(np.asarray(got)[1:], want[1:])
+    rows = pa.loop_pages(k2, table[:1], jnp.int32(1), w)
+    assert rows.shape == (1, PPS, PAGE, w)
+    assert np.array_equal(np.asarray(rows[0, 1, 1]), np.asarray(new[0, 0]))
+
+
+#: the jaxpr of ``gqa_decode_attention`` without a loop (and with a window
+#: of 128) at a fixed shape, hashed on the parent of PR 39: the looped
+#: instance adds nothing to what cells G and M trace
+_TODAY = {None: "cc7eef8447a478545ed235bd5e0b79e67779066b6978dd3414eaadf22b4"
+                "eaf0e",
+          128: "f19d4fe213792cb3caaf201187fa54833152cc508568daa868fbd10d4b96"
+               "de5c"}
+
+
+@pytest.mark.parametrize("window", [None, 128], ids=["full", "window"])
+def test_without_the_loop_the_kernel_traces_as_it_did(window):
+    import hashlib
+
+    def sds(s, d):
+        return jax.ShapeDtypeStruct(s, d)
+
+    q = sds((2, 16, 1, 128), jnp.bfloat16)
+    pool = sds((64, 16, 2048), jnp.bfloat16)
+    text = str(jax.make_jaxpr(
+        lambda q, k, v, t, c: pd.gqa_decode_attention(
+            q, k, v, t, c, 0.088, interpret=False, window=window))(
+        q, pool, pool, sds((2, 40), jnp.int32), sds((2,), jnp.int32)))
+    assert hashlib.sha256(text.encode()).hexdigest() == _TODAY[window]

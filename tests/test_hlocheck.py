@@ -333,7 +333,8 @@ def test_registry_names_are_stable():
                              "engine_prefill_latent",
                              "engine_decode_latent",
                              "engine_prefill_window",
-                             "engine_decode_window"}
+                             "engine_decode_window",
+                             "engine_prefill_loop", "engine_decode_loop"}
     assert REGISTRY["tp8_decode"].min_devices == 8
     assert all(REGISTRY[n].min_devices == 2 for n in REGISTRY
                if n.startswith("tp2_"))
