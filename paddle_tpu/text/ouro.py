@@ -116,6 +116,20 @@ def rotary_table(positions, cfg: OuroConfig):
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def _heads_of(y, w, d: int):
+    """``y @ w`` as heads ``[b, s, out / d, d]``, for the query and key
+    projections (``w`` ``[in, out]``, as published). The weight is the
+    product's left operand: inside the step programs' loop the TPU
+    compiler otherwise takes it with ``in`` minor and copies it into that
+    order before the loop, every call (at Ouro-2.6B 96 copies of 8 MB a
+    decode step, about 3 ms of 38 on a TPU v5e); a program without a loop
+    fuses that order into the product. The same products, summed over
+    ``in``."""
+    b, s, _ = y.shape
+    heads = jax.lax.dot_general(w, y, (((0,), (2,)), ((), ())))  # [o,b,s]
+    return jnp.moveaxis(heads, 0, -1).reshape(b, s, -1, d)
+
+
 # ------------------------------------------------------------------ layers
 class OuroAttention(nn.Layer):
     def __init__(self, cfg: OuroConfig):
@@ -135,8 +149,8 @@ class OuroAttention(nn.Layer):
         c = self.cfg
         b, s, _ = y.shape
         d, scale = c.head_dim, c.head_dim ** -0.5
-        q = jnp.matmul(y, self.q_proj.w).reshape(b, s, -1, d)
-        k = jnp.matmul(y, self.k_proj.w).reshape(b, s, -1, d)
+        q = _heads_of(y, self.q_proj.w, d)
+        k = _heads_of(y, self.k_proj.w, d)
         v = jnp.matmul(y, self.v_proj.w).reshape(b, s, -1, d)
         with jax.named_scope("rope"):
             q, k = _rotate(q, *rope), _rotate(k, *rope)

@@ -28,6 +28,7 @@ from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.serving import ServingConfig, ServingEngine
 from paddle_tpu.serving.spec import SpecConfig
+from paddle_tpu.text import ouro
 from paddle_tpu.text.ouro import OuroConfig, OuroForCausalLM
 from paddle_tpu.utils.flags import flag, set_flags
 
@@ -348,6 +349,62 @@ def test_engine_counts_pages_over_every_loop_and_layer(interpret,
         == sum(h[0] for h in held) > 0
     assert snap["serving_kv_pages_usable_total"] \
         == len(held) * (PAGES - 1)
+
+
+# ------------------------------------------- the query and key projections
+def _matmul_heads(y, w, d):
+    """The query and key projections as ``y @ w``, the weight the right
+    operand: the form whose weight the TPU compiler relayouts before the
+    loop."""
+    b, s, _ = y.shape
+    return jnp.matmul(y, w).reshape(b, s, -1, d)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kernel"])
+def test_the_weight_led_projections_serve_the_matmul_s_tokens(
+        interpret, monkeypatch, kernel):
+    """The query and key projections with the weight as the product's left
+    operand (``ouro._heads_of``) against ``y @ w``: through the engine's
+    prefill (whole and behind cached tokens: chunks of 6) and decode, in
+    float32, the same tokens and the same pools, bit for bit."""
+    interpret(kernel)
+    rng = np.random.default_rng(5)
+    prompts = [ids_of(rng, n) for n in (21, 13, 5)]
+
+    def run():
+        model, _, _ = build()
+        eng = engine(model, chunk_size=6)
+        seqs = serve(eng, prompts, 12)
+        return seqs + [np.asarray(pl[k]) for pl in eng.cache.pools
+                       for k in ("k_pool", "v_pool")]
+
+    got = run()
+    monkeypatch.setattr(ouro, "_heads_of", _matmul_heads)
+    for a, b in zip(got, run(), strict=True):
+        assert np.array_equal(a, b)
+
+
+def test_a_published_checkpoint_loads_unchanged():
+    """A checkpoint in the published ``[in, out]`` orientation (numpy, as a
+    loader reads it; head_dim 8, so that the projections are not square)
+    loads into the model as it is: the model's state is the checkpoint's,
+    name for name, shape for shape, value for value, and an engine over it
+    serves the tokens of the model the checkpoint came from."""
+    src, _, _ = build(seed=3, head_dim=8)
+    ckpt = {k: v.numpy() for k, v in src.state_dict().items()}
+    assert ckpt["model.layers.0.self_attn.q_proj.weight"].shape == (64, 32)
+    assert ckpt["model.layers.0.self_attn.k_proj.weight"].shape == (64, 32)
+    prompt = ids_of(np.random.default_rng(4), 11)
+    want, = serve(engine(src), [prompt], 10)
+    dst, _, _ = build(seed=4, head_dim=8)
+    assert dst.set_state_dict(ckpt) == ([], [])
+    state = dst.state_dict()
+    assert set(state) == set(ckpt)
+    for k, v in state.items():
+        assert v.numpy().shape == ckpt[k].shape
+        assert np.array_equal(v.numpy(), ckpt[k])
+    got, = serve(engine(dst), [prompt], 10)
+    assert np.array_equal(got, want)
 
 
 # ------------------------------------------------------------- the contract

@@ -8,7 +8,8 @@ block shapes, its one-head page DMA and its head_dim-64 pool. Each case
 here lowers one main-path kernel at a published width for ``v5e:2x2`` and
 finds the kernel's ``tpu_custom_call`` in the compiled HLO — about two
 seconds each, no chip time. Nothing runs, so nothing here says anything
-about results or speed.
+about results or speed. The last cases compile a tiny Ouro engine's whole
+step programs and read what the compiler moves before their loop.
 
 Widths: gpt3-1.3b serving (16 heads x 128, page 16, 64 pages per
 sequence) and gpt3-350m training (16 heads x 64, batch 8 x seq 1024).
@@ -303,3 +304,68 @@ def test_mla_decode_kernel_still_compiles_for_v5e_at_cell_k_s_shape(v5e):
         ((256, 160), jnp.int32), ((256,), jnp.int32), device=v5e)
     assert text.count("tpu_custom_call") == 1
     assert "mla_decode_attention" in text
+
+
+# --------------------------------------- Ouro's loop and its projections
+def _ouro_engine():
+    """A tiny bfloat16 Ouro engine on the CPU (3 layers run 3 times, 4
+    heads of 16)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+    from paddle_tpu.text import ouro
+
+    paddle.seed(0)
+    model = ouro.OuroForCausalLM(ouro.OuroConfig(
+        vocab_size=97, hidden_size=64, intermediate_size=96,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
+        head_dim=16, total_ut_steps=3, max_position_embeddings=64))
+    model.to(dtype="bfloat16")
+    return ServingEngine(model, ServingConfig(
+        max_batch=2, num_pages=40, page_size=4, max_prompt_len=32,
+        enable_prefix_caching=False))
+
+
+def _matmul_heads(y, w, d):
+    b, s, _ = y.shape
+    return jnp.matmul(y, w).reshape(b, s, -1, d)
+
+
+@pytest.mark.parametrize("weight_led", [True, False],
+                         ids=["weight-led", "matmul"])
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_ouro_loop_relayouts_no_weight_before_it(v5e, monkeypatch, program,
+                                                 weight_led):
+    """The engine's decode and prefill programs of a tiny Ouro, compiled
+    for a v5e: inside the loop the compiler takes the weight of ``y @ w``
+    for the query and key projections with ``in`` minor and copies it into
+    that order before the loop, every call (two copies a layer: at
+    Ouro-2.6B 96 of 8 MB a decode step). With the weight as the product's
+    left operand (``ouro._heads_of``) no rank-2 weight is copied or
+    transposed outside the loop. The CPU's compiler cannot show it."""
+    import re
+
+    from jax.experimental.layout import Format
+
+    from paddle_tpu.kernels import _common
+    from paddle_tpu.text import ouro
+
+    if not weight_led:
+        monkeypatch.setattr(ouro, "_heads_of", _matmul_heads)
+    eng = _ouro_engine()
+    if program == "decode":
+        fn, args = eng._decode_impl, eng._decode_args()
+    else:
+        fn, args = eng._prefill_impl, eng._prefill_args(
+            eng._prefill_program(8), 0, 0, [5, 6, 7], 0)
+    monkeypatch.setattr(_common, "on_tpu_backend", lambda: True)
+    # the operands' layouts as the engine holds them (a shape alone leaves
+    # the parameters' layouts to the compiler)
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=Format(a.format.layout, v5e)), args)
+    text = jax.jit(fn, donate_argnums=(1,)).lower(*shapes).compile() \
+        .as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}\n")]
+    assert entry.count(" while(") == 1
+    moved = re.findall(r"= bf16\[\d+,\d+\]\S* (?:copy|transpose)\(", entry)
+    assert len(moved) == (0 if weight_led else 2 * 3)
