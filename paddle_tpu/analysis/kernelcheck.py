@@ -1188,6 +1188,53 @@ def _build_ssm_decode_update():
         composite=su.ssm_update_reference, composite_args=args)
 
 
+def _build_power_retention_decode():
+    """The power-retention decode kernel at a canonical serving shape: 4
+    slots of 2 groups of 5 query heads at head size 128 (D = 8,256 rows of
+    float32 state a group), one (group, slot) a grid step, the expansion
+    of the raw vectors inside it. As the Mamba kernel, its blocks' index is
+    the slot's LIVE ROW (scalar-prefetched: a dead slot names the live
+    block before it), resolved at ``index_args`` with every slot live; a
+    run of dead slots revisits one block in sequence, by declaration. The
+    state and z are aliased in place; the HBM model counts them in and out
+    once a (group, slot)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ..kernels import power_retention as pr
+
+    slots, groups, group, d = 4, 2, 5, 128
+    dd = pr.feature_dim(d)
+    f32 = jnp.float32
+    state = _sds((slots, groups, dd, d), f32)
+    z = _sds((slots,) + pr.z_shape(groups, d), f32)
+    k = _sds((slots, groups, d), f32)
+    q = _sds((slots, groups, group, d), f32)
+    v = _sds((slots, groups, d), f32)
+    gate = _sds((slots, groups), f32)
+    active = _sds((slots,), jnp.bool_)
+    ok, why = pr.retention_kernel_eligible(d)
+    constraints = (
+        ("retention_kernel_eligible", ok, why or
+         "the canonical shape must pass the decode kernel's gate"),
+        ("lane_rows_only", not pr.retention_kernel_eligible(64)[0],
+         "a head size that is not whole 128-lane rows must take the plain "
+         "recurrence"),
+    )
+    args = (state, z, k, q, v, gate, active)
+    return dict(
+        fn=pr.retention_decode_update, args=args,
+        budget=KernelBudget(allow_data_dependent_outputs=True,
+                            allow_output_revisits=True),
+        constraints=constraints,
+        index_args=(np.arange(slots, dtype=np.int32),
+                    np.ones(slots, np.int32)),
+        # the outer product phi(k) v^T and the readout of every head on the
+        # MXU (2 flops a MAC), the decay and add on the VPU
+        flops=float(2 * slots * groups * dd * d * (1 + group + 1)),
+        composite=pr.retention_update_reference, composite_args=args)
+
+
 def _build_ln(which: str):
     import jax.numpy as jnp
 
@@ -1334,6 +1381,14 @@ REGISTRY: dict[str, KernelSpec] = {s.name: s for s in (
                "dead slots name the live block before them and move "
                "nothing",
                _build_ssm_decode_update),
+    KernelSpec("power_retention_decode", "gated power-retention decode "
+               "(degree 2): a grid step a (group, slot) brings the group's "
+               "float32 state of 8,256 x 128 to VMEM once, expands the raw "
+               "key and queries an offset at a time, advances the state in "
+               "place a 128-row tile at a time and reads every query head "
+               "of the group out of it on the MXU; dead slots name the "
+               "live block before them and move nothing",
+               _build_power_retention_decode),
     KernelSpec("fused_layernorm_fwd", "fused LayerNorm forward (one HBM "
                "pass per row block, stats saved for the backward)",
                lambda: _build_ln("fwd")),

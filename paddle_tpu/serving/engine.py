@@ -482,7 +482,8 @@ from .spec import SpecConfig, accept_counts, draft_window, propose_ngram
 @dataclass(frozen=True)
 class ServingConfig:
     max_batch: int = 4
-    num_pages: int = 64
+    num_pages: int = 64  # not read for a model that keeps nothing a
+    # token (kv_cache.PagedKVCache.paged False: no page on the device)
     group_pages: dict | None = None  # {group name: pages}: the pages (null
     # page included) of each page group behind the model's first, for a
     # model that states several (kv_cache.PageGroup: window layers beside
@@ -756,6 +757,9 @@ class ServingEngine:
         # of a launch is (``slots`` in every layer's cache; None: row i is
         # slot i, as in decode)
         self._slot_state = bool(self.cache.cfg.slot_leaf_keys)
+        # a model that keeps nothing a token has no page: its launches
+        # upload no table and its layers find none
+        self._paged = self.cache.paged
         # several page groups: a launch uploads their tables stacked, and
         # each layer is handed its own group's
         self._layer_group = (self.cache.cfg.group_of_layer
@@ -945,9 +949,9 @@ class ServingEngine:
         # what a decode step uploads, from the operands' shapes: the whole
         # page table and the five per-slot vectors (serve.decode.upload)
         self._decode_upload_bytes = sum(
-            a.nbytes for a in (self.cache.tables, self._ctx,
-                               self._last_tok, self._active, self._rids,
-                               self._gen))
+            a.nbytes for a in (self._ctx, self._last_tok, self._active,
+                               self._rids, self._gen)) \
+            + (self.cache.tables.nbytes if self._paged else 0)
         # the cache's copy-on-write page copy is a span of the same
         # mechanism (serve.cow_copy)
         self.cache.spans = self._attr
@@ -1063,9 +1067,12 @@ class ServingEngine:
         (``PagedCacheSpec.head_at_positions``): the one position a row
         whose logits are read; the logits are then ``[rows, 1, vocab]``.
         ``counters`` is what the layers counted, summed (int32
-        [len(spec.counters)]), or None for a model that counts nothing."""
-        shared = dict(page_table=table, ctx_lens=ctx, valid=valid,
-                      kv_limit=kv_limit)
+        [len(spec.counters)]), or None for a model that counts nothing.
+        ``table`` is None for a model that keeps nothing a token: its
+        layers' caches hold no ``page_table``."""
+        shared = dict(ctx_lens=ctx, valid=valid, kv_limit=kv_limit)
+        if table is not None:
+            shared["page_table"] = table
         if self._slot_state:
             shared["slots"] = None if slots is None else jnp.reshape(
                 slots.astype(jnp.int32), (-1,))
@@ -1112,9 +1119,10 @@ class ServingEngine:
         device and the host fetches the same array when it comes round.
         Compiles once per pad bucket (padded_ids shape)."""
         n = padded_ids.shape[0]
-        # one row; of a model of several page groups one row a group
-        table = page_row[None, :] if page_row.ndim == 1 \
-            else page_row[:, None, :]
+        # one row; of a model of several page groups one row a group; of
+        # a model with no page none
+        table = None if page_row is None else page_row[None, :] \
+            if page_row.ndim == 1 else page_row[:, None, :]
         ctx = jnp.reshape(ctx0.astype(jnp.int32), (1,))
         valid = (jnp.arange(n, dtype=jnp.int32) < tail_len)[None, :]
         # a model that takes it computes its head at the last real token
@@ -1911,7 +1919,8 @@ class ServingEngine:
         bucket = prog.tokens
         with att.span("prefill.upload", awaited=self._prev_toks,
                       rid=req.rid, bytes=4 * bucket + 16
-                      + self.cache.tables[..., req.slot, :].nbytes):
+                      + (self.cache.tables[..., req.slot, :].nbytes
+                         if self._paged else 0)):
             args = self._prefill_args(prog, req.slot, req.rid,
                                       req.prompt[start:start + n], start)
         if tr is not None and not chunked:  # a chunked one: at admission
@@ -2093,7 +2102,8 @@ class ServingEngine:
         return (self._p, self.cache.pools, jnp.asarray(padded),
                 jnp.asarray(len(ids), jnp.int32),
                 jnp.asarray(start, jnp.int32),
-                jnp.asarray(self.cache.tables[..., slot, :]),
+                jnp.asarray(self.cache.tables[..., slot, :])
+                if self._paged else None,
                 jnp.asarray(rid, jnp.int32), jnp.asarray(slot, jnp.int32),
                 self._prev_toks)
 
@@ -2107,7 +2117,8 @@ class ServingEngine:
         # jnp.asarray returns, and the host writes _ctx and _gen right
         # after the dispatch, the page table and the rest while it runs
         up = lambda a: jnp.asarray(a.copy())  # noqa: E731
-        return (self._p, self.cache.pools, up(self.cache.tables),
+        return (self._p, self.cache.pools,
+                up(self.cache.tables) if self._paged else None,
                 up(self._ctx), self._prev_toks,
                 up(self._last_tok if override is None else override),
                 up(self._active if active is None else active),
@@ -2218,7 +2229,8 @@ class ServingEngine:
                                     self._ctx, live_rows=active)
                 self._prev_toks = toks
                 self.metrics.on_decode_step(overlapped=prev is not None)
-                self.metrics.on_kv_pages(*self.cache.pages_held())
+                if self._paged:
+                    self.metrics.on_kv_pages(*self.cache.pages_held())
                 if self._layer_group is not None:
                     self.metrics.on_kv_residency(*self.cache.residency())
                 for slot, req in launched:

@@ -16,6 +16,10 @@ recurrent layer's state, ``[max_batch, ...]``), which no allocator hands
 out, which the model zeroes in the program when a request starts at
 position 0, which ``swap_out`` / ``swap_in`` carry in the ``SwapHandle``
 beside the slot's pages, and which makes the pages unshareable by prefix.
+A model that keeps NOTHING a token (every leaf per-slot: a model of
+recurrent state alone) has no page on the device: the cache keeps its page
+bookkeeping on the host only, sized so that it never runs out
+(``PagedKVCache.paged`` is False), and the engine uploads no table.
 The host side is bookkeeping only: a refcounted block allocator
 and per-slot page tables, mirrored into a dense ``[max_batch,
 pages_per_seq]`` int32 array each step — static shape, so table churn never
@@ -87,6 +91,7 @@ hit. The tier LRU-drops its own oldest entries past the byte bound.
 """
 from __future__ import annotations
 
+import dataclasses
 import itertools
 import math
 from collections import OrderedDict
@@ -244,7 +249,7 @@ class SwapHandle:
     so a swap round-trip is bit-exact in both modes.
     """
     n_pages: int
-    k: np.ndarray
+    k: np.ndarray | None  # None: a pool with no paged leaf
     v: np.ndarray | None = None
     k_scale: np.ndarray | None = None
     v_scale: np.ndarray | None = None
@@ -718,6 +723,18 @@ class PagedKVCache:
         if len(specs) > 1 and cfg.tp is not None:
             raise ValueError("page groups have no placement under tensor "
                              "parallelism")
+        # a pool of per-slot leaves alone keeps nothing a token: no page
+        # exists on the device and no table is read. The pages below are
+        # the host's bookkeeping only, as many as every slot's longest
+        # context takes, so admission is bounded by the slots and the
+        # context limit alone
+        self.paged = bool(cfg.pool_leaf_keys)
+        if not self.paged:
+            if len(specs) > 1:
+                raise ValueError("page groups of a pool with no paged leaf")
+            cfg = dataclasses.replace(
+                cfg, num_pages=_RESERVED_PAGES
+                + cfg.max_batch * cfg.pages_per_seq)
         self.cfg = cfg
         # one table a group, stacked: a launch uploads them as one array.
         # The FIRST group's allocator, table and slot pages are the
@@ -1540,15 +1557,15 @@ class PagedKVCache:
         rest = tuple((len(g.pages[slot]), g.first[slot])
                      for g in self._rest)
         n = max([len(pages)] + [k for k, _ in rest])
-        got = self._gather_jit(self.pools,
-                               jnp.asarray(self._swap_idx(slot, pages)))
+        got = () if not self.paged else self._gather_jit(
+            self.pools, jnp.asarray(self._swap_idx(slot, pages)))
         state = ()
         if self.cfg.slot_leaf_keys:
             state = tuple(np.asarray(a) for a in self._state_gather_jit(
                 self.pools, jnp.asarray(slot, jnp.int32)))
         handle = SwapHandle(n_pages=len(pages), state=state, rest=rest,
-                            **_by_field([np.asarray(a)[:, :n].copy()
-                                         for a in got]))
+                            **{"k": None, **_by_field(
+                                [np.asarray(a)[:, :n].copy() for a in got])})
         self.release(slot)
         return handle
 
@@ -1577,8 +1594,10 @@ class PagedKVCache:
             full = np.zeros((a.shape[0], w) + a.shape[2:], a.dtype)
             full[:, :a.shape[1]] = a
             args.append(jnp.asarray(full))
-        # pad rows scatter zeros into the null page — never read unmasked
-        self.pools = self._scatter_jit(self.pools, *args)
+        if self.paged:
+            # pad rows scatter zeros into the null page — never read
+            # unmasked
+            self.pools = self._scatter_jit(self.pools, *args)
         if handle.state:
             # the slot may be another than the one swapped out: the state
             # goes where the pages' table row now is

@@ -108,6 +108,11 @@ KV quantization + host cache tier (pre-seeded like everything else):
                                   slots' state rows the update read and
                                   wrote (equal where dead slots are
                                   skipped)
+- serving_retention_state_rows_live_total,
+  serving_retention_state_rows_moved_total
+                                  the same two of a power-retention model
+                                  (text/brumby.py), summed over its
+                                  retention layers
 - serving_kv_bytes_per_token      gauge: device bytes one resident token
                                   costs across the layers that page (codes
                                   + amortized scales), set at construction
@@ -392,6 +397,8 @@ _SEEDED = ("tokens_total", "prefills_total", "prefill_overlapped_total",
            "moe_assignments_total", "moe_local_assignments_total",
            "moe_expert_slots_total", "moe_expert_hits_total",
            "ssm_state_rows_live_total", "ssm_state_rows_moved_total",
+           "retention_state_rows_live_total",
+           "retention_state_rows_moved_total",
            "pallas_fallback_total",
            "flash_pad_total", "flash_edge_fallback_total",
            "analysis_retraces_total", "analysis_host_syncs_total",

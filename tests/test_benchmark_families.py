@@ -51,6 +51,11 @@ TINY_OURO = dict(vocab_size=96, hidden_size=64, intermediate_size=80,
                  num_hidden_layers=3, num_attention_heads=4,
                  num_key_value_heads=4, head_dim=16, total_ut_steps=3,
                  max_position_embeddings=64)
+#: Brumby at toy widths: 2 layers, 10 query heads over 2 KV heads of 16
+TINY_BRUMBY = dict(vocab_size=96, hidden_size=64, intermediate_size=80,
+                   num_hidden_layers=2, num_attention_heads=10,
+                   num_key_value_heads=2, head_dim=16,
+                   max_position_embeddings=64, chunk_size=8)
 
 
 def load(path):
@@ -61,7 +66,8 @@ def load(path):
 def tiny_model(config: dict) -> dict:
     tiny = {"gpt3": TINY_GPT3, "kimi_k2": TINY_KIMI,
             "granite_hybrid": TINY_GRANITE,
-            "mellum": TINY_MELLUM, "ouro": TINY_OURO}[config["family"]]
+            "mellum": TINY_MELLUM, "ouro": TINY_OURO,
+            "brumby": TINY_BRUMBY}[config["family"]]
     return dict(config["model"], **tiny)
 
 
@@ -96,6 +102,12 @@ def program_model(config: dict, model: dict):
 
         with paddle.LazyGuard():
             return OuroForCausalLM(ouro.program_config(model))
+    if config["family"] == "brumby":
+        from benchmark.families import brumby
+        from paddle_tpu.text.brumby import BrumbyForCausalLM
+
+        with paddle.LazyGuard():
+            return BrumbyForCausalLM(brumby.program_config(model))
     from benchmark.families import kimi_k2
     from paddle_tpu.text.kimi_k2 import KimiK2ForCausalLM
 
@@ -409,3 +421,68 @@ def test_ouro_copy_of_the_reference_gives_the_programs_logits():
     want = ouro.logits_at(leaves_of, jnp.asarray(ids), positions, model)
     assert got.dtype == jnp.float32
     assert float(jnp.max(jnp.abs(got - want))) < 5e-6
+
+
+def _tiny_brumby():
+    from benchmark.families import brumby
+    from benchmark.lib import weights
+
+    config = {"model": dict(load(os.path.join(
+        ROOT, "benchmark", "configs", "brumby-14b-serve.json"))["model"],
+        **TINY_BRUMBY), "precision": {"parameters": "bfloat16"}}
+    leaves_of = weights.for_reference(brumby, config, seed=2147483661)
+    rng = np.random.default_rng(0)
+    ids = np.zeros((2, 40), np.int32)
+    ids[:, :30] = rng.integers(1, 96, (2, 30))
+    positions = jnp.asarray([[3, 10, 29], [0, 17, 28]], jnp.int32)
+    return brumby, config, leaves_of, ids, positions
+
+
+def test_brumby_copy_of_the_reference_gives_the_repos_logits():
+    """The same float32 leaves (the gates' biases as the family uses them)
+    through ``benchmark/families/brumby.py``'s ``logits_at`` (the attention
+    form a row and a block of query rows at a time, the head a vocabulary
+    block at a time at the positions asked for, zeros padded behind the
+    rows) and through ``tests/refs/brumby_reference.py``: the same logits
+    to float32 round-off (2e-6); both controls run and are not the
+    reference."""
+    import brumby_reference as ref
+
+    brumby, config, leaves_of, ids, positions = _tiny_brumby()
+    model = config["model"]
+    got = brumby.logits_at(leaves_of, jnp.asarray(ids), positions, model)
+    cfg = brumby.program_config(model)
+    want = ref.forward(brumby.as_used(leaves_of()),
+                       jnp.asarray(ids[:, :30]), cfg)
+    want = jnp.take_along_axis(want, positions[..., None], axis=1)
+    assert got.shape == want.shape == (2, 3, 96)
+    assert float(jnp.max(jnp.abs(want))) > 0.05
+    assert float(jnp.max(jnp.abs(got - want))) < 2e-6
+    for policy in ("fp8", "state_reset"):
+        low = brumby.logits_at(leaves_of, jnp.asarray(ids), positions,
+                               model, policy)
+        assert 1e-4 < float(jnp.max(jnp.abs(low - want))) < 10.0, policy
+
+
+def test_brumby_copy_of_the_reference_gives_the_programs_logits():
+    """The benchmark's leaves installed into the program's model as the
+    family builds it (``as_used``, bfloat16 as the configuration serves
+    them, held in float32 here) and its whole forward (the chunked form)
+    against the family's ``logits_at`` (the attention form)."""
+    from benchmark.lib import weights
+    from benchmark.lib.common import install_weights
+    from paddle_tpu.text.brumby import BrumbyForCausalLM
+
+    brumby, config, leaves_of, ids, positions = _tiny_brumby()
+    model = config["model"]
+    with paddle.LazyGuard():
+        program = BrumbyForCausalLM(brumby.program_config(model))
+    install_weights(program, {n: a.astype(jnp.float32) for n, a in
+                              brumby.as_used(weights.for_program(
+                                  brumby, config, 2147483661)).items()})
+    program.eval()
+    got = program(paddle.to_tensor(ids[:, :30]))._value
+    got = jnp.take_along_axis(got, positions[..., None], axis=1)
+    want = brumby.logits_at(leaves_of, jnp.asarray(ids), positions, model)
+    assert got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5

@@ -222,3 +222,117 @@ def test_the_invariants_catch_what_they_name(break_it, message):
 def test_what_groups_cannot_be_refuses(config, message):
     with pytest.raises(ValueError, match=message):
         cache(**config)
+
+
+# ------------------------------------------------ a pool of no paged leaf
+STATE = (CacheLeaf("state", (2, 6, 4), np.float32, per_slot=True),
+         CacheLeaf("z", (2, 1, 8), np.float32, per_slot=True))
+
+
+def stateful(**over):
+    cfg = dict(num_layers=3, leaves=STATE, num_pages=5, page_size=PAGE,
+               max_batch=3, pages_per_seq=PPS, enable_prefix_caching=False)
+    cfg.update(over)
+    return PagedKVCache(PagedCacheConfig(**cfg))
+
+
+def test_a_pool_of_no_paged_leaf_allocates_no_page():
+    """Every leaf a slot's: the device holds the slots' state and nothing
+    else, no token costs a byte, and the pages are the host's bookkeeping
+    alone, enough for every slot's longest context whatever ``num_pages``
+    says: admission is bounded by the slots and the context limit."""
+    c = stateful()
+    assert not c.paged and c.cfg.pool_leaf_keys == ()
+    assert [sorted(pl) for pl in c.pools] == [["state", "z"]] * 3
+    assert [pl["state"].shape for pl in c.pools] == [(3, 2, 6, 4)] * 3
+    assert c.cfg.kv_bytes_per_token == 0
+    assert c.cfg.state_bytes_per_slot == 3 * 4 * (2 * 6 * 4 + 2 * 8)
+    assert c.cfg.num_pages == 1 + 3 * PPS
+    assert c.fits_ever(PPS * PAGE) and not c.fits_ever(PPS * PAGE + 1)
+    for slot in range(3):
+        assert c.admit(slot, PPS * PAGE - 2)
+        assert c.grow(slot, PPS * PAGE)
+    c.check_invariants()
+    for slot in range(3):
+        c.release(slot)
+    assert c.allocator.pages_in_use == 0
+    paged = PagedKVCache(PagedCacheConfig(
+        num_layers=3, leaves=LEAVES, num_pages=5, page_size=PAGE,
+        max_batch=3, pages_per_seq=PPS))
+    assert paged.paged and paged.cfg.num_pages == 5
+
+
+def test_a_swap_of_no_paged_leaf_carries_the_state_alone():
+    import jax.numpy as jnp
+
+    c = stateful()
+    rng = np.random.default_rng(0)
+    c.pools = [{k: jnp.asarray(rng.normal(size=a.shape), a.dtype)
+                for k, a in pl.items()} for pl in c.pools]
+    before = [{k: np.asarray(a) for k, a in pl.items()} for pl in c.pools]
+    assert c.admit(1, 9)
+    handle = c.swap_out(1)
+    assert handle.arrays == () and handle.k is None
+    assert [a.shape for a in handle.state] == [(3, 2, 6, 4), (3, 2, 1, 8)]
+    assert c.swap_in(2, handle)
+    for old, pl in zip(before, c.pools):
+        for k in ("state", "z"):
+            assert np.array_equal(np.asarray(pl[k][2]), old[k][1])
+    assert "swap_gather" in c.compile_counts
+    assert c.compile_counts["swap_scatter"] == 0
+
+
+def test_a_pool_of_no_paged_leaf_has_no_groups():
+    with pytest.raises(ValueError, match="no paged leaf"):
+        stateful(groups=GROUPS, group_pages=(4,), num_layers=4)
+
+
+def test_an_engine_of_no_paged_leaf_uploads_no_table(monkeypatch):
+    """A model that keeps nothing a token: the engine's launches take no
+    page table (the step programs have no such operand) and its layers'
+    caches hold none; ``num_pages`` is not read."""
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+    from paddle_tpu.text.brumby import BrumbyConfig, BrumbyForCausalLM
+
+    paddle.seed(0)
+    model = BrumbyForCausalLM(BrumbyConfig(
+        vocab_size=61, hidden_size=32, intermediate_size=48,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=8, max_position_embeddings=64, chunk_size=4))
+    eng = ServingEngine(model, ServingConfig(
+        max_batch=2, num_pages=3, page_size=16, max_prompt_len=16,
+        enable_prefix_caching=False))
+    assert not eng.cache.paged and eng.cache.cfg.pages_per_seq == 4
+    assert sum(a.nbytes for pl in eng.cache.pools for a in pl.values()) \
+        == 2 * eng.cache.cfg.state_bytes_per_slot
+    seen = []
+    real = model.forward
+
+    def spy(ids, caches=None):
+        seen.append(sorted(caches[0]))
+        return real(ids, caches=caches)
+
+    monkeypatch.setattr(model, "forward", spy)
+    launches = []
+    real_launch = ServingEngine._launch
+
+    def launch(self, prog, args, *a, **k):
+        launches.append((prog.phase, args))
+        return real_launch(self, prog, args, *a, **k)
+
+    monkeypatch.setattr(ServingEngine, "_launch", launch)
+    rids = [eng.add_request(np.arange(1, 12 - 4 * i), 5) for i in range(3)]
+    done = {}
+    while len(done) < len(rids):
+        eng.step()
+        done.update(eng.pop_finished())
+    assert {p for p, _ in launches} == {"prefill", "decode"}
+    for phase, args in launches:
+        # prefill: (params, pools, ids, n, start, TABLE ROW, rid, slot,
+        # prev); decode: (params, pools, TABLE, ctx, ...)
+        assert args[5 if phase == "prefill" else 2] is None
+    assert seen and all("page_table" not in keys for keys in seen)
+    assert eng._decode_upload_bytes == sum(
+        a.nbytes for a in (eng._ctx, eng._last_tok, eng._active, eng._rids,
+                           eng._gen))

@@ -8,12 +8,15 @@ block shapes, its one-head page DMA and its head_dim-64 pool. Each case
 here lowers one main-path kernel at a published width for ``v5e:2x2`` and
 finds the kernel's ``tpu_custom_call`` in the compiled HLO — about two
 seconds each, no chip time. Nothing runs, so nothing here says anything
-about results or speed. The last cases compile a tiny Ouro engine's whole
-step programs and read what the compiler moves before their loop.
+about results or speed. Later cases compile a tiny Ouro engine's whole
+step programs and read what the compiler moves before their loop, and
+Brumby's power-retention decode: the kernel at serving widths, and the
+decode program of a model that keeps no page.
 
 Widths: gpt3-1.3b serving (16 heads x 128, page 16, 64 pages per
 sequence) and gpt3-350m training (16 heads x 64, batch 8 x seq 1024).
 """
+import math
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
@@ -369,3 +372,81 @@ def test_ouro_loop_relayouts_no_weight_before_it(v5e, monkeypatch, program,
     assert entry.count(" while(") == 1
     moved = re.findall(r"= bf16\[\d+,\d+\]\S* (?:copy|transpose)\(", entry)
     assert len(moved) == (0 if weight_led else 2 * 3)
+
+
+# ------------------------------------------ Brumby's power-retention decode
+def test_power_retention_decode_kernel_compiles(v5e):
+    """The decode kernel at Brumby-14B's serving widths: 16 slots of 8
+    groups of 5 query heads of 128 (a group's state 8,256 x 128 float32),
+    the state and z donated and aliased in place."""
+    from paddle_tpu.kernels import power_retention as pr
+
+    slots, groups, group, d = 16, 8, 5, 128
+    dd, f32 = pr.feature_dim(d), jnp.float32
+
+    def step(state, z, k, q, v, g, active):
+        return pr.retention_decode_update(state, z, k, q, v, g, active)
+
+    shapes = [((slots, groups, dd, d), f32),
+              ((slots,) + pr.z_shape(groups, d), f32),
+              ((slots, groups, d), f32), ((slots, groups, group, d), f32),
+              ((slots, groups, d), f32), ((slots, groups), f32),
+              ((slots,), jnp.bool_)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=v5e) for s, t in shapes]
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "power_retention_decode" in text
+    # the state stays where it is: its 541 MB are aliased, not copied
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= slots * groups * dd * d * 4
+
+
+def test_brumby_decode_program_takes_no_page_pool(v5e, monkeypatch):
+    """The engine's decode program of a one-layer Brumby at its published
+    widths, compiled for a v5e from shapes alone: its arguments are the
+    weights, the slots' state and the per-slot vectors, and nothing else
+    (no page pool, no page table); the kernel is in it."""
+    from jax.experimental.layout import Format, Layout
+
+    import paddle_tpu as paddle
+    from paddle_tpu.kernels import _common
+    from paddle_tpu.serving import ServingConfig, ServingEngine
+    from paddle_tpu.serving import kv_cache
+    from paddle_tpu.text.brumby import BrumbyConfig, BrumbyForCausalLM
+
+    slots = 2
+    with paddle.LazyGuard():
+        model = BrumbyForCausalLM(BrumbyConfig(num_hidden_layers=1))
+    params, _ = model.functional_state()
+    for t in params.values():   # bfloat16 shapes, never materialized
+        t._value = jax.ShapeDtypeStruct(tuple(t._value.shape), jnp.bfloat16)
+        t._lazy_init = None
+    model.eval()
+    monkeypatch.setattr(kv_cache, "init_pools", lambda cfg: [
+        {lf.name: jax.ShapeDtypeStruct((cfg.max_batch,) + tuple(lf.shape),
+                                       lf.dtype) for lf in leaves}
+        for leaves in cfg.layer_leaves])
+    eng = ServingEngine(model, ServingConfig(
+        max_batch=slots, max_prompt_len=16, enable_prefix_caching=False,
+        enable_tracing=False))
+    monkeypatch.setattr(_common, "on_tpu_backend", lambda: True)
+    args = eng._decode_args()
+    assert args[2] is None
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=Format(
+            Layout(major_to_minor=tuple(range(len(a.shape)))), v5e)), args)
+    compiled = jax.jit(eng._decode_impl, donate_argnums=(1,)).lower(
+        *shapes).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    weights = sum(math.prod(a.shape) * 2 for a in eng._p.values())
+    state = slots * eng.cache.cfg.state_bytes_per_slot
+    small = compiled.memory_analysis().argument_size_in_bytes \
+        - weights - state
+    # what is left is the per-slot vectors and the tiling's padding of the
+    # small leaves (the gate's [5120, 8] weight takes whole 128-lane rows:
+    # 1.2 MB); a pool of keys and values for the two slots' contexts
+    # (32,768 tokens at 4 KB a token) would be 268 MB
+    assert 0 <= small < 2 << 20, small
+    leaves = jax.tree_util.tree_leaves(args)
+    assert all(a.dtype != jnp.int32 or a.ndim < 2 for a in leaves)
