@@ -42,8 +42,10 @@ lanes (one XLU rotate and two multiplies). A tile's other work:
   row by row (``_ROW_K``, ``_ROW_V``), so the accumulator sums in one pass
   what six passes would;
 - the readout of every query head at once, the ``phi`` rows against the
-  new tile accumulated over the tiles, at ``HIGHEST`` precision (float32
-  arithmetic);
+  new tile accumulated over the tiles, in float32 arithmetic: the six
+  products of bfloat16 pieces that ``HIGHEST`` forms, but with each of the
+  tile's three pieces latched into the MXU once, the rows' pieces stacked
+  against it (``_readout``);
 - ``z`` is kept lane-dense, ``[tiles, d]`` (row ``t`` the entries of
   ``phi``'s lane row ``t``, the last row's upper half zero): a row
   updates with the ``phi(k)`` row of the same lanes, and the normaliser
@@ -227,6 +229,24 @@ def _pieces(x):
     return p0, p1, rest - p1
 
 
+def _readout(pq, new):
+    """``phi^T new`` of a tile in float32 arithmetic: ``pq`` the three
+    pieces of the ``[r, d]`` ``phi`` rows, ``new`` the tile ``[d, d]``.
+    The six products of pieces ``i + j <= 2`` that ``HIGHEST`` forms, with
+    each of the tile's pieces the MXU's stationary operand ONCE (where
+    ``HIGHEST`` latches it once a product): its piece ``j`` against the
+    rows' pieces ``0 .. 2 - j`` stacked, ``3 r``, ``2 r`` and ``r`` rows.
+    Summed smallest first."""
+    r = pq[0].shape[0]
+    rows = jnp.concatenate(pq, axis=0).astype(jnp.bfloat16)     # [3 r, d]
+    # b_j's row block i: the rows' piece i against the tile's piece j
+    b0, b1, b2 = (jax.lax.dot_general(
+        rows[:(3 - j) * r], piece.astype(jnp.bfloat16),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        for j, piece in enumerate(_pieces(new)))
+    return ((b0[2 * r:] + b1[r:] + b2) + (b0[r:2 * r] + b1[:r])) + b0[:r]
+
+
 def state_parts(d: int) -> int:
     """Parts a group's state is moved in: ``STATE_PARTS`` where each is
     whole 8-sublane tiles, else 1."""
@@ -262,7 +282,6 @@ def _kernel(d, parts, rows_ref, mode_ref, gamma_ref, x_ref, v_ref,
     from jax.experimental.pallas import tpu as pltpu
 
     g, s, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    hi = jax.lax.Precision.HIGHEST
     half = d // 2
 
     def phi_rows(x, t):
@@ -291,14 +310,13 @@ def _kernel(d, parts, rows_ref, mode_ref, gamma_ref, x_ref, v_ref,
                 den = den + ph * zrow
             den_ref[...] = den
         for t, a, n, at in _segments(d, part, parts):
-            ph = phi_rows(x, t)
+            pq = _pieces(phi_rows(x, t))
             # phi(k)'s pieces against v's: the tile's outer product in one
             # pass (the pieces are bfloat16 numbers, so the pass's rounding
             # to bfloat16 changes nothing)
-            pk = _pieces(jnp.broadcast_to(ph[0:1], (r, d)))
             lhs = jnp.zeros((r, d), jnp.float32)
             for i, piece in enumerate(_ROW_K):
-                lhs = jnp.where(row == i, pk[piece], lhs)
+                lhs = jnp.where(row == i, pq[piece][0:1], lhs)
             outer = jax.lax.dot_general(
                 lhs, vm, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)       # [d, d]
@@ -310,9 +328,7 @@ def _kernel(d, parts, rows_ref, mode_ref, gamma_ref, x_ref, v_ref,
                 new = jnp.concatenate(
                     ([around[0]] if a else []) + [new]
                     + ([around[1]] if d - a - n else []), axis=0)
-            num = num + jax.lax.dot_general(
-                ph, new, (((1,), (0,)), ((), ())), precision=hi,
-                preferred_element_type=jnp.float32)
+            num = num + _readout(pq, new)
         if part < parts - 1:
             num_ref[...] = num
         else:

@@ -6,8 +6,11 @@ the one dispatch gate. Everything is float32 on the CPU: states of order
 1 to 10 move by a decayed add, exact to the last bit or two (``TOL``
 relative); outputs are ratios of sums of ``D`` products, 1e-5 relative.
 A dropped decay, a z left behind or a state written to the wrong slot
-shows at 1e-2 and up.
+shows at 1e-2 and up. The ``_full`` cases hold every output to
+``FULL_TOL`` of itself, under what the smallest of the readout's six
+products of bfloat16 pieces moves it by.
 """
+import functools
 import math
 
 import jax.numpy as jnp
@@ -32,19 +35,35 @@ MASKS = {
 #: (head size, groups, group): D = 36 (4 whole offsets and a half), 136
 #: (8 and a half) and 528 (16 and a half), group sizes 1 and 5
 SHAPES = {"d8_g1": (8, 3, 1), "d16_g5": (16, 2, 5), "d32_g5": (32, 2, 5)}
+#: a ``_full`` case's operands are positive, so that a readout's float32
+#: sums cancel nothing and land within about 1.5e-6 of the reference, and
+#: its state's and queries' significands fill all 24 bits, so that none of
+#: their bfloat16 pieces is 0: leaving out any one of the readout's six
+#: products of pieces then moves an output by 8e-6 of itself or more
+FULL_TOL = 3e-6
 
 
-def operands(d, groups, group, seed=0, slots=SLOTS):
+def full_significand(a):
+    """``a`` (float32) with bits 15 and 0 of each number set: its
+    significand fills all 24 bits, and none of its three bfloat16 pieces
+    (``_pieces``: bits 22-16, 15-8 and 7-0) is 0."""
+    bits = np.asarray(a, np.float32).view(np.int32) | np.int32(0x8001)
+    return jnp.asarray(bits.view(np.float32))
+
+
+def operands(d, groups, group, seed=0, slots=SLOTS, full=False):
     rng = np.random.default_rng(seed)
     dd = pr.feature_dim(d)
-    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    draw = functools.partial(rng.uniform, 0.5, 2.0) if full else rng.normal
+    f = lambda *s: jnp.asarray(draw(size=s), jnp.float32)  # noqa: E731
+    fill = full_significand if full else (lambda a: a)
     z = pr.z_rows(jnp.asarray(rng.uniform(0.5, 2.0, (slots, groups, dd)),
                               jnp.float32), d)
     return dict(
-        state=f(slots, groups, dd, d),
+        state=fill(f(slots, groups, dd, d)),
         z=z,
         k=f(slots, groups, d),
-        q=f(slots, groups, group, d),
+        q=fill(f(slots, groups, group, d)),
         v=f(slots, groups, d),
         log_gamma=-jnp.asarray(rng.uniform(0.0, 0.1, (slots, groups)),
                                jnp.float32))
@@ -94,20 +113,27 @@ def test_phi_is_the_symmetric_degree_2_expansion():
     assert np.array_equal(np.asarray(pr.z_flat(rows, 128)), np.asarray(x))
 
 
-@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize(
+    "shape", sorted(SHAPES) + [s + "_full" for s in sorted(SHAPES)])
 @pytest.mark.parametrize("mask", MASKS, ids=list(MASKS))
 def test_kernel_is_the_recurrence_and_skips_dead_slots(mask, shape):
     """Every slot mask: the live slots' S, z and outputs are the plain
     recurrence's, a dead slot's S and z are untouched bit for bit and its
-    output is 0."""
-    o = operands(*SHAPES[shape])
+    output is 0. A ``_full`` case also holds each live output to
+    ``FULL_TOL`` of itself: the readout keeps all six products."""
+    full = shape.endswith("_full")
+    o = operands(*SHAPES[shape.removesuffix("_full")], full=full)
     active = jnp.asarray(MASKS[mask], bool)
     want = pr.retention_update_reference(**o, active=active)
     got = pr.retention_decode_update(**o, active=active, interpret=True)
     for g, w in zip(got, want):
         assert g.shape == w.shape
         assert close(g, w)
-    dead = ~np.asarray(active)
+    live = np.asarray(active)
+    if full:
+        g, w = np.asarray(got[2])[live], np.asarray(want[2])[live]
+        assert np.all(np.abs(g - w) <= FULL_TOL * np.abs(w))
+    dead = ~live
     assert np.array_equal(np.asarray(got[0])[dead], np.asarray(o["state"])[
         dead])
     assert np.array_equal(np.asarray(got[1])[dead], np.asarray(o["z"])[dead])
